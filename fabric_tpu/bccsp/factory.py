@@ -9,7 +9,8 @@ are `SW` and `JAXTPU` (the latter replacing the PKCS11 hardware slot).
 from __future__ import annotations
 
 import logging
-from dataclasses import dataclass, field
+import os
+from dataclasses import dataclass
 from typing import Optional
 
 from .provider import Provider
@@ -31,68 +32,35 @@ class FactoryOpts:
     #                                  channel queue depth (parallel/placement)
     mesh_devices: Optional[int] = None   # cap the device count the mesh /
     #                                  placement scheduler may use (None: all)
-    degrade: Optional[bool] = None   # wrap in DegradingProvider (breaker
-    #                                  + SW fallback on device sickness).
+    degrade: Optional[bool] = None   # on device failure re-verify the
+    #                                  batch on SW (breaker in front).
     #                                  None = auto: ON for JAXTPU (a node
     #                                  that loses its accelerator keeps
     #                                  committing on SW, healthz flags it),
-    #                                  OFF for SW.  Explicit False is the
-    #                                  fail-stop escape hatch.
-    compile_cache_dir: Optional[str] = None   # persistent XLA cache dir
-    #                                  (node config "compile_cache_dir" /
-    #                                  FABRIC_TPU_<ROLE>_COMPILE_CACHE_DIR)
+    #                                  OFF for SW.  Explicit False is
+    #                                  fail-stop: a device error reaches
+    #                                  the caller, nothing is recomputed.
 
 
-def default_cache_dir() -> str:
-    import os
-    return os.environ.get("JAX_COMPILATION_CACHE_DIR",
-                          os.path.expanduser("~/.cache/fabric_tpu_xla"))
+# the one in-code home of the persistent compilation cache: a fixed path
+# inside the checkout (the path is part of the cache key — a directory
+# that moves never hits)
+_CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".cache", "jax")
 
 
-def enable_compile_cache(cache_dir: Optional[str] = None) -> None:
-    """Point jax at the persistent compilation cache so node cold-starts
-    reuse every previously-compiled kernel (round-2 flagged 200s+ cold
-    compiles; the cache survives across processes on one host).  Must go
-    through jax.config — the env var alone is too late on images whose
-    sitecustomize imports jax at interpreter start.
-
-    Precedence: explicit `cache_dir` (node config / warmup --cache-dir)
-    > JAX_COMPILATION_CACHE_DIR > ~/.cache/fabric_tpu_xla.  Prebake with
-    `python -m fabric_tpu.node.warmup --cache-dir <dir>` at provisioning
-    time, then start nodes against the same dir."""
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir",
-                          cache_dir or default_cache_dir())
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        logger.debug("persistent compile cache unavailable", exc_info=True)
-
-
-# written by node.warmup when a prebake COMPLETES; its presence is what
-# makes a cache dir count as a warmup artifact
-WARMUP_MANIFEST = "fabric_tpu_warmup.json"
-
-
-def compile_cache_is_warm(cache_dir: Optional[str] = None,
-                          min_entries: int = 4) -> bool:
-    """True when the cache dir holds a COMPLETED warmup artifact: the
-    manifest `node.warmup` writes after prebaking, plus at least
-    `min_entries` compiled kernels.  Incidental cache entries left by an
-    ordinary test run do NOT count — the slow-marked kernel test
-    modules rejoin the quick gate off this check, so it must flip only
-    on an explicit prebake, never as a side effect of running tests.
-    Also used by ops checks."""
-    import os
-    d = cache_dir or default_cache_dir()
-    if not os.path.isfile(os.path.join(d, WARMUP_MANIFEST)):
-        return False
-    try:
-        names = os.listdir(d)
-    except OSError:
-        return False
-    return sum(1 for n in names if not n.startswith(".")
-               and n != WARMUP_MANIFEST) >= min_entries
+def enable_compile_cache() -> str:
+    """Where compiled programs persist across processes; returns the
+    directory.  JAX_COMPILATION_CACHE_DIR is the outside handle: when it
+    is set jax reads it itself and nothing is set in code.  Otherwise
+    the cache lives at <checkout>/.cache/jax."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    import jax
+    jax.config.update("jax_compilation_cache_dir", _CHECKOUT_CACHE)
+    return _CHECKOUT_CACHE
 
 
 _placement = None                # PlacementScheduler when opts.placement
@@ -109,17 +77,17 @@ def init_factories(opts: Optional[FactoryOpts] = None) -> Provider:
     if kind == "SW":
         _default = SoftwareProvider(require_low_s=opts.require_low_s)
     elif kind == "JAXTPU":
-        enable_compile_cache(opts.compile_cache_dir)
-        from .jaxtpu import JaxTpuProvider
-        import jax
-        devices = jax.devices()
+        enable_compile_cache()
+        from .jaxtpu import JaxTpuProvider, accelerator_devices
+        devices = accelerator_devices()
         if opts.mesh_devices:
             devices = devices[:opts.mesh_devices]
         mesh = None
         if opts.use_mesh and len(devices) > 1:
             from fabric_tpu.parallel import mesh as meshmod
             mesh = meshmod.make_mesh(devices)
-        _default = JaxTpuProvider(require_low_s=opts.require_low_s, mesh=mesh)
+        _default = JaxTpuProvider(require_low_s=opts.require_low_s,
+                                  mesh=mesh, degrade=degrade)
         if opts.placement and len(devices) > 1:
             from fabric_tpu.parallel.placement import PlacementScheduler
             wrap = None
@@ -133,7 +101,8 @@ def init_factories(opts: Optional[FactoryOpts] = None) -> Provider:
             _placement = PlacementScheduler(
                 devices=devices,
                 provider_factory=lambda m: JaxTpuProvider(
-                    require_low_s=opts.require_low_s, mesh=m),
+                    require_low_s=opts.require_low_s, mesh=m,
+                    degrade=degrade),
                 wrap=wrap)
     else:
         raise ValueError(f"unknown BCCSP provider {opts.default!r}")
@@ -162,10 +131,13 @@ def provider_for_channel(channel_id: str,
 
 
 def get_default() -> Provider:
-    """GetDefault equivalent: lazily initializes a JAXTPU provider."""
+    """GetDefault equivalent.  A process that never called
+    init_factories gets the software provider: clients, admin tools and
+    launchers reach this through every handshake and identity check,
+    and must not take the chip from the node that asked for it."""
     global _default
     if _default is None:
-        init_factories()
+        init_factories(FactoryOpts(default="SW"))
     return _default
 
 

@@ -54,6 +54,11 @@ def hash_payload(data: bytes, algo: str = HASH_SHA256) -> bytes:
         raise ValueError(f"unsupported hash {algo!r}") from e
 
 
+class DeviceError(RuntimeError):
+    """A device provider failed and was not asked to degrade: nothing
+    verified the batch in its place.  The original error is the cause."""
+
+
 class Provider:
     """Abstract BCCSP provider. Concrete: sw.SoftwareProvider, jaxtpu.JaxTpuProvider."""
 

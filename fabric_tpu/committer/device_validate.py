@@ -40,6 +40,7 @@ a device-validated block therefore issues exactly one dispatch
 
 from __future__ import annotations
 
+import logging
 import struct
 import threading
 from typing import Dict, List, Optional, Tuple
@@ -48,6 +49,8 @@ import numpy as np
 
 from fabric_tpu.protocol import Version
 from fabric_tpu.protocol.txflags import TxFlags
+
+logger = logging.getLogger("fabric_tpu.committer.device_validate")
 
 # lane status codes (native/fastparse.c rwset_lanes / wire.LANE_*)
 _OK, _SKIP, _BAD, _RANGE, _UNKNOWN = 0, 1, 2, 3, 4
@@ -190,8 +193,17 @@ class DeviceValidator:
         except _Demote as d:
             _note(_C_DEMOTE, channel=self.channel_id, reason=d.reason)
             return None
+        except (TypeError, AttributeError, NameError, ImportError):
+            # a programming error (an API this installation does not
+            # have, a wrong argument) is not a block shape: demoting it
+            # would leave the path dead with nobody the wiser
+            raise
         except Exception:
-            # correctness never depends on this path existing
+            # a block this path chokes on (hostile bytes in a lane) must
+            # not wedge the peer: the host path validates it instead
+            logger.exception("[%s] device validation of block %d failed; "
+                             "demoting to the host path",
+                             self.channel_id, num)
             _note(_C_DEMOTE, channel=self.channel_id, reason="error")
             return None
 
@@ -425,7 +437,7 @@ class DeviceValidator:
         import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as PSpec
-        from fabric_tpu.parallel.mesh import BATCH_AXIS, _shard_map
+        from fabric_tpu.parallel.mesh import BATCH_AXIS
 
         mesh = self._get_mesh()
         use_mesh = mesh is not None
@@ -500,12 +512,12 @@ class DeviceValidator:
             rep, sh = PSpec(), PSpec(BATCH_AXIS)
             in_specs = ((rep,) * 5 + (rep,) * 3 + (sh,) * 4 + (sh,) * 5
                         + (rep,) * 3 + (rep,) * 6)
-            # check_rep=False: the rep-checker mis-types the fori_loop
-            # carry (wseq is replicated — every cross-shard sum is
-            # psum'd before it feeds the carry — but the 0.4.x checker
-            # can't prove it and rejects the program)
-            fn = _shard_map(local, mesh=mesh, in_specs=in_specs,
-                            out_specs=(rep, rep), check_rep=False)
+            # check_vma=False: wseq is replicated — every cross-shard
+            # sum is psum'd before it feeds the fori_loop carry — but
+            # the checker types the carry as varying and rejects the
+            # program
+            fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                               out_specs=(rep, rep), check_vma=False)
         else:
             fn = local
         prog = jax.jit(fn)

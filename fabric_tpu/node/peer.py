@@ -31,6 +31,7 @@ import time
 from typing import Dict, List, Optional, Tuple
 
 from fabric_tpu.bccsp.factory import FactoryOpts, init_factories
+from fabric_tpu.bccsp.provider import DeviceError
 from fabric_tpu.chaincode import (
     ChaincodeDefinition,
     ChaincodeRegistry,
@@ -700,6 +701,13 @@ class PeerChannel:
                 backoff = 0.2
                 if not got:
                     time.sleep(0.1)
+            except DeviceError as exc:
+                # `bccsp_degrade: false`: nothing may verify in the
+                # device's place, so the peer stops rather than serve
+                # endorsements it can no longer commit
+                self.deliver_healthy = False
+                self.node.fail_stop(exc)
+                return
             except Exception:
                 self.deliver_healthy = False
                 logger.debug("deliver pull failed; retrying", exc_info=True)
@@ -737,14 +745,14 @@ class PeerNode:
         # `bccsp_degrade` unset -> None -> the factory's auto rule:
         # degrade ON for JAXTPU (a peer that loses its accelerator keeps
         # committing on SW, healthz flags it), OFF for SW.
-        # `bccsp_degrade: false` is the fail-stop escape hatch.
+        # `bccsp_degrade: false` is fail-stop: a device error reaches
+        # the committer and nothing is recomputed on SW.
         self.provider = init_factories(
             FactoryOpts(default=cfg.get("bccsp", "SW"),
                         degrade=cfg.get("bccsp_degrade"),
                         use_mesh=bool(cfg.get("bccsp_mesh", False)),
                         placement=bool(cfg.get("bccsp_placement", False)),
-                        mesh_devices=cfg.get("bccsp_mesh_devices"),
-                        compile_cache_dir=cfg.get("compile_cache_dir")))
+                        mesh_devices=cfg.get("bccsp_mesh_devices")))
         self.signer = load_signing_identity(
             cfg["mspid"], cfg["cert_pem"].encode(), cfg["key_pem"].encode())
         self.mspid = cfg["mspid"]
@@ -851,6 +859,7 @@ class PeerNode:
         self.gossip_mux = ChannelMux(transport, channel_cfg.channel_id)
 
         self._stop = threading.Event()
+        self.fatal: Optional[BaseException] = None   # set by fail_stop
         # serving -> draining -> drained (fleet lifecycle: rolling
         # restarts drain a peer before killing it)
         self.lifecycle = "serving"
@@ -990,6 +999,11 @@ class PeerNode:
             # GET /state: per-channel shard sizes, checkpoint generation/
             # savepoint, and how much the last reopen had to replay
             self.ops.register_route("GET", "/state", self._state_route)
+            # POST /bccsp/warmup {"generic": [buckets], "rows": [buckets]}:
+            # one dispatch at exactly each named shape, in this process,
+            # so nothing compiles inside a request's time-out later
+            self.ops.register_route("POST", "/bccsp/warmup",
+                                    self._warmup_route)
             # GET /byzantine: quarantine standings, per-channel witness
             # stats, fraud proofs
             if self.byzantine is not None:
@@ -1397,7 +1411,39 @@ class PeerNode:
                         by_reason.get("policy_width", 0)),
                 }
             out[cid] = st
-        return 200, {"channels": out}
+        return 200, {"channels": out, "provider": self._provider_status()}
+
+    def _provider_status(self) -> dict:
+        """The crypto provider as this process has it: the live backend,
+        its counters, which native extensions loaded, and — for a device
+        provider — the installation and devices as JAX reports them."""
+        import dataclasses
+        prov = self.provider
+        snap = getattr(prov, "stats_snapshot", None)
+        snap = snap() if callable(snap) else None
+        status = {
+            "name": prov.name,
+            "backend": getattr(prov, "backend", prov.name),
+            "degraded": bool(getattr(prov, "degraded", False)),
+            "native": {n: f"fabric_tpu.native.{n}" in sys.modules
+                       for n in ("_ftlv", "_fastcollect", "_fastparse")},
+            "stats": None, "device": None}
+        if snap is not None:
+            from fabric_tpu.bccsp.jaxtpu import device_report
+            status["stats"] = dataclasses.asdict(snap)
+            status["device"] = device_report()
+        return status
+
+    def _warmup_route(self, path, body):
+        from fabric_tpu.node.warmup import warm_lanes
+        req = json.loads(body or b"{}")
+        t0 = time.perf_counter()
+        timings = warm_lanes(self.provider,
+                             generic=[int(b) for b in req.get("generic", [])],
+                             rows=[int(b) for b in req.get("rows", [])])
+        return 200, {"timings": timings,
+                     "seconds": round(time.perf_counter() - t0, 3),
+                     "provider": self._provider_status()}
 
     def _rpc_chain_info(self, body: dict, peer_identity) -> dict:
         return self._chan(body).qscc.get_chain_info(peer_identity)
@@ -1575,6 +1621,13 @@ class PeerNode:
                     self.rpc.addr, len(self.channels))
         return self
 
+    def fail_stop(self, exc: BaseException) -> None:
+        """Stop serving because of `exc`; `main` exits non-zero."""
+        logger.critical("peer %s stopping: %s", self.mspid, exc,
+                        exc_info=exc)
+        self.fatal = exc
+        self.stop()
+
     def stop(self) -> None:
         self._stop.set()
         if self.gateway is not None:
@@ -1607,9 +1660,9 @@ def main(argv=None) -> int:
     logging.basicConfig(level=logging.INFO)
     from fabric_tpu.config.localconfig import load_node_config
     cfg = load_node_config(argv[0], "peer")
-    PeerNode(cfg, data_dir=cfg["data_dir"]).start()
-    threading.Event().wait()   # serve until killed
-    return 0
+    node = PeerNode(cfg, data_dir=cfg["data_dir"]).start()
+    node._stop.wait()          # serve until killed or fail-stopped
+    return 1 if node.fatal is not None else 0
 
 
 if __name__ == "__main__":

@@ -120,7 +120,8 @@ def provision_orderers(base_dir: str, n: int, channel_id: str = "ch",
     return paths
 
 
-def _free_ports(n: int) -> List[int]:
+def free_ports(n: int) -> List[int]:
+    """n ports the OS just handed out (bound momentarily, released)."""
     import socket
     ports, socks = [], []
     for _ in range(n):
@@ -140,7 +141,8 @@ def provision_network(base_dir: str, n_orderers: int = 3,
                       chaincodes: List[dict] = None,
                       collections: List[dict] = None,
                       batch: BatchConfig = None,
-                      spare_orderers: int = 0) -> dict:
+                      spare_orderers: int = 0,
+                      clients_per_org: int = 1) -> dict:
     """Full dev network: orderer cluster + peer-org peers on one channel.
 
     The nwo-style harness (reference: integration/nwo/network.go:173) —
@@ -155,6 +157,10 @@ def provision_network(base_dir: str, n_orderers: int = 3,
     Their cfg paths land under "spare_orderers"; each cfg carries its
     own "cert_fp" so a drill can build the add_consenter request
     without re-deriving it.
+
+    `clients_per_org`: enrolled P-256 client identities per peer org.
+    "clients" keeps naming each org's first; all of them are listed
+    under "client_pool" ({org: [cfg paths]}).
     """
     from fabric_tpu.orderer.cluster import cert_fingerprint
 
@@ -163,7 +169,7 @@ def provision_network(base_dir: str, n_orderers: int = 3,
     all_orgs = {"OrdererOrg": ord_org, **p_orgs}
 
     n_peers = len(p_orgs) * peers_per_org
-    ports = _free_ports(n_orderers + n_peers + spare_orderers)
+    ports = free_ports(n_orderers + n_peers + spare_orderers)
     ord_ports = ports[:n_orderers]
     peer_ports = ports[n_orderers:n_orderers + n_peers]
     spare_ports = ports[n_orderers + n_peers:]
@@ -316,27 +322,38 @@ def provision_network(base_dir: str, n_orderers: int = 3,
     from fabric_tpu.bccsp import SCHEME_ED25519
     clients = {}
     clients_ed25519 = {}
-    for org_name, org in p_orgs.items():
-        for scheme, book in (
-                (None, clients), (SCHEME_ED25519, clients_ed25519)):
-            ccert, ckey = org.issuer.issue(f"client@{org_name}",
-                                           scheme=scheme)
-            suffix = f"_{scheme}" if scheme else ""
-            path = os.path.join(base_dir,
-                                f"client_{org_name}{suffix}.json")
-            with open(path, "w") as f:
-                json.dump({
-                    "mspid": org_name,
-                    "cert_pem": _cert_pem(ccert).decode(),
-                    "key_pem": _key_pem(ckey).decode(),
-                    "channel_config_hex": cfg_hex,
-                    "channel_id": channel_id,
-                    "orderers": [["127.0.0.1", p]
-                                 for p in ord_ports + spare_ports],
-                    "peers": [["127.0.0.1", p, o]
-                              for (o, k, p) in peer_list],
-                }, f)
-            book[org_name] = path
+    client_pool = {org_name: [] for org_name in p_orgs}
+
+    def _write_client(org_name, common_name, scheme, file_name) -> str:
+        ccert, ckey = p_orgs[org_name].issuer.issue(common_name,
+                                                    scheme=scheme)
+        path = os.path.join(base_dir, file_name)
+        with open(path, "w") as f:
+            json.dump({
+                "mspid": org_name,
+                "cert_pem": _cert_pem(ccert).decode(),
+                "key_pem": _key_pem(ckey).decode(),
+                "channel_config_hex": cfg_hex,
+                "channel_id": channel_id,
+                "orderers": [["127.0.0.1", p]
+                             for p in ord_ports + spare_ports],
+                "peers": [["127.0.0.1", p, o]
+                          for (o, k, p) in peer_list],
+            }, f)
+        return path
+
+    for org_name in p_orgs:
+        clients[org_name] = _write_client(
+            org_name, f"client@{org_name}", None,
+            f"client_{org_name}.json")
+        clients_ed25519[org_name] = _write_client(
+            org_name, f"client@{org_name}", SCHEME_ED25519,
+            f"client_{org_name}_{SCHEME_ED25519}.json")
+        client_pool[org_name].append(clients[org_name])
+        for i in range(1, clients_per_org):
+            client_pool[org_name].append(_write_client(
+                org_name, f"client{i}@{org_name}", None,
+                f"client_{org_name}_{i}.json"))
     # per-org ADMIN identities (channel-config admin certs): the admin
     # CLI's install/join verbs are Admins-gated
     admins = {}
@@ -354,4 +371,4 @@ def provision_network(base_dir: str, n_orderers: int = 3,
     return {"orderers": orderer_paths, "peers": peer_paths,
             "spare_orderers": spare_paths,
             "clients": clients, "clients_ed25519": clients_ed25519,
-            "admins": admins}
+            "client_pool": client_pool, "admins": admins}

@@ -1,26 +1,19 @@
 """AOT warmup: pre-compile the provider's kernel set into the cache.
 
-Round-2/3 verdicts flagged node cold-start: every (kernel, bucket-shape)
-pair costs minutes of XLA compilation on first dispatch.  This tool runs
-each configured kernel once per bucket shape so the persistent
-compilation cache (bccsp/factory.enable_compile_cache) is hot before a
-node starts serving — run it at provisioning time or from the node's
-init.
+Every (kernel, bucket-shape) pair costs a cold XLA compile on first
+dispatch.  This tool runs each configured kernel once per bucket shape
+so the persistent compilation cache (bccsp/factory.enable_compile_cache)
+is hot before a node starts serving — run it at provisioning time:
 
-The prebake recipe (turns the BENCH_r05 146.6 s compile+first-call into
-a cache hit for every later process on the host):
+    python -m fabric_tpu.node.warmup
 
-    # provisioning time: compile every kernel into a shared artifact dir
-    python -m fabric_tpu.node.warmup --cache-dir /var/cache/fabric_tpu_xla
+The cache lives where JAX_COMPILATION_CACHE_DIR says, else at
+<checkout>/.cache/jax; a node started with the same setting loads every
+program this compiled instead of compiling it.
 
-    # node start: point the node at the same artifact
-    FABRIC_TPU_PEER_COMPILE_CACHE_DIR=/var/cache/fabric_tpu_xla ...
-    # (or "compile_cache_dir" in the node JSON config)
-
-Without --cache-dir the JAX_COMPILATION_CACHE_DIR env var or
-~/.cache/fabric_tpu_xla is used.  The same artifact lets the slow-marked
-kernel test modules rejoin the quick pytest gate: they drop their `slow`
-mark when bccsp.factory.compile_cache_is_warm() sees a prebaked dir.
+`warm_lanes` is the exact-shape form a running node uses (the peer's
+POST /bccsp/warmup ops route): one dispatch per named generic-lane
+bucket and rows-lane bucket, in the process that will serve them.
 """
 
 from __future__ import annotations
@@ -85,14 +78,65 @@ def gen_ed25519_sigs(n: int, n_keys: int = 4, seed: int = 7):
 
 
 def warmup(buckets, schemes=("p256", "p256-rows", "ed25519", "idemix"),
-           verbose: bool = True, cache_dir=None) -> dict:
+           verbose: bool = True) -> dict:
     from fabric_tpu.bccsp.factory import FactoryOpts, init_factories
 
-    provider = init_factories(FactoryOpts(default="JAXTPU",
-                                          compile_cache_dir=cache_dir))
-    timings = _warm_kernels(provider, buckets, schemes, verbose)
-    _write_manifest(cache_dir, buckets, schemes, timings)
-    return timings
+    provider = init_factories(FactoryOpts(default="JAXTPU"))
+    return _warm_kernels(provider, buckets, schemes, verbose)
+
+
+def warm_lanes(provider, generic=(), rows=()) -> dict:
+    """One P-256 dispatch at exactly each named shape: `generic` are
+    generic-ladder buckets (powers of two from MIN_BUCKET), `rows` are
+    fixed-comb row buckets (members of ROW_BUCKETS).  Returns seconds
+    per shape; every verdict must be True or this raises.
+
+    The shapes go out on one thread each: tracing a program holds the
+    interpreter lock, but XLA compiles (and loads from the persistent
+    cache) outside it, so the compiles of different shapes overlap."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    import numpy as np
+
+    from fabric_tpu.bccsp import SCHEME_P256
+    from fabric_tpu.bccsp.jaxtpu import MIN_BUCKET
+
+    # 128 keys, each far under fast_key_threshold per batch: these stay
+    # on the generic ladder whatever the bucket
+    spread = gen_p256_sigs(128, n_keys=128, seed=11)
+    # one resident key filling exactly `bucket` rows
+    hot = gen_p256_sigs(64, n_keys=1, seed=13)
+    jobs = []
+    for bucket in generic:
+        n = bucket if bucket == MIN_BUCKET else bucket // 2 + 1
+        if n // len(spread) >= provider.fast_key_threshold:
+            raise ValueError(f"generic bucket {bucket} too large to warm")
+        jobs.append((f"generic@{bucket}",
+                     (spread * (n // len(spread) + 1))[:n]))
+    for bucket in rows:
+        if bucket not in provider.ROW_BUCKETS:
+            raise ValueError(f"rows bucket {bucket} not in ROW_BUCKETS")
+        n = bucket * provider.fast_row_c
+        jobs.append((f"rows@{bucket}", (hot * (n // len(hot) + 1))[:n]))
+    if rows:
+        provider.key_tables.get_or_build(hot[0].pubkey)
+    # one jitted function per lane, made before the threads race for it
+    for lane in ([SCHEME_P256] if generic else []) + \
+            (["p256-rows"] if rows else []):
+        provider._get_fn(lane)
+
+    def one(job):
+        name, items = job
+        t0 = time.perf_counter()
+        ok = provider.batch_verify(items)
+        if not bool(np.asarray(ok).all()):
+            raise RuntimeError(f"warmup {name}: bad verdicts")
+        return name, round(time.perf_counter() - t0, 3)
+
+    if not jobs:
+        return {}
+    with ThreadPoolExecutor(len(jobs)) as pool:
+        return dict(pool.map(one, jobs))
 
 
 def _warm_kernels(provider, buckets, schemes, verbose: bool) -> dict:
@@ -142,26 +186,6 @@ def _warm_kernels(provider, buckets, schemes, verbose: bool) -> dict:
     return timings
 
 
-def _write_manifest(cache_dir, buckets, schemes, timings) -> None:
-    """Stamp the completed prebake: compile_cache_is_warm() requires
-    this manifest, so incidental cache entries from ordinary runs never
-    flip the warm check — only a finished warmup does."""
-    import json
-    import os
-
-    from fabric_tpu.bccsp.factory import WARMUP_MANIFEST, default_cache_dir
-
-    d = cache_dir or default_cache_dir()
-    try:
-        os.makedirs(d, exist_ok=True)
-        with open(os.path.join(d, WARMUP_MANIFEST), "w") as f:
-            json.dump({"buckets": list(buckets), "schemes": list(schemes),
-                       "timings": timings, "completed_unix": time.time()},
-                      f, indent=1)
-    except OSError:
-        pass    # cache dir unwritable: warmed this process, no artifact
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="fabric-tpu-warmup")
     ap.add_argument("--buckets", default="12288,16384,32768",
@@ -169,21 +193,12 @@ def main(argv=None) -> int:
                          "96-row grid bucket; 16384/32768 the 128/256)")
     ap.add_argument("--schemes",
                     default="p256,p256-rows,ed25519,idemix")
-    ap.add_argument("--cache-dir", default=None,
-                    help="persistent XLA compilation cache dir to prebake "
-                         "(default: JAX_COMPILATION_CACHE_DIR or "
-                         "~/.cache/fabric_tpu_xla); point nodes at the "
-                         "same dir via compile_cache_dir in their config")
     args = ap.parse_args(argv)
     timings = warmup([int(b) for b in args.buckets.split(",")],
-                     tuple(args.schemes.split(",")),
-                     cache_dir=args.cache_dir)
-    from fabric_tpu.bccsp.factory import compile_cache_is_warm, \
-        default_cache_dir
-    d = args.cache_dir or default_cache_dir()
-    state = "warm" if compile_cache_is_warm(d) else "EMPTY"
+                     tuple(args.schemes.split(",")))
+    from fabric_tpu.bccsp.factory import enable_compile_cache
     print("warm:", timings)
-    print(f"cache artifact: {d} ({state})")
+    print(f"compile cache: {enable_compile_cache()}")
     return 0
 
 

@@ -24,8 +24,8 @@ cyclotomic squarings, BN exponent chains) are documented headroom, not
 yet built.
 
 Differential testing: component ops + a Miller-loop prefix match the
-host oracle on CPU (tests/test_bn254_batch.py); the full pairing is
-cross-checked on TPU by experiments/bench_pairing.py.
+host oracle on CPU (tests/test_bn254_batch.py); the full pairing has
+no cross-check on the TPU in the tree (not measured on today's code).
 """
 
 from __future__ import annotations

@@ -77,26 +77,34 @@ def count_open_fds() -> Optional[int]:
         return None
 
 
+def _initialized_jax():
+    """The jax module if this process has already initialized a backend,
+    else None.  Asking jax.devices() or jax.live_arrays() initializes
+    one, and on a TPU host that takes the chip from the process that
+    owns it — so a process that merely imported jax is left alone."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    from jax._src import xla_bridge
+    return jax if xla_bridge.backends_are_initialized() else None
+
+
 def provenance() -> dict:
     """Where a measurement ran: {platform, device_kind, n_devices,
-    hostname}.  `platform` is "tpu" only on real TPU devices —
-    everything else (host-platform virtual meshes included) is
-    "cpu-virtual", so a bench JSON carries the ROADMAP's wall-clock
-    caveat in-band.  Never initializes jax itself: callers that bench
-    devices have already imported it."""
-    out = {"platform": "cpu-virtual", "device_kind": "unknown",
+    hostname}.  `platform` is "tpu" only on real TPU devices; any other
+    initialized backend (host-platform virtual meshes included) is
+    "cpu-virtual", and a process that initialized none is "none".
+    Never initializes a backend itself: callers that measure devices
+    already have."""
+    out = {"platform": "none", "device_kind": "unknown",
            "n_devices": 0, "hostname": socket.gethostname()}
-    jax = sys.modules.get("jax")
+    jax = _initialized_jax()
     if jax is not None:
-        try:
-            devs = jax.devices()
-            out["n_devices"] = len(devs)
-            out["device_kind"] = str(
-                getattr(devs[0], "device_kind", devs[0]))
-            if getattr(devs[0], "platform", "cpu") == "tpu":
-                out["platform"] = "tpu"
-        except Exception:
-            pass
+        devs = jax.devices()
+        out["n_devices"] = len(devs)
+        out["device_kind"] = str(devs[0].device_kind)
+        out["platform"] = ("tpu" if devs[0].platform == "tpu"
+                           else "cpu-virtual")
     return out
 
 
@@ -207,7 +215,7 @@ class ResourceCollector:
     def _collect_jax(self, snap: dict) -> None:
         """Live device-buffer bytes — only when jax is ALREADY loaded
         (sampling must never initialize a backend)."""
-        jax = sys.modules.get("jax")
+        jax = _initialized_jax()
         if jax is None:
             return
         try:

@@ -144,5 +144,8 @@ class OperationsServer:
         return self
 
     def stop(self) -> None:
-        self._httpd.shutdown()
+        # shutdown() waits for serve_forever to acknowledge: on a server
+        # that was built but never started it would wait forever
+        if self._thread.is_alive():
+            self._httpd.shutdown()
         self._httpd.server_close()

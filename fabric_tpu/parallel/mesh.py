@@ -29,11 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as PSpec
 
-try:                                      # jax >= 0.4.x moved it to top level
-    _shard_map = jax.shard_map
-except AttributeError:                    # 0.4.37 ships it under experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from fabric_tpu.ops import p256, ed25519
 
 BATCH_AXIS = "batch"
@@ -165,7 +160,7 @@ def sharded_p256_verify(mesh: Mesh, require_low_s: bool = True):
         count = jax.lax.psum(jnp.sum(v.astype(jnp.int32)), BATCH_AXIS)
         return v, count
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=lane_specs("p256"),
         out_specs=(PSpec(BATCH_AXIS), PSpec()))
@@ -188,7 +183,7 @@ def sharded_p256_rows_verify(mesh: Mesh, require_low_s: bool = True):
         count = jax.lax.psum(jnp.sum(v.astype(jnp.int32)), BATCH_AXIS)
         return v, count
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=lane_specs("p256-rows"),
         out_specs=(PSpec(BATCH_AXIS), PSpec()))
@@ -206,7 +201,7 @@ def sharded_ed25519_rows_verify(mesh: Mesh):
         count = jax.lax.psum(jnp.sum(v.astype(jnp.int32)), BATCH_AXIS)
         return v, count
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=lane_specs("ed25519-rows"),
         out_specs=(PSpec(BATCH_AXIS), PSpec()))
@@ -223,7 +218,7 @@ def sharded_ed25519_verify(mesh: Mesh):
         count = jax.lax.psum(jnp.sum(v.astype(jnp.int32)), BATCH_AXIS)
         return v, count
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=lane_specs("ed25519"),
         out_specs=(PSpec(BATCH_AXIS), PSpec()))
@@ -248,7 +243,7 @@ def sharded_idemix_pair_verify(mesh: Mesh):
         count = jax.lax.psum(jnp.sum(v.astype(jnp.int32)), BATCH_AXIS)
         return v, count
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=lane_specs("idemix-pair"),
         out_specs=(PSpec(BATCH_AXIS), PSpec()))

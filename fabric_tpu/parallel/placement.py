@@ -48,7 +48,7 @@ class PlacementScheduler:
                  idle_halflife_s: float = 30.0,
                  clock: Optional[Callable[[], float]] = None):
         """`provider_factory(mesh) -> Provider` builds the per-span
-        provider (a single-device provider when the span is one chip);
+        provider (over a one-device mesh when the span is one chip);
         `wrap(provider) -> provider` optionally decorates each one once
         (the factory passes the degradation breaker here so per-channel
         providers keep the SW-fallback behaviour of the global one)."""
@@ -81,16 +81,11 @@ class PlacementScheduler:
         key = (lo, size)
         p = self._providers.get(key)
         if p is None:
-            if size == 1:
-                m = None            # single chip: skip shard_map overhead
-                p = self.provider_factory(m)
-                # pin dispatches to the span's chip, not devices()[0]
-                dev = self.devices[lo]
-                if hasattr(p, "device_labels"):
-                    p.device_labels = (f"{dev.platform}:{dev.id}",)
-            else:
-                m = meshmod.make_mesh(self.devices[lo:lo + size])
-                p = self.provider_factory(m)
+            # a one-chip span is a one-device mesh: a meshless provider
+            # would put its banks and dispatches on devices()[0]
+            # whichever chip the span names
+            m = meshmod.make_mesh(self.devices[lo:lo + size])
+            p = self.provider_factory(m)
             if self.wrap is not None:
                 p = self.wrap(p)
             self._providers[key] = p

@@ -115,6 +115,113 @@ def test_factory_degrade_defaults_on_under_jaxtpu():
     assert not isinstance(p, DegradingProvider)
 
 
+def test_fail_stop_lane_error_reaches_the_caller(monkeypatch):
+    """degrade=False: an exception inside a lane is the caller's, as a
+    DeviceError; nothing is recomputed on the software provider."""
+    from fabric_tpu.bccsp.provider import DeviceError
+
+    boom = RuntimeError("injected lane failure")
+
+    def broken(items, idxs, pending):
+        raise boom
+
+    p = init_factories(FactoryOpts(default="JAXTPU", degrade=False))
+    assert isinstance(p, JaxTpuProvider) and not p.degrade
+    monkeypatch.setattr(p, "_verify_p256", broken)
+    items = make_items(SoftwareProvider(), n_p256=3, n_ed=0)
+    with pytest.raises(DeviceError) as err:
+        p.batch_verify(items)
+    assert err.value.__cause__ is boom
+    assert p.stats["fallbacks"] == 0
+
+    # a provider that was asked to degrade still answers, and counts it
+    q = JaxTpuProvider(degrade=True)
+    monkeypatch.setattr(q, "_verify_p256", broken)
+    assert q.batch_verify(items).all()
+    assert q.stats["fallbacks"] == 1
+
+
+def test_fail_stop_resolve_error_reaches_the_caller(monkeypatch):
+    from fabric_tpu.bccsp.provider import DeviceError
+
+    def raising():
+        raise RuntimeError("injected resolve failure")
+
+    def lane(items, idxs, pending):
+        pending.append((idxs, raising))
+
+    p = JaxTpuProvider()
+    monkeypatch.setattr(p, "_verify_p256", lane)
+    resolve = p.batch_verify_async(
+        make_items(SoftwareProvider(), n_p256=2, n_ed=0))
+    with pytest.raises(DeviceError):
+        resolve()
+    assert p.stats["fallbacks"] == 0
+
+
+def test_jaxtpu_refuses_a_cpu_nobody_asked_for(monkeypatch):
+    """Where JAX finds no accelerator it falls back to the CPU silently;
+    only JAX_PLATFORMS naming the CPU makes that the provider's device."""
+    from fabric_tpu.bccsp import jaxtpu
+
+    monkeypatch.setattr(jaxtpu, "_requested_platforms", lambda: "")
+    with pytest.raises(RuntimeError, match="no accelerator"):
+        JaxTpuProvider()
+    with pytest.raises(RuntimeError, match="no accelerator"):
+        init_factories(FactoryOpts(default="JAXTPU"))
+    monkeypatch.setattr(jaxtpu, "_requested_platforms", lambda: "tpu,cpu")
+    assert JaxTpuProvider().name == "jaxtpu"
+    init_factories(FactoryOpts(default="SW"))
+
+
+def test_compile_cache_dir_comes_from_the_environment(monkeypatch):
+    import os
+
+    import jax
+
+    from fabric_tpu.bccsp import factory
+
+    def refuse(*a, **kw):
+        raise AssertionError("jax.config.update called although "
+                             "JAX_COMPILATION_CACHE_DIR is set")
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/where")
+    monkeypatch.setattr(jax.config, "update", refuse)
+    assert factory.enable_compile_cache() == "/some/where"
+    monkeypatch.undo()
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        assert factory.enable_compile_cache() == os.path.join(
+            repo, ".cache", "jax")
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            repo, ".cache", "jax")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_get_default_before_init_stays_off_jax():
+    """Clients, admin tools and launchers reach get_default() through
+    every handshake; that must not import jax (and take the chip)."""
+    import os
+    import subprocess
+    import sys
+
+    code = ("import sys\n"
+            "from fabric_tpu.bccsp.factory import get_default\n"
+            "from fabric_tpu.msp.ca import DevOrg\n"
+            "ident = DevOrg('O').new_identity('a')\n"
+            "assert ident.verify(b'm', ident.sign(b'm'))\n"
+            "assert get_default().name == 'sw', get_default().name\n"
+            "assert 'jax' not in sys.modules, 'jax imported'\n")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run([sys.executable, "-c", code], cwd=repo,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr
+
+
 def test_degrading_provider_delegates_primary_attributes():
     from fabric_tpu.bccsp.degrade import DegradingProvider
     primary = JaxTpuProvider()

@@ -3,8 +3,8 @@
 The full pairing (Miller + ~2800-bit final exponentiation) is too slow
 for the eager CPU path, so CPU coverage is compositional: tower ops and
 a Miller-loop PREFIX match the host bit-for-bit; the host ate itself is
-validated against bilinearity here; the full device pairing is
-cross-checked on real TPU by experiments/bench_pairing.py.
+validated against bilinearity here; the full device pairing has no
+cross-check on a real TPU in the tree.
 """
 import random
 

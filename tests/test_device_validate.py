@@ -364,3 +364,32 @@ def test_stash_miss_falls_back(sw_provider, orgs):
     # host fallback ran with the tampered (all-invalid) flags
     assert committer.ledger.get_state("cc", "k00") is None
     assert res is not None
+
+
+def test_programming_error_raises_instead_of_demoting(sw_provider, orgs,
+                                                      monkeypatch):
+    """A TypeError inside the device path (an API this installation does
+    not have) is not a block shape: it must surface, not turn into a
+    quiet reason="error" demotion that leaves the path dead."""
+    from fabric_tpu.committer.device_validate import DeviceValidator
+
+    def broken(self, *a, **kw):
+        raise TypeError("shard_map() got an unexpected keyword argument")
+
+    committer = make_stack(sw_provider, orgs, device=True)
+    block = build.new_block(0, b"\x00" * 32, seed_block(orgs, 3))
+    monkeypatch.setattr(DeviceValidator, "_dispatch", broken)
+    before = _snap()
+    with pytest.raises(TypeError):
+        committer.validator.validate(block)
+    assert _snap()["demotions"] == before["demotions"]
+
+    # anything else a block can provoke still demotes to the host path
+    def choke(self, *a, **kw):
+        raise ValueError("hostile lane bytes")
+
+    monkeypatch.setattr(DeviceValidator, "_dispatch", choke)
+    res = committer.store_block(block)
+    assert res.final_flags.valid_count() == 3
+    after = _snap()
+    assert after["demotions"]["error"] - before["demotions"]["error"] == 1

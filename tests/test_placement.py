@@ -231,18 +231,21 @@ def test_scheduler_wrap_applied_once_per_span():
     assert len(wrapped) == 1
 
 
-def test_single_device_span_pins_device_label():
+def test_single_device_span_is_a_one_device_mesh():
     ps = _scheduler(n=2)
     ps.provider_for("a", demand=1)
     for _ in range(20):
         ps.provider_for("b", demand=1)
     ps.provider_for("a", demand=1)   # materialize a's span provider too
-    # both channels at 1 device each: span providers are meshless but
-    # labeled with the actual chip they were pinned to
-    labels = {ch: ps._providers[(v["span_start"], v["devices"])].device_labels
-              for ch, v in ps.snapshot()["channels"].items()
-              if v["devices"] == 1}
-    assert all(lab in {("cpu:0",), ("cpu:1",)} for lab in labels.values())
+    # both channels at 1 device each: each span provider is built over a
+    # mesh of exactly its own chip (a meshless provider would put banks
+    # and dispatches on devices()[0] whichever chip the span names)
+    spans = {v["span_start"] for v in ps.snapshot()["channels"].values()
+             if v["devices"] == 1}
+    assert spans == {0, 1}
+    for lo in spans:
+        mesh = ps._providers[(lo, 1)].mesh
+        assert [d.id for d in mesh.devices.flat] == [lo]
 
 
 # -- factory wiring ----------------------------------------------------------

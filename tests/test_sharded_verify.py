@@ -11,8 +11,7 @@ The provider's atomic SW fallback would MASK a broken sharded dispatch
 gates on stats["fallbacks"] == 0.
 
 Mesh dispatches always jit (minutes of XLA:CPU compile, cold) — the
-module carries the slow mark unless the persistent compile cache holds
-a completed warmup artifact, the same contract as test_mesh.py.
+module carries the slow mark, the same as test_mesh.py.
 """
 
 import hashlib
@@ -23,12 +22,11 @@ import pytest
 
 import jax
 
-from fabric_tpu.bccsp.factory import compile_cache_is_warm
 from fabric_tpu.bccsp.provider import (SCHEME_ED25519, SCHEME_P256,
                                        VerifyItem)
 from fabric_tpu.bccsp.sw import SoftwareProvider
 
-pytestmark = [] if compile_cache_is_warm() else [pytest.mark.slow]
+pytestmark = [pytest.mark.slow]
 
 if len(jax.devices()) < 8:
     pytestmark = [pytest.mark.skip(reason="needs 8 (virtual) devices: set "
