@@ -38,6 +38,11 @@ endorsement costs the device three single-signature dispatches (the
 proposal check and two fan-out handshakes).  Where the clock cuts the
 count the cut is printed; the shapes are never cut.
 
+On a host with more than one chip the device peer runs `"bccsp_mesh":
+true` over all of them, and the smoke also checks that every chip took
+dispatches and holds memory.  Nothing else differs, and nothing is
+passed in: `python chip_smoke.py` is the whole interface.
+
 The last line of stdout is one JSON object naming the device as JAX
 reported it to the device peer.  Any failed check exits non-zero
 without it.
@@ -74,16 +79,6 @@ CONFLICT_PAIRS = 8           # deliberate same-key pairs
 PILOT_TX = 128
 BIG_BLOCK_TX = 10_000        # x (3 endorsements + 1 creator) = 40,000 sigs
 WORKERS = 32
-
-# program shapes the path uses (bccsp/jaxtpu.py): single-signature
-# handshake/proposal checks and <=64-tx ingress stamps ride generic@128;
-# a block's ~8-per-key creator signatures generic@256/512; three
-# resident endorser keys rows@4 (one gateway batch) and rows@16 (a
-# 500-tx block: 3 x 4 rows); the 10,000-tx block rows@384 (3 x 79 rows
-# of endorsements + 64 x 2 of creators)
-WARM_GENERIC = (128, 256, 512)
-WARM_ROWS = (4, 16)
-BIG_BLOCK_ROWS = (384,)
 
 LIMIT_S = 1200.0             # the contract's limit, compilation included
 ENDORSE_UNTIL_S = 640.0      # no new endorsement after this much of it
@@ -187,7 +182,7 @@ class Network:
     """The provisioned deployment as OS processes, plus what a client
     needs to talk to it."""
 
-    def __init__(self, base: str, mesh: bool = False):
+    def __init__(self, base: str, mesh: bool):
         from fabric_tpu.config import BatchConfig
         from fabric_tpu.node.provision import free_ports, provision_network
         from fabric_tpu.testing.procnet import load_client
@@ -546,17 +541,18 @@ def write_big_block(nw: Network, tip: dict, envs: list) -> str:
     return path
 
 
-def replay_big_block(nw: Network, path: str, big_rows) -> dict:
+def replay_big_block(nw: Network, path: str) -> dict:
     """The block through every (stopped) peer's own committer, each in
     a process of its own: Org1's with the device provider, Org2's and
     Org3's host-only.  -> {org: report}"""
+    from fabric_tpu.node.warmup import BLOCK_10K_ROWS
     procs = {}
     for cfg_path in nw.net["peers"]:
         org = read_json(cfg_path)["mspid"]
         argv = [sys.executable, "-m", "fabric_tpu.testing.replay",
                 cfg_path, path]
         if org == PEER_ORGS[0]:
-            argv += ["--warm-rows", ",".join(map(str, big_rows))]
+            argv += ["--warm-rows", ",".join(map(str, BLOCK_10K_ROWS))]
         with open(os.path.join(nw.base, f"replay{org}.log"), "wb") as log:
             procs[org] = subprocess.Popen(argv, env=nw.env, stderr=log,
                                           stdout=subprocess.PIPE)
@@ -573,23 +569,17 @@ def replay_big_block(nw: Network, path: str, big_rows) -> dict:
 
 # -- main --------------------------------------------------------------------
 
-def run(require_accelerator: bool = True, target_tx: int = TARGET_TX,
-        pilot_tx: int = PILOT_TX, big_block_tx: int = BIG_BLOCK_TX,
-        warm_generic=WARM_GENERIC, warm_rows=WARM_ROWS,
-        big_rows=BIG_BLOCK_ROWS,
-        endorse_until_s: float = ENDORSE_UNTIL_S,
-        mesh: bool = False) -> dict:
-    """The whole smoke; returns the device dict for the last line.  The
-    keyword arguments are for the builder's runs at another size (a dry
-    run of the launcher on the CPU, the four-chip mesh run); `main`
-    passes none of them."""
-    if require_accelerator:
-        found = probe_accelerator()
-        say(f"preflight: jax finds {found}")
+def run() -> dict:
+    """The whole smoke; returns the device dict for the last line."""
+    found = probe_accelerator()
+    mesh = found["count"] > 1
+    say(f"preflight: jax finds {found}"
+        + ("; the device peer shards over all of them" if mesh else ""))
     sys.path.insert(0, REPO)
     build_native()
 
     from fabric_tpu.bccsp.factory import FactoryOpts, init_factories
+    from fabric_tpu.node.warmup import SERVED_GENERIC, SERVED_ROWS
     from fabric_tpu.testing.procnet import wait_orderer_leader, wait_status
     init_factories(FactoryOpts(default="SW"))     # the launcher's own
 
@@ -619,21 +609,20 @@ def run(require_accelerator: bool = True, target_tx: int = TARGET_TX,
         say(f"device peer: jax {device['jax']} / jaxlib {device['jaxlib']} "
             f"/ libtpu {device['libtpu']}; devices {device['devices']}; "
             f"compile cache at {device['compile_cache_dir']}")
-        if require_accelerator:
-            check(device["platform"] == "tpu",
-                  f"device peer's platform is tpu ({device['device_kind']} "
-                  f"x {device['device_count']})")
+        check(device["platform"] == "tpu",
+              f"device peer's platform is tpu ({device['device_kind']} "
+              f"x {device['device_count']})")
 
         # the big block's envelopes are built while the peer compiles
         big_box = {}
         big_thread = threading.Thread(
             target=lambda: big_box.update(envs=build_big_block_envelopes(
-                nw, big_block_tx, SEED + 1)), daemon=True)
+                nw, BIG_BLOCK_TX, SEED + 1)), daemon=True)
         big_thread.start()
 
         warm = http_json("POST", nw.ops[dev_org] + "/bccsp/warmup",
-                         {"generic": list(warm_generic),
-                          "rows": list(warm_rows)}, timeout=LIMIT_S)
+                         {"generic": list(SERVED_GENERIC),
+                          "rows": list(SERVED_ROWS)}, timeout=LIMIT_S)
         say(f"warm-up in the device peer: {warm['timings']} "
             f"({warm['seconds']} s)")
         wait_status(nw.peer_addr[dev_org], nw.signer, nw.msps,
@@ -642,7 +631,7 @@ def run(require_accelerator: bool = True, target_tx: int = TARGET_TX,
         traffic.connect_all()
         say(f"{len(traffic.gws)} client identities connected to the gateway")
 
-        pilot = traffic.endorse_until(traffic.plan(pilot_tx),
+        pilot = traffic.endorse_until(traffic.plan(PILOT_TX),
                                       time.monotonic() + 300.0)
         traffic.run(pilot)
         h_pilot = nw.wait_heights(max(t["block"] for t in pilot) + 1,
@@ -658,16 +647,15 @@ def run(require_accelerator: bool = True, target_tx: int = TARGET_TX,
 
         # ---- the serving window ------------------------------------------
         t_serve = time.monotonic()
-        deadline = _T0 + endorse_until_s
-        plan = traffic.plan(target_tx)
-        endorsed = traffic.endorse_until(plan, deadline)
+        endorsed = traffic.endorse_until(traffic.plan(TARGET_TX),
+                                         _T0 + ENDORSE_UNTIL_S)
         t_endorsed = time.monotonic()
-        if len(endorsed) < target_tx:
-            say(f"CUT: endorsed {len(endorsed)} of {target_tx} planned tx "
-                f"before the {endorse_until_s:.0f} s mark of the "
+        if len(endorsed) < TARGET_TX:
+            say(f"CUT: endorsed {len(endorsed)} of {TARGET_TX} planned tx "
+                f"before the {ENDORSE_UNTIL_S:.0f} s mark of the "
                 f"{LIMIT_S:.0f} s limit (count cut, shapes kept)")
-        check(len(endorsed) >= min(500, target_tx), "at least one full "
-              f"block's worth of transactions endorsed ({len(endorsed)})")
+        check(len(endorsed) >= 500, "at least one full block's worth of "
+              f"transactions endorsed ({len(endorsed)})")
         acked = traffic.run(endorsed)
         serve_s = time.monotonic() - t_serve
         h_serve = nw.wait_heights(max(t["block"] for t in acked) + 1,
@@ -743,8 +731,7 @@ def run(require_accelerator: bool = True, target_tx: int = TARGET_TX,
         big_thread.join()
         envs = big_box["envs"]
         t_big = time.monotonic()
-        reports = replay_big_block(nw, write_big_block(nw, tip, envs),
-                                   big_rows)
+        reports = replay_big_block(nw, write_big_block(nw, tip, envs))
         big_s = time.monotonic() - t_big
         dev = reports[dev_org]
         big_tampered = sum(tampered for _, _, tampered in envs)

@@ -100,8 +100,8 @@ def init_factories(opts: Optional[FactoryOpts] = None) -> Provider:
                         p, SoftwareProvider(require_low_s=low_s))
             _placement = PlacementScheduler(
                 devices=devices,
-                provider_factory=lambda m: JaxTpuProvider(
-                    require_low_s=opts.require_low_s, mesh=m,
+                provider_factory=lambda m, d: JaxTpuProvider(
+                    require_low_s=opts.require_low_s, mesh=m, device=d,
                     degrade=degrade),
                 wrap=wrap)
     else:

@@ -177,6 +177,7 @@ class JaxTpuProvider(prov.Provider):
     name = "jaxtpu"
 
     def __init__(self, require_low_s: bool = True, mesh=None,
+                 device=None,
                  fallback: Optional[SoftwareProvider] = None,
                  degrade: bool = False,
                  fast_row_c: Optional[int] = None,
@@ -194,13 +195,21 @@ class JaxTpuProvider(prov.Provider):
                               device-resident table slot
           max_cached_keys     table-bank slots (HBM residency cap)
 
+        `mesh`: batches are sharded over it (the `sharded_*` programs).
+        `device` (meshless only): the one device the banks live on and
+        every dispatch runs on — the placement scheduler's one-chip
+        span; None is jax's default device.
+
         `degrade`: on a device failure recompute the batch on
         `fallback` (counted in stats["fallbacks"]) instead of raising.
         `fallback` always serves the host-side verbs (sign, key_gen).
         """
         import os
         self.require_low_s = require_low_s
+        if mesh is not None and device is not None:
+            raise ValueError("a provider takes a mesh or a device, not both")
         self.mesh = mesh
+        self.device = device
         self.degrade = bool(degrade)
         self.fallback = fallback or SoftwareProvider(require_low_s=require_low_s)
         devices = accelerator_devices()
@@ -257,10 +266,10 @@ class JaxTpuProvider(prov.Provider):
 
         self.key_tables = DeviceBank(
             max_keys, (_pt.COMB_WINDOWS * _pt.COMB_ENTRIES, 2 * _pt.L),
-            _build_p256, mesh=mesh)
+            _build_p256, mesh=mesh, device=device)
         self.ed_key_tables = DeviceBank(
             max_keys, (_et.COMB_WINDOWS * _et.COMB_ROWS, 3 * _et.L),
-            _build_ed, mesh=mesh)
+            _build_ed, mesh=mesh, device=device)
         self.fast_key_threshold = int(
             fast_key_threshold if fast_key_threshold is not None
             else os.environ.get("FABRIC_TPU_FAST_KEY_THRESHOLD", "64"))
@@ -270,7 +279,7 @@ class JaxTpuProvider(prov.Provider):
         if mesh is not None:
             devs = list(np.asarray(mesh.devices).flat)
         else:
-            devs = devices[:1]
+            devs = [device] if device is not None else devices[:1]
         self.device_labels = tuple(
             f"{d.platform}:{d.id}" for d in devs)
 
@@ -379,6 +388,15 @@ class JaxTpuProvider(prov.Provider):
                     self._fns[key] = jax.jit(ed25519.verify_words_rows)
             else:
                 raise ValueError(f"unsupported scheme {scheme!r}")
+            if self.device is not None:
+                # host arrays go to jax's default device, and a program
+                # runs where its arguments are
+                fn, dev = self._fns[key], self.device
+
+                def pinned(*a):
+                    with jax.default_device(dev):
+                        return fn(*a)
+                self._fns[key] = pinned
         return self._fns[key]
 
     def _parse_p256(self, items, idxs):
@@ -996,6 +1014,71 @@ class JaxTpuProvider(prov.Provider):
         base = (packed["flags"], packed["A"], packed["B"],
                 packed["A"], packed["B"], x1, y1, x1)
         return fn, base + (y2,), base + (y1,)
+
+    def warm(self, generic=(), rows=()) -> dict:
+        """One P-256 dispatch at exactly each named program shape, so a
+        serving process compiles nothing later: `generic` are generic-
+        ladder buckets (powers of two from MIN_BUCKET), `rows` are
+        fixed-comb row buckets (members of ROW_BUCKETS).  Returns
+        seconds per shape; a wrong verdict raises.
+
+        The shapes go out on one thread each: tracing a program holds
+        the interpreter lock, but XLA compiles (and loads from the
+        persistent cache) outside it, so the compiles of different
+        shapes overlap."""
+        import hashlib
+        import time
+        from concurrent.futures import ThreadPoolExecutor
+
+        def signed(n_keys: int) -> list:
+            out = []
+            for i in range(n_keys):
+                key = self.fallback.key_gen(SCHEME_P256)
+                digest = hashlib.sha256(b"warm %d" % i).digest()
+                out.append(VerifyItem(SCHEME_P256, key.public_bytes(),
+                                      self.fallback.sign(key, digest),
+                                      digest))
+            return out
+
+        jobs = []
+        if generic:
+            # 128 keys, each far under fast_key_threshold per batch:
+            # these stay on the generic ladder whatever the bucket
+            spread = signed(MIN_BUCKET)
+            for bucket in generic:
+                n = bucket if bucket == MIN_BUCKET else bucket // 2 + 1
+                if (bucket != _bucket(n)
+                        or -(-n // len(spread)) >= self.fast_key_threshold):
+                    raise ValueError(f"generic bucket {bucket} cannot be "
+                                     "warmed")
+                jobs.append((f"generic@{bucket}",
+                             (spread * -(-n // len(spread)))[:n]))
+            # one jitted function per lane, made before the threads
+            # race for it
+            self._get_fn(SCHEME_P256)
+        if rows:
+            # one resident key filling exactly `bucket` rows
+            hot = signed(1)
+            self.key_tables.get_or_build(hot[0].pubkey)
+            for bucket in rows:
+                if bucket not in self.ROW_BUCKETS:
+                    raise ValueError(f"rows bucket {bucket} not in "
+                                     "ROW_BUCKETS")
+                jobs.append((f"rows@{bucket}",
+                             hot * (bucket * self.fast_row_c)))
+            self._get_fn("p256-rows")
+
+        def one(job):
+            name, items = job
+            t0 = time.perf_counter()
+            if not self.batch_verify(items).all():
+                raise RuntimeError(f"warm {name}: bad verdicts")
+            return name, round(time.perf_counter() - t0, 3)
+
+        if not jobs:
+            return {}
+        with ThreadPoolExecutor(len(jobs)) as pool:
+            return dict(pool.map(one, jobs))
 
     # -- the batch verbs ----------------------------------------------------
 

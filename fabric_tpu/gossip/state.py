@@ -14,6 +14,7 @@ import logging
 import threading
 from typing import Callable, Dict, List, Optional
 
+from fabric_tpu.bccsp.provider import DeviceError
 from fabric_tpu.protocol import Block
 from fabric_tpu.protocol import wire
 
@@ -43,6 +44,9 @@ class GossipState:
         # byzantine.ProofGossip, wired post-construction alongside the
         # monitor; None = fraud proofs stay node-local (pre-r14 behavior)
         self.proofs = None
+        # callable(exc), wired post-construction by the peer channel
+        # (PeerNode.fail_stop); None = a DeviceError leaves handle()
+        self.on_device_error = None
         self._buffer: Dict[int, Block] = {}
         # deliver loop + gossip dispatch threads both drain; the lock
         # closes the pop->store window (two threads pop adjacent heights
@@ -64,14 +68,23 @@ class GossipState:
         if (self.monitor is not None
                 and self.monitor.blocked_source(self._byz_key(frm))):
             return                      # quarantined gossip source
-        if msg_type == MSG_BLOCK:
-            self._on_block_msg(frm, body)
-        elif msg_type == MSG_STATE_REQ:
-            self._on_state_req(frm, body)
-        elif msg_type == MSG_STATE_RESP:
-            for raw in body.get("blocks", []):
-                self._on_block_msg(frm, {"block": raw})
-        self._drain()
+        try:
+            if msg_type == MSG_BLOCK:
+                self._on_block_msg(frm, body)
+            elif msg_type == MSG_STATE_REQ:
+                self._on_state_req(frm, body)
+            elif msg_type == MSG_STATE_RESP:
+                for raw in body.get("blocks", []):
+                    self._on_block_msg(frm, {"block": raw})
+            self._drain()
+        except DeviceError as exc:
+            # this is the gossip dispatch thread, whose transport logs
+            # and drops whatever a handler raises: a fail-stop node must
+            # hear of a sick device from a gossiped block's signature
+            # check or commit as it does from the deliver loop's
+            if self.on_device_error is None:
+                raise
+            self.on_device_error(exc)
 
     @staticmethod
     def _byz_key(frm: str) -> str:

@@ -470,6 +470,7 @@ class PeerChannel:
             node.gossip_mux.transport.id, self.coordinator,
             mcs=self.mcs, signer=node.signer,
             bootstrap=bootstrap, msps=self.msps)
+        self.gossip.state.on_device_error = node.fail_stop
 
         # byzantine containment: per-channel witness log + monitor over
         # the node-scoped quarantine registry.  Judges every block at
@@ -1435,12 +1436,11 @@ class PeerNode:
         return status
 
     def _warmup_route(self, path, body):
-        from fabric_tpu.node.warmup import warm_lanes
         req = json.loads(body or b"{}")
         t0 = time.perf_counter()
-        timings = warm_lanes(self.provider,
-                             generic=[int(b) for b in req.get("generic", [])],
-                             rows=[int(b) for b in req.get("rows", [])])
+        timings = self.provider.warm(
+            generic=[int(b) for b in req.get("generic", [])],
+            rows=[int(b) for b in req.get("rows", [])])
         return 200, {"timings": timings,
                      "seconds": round(time.perf_counter() - t0, 3),
                      "provider": self._provider_status()}

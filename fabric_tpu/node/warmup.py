@@ -1,9 +1,9 @@
 """AOT warmup: pre-compile the provider's kernel set into the cache.
 
-Every (kernel, bucket-shape) pair costs a cold XLA compile on first
-dispatch.  This tool runs each configured kernel once per bucket shape
-so the persistent compilation cache (bccsp/factory.enable_compile_cache)
-is hot before a node starts serving — run it at provisioning time:
+Every (kernel, program-shape) pair costs a cold XLA compile on first
+dispatch.  This tool runs each configured kernel once per shape so the
+persistent compilation cache (bccsp/factory.enable_compile_cache) is
+hot before a node starts serving — run it at provisioning time:
 
     python -m fabric_tpu.node.warmup
 
@@ -11,9 +11,10 @@ The cache lives where JAX_COMPILATION_CACHE_DIR says, else at
 <checkout>/.cache/jax; a node started with the same setting loads every
 program this compiled instead of compiling it.
 
-`warm_lanes` is the exact-shape form a running node uses (the peer's
-POST /bccsp/warmup ops route): one dispatch per named generic-lane
-bucket and rows-lane bucket, in the process that will serve them.
+P-256 is warmed by exact lane and bucket (`JaxTpuProvider.warm`, which
+a running peer also offers as POST /bccsp/warmup), at the shapes below;
+Ed25519 by total batch size (`--buckets`); Idemix at its first three
+batch buckets.
 """
 
 from __future__ import annotations
@@ -22,37 +23,17 @@ import argparse
 import sys
 import time
 
-
-def gen_p256_sigs(n: int, n_keys: int, seed: int = 2026):
-    import hashlib
-    import random
-
-    from fabric_tpu.crypto import hashes
-    from fabric_tpu.crypto import ec
-    from fabric_tpu.crypto import (
-        decode_dss_signature, encode_dss_signature)
-    from fabric_tpu.crypto import (
-        Encoding, PublicFormat)
-
-    from fabric_tpu.bccsp import SCHEME_P256, VerifyItem
-    from fabric_tpu.ops import p256
-
-    rng = random.Random(seed)
-    keys = [ec.generate_private_key(ec.SECP256R1()) for _ in range(n_keys)]
-    pubs = [k.public_key().public_bytes(Encoding.X962,
-                                        PublicFormat.UncompressedPoint)
-            for k in keys]
-    items = []
-    for i in range(n):
-        msg = rng.randbytes(48)
-        digest = hashlib.sha256(msg).digest()
-        r, s = decode_dss_signature(
-            keys[i % n_keys].sign(msg, ec.ECDSA(hashes.SHA256())))
-        if s > p256.HALF_N:
-            s = p256.N - s
-        items.append(VerifyItem(SCHEME_P256, pubs[i % n_keys],
-                                encode_dss_signature(r, s), digest))
-    return items
+# The P-256 program shapes the served path uses (bccsp/jaxtpu.py), as
+# chip_smoke.py saw them on the chip under the 3-org AND policy with 64
+# client identities.  Generic ladder: single-signature handshake and
+# proposal checks and <=64-tx ingress stamps ride bucket 128; a 500-tx
+# block's ~8-per-key creator signatures 256 and 512.  Rows lane (three
+# resident endorser keys): 4 rows for one gateway batch, 16 for a 500-tx
+# block (3 x 4 rows).  A 10,000-tx block: 384 rows (3 x 79 rows of
+# endorsements + 64 x 2 of creators).
+SERVED_GENERIC = (128, 256, 512)
+SERVED_ROWS = (4, 16)
+BLOCK_10K_ROWS = (384,)
 
 
 def gen_ed25519_sigs(n: int, n_keys: int = 4, seed: int = 7):
@@ -82,64 +63,6 @@ def warmup(buckets, schemes=("p256", "p256-rows", "ed25519", "idemix"),
     from fabric_tpu.bccsp.factory import FactoryOpts, init_factories
 
     provider = init_factories(FactoryOpts(default="JAXTPU"))
-    return _warm_kernels(provider, buckets, schemes, verbose)
-
-
-def warm_lanes(provider, generic=(), rows=()) -> dict:
-    """One P-256 dispatch at exactly each named shape: `generic` are
-    generic-ladder buckets (powers of two from MIN_BUCKET), `rows` are
-    fixed-comb row buckets (members of ROW_BUCKETS).  Returns seconds
-    per shape; every verdict must be True or this raises.
-
-    The shapes go out on one thread each: tracing a program holds the
-    interpreter lock, but XLA compiles (and loads from the persistent
-    cache) outside it, so the compiles of different shapes overlap."""
-    from concurrent.futures import ThreadPoolExecutor
-
-    import numpy as np
-
-    from fabric_tpu.bccsp import SCHEME_P256
-    from fabric_tpu.bccsp.jaxtpu import MIN_BUCKET
-
-    # 128 keys, each far under fast_key_threshold per batch: these stay
-    # on the generic ladder whatever the bucket
-    spread = gen_p256_sigs(128, n_keys=128, seed=11)
-    # one resident key filling exactly `bucket` rows
-    hot = gen_p256_sigs(64, n_keys=1, seed=13)
-    jobs = []
-    for bucket in generic:
-        n = bucket if bucket == MIN_BUCKET else bucket // 2 + 1
-        if n // len(spread) >= provider.fast_key_threshold:
-            raise ValueError(f"generic bucket {bucket} too large to warm")
-        jobs.append((f"generic@{bucket}",
-                     (spread * (n // len(spread) + 1))[:n]))
-    for bucket in rows:
-        if bucket not in provider.ROW_BUCKETS:
-            raise ValueError(f"rows bucket {bucket} not in ROW_BUCKETS")
-        n = bucket * provider.fast_row_c
-        jobs.append((f"rows@{bucket}", (hot * (n // len(hot) + 1))[:n]))
-    if rows:
-        provider.key_tables.get_or_build(hot[0].pubkey)
-    # one jitted function per lane, made before the threads race for it
-    for lane in ([SCHEME_P256] if generic else []) + \
-            (["p256-rows"] if rows else []):
-        provider._get_fn(lane)
-
-    def one(job):
-        name, items = job
-        t0 = time.perf_counter()
-        ok = provider.batch_verify(items)
-        if not bool(np.asarray(ok).all()):
-            raise RuntimeError(f"warmup {name}: bad verdicts")
-        return name, round(time.perf_counter() - t0, 3)
-
-    if not jobs:
-        return {}
-    with ThreadPoolExecutor(len(jobs)) as pool:
-        return dict(pool.map(one, jobs))
-
-
-def _warm_kernels(provider, buckets, schemes, verbose: bool) -> dict:
     timings = {}
     if "idemix" in schemes:
         # the BN254 dual-pairing lane: the batch dimension buckets in
@@ -154,43 +77,31 @@ def _warm_kernels(provider, buckets, schemes, verbose: bool) -> dict:
             assert bool(np.asarray(fn(*green)).all())
             timings[f"idemix-pair@{b}"] = round(time.perf_counter() - t0, 1)
         if verbose:
-            print("idemix-pair:", {k: v for k, v in timings.items()
-                                   if k.startswith("idemix")}, flush=True)
-    for bucket in buckets:
-        if "p256" in schemes:
-            items = gen_p256_sigs(min(bucket, 64), n_keys=8)
-            reps = (bucket // len(items)) + 1
-            t0 = time.perf_counter()
-            provider.batch_verify((items * reps)[:bucket])
-            timings[f"p256@{bucket}"] = round(time.perf_counter() - t0, 1)
-        if "p256-rows" in schemes:
-            items = gen_p256_sigs(min(bucket, 64), n_keys=2, seed=5)
-            for it in items:
-                provider.key_tables.get_or_build(it.pubkey)
-            reps = (bucket // len(items)) + 1
-            t0 = time.perf_counter()
-            provider.batch_verify((items * reps)[:bucket])
-            timings[f"p256-rows@{bucket}"] = round(
-                time.perf_counter() - t0, 1)
-        if "ed25519" in schemes:
+            print("idemix-pair:", timings, flush=True)
+    p256 = provider.warm(
+        generic=SERVED_GENERIC if "p256" in schemes else (),
+        rows=SERVED_ROWS + BLOCK_10K_ROWS if "p256-rows" in schemes else ())
+    if verbose and p256:
+        print("p256:", p256, flush=True)
+    timings.update(p256)
+    if "ed25519" in schemes:
+        for bucket in buckets:
             items = gen_ed25519_sigs(min(bucket, 64))
             reps = (bucket // len(items)) + 1
             t0 = time.perf_counter()
             provider.batch_verify((items * reps)[:bucket])
             timings[f"ed25519@{bucket}"] = round(time.perf_counter() - t0, 1)
-        if verbose:
-            print(f"bucket {bucket}: "
-                  + ", ".join(f"{k.split('@')[0]}={v}s"
-                              for k, v in timings.items()
-                              if k.endswith(f"@{bucket}")), flush=True)
+            if verbose:
+                print(f"ed25519@{bucket}: "
+                      f"{timings[f'ed25519@{bucket}']}s", flush=True)
     return timings
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="fabric-tpu-warmup")
     ap.add_argument("--buckets", default="12288,16384,32768",
-                    help="comma-separated batch sizes (12288 lands the "
-                         "96-row grid bucket; 16384/32768 the 128/256)")
+                    help="comma-separated Ed25519 batch sizes (12288 lands "
+                         "the 96-row grid bucket; 16384/32768 the 128/256)")
     ap.add_argument("--schemes",
                     default="p256,p256-rows,ed25519,idemix")
     args = ap.parse_args(argv)

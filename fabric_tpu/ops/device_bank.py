@@ -45,11 +45,14 @@ class DeviceBank:
 
     def __init__(self, max_keys: int, entry_shape: Tuple[int, ...],
                  build_fn: Callable[[bytes], Optional[np.ndarray]],
-                 mesh=None):
+                 mesh=None, device=None):
+        """`mesh`: the bank is replicated over it.  `device` (meshless
+        only): the bank lives on that device; None is jax's default."""
         self.max_keys = int(max_keys)
         self.entry_shape = tuple(entry_shape)
         self.build_fn = build_fn
         self.mesh = mesh
+        self.device = device
         self._slots: "OrderedDict[bytes, int]" = OrderedDict()
         self._free = list(range(self.max_keys - 1, -1, -1))
         self._bank = None
@@ -90,7 +93,10 @@ class DeviceBank:
             self._upd = jax.jit(
                 lambda b, t, i: b.at[i].set(t), out_shardings=sharding)
         else:
-            self._bank = jnp.asarray(zeros)
+            # committed to `device` when one is named: the update and
+            # every dispatch that takes the bank then run there
+            self._bank = (jnp.asarray(zeros) if self.device is None
+                          else jax.device_put(zeros, self.device))
             # no donation: in-flight dispatches may still hold the old
             # bank; the on-device copy (~tens of MB at HBM bandwidth)
             # is negligible at table-build frequency

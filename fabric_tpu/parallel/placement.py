@@ -47,8 +47,9 @@ class PlacementScheduler:
                  ewma_alpha: float = 0.3,
                  idle_halflife_s: float = 30.0,
                  clock: Optional[Callable[[], float]] = None):
-        """`provider_factory(mesh) -> Provider` builds the per-span
-        provider (over a one-device mesh when the span is one chip);
+        """`provider_factory(mesh, device) -> Provider` builds the
+        per-span provider: over a mesh of the span's chips, or, when the
+        span is one chip, meshless on that device (mesh None);
         `wrap(provider) -> provider` optionally decorates each one once
         (the factory passes the degradation breaker here so per-channel
         providers keep the SW-fallback behaviour of the global one)."""
@@ -58,8 +59,8 @@ class PlacementScheduler:
         if provider_factory is None:
             from fabric_tpu.bccsp.jaxtpu import JaxTpuProvider
 
-            def provider_factory(m):
-                return JaxTpuProvider(mesh=m)
+            def provider_factory(m, d):
+                return JaxTpuProvider(mesh=m, device=d)
         self.devices = list(devices)
         self.provider_factory = provider_factory
         self.wrap = wrap
@@ -81,11 +82,14 @@ class PlacementScheduler:
         key = (lo, size)
         p = self._providers.get(key)
         if p is None:
-            # a one-chip span is a one-device mesh: a meshless provider
-            # would put its banks and dispatches on devices()[0]
-            # whichever chip the span names
-            m = meshmod.make_mesh(self.devices[lo:lo + size])
-            p = self.provider_factory(m)
+            span = self.devices[lo:lo + size]
+            if size == 1:
+                # one chip: the meshless programs (no shard_map, the
+                # ones a one-chip node runs), with banks and dispatches
+                # on the span's chip rather than devices()[0]
+                p = self.provider_factory(None, span[0])
+            else:
+                p = self.provider_factory(meshmod.make_mesh(span), None)
             if self.wrap is not None:
                 p = self.wrap(p)
             self._providers[key] = p

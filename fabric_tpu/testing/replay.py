@@ -1,7 +1,7 @@
 """Hand blocks to a stopped peer's committer, in a process of its own.
 
     python -m fabric_tpu.testing.replay <peer.json> <block file>...
-        [--warm-generic 128,256] [--warm-rows 384]
+        [--warm-rows 384]
 
 Builds the peer from its node config in library form — same provider,
 same channel wiring, the ledger its data_dir already holds — without
@@ -27,7 +27,7 @@ import sys
 import time
 
 
-def replay(cfg: dict, block_paths, warm_generic=(), warm_rows=()) -> dict:
+def replay(cfg: dict, block_paths, warm_rows=()) -> dict:
     from fabric_tpu.node.peer import PeerNode
     from fabric_tpu.protocol import wire
     from fabric_tpu.protocol.types import META_TXFLAGS
@@ -36,11 +36,7 @@ def replay(cfg: dict, block_paths, warm_generic=(), warm_rows=()) -> dict:
     node = PeerNode(cfg, data_dir=cfg["data_dir"])
     try:
         init_s = time.perf_counter() - t0
-        warm = {}
-        if warm_generic or warm_rows:
-            from fabric_tpu.node.warmup import warm_lanes
-            warm = warm_lanes(node.provider, generic=warm_generic,
-                              rows=warm_rows)
+        warm = node.provider.warm(rows=warm_rows) if warm_rows else {}
         warm_s = time.perf_counter() - t0 - init_s
         blocks = []
         for path in block_paths:
@@ -76,14 +72,12 @@ def main(argv=None) -> int:
     ap.add_argument("config", help="the stopped peer's node JSON")
     ap.add_argument("blocks", nargs="+",
                     help="files holding one serialized Block each")
-    ap.add_argument("--warm-generic", default="", type=_buckets,
-                    help="generic-lane buckets to dispatch once first")
     ap.add_argument("--warm-rows", default="", type=_buckets,
                     help="rows-lane buckets to dispatch once first")
     args = ap.parse_args(argv)
     from fabric_tpu.config.localconfig import load_node_config
     cfg = load_node_config(args.config, "peer")
-    report = replay(cfg, args.blocks, args.warm_generic, args.warm_rows)
+    report = replay(cfg, args.blocks, args.warm_rows)
     print(json.dumps(report), flush=True)
     return 0
 

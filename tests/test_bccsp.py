@@ -159,6 +159,26 @@ def test_fail_stop_resolve_error_reaches_the_caller(monkeypatch):
     assert p.stats["fallbacks"] == 0
 
 
+def test_device_pins_banks_and_dispatches():
+    """JaxTpuProvider(device=d) — the placement scheduler's one-chip
+    span — runs the meshless program on d and keeps its banks there,
+    not on devices()[0]."""
+    import jax
+    dev = jax.devices()[3]
+    p = JaxTpuProvider(device=dev, max_cached_keys=2)
+    assert p.device_labels == (f"{dev.platform}:{dev.id}",)
+    assert p.key_tables.array().devices() == {dev}
+    items = make_items(SoftwareProvider(), n_p256=2, n_ed=0)
+    slot = p.key_tables.get_or_build(items[0].pubkey)
+    assert slot is not None and p.key_tables.array().devices() == {dev}
+    keep, arrays = p._pack_p256(items, range(len(items)))
+    out = p._get_fn(SCHEME_P256)(*p._pad(arrays, len(keep)))
+    assert out.devices() == {dev}
+    assert np.asarray(out)[:len(keep)].all()
+    with pytest.raises(ValueError):
+        JaxTpuProvider(device=dev, mesh=object())
+
+
 def test_jaxtpu_refuses_a_cpu_nobody_asked_for(monkeypatch):
     """Where JAX finds no accelerator it falls back to the CPU silently;
     only JAX_PLATFORMS naming the CPU makes that the provider's device."""
@@ -231,3 +251,23 @@ def test_degrading_provider_delegates_primary_attributes():
 
 def test_empty_batch(tpu):
     assert tpu.batch_verify([]).shape == (0,)
+
+
+def test_warm_dispatches_exactly_the_named_shapes(tpu):
+    """provider.warm lands one dispatch on each named lane and bucket —
+    what POST /bccsp/warmup and chip_smoke.py rely on to keep compiles
+    out of the serving window."""
+    before = dict(tpu.stats)
+    timings = tpu.warm(generic=[128, 256], rows=[4])
+    assert sorted(timings) == ["generic@128", "generic@256", "rows@4"]
+    assert tpu.stats["dispatches"] - before["dispatches"] == 3
+    assert tpu.stats["device_sigs"] - before["device_sigs"] == (
+        128 + 129 + 4 * tpu.fast_row_c)
+    assert tpu.stats["fast_key_sigs"] - before["fast_key_sigs"] == (
+        4 * tpu.fast_row_c)
+    assert tpu.stats["fallbacks"] == 0
+    with pytest.raises(ValueError):
+        tpu.warm(generic=[300])
+    with pytest.raises(ValueError):
+        tpu.warm(rows=[5])
+    assert tpu.warm() == {}

@@ -334,6 +334,21 @@ def test_full_topology_endorse_order_commit_privdata(tmp_path):
         stop_nodes(procs)
 
 
+class SickDevice:
+    """A provider whose device fails every verify, as JaxTpuProvider
+    reports it under `bccsp_degrade: false`."""
+
+    def __init__(self, inner):
+        self._inner = inner
+
+    def batch_verify(self, items):
+        from fabric_tpu.bccsp.provider import DeviceError
+        raise DeviceError("injected: device resolve failed")
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+
 def test_peer_fail_stops_on_device_error(tmp_path):
     """`bccsp_degrade: false`: a DeviceError on the commit path stops the
     peer (main exits non-zero) instead of being retried forever or
@@ -353,17 +368,6 @@ def test_peer_fail_stops_on_device_error(tmp_path):
         pcfg = json.load(f)
     orderer = OrdererNode(ocfg, data_dir=ocfg["data_dir"]).start()
     peer = PeerNode(pcfg, data_dir=pcfg["data_dir"])
-
-    class SickDevice:
-        def __init__(self, inner):
-            self._inner = inner
-
-        def batch_verify(self, items):
-            raise DeviceError("injected: device resolve failed")
-
-        def __getattr__(self, name):
-            return getattr(self._inner, name)
-
     peer.provider = SickDevice(peer.provider)    # what the deliver loop asks
     peer.start()
     try:
@@ -381,6 +385,56 @@ def test_peer_fail_stops_on_device_error(tmp_path):
         assert peer._stop.wait(30.0), "peer kept running on a sick device"
         assert isinstance(peer.fatal, DeviceError)
         assert peer.ledger.height == 0       # nothing committed in its place
+    finally:
+        peer.stop()
+        orderer.stop()
+
+
+def test_peer_fail_stops_on_device_error_from_gossip(tmp_path):
+    """The same from the gossip intake thread: a block another peer
+    sent reaches the signature check and the committer through
+    `GossipState.handle`, whose transport would log and drop the error."""
+    from fabric_tpu.bccsp.provider import DeviceError
+    from fabric_tpu.config import BatchConfig
+    from fabric_tpu.gossip.state import MSG_BLOCK
+    from fabric_tpu.node.orderer import OrdererNode
+    from fabric_tpu.node.peer import PeerNode
+    from fabric_tpu.node.provision import provision_network
+    from fabric_tpu.orderer.deliver import SeekInfo
+
+    net = provision_network(
+        str(tmp_path), n_orderers=1, peer_orgs=["Org1"],
+        batch=BatchConfig(max_message_count=1, timeout_s=0.1))
+    with open(net["orderers"][0]) as f:
+        ocfg = json.load(f)
+    with open(net["peers"][0]) as f:
+        pcfg = json.load(f)
+    orderer = OrdererNode(ocfg, data_dir=ocfg["data_dir"]).start()
+    # never started: its deliver loop must not find the block first
+    peer = PeerNode(pcfg, data_dir=pcfg["data_dir"])
+    ch = peer.channels["ch"]
+    try:
+        cc, signer, msps = load_client(net["clients"]["Org1"])
+        addr = wait_orderer_leader([tuple(o) for o in cc["orderers"]],
+                                   signer, msps, deadline_s=30.0)
+        conn = connect(addr, signer, msps)
+        try:
+            out = conn.call("broadcast",
+                            {"envelope": _env(0, signer).serialize()},
+                            timeout=10.0)
+        finally:
+            conn.close()
+        assert out["status"] == 200, out
+        height = peer.ledger.height
+        block, _, _ = next(iter(ch.deliver_client.deliver(
+            "ch", SeekInfo(start=height, stop=height,
+                           behavior="block_until_ready"), timeout_s=10)))
+        ch.mcs.provider = SickDevice(ch.mcs.provider)
+        ch.gossip.state.handle(MSG_BLOCK, "127.0.0.1:1",
+                               {"block": block.serialize()})
+        assert peer._stop.is_set(), "peer kept running on a sick device"
+        assert isinstance(peer.fatal, DeviceError)
+        assert peer.ledger.height == height  # nothing committed in its place
     finally:
         peer.stop()
         orderer.stop()

@@ -18,9 +18,9 @@ class FakeDev:
 
 
 class FakeProvider:
-    def __init__(self, mesh):
+    def __init__(self, mesh, device=None):
         self.mesh = mesh
-        self.device_labels = ("cpu:0",)
+        self.device = device
 
 
 def _scheduler(n=8, **kw):
@@ -231,21 +231,22 @@ def test_scheduler_wrap_applied_once_per_span():
     assert len(wrapped) == 1
 
 
-def test_single_device_span_is_a_one_device_mesh():
+def test_single_device_span_is_meshless_on_its_own_chip():
     ps = _scheduler(n=2)
     ps.provider_for("a", demand=1)
     for _ in range(20):
         ps.provider_for("b", demand=1)
     ps.provider_for("a", demand=1)   # materialize a's span provider too
-    # both channels at 1 device each: each span provider is built over a
-    # mesh of exactly its own chip (a meshless provider would put banks
-    # and dispatches on devices()[0] whichever chip the span names)
+    # both channels at 1 device each: each span provider is meshless
+    # (the programs a one-chip node runs) and is handed its own chip —
+    # tests/test_bccsp.py shows the provider keeps banks and dispatches
+    # there rather than on devices()[0]
     spans = {v["span_start"] for v in ps.snapshot()["channels"].values()
              if v["devices"] == 1}
     assert spans == {0, 1}
     for lo in spans:
-        mesh = ps._providers[(lo, 1)].mesh
-        assert [d.id for d in mesh.devices.flat] == [lo]
+        p = ps._providers[(lo, 1)]
+        assert p.mesh is None and p.device.id == lo
 
 
 # -- factory wiring ----------------------------------------------------------
