@@ -32,6 +32,7 @@ parallel/placement.py (one provider per device subset).
 from __future__ import annotations
 
 import logging
+import time
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
 
@@ -40,6 +41,7 @@ import numpy as np
 from fabric_tpu.crypto import decode_dss_signature
 
 from . import provider as prov
+from .dispatch_account import DispatchAccount
 from .provider import (VerifyItem, SCHEME_P256, SCHEME_ED25519,
                        SCHEME_IDEMIX)
 from .sw import SoftwareProvider
@@ -156,6 +158,59 @@ def _bucket(n: int) -> int:
     while b < n:
         b <<= 1
     return b
+
+
+class _TableBuilds:
+    """The comb tables one batch makes resident: each build timed
+    (`provider_table_build_seconds`), and the stretch they took recorded
+    as one `provider.table_build` span under the batch's span."""
+
+    def __init__(self, bank, clock):
+        self.bank = bank
+        self.clock = clock
+        self.built = []              # (start, end) of each build
+
+    def get_or_build(self, pubkey: bytes):
+        n0 = self.bank.stats["builds"]
+        t0 = self.clock()
+        slot = self.bank.get_or_build(pubkey, pin=True)
+        if self.bank.stats["builds"] > n0:
+            self.built.append((t0, self.clock()))
+        return slot
+
+    def record(self, pending: "_Pending") -> None:
+        if not self.built:
+            return
+        build_s = sum(t1 - t0 for t0, t1 in self.built)
+        # the builds are not the next dispatch's packing
+        pending.t_mark += build_s
+        try:
+            from fabric_tpu.ops_plane import registry, tracing
+            hist = registry.histogram(
+                "provider_table_build_seconds",
+                "host build + upload of one key's comb table")
+            for t0, t1 in self.built:
+                hist.observe(t1 - t0)
+            if pending.span.recording:
+                tracing.tracer.record_span(
+                    "provider.table_build", self.built[0][0],
+                    self.built[-1][1], parent=pending.span.context,
+                    attributes={"keys": len(self.built),
+                                "build_s": round(build_s, 6)})
+        except Exception:
+            pass
+
+
+class _Pending(list):
+    """The dispatches of one batch awaiting resolve — (keep, out, post,
+    record) each — with what the dispatch account needs of the batch:
+    who asked, and the time its host packing is counted from."""
+
+    def __init__(self, site: str, t_call: float):
+        super().__init__()
+        self.site = site
+        self.t_mark = t_call
+        self.span = None             # the batch's bccsp.batch_verify span
 
 
 @dataclass(frozen=True)
@@ -282,6 +337,10 @@ class JaxTpuProvider(prov.Provider):
             devs = [device] if device is not None else devices[:1]
         self.device_labels = tuple(
             f"{d.platform}:{d.id}" for d in devs)
+        # the account of every dispatch (dispatch_account.py); the clock
+        # is an attribute so a test can script it
+        self.account = DispatchAccount(self.device_labels)
+        self._clock = time.perf_counter
 
     def stats_snapshot(self) -> ProviderStats:
         """Point-in-time copy of the provider's counters plus the table
@@ -527,23 +586,42 @@ class JaxTpuProvider(prov.Provider):
         except Exception:
             pass
 
-    def _dispatch(self, fn, keep, arrays, pending, extra_args=()):
+    def _dispatched(self, pending, lane: str, program: str, sigs: int,
+                    t_enq0: float):
+        """A lane's compiled program was just called (at `t_enq0`, the
+        call has returned): count it and leave its record in the
+        account.  Every dispatch comes through here, so the account's
+        totals are the stats' own.  The call itself stays in its lane's
+        own function: a first call traces and lowers the program, and
+        that was measured 14 s slower for rows@384 when the call went
+        through a shared `fn(*args)` helper (PERF.md §6, PR 24)."""
+        t_enq1 = self._clock()
+        self.stats["dispatches"] += 1
+        self.stats["device_sigs"] += sigs
+        rec = self.account.enqueued(lane, program, pending.site, sigs,
+                                    pending.t_mark, t_enq0, t_enq1)
+        pending.t_mark = t_enq1
+        return rec
+
+    def _dispatch(self, fn, keep, arrays, pending, kind="generic"):
         """Pad to buckets, chunk beyond MAX_BUCKET (bounds the compiled-
         program set while arbitrarily large blocks still use the device),
         ENQUEUE the device calls (jax dispatch is async), and record
-        (keep, out) pairs for the resolve step."""
+        (keep, out, post, record) for the resolve step.  `kind` names
+        the program family (`generic@128`, `ed25519@128`)."""
         for lo in range(0, len(keep), MAX_BUCKET):
             hi = min(lo + MAX_BUCKET, len(keep))
             chunk = [a[..., lo:hi] for a in arrays]
             padded = self._pad(chunk, hi - lo)
-            out = fn(*extra_args, *padded)
-            self.stats["dispatches"] += 1
-            self.stats["device_sigs"] += hi - lo
+            bucket = int(np.asarray(padded[0]).shape[-1])
+            t_enq0 = self._clock()
+            out = fn(*padded)
+            rec = self._dispatched(pending, "generic", f"{kind}@{bucket}",
+                                   hi - lo, t_enq0)
             self.stats["h2d_bytes"] += sum(
                 np.asarray(a).nbytes for a in padded)
-            self._observe_lane("generic", hi - lo,
-                               int(np.asarray(padded[0]).shape[-1]))
-            pending.append((keep[lo:hi], out))
+            self._observe_lane("generic", hi - lo, bucket)
+            pending.append((keep[lo:hi], out, None, rec))
 
     # Row-grid geometry for the fast lane (ops/p256_fixed.verify_words_
     # rows): signatures pack key-major into rows of FAST_ROW_C lanes, so
@@ -635,6 +713,7 @@ class JaxTpuProvider(prov.Provider):
         # another thread) must not evict it, or its rows would verify
         # against the wrong table
         pinned = set()
+        builds = _TableBuilds(self.key_tables, self._clock)
         try:
             for g in np.argsort(-counts, kind="stable"):
                 g = int(g)
@@ -643,10 +722,11 @@ class JaxTpuProvider(prov.Provider):
                 pk = pks[g]
                 slot = self.key_tables.lookup(pk, pin=True)
                 if slot is None and counts[g] >= self.fast_key_threshold:
-                    slot = self.key_tables.get_or_build(pk, pin=True)
+                    slot = builds.get_or_build(pk)
                 if slot is not None:
                     pinned.add(slot)
                     slots[g] = slot
+            builds.record(pending)
             fsel = np.nonzero(valid & (slots[key_ids] >= 0))[0]
             if fsel.size:
                 self._dispatch_rows_vec(fsel, key_ids, slots, rsw, ew,
@@ -724,10 +804,12 @@ class JaxTpuProvider(prov.Provider):
                 np.ascontiguousarray(rsw[flat, :8].T).reshape(8, Rb, C),
                 np.ascontiguousarray(rsw[flat, 8:].T).reshape(8, Rb, C),
                 np.ascontiguousarray(ew[flat].T).reshape(8, Rb, C)]
+            t_enq0 = self._clock()
             out = fn(bank, rk, *words)
             self.stats["h2d_bytes"] += (
                 sum(w.nbytes for w in words) + rk.nbytes)
-            self._enqueue_rows_out(out, og.reshape(-1), pending)
+            self._enqueue_rows_out(out, og.reshape(-1), pending,
+                                   f"rows@{Rb}", t_enq0)
 
     def _verify_p256_recs(self, items, idxs, pending):
         """Rec-based fallback lane split (no C extension)."""
@@ -737,17 +819,19 @@ class JaxTpuProvider(prov.Provider):
             groups.setdefault(rec[1], []).append(rec)
         generic, fast = [], []
         pinned = set()
+        builds = _TableBuilds(self.key_tables, self._clock)
         try:
             for pk, g in sorted(groups.items(),
                                 key=lambda kv: -len(kv[1])):
                 slot = self.key_tables.lookup(pk, pin=True)
                 if slot is None and len(g) >= self.fast_key_threshold:
-                    slot = self.key_tables.get_or_build(pk, pin=True)
+                    slot = builds.get_or_build(pk)
                 if slot is None:
                     generic.extend(g)
                 else:
                     pinned.add(slot)
                     fast.append((slot, g))
+            builds.record(pending)
             # largest groups first: keeps per-dispatch row chunks dense
             fast.sort(key=lambda t: -len(t[1]))
             if fast:
@@ -809,12 +893,14 @@ class JaxTpuProvider(prov.Provider):
             out.append((row_key, frecs, slots, Rb))
         return out
 
-    def _enqueue_rows_out(self, out, slots, pending):
-        self.stats["dispatches"] += 1
+    def _enqueue_rows_out(self, out, slots, pending, program, t_enq0):
+        """One row-grid dispatch, just called: `slots` are the batch
+        positions of the grid's cells, -1 for padding (dropped at
+        resolve)."""
         slots_np = np.asarray(slots)
         valid = slots_np >= 0
         keep = slots_np[valid]
-        self.stats["device_sigs"] += len(keep)
+        rec = self._dispatched(pending, "rows", program, len(keep), t_enq0)
         self.stats["fast_key_sigs"] += len(keep)
         # rows-lane pad slots interleave (within-row pad + pad rows), so
         # the per-device split counts the valid mask over each device's
@@ -829,9 +915,7 @@ class JaxTpuProvider(prov.Provider):
         self._observe_lane("rows", len(keep), len(slots_np),
                            per_device=per_device)
         pending.append(
-            (keep,
-             lambda out=out, valid=valid:
-                 np.asarray(out).reshape(-1)[valid]))
+            (keep, out, lambda a, valid=valid: a.reshape(-1)[valid], rec))
 
     def _dispatch_rows(self, fast, pending):
         """P-256 row-grid dispatches (fast: [(bank_slot, recs)], recs:
@@ -846,10 +930,12 @@ class JaxTpuProvider(prov.Provider):
                 [rec[j] for rec in frecs]).reshape(8, Rb, C)
                 for j in (2, 3, 4)]
             rk = np.asarray(row_key, dtype=np.int32)
+            t_enq0 = self._clock()
             out = fn(bank, rk, *words)
             self.stats["h2d_bytes"] += (
                 sum(w.nbytes for w in words) + rk.nbytes)
-            self._enqueue_rows_out(out, slots, pending)
+            self._enqueue_rows_out(out, slots, pending, f"rows@{Rb}",
+                                   t_enq0)
 
     def _dispatch_ed_rows(self, fast, pending):
         """ed25519 row-grid dispatches (fast: [(bank_slot, recs)], recs:
@@ -866,10 +952,12 @@ class JaxTpuProvider(prov.Provider):
             args = (ry.reshape(8, Rb, C),
                     r_sign.reshape(Rb, C).astype(np.int32),
                     s.reshape(8, Rb, C), k.reshape(8, Rb, C))
+            t_enq0 = self._clock()
             out = fn(bank, rk, *args)
             self.stats["h2d_bytes"] += (
                 sum(np.asarray(a).nbytes for a in args) + rk.nbytes)
-            self._enqueue_rows_out(out, slots, pending)
+            self._enqueue_rows_out(out, slots, pending,
+                                   f"ed25519-rows@{Rb}", t_enq0)
 
     def _verify_ed25519(self, items, idxs, pending):
         """Two-lane ed25519 dispatch (the P-256 design): cached-A keys
@@ -887,17 +975,19 @@ class JaxTpuProvider(prov.Provider):
             groups.setdefault(rec[1], []).append(rec)
         fast, generic = [], []
         pinned = set()
+        builds = _TableBuilds(self.ed_key_tables, self._clock)
         try:
             for pk, g in sorted(groups.items(),
                                 key=lambda kv: -len(kv[1])):
                 slot = self.ed_key_tables.lookup(pk, pin=True)
                 if slot is None and len(g) >= self.fast_key_threshold:
-                    slot = self.ed_key_tables.get_or_build(pk, pin=True)
+                    slot = builds.get_or_build(pk)
                 if slot is None:
                     generic.extend(g)
                 else:
                     pinned.add(slot)
                     fast.append((slot, g))
+            builds.record(pending)
             fast.sort(key=lambda t: -len(t[1]))
             if fast:
                 self._dispatch_ed_rows(fast, pending)
@@ -911,7 +1001,7 @@ class JaxTpuProvider(prov.Provider):
                 [rec[1] for rec in generic], [rec[2] for rec in generic],
                 [rec[3] for rec in generic]))
             self._dispatch(self._get_fn(SCHEME_ED25519), keep, arrays,
-                           pending)
+                           pending, kind="ed25519")
 
     # -- idemix: batched BN254 pairing checks (BASELINE config 4) -----------
 
@@ -959,7 +1049,7 @@ class JaxTpuProvider(prov.Provider):
                 from fabric_tpu.idemix.msp import verify_item_host
                 return np.array([verify_item_host(it) for it in its],
                                 dtype=bool)
-            pending.append((idxs, _idemix_out))
+            pending.append((idxs, _idemix_out, None, None))
             return
 
         from fabric_tpu.idemix import bn254 as hb
@@ -990,12 +1080,13 @@ class JaxTpuProvider(prov.Provider):
             x2 = np.stack([bnmod.int_to_limbs(p[2][0]) for p in padded], 1)
             y2 = np.stack([bnmod.int_to_limbs((hb.P - p[2][1]) % hb.P)
                            for p in padded], 1)
+            t_enq0 = self._clock()
             out = fn(packed_w["flags"], packed_w["A"], packed_w["B"],
                      packed_g2["A"], packed_g2["B"], x1, y1, x2, y2)
-            self.stats["dispatches"] += 1
-            self.stats["device_sigs"] += len(g)
+            rec = self._dispatched(pending, "idemix", f"idemix@{b}", len(g),
+                                   t_enq0)
             self._observe_lane("idemix", len(g), b)
-            pending.append(([p[0] for p in g], out))
+            pending.append(([p[0] for p in g], out, None, rec))
 
     def idemix_pair_probe(self, batch: int = None):
         """(fn, green_args, red_args) for the BN254 dual-pairing lane:
@@ -1027,7 +1118,6 @@ class JaxTpuProvider(prov.Provider):
         persistent cache) outside it, so the compiles of different
         shapes overlap."""
         import hashlib
-        import time
         from concurrent.futures import ThreadPoolExecutor
 
         def signed(n_keys: int) -> list:
@@ -1071,7 +1161,9 @@ class JaxTpuProvider(prov.Provider):
         def one(job):
             name, items = job
             t0 = time.perf_counter()
-            if not self.batch_verify(items).all():
+            with prov.dispatch_site("warmup"):
+                ok = self.batch_verify(items).all()
+            if not ok:
                 raise RuntimeError(f"warm {name}: bad verdicts")
             return name, round(time.perf_counter() - t0, 3)
 
@@ -1091,9 +1183,9 @@ class JaxTpuProvider(prov.Provider):
         with `degrade` it recomputes the whole batch on the sw provider
         instead (atomic: never a mix of device and sw verdicts)."""
         from fabric_tpu.ops_plane import tracing
+        pending = _Pending(prov.current_site(), self._clock())
         items = list(items)
         verdicts = np.zeros(len(items), dtype=bool)
-        pending = []
         # device-time bridge: one span per dispatched batch, started at
         # enqueue on the caller's trace and ended from whichever thread
         # resolves it, carrying batch size, block_until_ready wall time
@@ -1102,6 +1194,7 @@ class JaxTpuProvider(prov.Provider):
             "bccsp.batch_verify", require_parent=True,
             attributes={"provider": self.name, "batch_size": len(items)})
         snap0 = self.stats_snapshot() if span.recording else None
+        pending.span = span
         try:
             by_scheme = {}
             for i, it in enumerate(items):
@@ -1144,13 +1237,18 @@ class JaxTpuProvider(prov.Provider):
             pass
 
         def resolve():
-            import time as _time
-            t0 = _time.perf_counter()
+            t0 = time.perf_counter()
             try:
-                for keep, out in pending:
-                    if callable(out):
+                for keep, out, post, rec in pending:
+                    if callable(out):        # host-side lane (idemix on cpu)
                         out = out()
-                    verdicts[np.asarray(keep)] = np.asarray(out)[:len(keep)]
+                    if rec is not None:
+                        observed = self._await(out)
+                        self.account.ready(rec, self._clock(), observed)
+                    out = np.asarray(out)
+                    if post is not None:
+                        out = post(out)
+                    verdicts[np.asarray(keep)] = out[:len(keep)]
             except Exception as exc:
                 if not self.degrade:
                     span.end(status="ERROR")
@@ -1164,10 +1262,14 @@ class JaxTpuProvider(prov.Provider):
                 span.end(status="ERROR")
                 self._drain_queue_depth(len(pending))
                 return self.fallback.batch_verify(items)
-            wall = _time.perf_counter() - t0
+            wall = time.perf_counter() - t0
             self._drain_queue_depth(len(pending))
             if span.recording:
                 snap1 = self.stats_snapshot()
+                span.set_attribute(
+                    "dispatch_records",
+                    [rec.as_attribute() for _, _, _, rec in pending
+                     if rec is not None])
                 span.set_attribute("block_until_ready_s", round(wall, 6))
                 span.set_attribute(
                     "dispatches", snap1.dispatches - snap0.dispatches)
@@ -1190,10 +1292,6 @@ class JaxTpuProvider(prov.Provider):
                 registry.histogram(
                     "provider_resolve_seconds",
                     "batch_verify device resolve wait").observe(wall)
-                registry.gauge(
-                    "provider_device_sync_seconds",
-                    "last batch_verify device-sync (resolve) wait"
-                    ).set(wall)
                 registry.counter(
                     "provider_device_sigs_total",
                     "signatures resolved on device").add(len(items))
@@ -1202,6 +1300,18 @@ class JaxTpuProvider(prov.Provider):
             return verdicts
 
         return resolve
+
+    @staticmethod
+    def _await(out) -> bool:
+        """Block until a program's output is ready.  -> True when the
+        wait saw it become ready; False when it already was, so that
+        its end is only known to lie before now (or the lane computed
+        on the host and there was nothing to wait for)."""
+        is_ready = getattr(out, "is_ready", None)
+        if is_ready is None or is_ready():
+            return False
+        out.block_until_ready()
+        return True
 
     def _drain_queue_depth(self, n: int) -> None:
         if not n:

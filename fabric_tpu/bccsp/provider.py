@@ -8,7 +8,9 @@ Signing always stays on the host CPU — private keys never touch the TPU.
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
+import threading
 from dataclasses import dataclass, field
 from typing import NamedTuple, Optional, Sequence
 
@@ -52,6 +54,33 @@ def hash_payload(data: bytes, algo: str = HASH_SHA256) -> bytes:
         return hashlib.new(algo, data).digest()
     except ValueError as e:
         raise ValueError(f"unsupported hash {algo!r}") from e
+
+
+# Who asked: the site a verification is made for, read by a device
+# provider when it accounts for a dispatch.  Ambient on the calling
+# thread, so the wrapping providers (verify_plane.cache.CachingProvider,
+# degrade.DegradingProvider) carry it without an argument of their own.
+DISPATCH_SITES = ("handshake", "endorser", "gateway_ingress", "speculative",
+                  "validator", "block_sig", "warmup", "other")
+_site = threading.local()
+
+
+@contextlib.contextmanager
+def dispatch_site(name: str):
+    """Verifications made on this thread inside the block are `name`'s
+    (one of DISPATCH_SITES).  The innermost block wins."""
+    if name not in DISPATCH_SITES:
+        raise ValueError(f"unknown dispatch site {name!r}")
+    outer = current_site()
+    _site.name = name
+    try:
+        yield
+    finally:
+        _site.name = outer
+
+
+def current_site() -> str:
+    return getattr(_site, "name", "other")
 
 
 class DeviceError(RuntimeError):

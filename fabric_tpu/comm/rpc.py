@@ -209,6 +209,8 @@ class RpcServer:
 
     def __init__(self, host: str, port: int, signer, msps: Dict):
         self._unary: Dict[str, Callable] = {}
+        # methods whose request roots a trace when its frame brought none
+        self._root_trace: set = set()
         self._stream: Dict[str, Callable] = {}
         self._cast: Dict[str, Callable] = {}
         self._cancelled: dict = {}         # (channel id, rid) -> True
@@ -223,8 +225,15 @@ class RpcServer:
     def addr(self):
         return self.server.addr
 
-    def serve(self, method: str, fn: Callable) -> None:
+    def serve(self, method: str, fn: Callable,
+              root_trace: bool = False) -> None:
+        """`root_trace`: a request whose frame carried no trace context
+        starts a trace of its own here (at the tracer's sample rate)
+        instead of going untraced — for the verbs where requests enter
+        the system (the gateway's)."""
         self._unary[method] = fn
+        if root_trace:
+            self._root_trace.add(method)
 
     def serve_stream(self, method: str, fn: Callable) -> None:
         self._stream[method] = fn
@@ -286,10 +295,12 @@ class RpcServer:
         t0 = _time.perf_counter()
         ok = True
         # continue the caller's trace (W3C traceparent carried in the
-        # frame's "tp" field); no tp => no span, untraced traffic is free
+        # frame's "tp" field); no tp => no span, untraced traffic is
+        # free — except on an entry verb, which then roots the trace
         ctx = tracing.tracer.context_from(msg.get("tp"))
-        span = tracing.tracer.start_span("rpc." + method, parent=ctx,
-                                         require_parent=True)
+        span = tracing.tracer.start_span(
+            "rpc." + method, parent=ctx,
+            require_parent=method not in self._root_trace)
         span.__enter__()
         try:
             if method in self._stream:

@@ -40,6 +40,7 @@ from fabric_tpu.crypto import (
     hkdf_sha256,
 )
 
+from fabric_tpu.ops_plane import tracing
 from fabric_tpu.utils import serde
 
 from . import faults as _faults
@@ -129,8 +130,10 @@ def _verify_peer(hello: dict, transcript: bytes, sig: bytes, msps: Dict):
     if ident is None:
         raise HandshakeError("peer identity not valid in any channel MSP")
     from fabric_tpu.bccsp.factory import get_default
+    from fabric_tpu.bccsp.provider import dispatch_site
     item = ident.verify_item(transcript, sig)
-    ok = get_default().batch_verify([item])
+    with dispatch_site("handshake"):
+        ok = get_default().batch_verify([item])
     if not bool(ok[0]):
         raise HandshakeError("bad handshake transcript signature")
     return ident
@@ -138,33 +141,44 @@ def _verify_peer(hello: dict, transcript: bytes, sig: bytes, msps: Dict):
 
 def _handshake(sock: socket.socket, signer, msps: Dict,
                initiator: bool) -> SecureChannel:
-    eph = X25519PrivateKey.generate()
-    my_hello = serde.encode({
-        "identity": signer.serialize(),
-        "eph": eph.public_key().public_bytes_raw(),
-        "nonce": os.urandom(16),
-    })
-    if initiator:
-        _write_frame(sock, my_hello)
-        peer_hello_b = _read_frame(sock)
-        transcript = hashlib.sha256(my_hello + peer_hello_b).digest()
-    else:
-        peer_hello_b = _read_frame(sock)
-        _write_frame(sock, my_hello)
-        transcript = hashlib.sha256(peer_hello_b + my_hello).digest()
-    peer_hello = serde.decode(peer_hello_b)
+    # under the dialer's span (the gateway's fan-out).  An accepted
+    # connection has no context yet, so the responder's side records
+    # nothing until the hello carries the dialer's.  The body stays in
+    # this function: a first signature check on a device provider traces
+    # its program from here, and one more frame above a trace was
+    # measured to slow it by seconds (PERF.md §6, PR 24)
+    with tracing.tracer.start_span(
+            "comm.handshake", require_parent=True,
+            attributes={"role": "initiator" if initiator
+                        else "responder"}):
+        eph = X25519PrivateKey.generate()
+        my_hello = serde.encode({
+            "identity": signer.serialize(),
+            "eph": eph.public_key().public_bytes_raw(),
+            "nonce": os.urandom(16),
+        })
+        if initiator:
+            _write_frame(sock, my_hello)
+            peer_hello_b = _read_frame(sock)
+            transcript = hashlib.sha256(my_hello + peer_hello_b).digest()
+        else:
+            peer_hello_b = _read_frame(sock)
+            _write_frame(sock, my_hello)
+            transcript = hashlib.sha256(peer_hello_b + my_hello).digest()
+        peer_hello = serde.decode(peer_hello_b)
 
-    my_sig = signer.sign(transcript)
-    _write_frame(sock, my_sig)
-    peer_sig = _read_frame(sock)
-    ident = _verify_peer(peer_hello, transcript, peer_sig, msps)
+        my_sig = signer.sign(transcript)
+        _write_frame(sock, my_sig)
+        peer_sig = _read_frame(sock)
+        ident = _verify_peer(peer_hello, transcript, peer_sig, msps)
 
-    shared = eph.exchange(X25519PublicKey.from_public_bytes(peer_hello["eph"]))
-    k_init = _hkdf(shared, transcript, b"fabric-tpu-i2r")
-    k_resp = _hkdf(shared, transcript, b"fabric-tpu-r2i")
-    if initiator:
-        return SecureChannel(sock, ident, k_init, k_resp)
-    return SecureChannel(sock, ident, k_resp, k_init)
+        shared = eph.exchange(
+            X25519PublicKey.from_public_bytes(peer_hello["eph"]))
+        k_init = _hkdf(shared, transcript, b"fabric-tpu-i2r")
+        k_resp = _hkdf(shared, transcript, b"fabric-tpu-r2i")
+        if initiator:
+            return SecureChannel(sock, ident, k_init, k_resp)
+        return SecureChannel(sock, ident, k_resp, k_init)
 
 
 def dial(addr, signer, msps: Dict, timeout: float = 10.0) -> SecureChannel:

@@ -68,6 +68,10 @@ class Committer:
                 attributes={"channel": self.validator.channel_id,
                             "block": int(block.header.number),
                             "txs": n_txs(block)}) as span:
+            # when wire.parse_block made this block, before the hand-off
+            parsed = getattr(block, "parsed", None)
+            if parsed is not None:
+                tracing.tracer.record_span("wire.parse_block", *parsed)
             result = self._store_block_inner(block)
             if span.recording:
                 span.set_attribute("valid",
@@ -85,9 +89,8 @@ class Committer:
         if isinstance(pre, BlockCommitResult):
             return pre
         vr, new_cfg = pre
-        t_commit = time.perf_counter()
         stats = self.ledger.commit(block)
-        return self._postcommit(block, vr, stats, new_cfg, t_commit)
+        return self._postcommit(block, vr, stats, new_cfg)
 
     def _precommit(self, block: Block):
         """Everything that must happen BEFORE the ledger commit: the
@@ -100,10 +103,14 @@ class Committer:
         from fabric_tpu.protocol.txflags import TxFlags, ValidationCode
         from fabric_tpu.protocol.types import META_TXFLAGS
 
+        t0 = time.perf_counter()
         replayed = self._check_replay(block)
         if replayed is not None:
             return replayed
+        tracing.tracer.record_span("committer.replay_check", t0,
+                                   time.perf_counter())
         vr = self.validator.validate(block)
+        t_validated = time.perf_counter()
         # Commit-time config validation happens BEFORE the commit: a config
         # tx that fails (wrong sequence, Admins unsatisfied) must be
         # recorded with an INVALID flag, never committed as VALID with the
@@ -196,19 +203,27 @@ class Committer:
                          block=int(block.header.number))
                     flags.set(0, ValidationCode.INVALID_CONFIG_TRANSACTION)
                     block.metadata.items[META_TXFLAGS] = flags.to_bytes()
+        tracing.tracer.record_span("committer.config_check", t_validated,
+                                   time.perf_counter())
         return vr, new_cfg
 
-    def _postcommit(self, block: Block, vr, stats, new_cfg,
-                    t_commit: float) -> BlockCommitResult:
+    def _postcommit(self, block: Block, vr, stats,
+                    new_cfg) -> BlockCommitResult:
         """Everything AFTER the ledger commit: phase spans, metrics,
         commit listeners, and (for a valid config tx) the channel bundle
         application.  Runs on the retire thread under the pipeline."""
         from fabric_tpu.protocol.txflags import TxFlags
         from fabric_tpu.protocol.types import META_TXFLAGS
 
-        self._record_phase_spans(t_commit, stats)
+        # from the end of the ledger's last phase: its own tail (apply
+        # metrics, the log line) belongs here too
+        t0 = (stats.phase_spans[-1][2] if stats.phase_spans
+              else time.perf_counter())
+        self._record_phase_spans(stats)
         final = TxFlags.from_bytes(block.metadata.items[META_TXFLAGS])
         self._observe_metrics(block, vr, stats)
+        tracing.tracer.record_span("committer.observe", t0,
+                                   time.perf_counter())
         with tracing.tracer.start_span(
                 "committer.notify", require_parent=True,
                 attributes={"listeners": len(self._commit_listeners)}):
@@ -272,20 +287,11 @@ class Committer:
         return BlockCommitResult(None, None, final)
 
     @staticmethod
-    def _record_phase_spans(t0: float, stats) -> None:
-        """Retroactive child spans for the sequential ledger commit
-        phases, laid end-to-end from the commit start using the wall
-        times CommitStats already measured (kvledger.commit)."""
-        base = t0
-        for attr, name in (("state_validation_s", "ledger.mvcc"),
-                           ("block_commit_s", "ledger.block_commit"),
-                           ("state_commit_s", "ledger.state_commit"),
-                           ("history_commit_s", "ledger.history_commit")):
-            dur = getattr(stats, attr, None)
-            if dur is None:
-                continue
-            tracing.tracer.record_span(name, base, base + dur)
-            base += dur
+    def _record_phase_spans(stats) -> None:
+        """Retroactive child spans for the ledger commit phases, each
+        where it really ran (kvledger stamps the intervals)."""
+        for name, start, end in stats.phase_spans:
+            tracing.tracer.record_span(name, start, end)
 
     def _observe_metrics(self, block, vr, stats) -> None:
         """Per-phase commit metrics (metric parity: the reference's
@@ -429,10 +435,9 @@ class PipelinedCommitter:
                 return
             fut, block, ticket, vr, new_cfg = item
             try:
-                t_commit = time.perf_counter()
                 stats = self.ledger.commit_finish(ticket)
                 result = self.committer._postcommit(
-                    block, vr, stats, new_cfg, t_commit)
+                    block, vr, stats, new_cfg)
                 fut._set(result)
             except BaseException as exc:  # noqa: BLE001
                 self._break(exc, fut)

@@ -37,6 +37,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from fabric_tpu.bccsp import SCHEME_P256, VerifyItem
+from fabric_tpu.bccsp.provider import dispatch_site
 from fabric_tpu.msp import Identity
 from fabric_tpu.ops_plane import tracing
 from fabric_tpu.policy import PolicyEvaluator, SignaturePolicy, SignedData
@@ -664,7 +665,11 @@ class TxValidator:
                 # stale epoch — goes through the full dispatch below
                 hits: list = []
                 if cache is not None:
+                    t_filter = time.perf_counter()
                     miss_pos, raw_hits = cache.filter(new)
+                    tracing.tracer.record_span(
+                        "validator.cache_filter", t_filter,
+                        time.perf_counter(), attributes={"items": len(new)})
                     hits = [(new[i], v, tr) for i, v, tr in raw_hits]
                     new = [new[i] for i in miss_pos]
                     hit_n += len(hits)
@@ -678,8 +683,9 @@ class TxValidator:
                     flushed = len(keys)
                     return
                 # items are their OWN dedup keys (VerifyItem NamedTuple)
-                resolve = self._resolve_provider(
-                    len(new)).batch_verify_async(new)
+                with dispatch_site("validator"):
+                    resolve = self._resolve_provider(
+                        len(new)).batch_verify_async(new)
                 # EAGER background resolution: start fetching results
                 # the moment the dispatch is enqueued.  Relayed device
                 # transports serialize a result read behind any LATER
@@ -829,7 +835,11 @@ class TxValidator:
                 # verify-once partition — same contract as the classic
                 # flush: only MAC-verified fresh hits skip the device
                 if cache is not None:
+                    t_filter = time.perf_counter()
                     miss_pos, raw_hits = cache.filter(new)
+                    tracing.tracer.record_span(
+                        "validator.cache_filter", t_filter,
+                        time.perf_counter(), attributes={"items": len(new)})
                     positions = [flushed + i for i in miss_pos]
                     for i, v, tr in raw_hits:
                         hit_fills.append((flushed + i, v))
@@ -843,8 +853,9 @@ class TxValidator:
                         return
                 else:
                     positions = list(range(flushed, flushed + len(new)))
-                resolve = self._resolve_provider(
-                    len(new)).batch_verify_async(new)
+                with dispatch_site("validator"):
+                    resolve = self._resolve_provider(
+                        len(new)).batch_verify_async(new)
                 # eager background resolution — same rationale as the
                 # classic path's flush(): keep the result fetch ahead of
                 # any later dispatch on relayed transports
@@ -951,6 +962,16 @@ class TxValidator:
         except Exception:
             pass
 
+    def _store_verdicts(self, cache, items, verdicts) -> None:
+        """The dispatch's verdicts into the node's verdict cache — a
+        digest and a MAC per item, inside the dispatch-wait clock: its
+        own span, so that wait is told from this."""
+        t0 = time.perf_counter()
+        cache.store(items, verdicts, site="commit", scope=self.channel_id)
+        tracing.tracer.record_span(
+            "validator.cache_store", t0, time.perf_counter(),
+            attributes={"items": len(items)})
+
     def _finish_deep(self, state: dict) -> ValidationResult:
         block = state["block"]
         codes = state["codes"]
@@ -965,8 +986,7 @@ class TxValidator:
         for resolve, positions, sub in state["resolvers"]:
             out = resolve()
             if cache is not None:
-                cache.store(sub, out, site="commit",
-                            scope=self.channel_id)
+                self._store_verdicts(cache, sub, out)
             verdict[np.asarray(positions, dtype=np.intp)] = \
                 np.asarray(out, dtype=bool)
         self._note_coverage(state)
@@ -994,6 +1014,7 @@ class TxValidator:
             attributes={"block": int(block.header.number),
                         "txs": len(state["plans"])})
 
+        t0 += gate_s
         block.metadata.items[META_TXFLAGS] = flags.to_bytes()
         self._observe_block(collect_s, dispatch_s, gate_s)
         logger.info(
@@ -1002,6 +1023,10 @@ class TxValidator:
             self.channel_id, block.header.number, flags.valid_count(),
             n_txs(block), collect_s * 1e3, dispatch_s * 1e3,
             len(index), gate_s * 1e3)
+        # the flags' way into the block's metadata (a BlockView decodes
+        # its metadata here), the stage metrics, the log line
+        tracing.tracer.record_span("validator.finish", t0,
+                                   time.perf_counter())
         return ValidationResult(flags, collect_s, dispatch_s, gate_s,
                                 state["n_refs"], len(index))
 
@@ -1025,8 +1050,7 @@ class TxValidator:
                 continue
             out = resolve()
             if cache is not None:
-                cache.store(chunk_keys, out, site="commit",
-                            scope=self.channel_id)
+                self._store_verdicts(cache, chunk_keys, out)
             verdict.update(
                 (k, bool(v)) for k, v in zip(chunk_keys, out))
         self._note_coverage(state)
@@ -1059,6 +1083,7 @@ class TxValidator:
                         "txs": len(works)})
 
         n_refs = sum(1 + sum(len(s) for _, _, s in w.namespaces) for w in works)
+        t0 += gate_s
         block.metadata.items[META_TXFLAGS] = flags.to_bytes()
         self._observe_block(collect_s, dispatch_s, gate_s)
         logger.info(
@@ -1067,6 +1092,8 @@ class TxValidator:
             self.channel_id, block.header.number, flags.valid_count(),
             len(block.data), collect_s * 1e3, dispatch_s * 1e3, len(keys),
             gate_s * 1e3)
+        tracing.tracer.record_span("validator.finish", t0,
+                                   time.perf_counter())
         return ValidationResult(flags, collect_s, dispatch_s, gate_s,
                                 n_refs, len(keys))
 

@@ -16,6 +16,7 @@ import logging
 from dataclasses import dataclass
 from typing import Dict, Optional
 
+from fabric_tpu.bccsp.provider import dispatch_site
 from fabric_tpu.chaincode import ChaincodeRegistry, ChaincodeStub, SimulationError
 from fabric_tpu.endorser.proposal import (
     Proposal,
@@ -81,7 +82,8 @@ class Endorser:
         status to the client in all failure modes."""
         try:
             with tracing.tracer.start_span("endorser.validate",
-                                           require_parent=True):
+                                           require_parent=True), \
+                    dispatch_site("endorser"):
                 prop, creator = self._validate(sp)
             with tracing.tracer.start_span(
                     "endorser.simulate", require_parent=True,

@@ -23,6 +23,7 @@ import time
 from collections import OrderedDict
 from typing import Dict, List, Optional
 
+from fabric_tpu.bccsp.provider import dispatch_site
 from fabric_tpu.comm import connect
 from fabric_tpu.endorser.proposal import SignedProposal
 from fabric_tpu.gateway import admission as _admission
@@ -134,10 +135,13 @@ class GatewayService:
     # lifecycle ---------------------------------------------------------
 
     def register(self, rpc) -> None:
-        rpc.serve("gateway.evaluate", self._rpc_evaluate)
-        rpc.serve("gateway.endorse", self._rpc_endorse)
-        rpc.serve("gateway.submit", self._rpc_submit)
-        rpc.serve("gateway.commit_status", self._rpc_commit_status)
+        # requests enter the system here: a verb whose frame brought no
+        # trace context roots the request's trace itself
+        for verb, fn in (("evaluate", self._rpc_evaluate),
+                         ("endorse", self._rpc_endorse),
+                         ("submit", self._rpc_submit),
+                         ("commit_status", self._rpc_commit_status)):
+            rpc.serve("gateway." + verb, fn, root_trace=True)
 
     def register_ops(self, ops) -> None:
         """Mount GET /gateway on the hosting node's ops server: live
@@ -275,14 +279,19 @@ class GatewayService:
                         "signature": body["signature"],
                         "channel": ch.channel_id}
             for addr in self.node.peers:
+                # one span per target peer: dial + handshake + call
                 try:
-                    conn = connect(tuple(addr[:2]), self.node.signer,
-                                   ch.msps, timeout=self.fan_dial_timeout_s)
-                    try:
-                        out = conn.call("endorse", fan_body,
-                                        timeout=self.fan_call_timeout_s)
-                    finally:
-                        conn.close()
+                    with tracing.tracer.start_span(
+                            "gateway.fanout", require_parent=True,
+                            attributes={"peer": f"{addr[0]}:{addr[1]}"}):
+                        conn = connect(tuple(addr[:2]), self.node.signer,
+                                       ch.msps,
+                                       timeout=self.fan_dial_timeout_s)
+                        try:
+                            out = conn.call("endorse", fan_body,
+                                            timeout=self.fan_call_timeout_s)
+                        finally:
+                            conn.close()
                 except Exception as exc:
                     errors.append(f"{addr[0]}:{addr[1]}: {exc}")
                     continue
@@ -484,10 +493,11 @@ class GatewayService:
             spec = getattr(self.node, "speculative", None)
             if spec is not None:
                 try:
-                    attests = spec.stamp(
-                        [p.raw for p in batch],
-                        [p.channel_id for p in batch],
-                        spans=spans_order)
+                    with dispatch_site("gateway_ingress"):
+                        attests = spec.stamp(
+                            [p.raw for p in batch],
+                            [p.channel_id for p in batch],
+                            spans=spans_order)
                 except Exception:
                     logger.exception("verify-plane ingress stamp failed")
                     attests = None

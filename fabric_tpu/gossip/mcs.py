@@ -13,6 +13,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from fabric_tpu.bccsp.provider import dispatch_site
 from fabric_tpu.msp import deserialize_from_msps
 from fabric_tpu.orderer.blockwriter import block_signature_items
 from fabric_tpu.protocol import Block
@@ -36,7 +37,8 @@ class MessageCryptoService:
         items = self.block_verify_items(block)
         if not items:
             return False
-        return bool(np.asarray(self.provider.batch_verify(items)).all())
+        with dispatch_site("block_sig"):
+            return bool(np.asarray(self.provider.batch_verify(items)).all())
 
     def verify_window(self, blocks: List[Block]) -> List[bool]:
         """Batch-verify a window of blocks in ONE provider dispatch
@@ -51,8 +53,9 @@ class MessageCryptoService:
                 continue
             spans.append(slice(len(items), len(items) + len(bi)))
             items.extend(bi)
-        verdicts = (np.asarray(self.provider.batch_verify(items))
-                    if items else np.zeros(0, dtype=bool))
+        with dispatch_site("block_sig"):
+            verdicts = (np.asarray(self.provider.batch_verify(items))
+                        if items else np.zeros(0, dtype=bool))
         return [bool(verdicts[s].all()) if s is not None else False
                 for s in spans]
 

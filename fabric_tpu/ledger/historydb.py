@@ -180,7 +180,7 @@ class HistoryDB:
         return self._pool
 
     def _checkpoint_locked(self) -> dict:
-        t0 = time.monotonic()
+        t0 = time.perf_counter()
         gen = self._ckpt_gen + 1
 
         def _encode_shard(i: int) -> bytes:
@@ -215,9 +215,10 @@ class HistoryDB:
         self._blocks_since_ckpt = 0
         try:
             from fabric_tpu.ops_plane import tracing
-            tracing.event("history.checkpoint", channel=self.channel,
-                          gen=gen, savepoint=self._savepoint,
-                          seconds=round(time.monotonic() - t0, 6))
+            tracing.tracer.record_span(
+                "history.checkpoint", t0, time.perf_counter(),
+                attributes={"channel": self.channel, "gen": gen,
+                            "savepoint": self._savepoint})
         except Exception:
             pass
         return manifest

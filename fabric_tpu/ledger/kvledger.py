@@ -121,6 +121,16 @@ class CommitStats:
     history_commit_s: float = 0.0
     valid_txs: int = 0
     total_txs: int = 0
+    # (span name, start, end) of each phase as it really ran, on
+    # perf_counter: what the committer records as the ledger.* spans
+    phase_spans: list = field(default_factory=list)
+
+    def phase(self, name: str, attr: str, t0: float) -> None:
+        """Close a phase that began at `t0`: its seconds into `attr`,
+        its real interval into `phase_spans`."""
+        t1 = time.perf_counter()
+        setattr(self, attr, getattr(self, attr) + t1 - t0)
+        self.phase_spans.append((name, t0, t1))
 
 
 class KVLedger:
@@ -340,7 +350,7 @@ class KVLedger:
         # split the batch by shard before the apply takes shard locks
         # (the parallel-commit / device-validate planes do the same)
         batch.preshard(getattr(self.statedb, "n_shards", 1))
-        stats.state_validation_s = time.perf_counter() - t0
+        stats.phase("ledger.mvcc", "state_validation_s", t0)
         stats.valid_txs = flags.valid_count()
         # MVCC may have flipped more flags — write the final bitmap back
         block.metadata.items[META_TXFLAGS] = flags.to_bytes()
@@ -354,16 +364,16 @@ class KVLedger:
 
         t0 = time.perf_counter()
         self.blockstore.add_block(block)
-        stats.block_commit_s = time.perf_counter() - t0
+        stats.phase("ledger.block_commit", "block_commit_s", t0)
 
         t0 = time.perf_counter()
         self.statedb.apply_updates(batch, block.header.number)
-        stats.state_commit_s = time.perf_counter() - t0
+        stats.phase("ledger.state_commit", "state_commit_s", t0)
 
         if self.historydb is not None:
             t0 = time.perf_counter()
             self.historydb.commit(block.header.number, history)
-            stats.history_commit_s = time.perf_counter() - t0
+            stats.phase("ledger.history_commit", "history_commit_s", t0)
 
         self._observe_apply(len(batch), len(history))
         self.last_stats = stats
@@ -447,8 +457,10 @@ class KVLedger:
             flags = entry.flags
             stats = CommitStats(block_num=entry.num,
                                 total_txs=len(block.data))
-            stats.state_validation_s = (entry.validate_s
-                                        + time.perf_counter() - t0)
+            # the early waves ran at admit time, on the admitting
+            # thread: their seconds count, the span is this thread's
+            stats.state_validation_s = entry.validate_s
+            stats.phase("ledger.mvcc", "state_validation_s", t0)
             stats.valid_txs = flags.valid_count()
             block.metadata.items[META_TXFLAGS] = flags.to_bytes()
             self._commit_hash = hashlib.sha256(
@@ -463,16 +475,17 @@ class KVLedger:
             try:
                 t1 = time.perf_counter()
                 self.blockstore.add_block(block)
-                stats.block_commit_s = time.perf_counter() - t1
+                stats.phase("ledger.block_commit", "block_commit_s", t1)
 
                 t1 = time.perf_counter()
                 self.statedb.apply_updates(batch, entry.num)
-                stats.state_commit_s = time.perf_counter() - t1
+                stats.phase("ledger.state_commit", "state_commit_s", t1)
 
                 if self.historydb is not None:
                     t1 = time.perf_counter()
                     self.historydb.commit(entry.num, history)
-                    stats.history_commit_s = time.perf_counter() - t1
+                    stats.phase("ledger.history_commit",
+                                "history_commit_s", t1)
             finally:
                 self._commit_window.apply_ended()
             self._commit_window.retire(entry)

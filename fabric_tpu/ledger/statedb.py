@@ -711,7 +711,7 @@ class StateDB:
         return set(self._gen_pins)
 
     def _checkpoint_locked(self) -> dict:
-        t0 = time.monotonic()
+        t0 = time.perf_counter()
         gen = self._ckpt_gen + 1
 
         def _encode_shard(i: int) -> bytes:
@@ -751,7 +751,7 @@ class StateDB:
         ckpt.gc_generations(self.root, {gen, gen - 1} | self._live_pins())
         self._ckpt_gen = gen
         self._batches_since_ckpt = 0
-        self._observe_checkpoint(time.monotonic() - t0, gen)
+        self._observe_checkpoint(t0, time.perf_counter(), gen)
         return manifest
 
     def _recover(self) -> None:
@@ -846,12 +846,14 @@ class StateDB:
         except Exception:
             pass
 
-    def _observe_checkpoint(self, seconds: float, gen: int) -> None:
+    def _observe_checkpoint(self, t0: float, t1: float, gen: int) -> None:
+        seconds = t1 - t0
         try:
             from fabric_tpu.ops_plane import tracing
-            tracing.event("state.checkpoint", channel=self.channel,
-                          gen=gen, savepoint=self._savepoint,
-                          seconds=round(seconds, 6))
+            tracing.tracer.record_span(
+                "state.checkpoint", t0, t1,
+                attributes={"channel": self.channel, "gen": gen,
+                            "savepoint": self._savepoint})
         except Exception:
             pass
         if not self.channel:

@@ -21,6 +21,7 @@ import threading
 from typing import Dict, Optional
 
 from fabric_tpu.bccsp.factory import FactoryOpts, init_factories
+from fabric_tpu.bccsp.provider import dispatch_site
 from fabric_tpu.comm.rpc import RpcServer
 from fabric_tpu.config import Bundle, BundleSource, ChannelConfig
 from fabric_tpu.ledger.blkstorage import BlockStore
@@ -875,8 +876,10 @@ class OrdererNode:
                                 "signed_data": sd}):
                             block = Block.deserialize(item["block"])
                             items = block_signature_items(block, msps)
-                            if not items or not bool(
-                                    self.provider.batch_verify(items).all()):
+                            with dispatch_site("block_sig"):
+                                signed = bool(items) and bool(
+                                    self.provider.batch_verify(items).all())
+                            if not signed:
                                 raise ValueError(
                                     f"bad orderer signature on block "
                                     f"{block.header.number}")

@@ -31,7 +31,7 @@ import time
 from typing import Dict, List, Optional, Tuple
 
 from fabric_tpu.bccsp.factory import FactoryOpts, init_factories
-from fabric_tpu.bccsp.provider import DeviceError
+from fabric_tpu.bccsp.provider import DeviceError, dispatch_site
 from fabric_tpu.chaincode import (
     ChaincodeDefinition,
     ChaincodeRegistry,
@@ -659,8 +659,10 @@ class PeerChannel:
                                  behavior="block_until_ready"),
                         timeout_s=5):
                     items = block_signature_items(block, self.msps)
-                    if not items or not bool(
-                            self.node.provider.batch_verify(items).all()):
+                    with dispatch_site("block_sig"):
+                        signed = bool(items) and bool(
+                            self.node.provider.batch_verify(items).all())
+                    if not signed:
                         logger.warning("block %d failed orderer-signature "
                                        "verification; dropping window",
                                        block.header.number)

@@ -33,6 +33,13 @@ from typing import Callable, Optional, Tuple
 import numpy as np
 
 
+def bank_update(bank, table, slot):
+    """One key's table into its slot.  A named function, so that the
+    program it compiles to (`jit_bank_update`) is told apart in a trace
+    from the lanes' programs."""
+    return bank.at[slot].set(table)
+
+
 class DeviceBank:
     """Fixed-capacity slot allocator over one device-resident f32 bank.
 
@@ -90,8 +97,7 @@ class DeviceBank:
             from jax.sharding import NamedSharding, PartitionSpec
             sharding = NamedSharding(self.mesh, PartitionSpec())
             self._bank = jax.device_put(zeros, sharding)
-            self._upd = jax.jit(
-                lambda b, t, i: b.at[i].set(t), out_shardings=sharding)
+            self._upd = jax.jit(bank_update, out_shardings=sharding)
         else:
             # committed to `device` when one is named: the update and
             # every dispatch that takes the bank then run there
@@ -100,7 +106,7 @@ class DeviceBank:
             # no donation: in-flight dispatches may still hold the old
             # bank; the on-device copy (~tens of MB at HBM bandwidth)
             # is negligible at table-build frequency
-            self._upd = jax.jit(lambda b, t, i: b.at[i].set(t))
+            self._upd = jax.jit(bank_update)
 
     def array(self):
         """The device-resident (max_keys, *entry_shape) f32 bank."""

@@ -10,7 +10,10 @@ from __future__ import annotations
 import bisect
 import re
 import threading
+import time
 from typing import Dict, List, Optional, Tuple
+
+_T0 = time.perf_counter()
 
 _DEFAULT_BUCKETS = (0.001, 0.005, 0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
                     5.0, 10.0, float("inf"))
@@ -184,6 +187,13 @@ class Histogram:
             self._sum[k] = self._sum.get(k, 0.0) + value
             self._n[k] = self._n.get(k, 0) + 1
 
+    def clear(self) -> None:
+        """Drop every series (tests)."""
+        with self._lock:
+            self._counts.clear()
+            self._sum.clear()
+            self._n.clear()
+
     def state(self) -> Tuple[List[int], float, int]:
         """Aggregate (bucket counts, sum, n) across every label set.
 
@@ -273,7 +283,14 @@ class MetricsRegistry:
             return m
 
     def expose_text(self) -> str:
-        """Prometheus text exposition format (system.go:183 /metrics)."""
+        """Prometheus text exposition format (system.go:183 /metrics).
+        Each exposition stamps `process_uptime_seconds`: two of them
+        give the seconds between on `perf_counter`, the clock the spans
+        and the dispatch account use."""
+        self.gauge("process_uptime_seconds",
+                   "perf_counter seconds since this registry's module "
+                   "was loaded, set at each exposition").set(
+                       time.perf_counter() - _T0)
         with self._lock:
             metrics = list(self._metrics.values())
         lines: List[str] = []

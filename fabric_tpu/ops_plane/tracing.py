@@ -18,12 +18,23 @@ block's trace id, and the gateway's commit_status span links to it, so
 `GET /traces/<request-id>` exports the request's spans *and* the linked
 block's spans in one Chrome trace-event JSON (Perfetto-loadable).
 
+A gateway verb whose frame carried no context roots a request trace
+itself (comm/rpc.py `serve(..., root_trace=True)`), so a plain client
+is traced too, at `sample_rate`.
+
 The flight recorder is bounded: last N complete traces + K slowest.
-Everything is off by default — `tracer` starts disabled and every
-instrumentation site gets the shared no-op span, keeping the hot path
-at one attribute load — and is switched on per-node via the `tracing`
-sub-dict of localconfig (`FABRIC_TPU_PEER_TRACING__SAMPLE_RATE=0.1`
-etc.), mirroring how Fabric gates its operations surface.
+The module's `tracer` starts disabled — a process that configures
+nothing (a client, a tool, a test) gets the shared no-op span at every
+instrumentation site, one attribute load — but **nodes configure it
+on**: `PeerNode` and `OrdererNode` call `configure(cfg["tracing"])`,
+whose default is enabled at `sample_rate` 1.0.  The `tracing` sub-dict
+of localconfig turns it off or down per node (`{"enabled": false}`,
+`FABRIC_TPU_PEER_TRACING__SAMPLE_RATE=0.1`).  What it costs when on is
+measured in PERF.md §6.
+
+Span durations are kept once, in the `span_duration_seconds{span=...}`
+histogram (the process tracer's is the ops registry's, so `/metrics`
+has it); `/spans/stats` and `span_stats()` read that same histogram.
 """
 
 from __future__ import annotations
@@ -35,7 +46,7 @@ import time
 from collections import OrderedDict
 from typing import Dict, List, NamedTuple, Optional
 
-from .metrics import registry as default_registry
+from .metrics import MetricsRegistry, registry as default_registry
 
 # one wall-clock anchor so exported timestamps are perf_counter-precise
 # relative to each other yet land on real epoch time in Perfetto
@@ -296,7 +307,10 @@ class Tracer:
     """Process-wide tracer.  Sampling is decided once at root-span
     creation and rides the context flags everywhere downstream."""
 
-    def __init__(self, recorder: Optional[FlightRecorder] = None):
+    def __init__(self, recorder: Optional[FlightRecorder] = None,
+                 registry: Optional[MetricsRegistry] = None):
+        """`registry` holds the span-duration histogram; a tracer given
+        none keeps a private one (tests)."""
         self.enabled = False
         self.sample_rate = 1.0
         self.recorder = recorder or FlightRecorder()
@@ -305,8 +319,9 @@ class Tracer:
         # trace_id -> {"spans": [dict], "open_roots": set, "t0": perf,
         #              "root_name": str, "start_wall": float}
         self._active: Dict[str, dict] = {}
-        self._stats: Dict[str, list] = {}    # name -> [n, sum, max, buckets]
-        self._registry = default_registry
+        self._durations = (registry or MetricsRegistry()).histogram(
+            "span_duration_seconds",
+            "Duration of tracer spans by span name", buckets=_SPAN_BUCKETS)
         self._rand = random.Random(os.urandom(8))
 
     # -- configuration ------------------------------------------------------
@@ -478,37 +493,23 @@ class Tracer:
 
     def _observe(self, name: str, dur: float) -> None:
         try:
-            with self._lock:
-                st = self._stats.get(name)
-                if st is None:
-                    st = [0, 0.0, 0.0, [0] * len(_SPAN_BUCKETS)]
-                    self._stats[name] = st
-                st[0] += 1
-                st[1] += dur
-                st[2] = max(st[2], dur)
-                for i, ub in enumerate(_SPAN_BUCKETS):
-                    if dur <= ub:
-                        st[3][i] += 1
-                        break
-            self._registry.histogram(
-                "span_duration_seconds",
-                "Duration of tracer spans by span name",
-                buckets=_SPAN_BUCKETS).observe(dur, span=name)
+            self._durations.observe(dur, span=name)
         except Exception:
             pass                 # stats must never break the traced path
 
     def span_stats(self) -> dict:
-        with self._lock:
-            out = {}
-            for name, (n, total, mx, buckets) in sorted(self._stats.items()):
-                out[name] = {
-                    "count": n,
-                    "total_s": round(total, 6),
-                    "mean_ms": round(total / n * 1e3, 3) if n else 0.0,
-                    "max_ms": round(mx * 1e3, 3),
-                    "buckets": {("+Inf" if ub == float("inf") else repr(ub)): c
-                                for ub, c in zip(_SPAN_BUCKETS, buckets)},
-                }
+        """Per span name: count, total, mean and per-bin bucket counts,
+        from the `span_duration_seconds` histogram."""
+        out = {}
+        for name, (buckets, total, n) in sorted(
+                self._durations.state_by("span").items()):
+            out[name] = {
+                "count": n,
+                "total_s": round(total, 6),
+                "mean_ms": round(total / n * 1e3, 3) if n else 0.0,
+                "buckets": {("+Inf" if ub == float("inf") else repr(ub)): c
+                            for ub, c in zip(_SPAN_BUCKETS, buckets)},
+            }
         return out
 
     # -- export -------------------------------------------------------------
@@ -583,11 +584,12 @@ class Tracer:
         """Drop all state (tests)."""
         with self._lock:
             self._active.clear()
-            self._stats.clear()
+        self._durations.clear()
         self.recorder.clear()
 
 
-tracer = Tracer()                # the process default
+# the process default; its span durations are on /metrics
+tracer = Tracer(registry=default_registry)
 
 
 def configure(cfg: Optional[dict] = None, *,

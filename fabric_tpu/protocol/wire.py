@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import hashlib
 import struct
+import time
 from typing import Any, Dict, List, Optional, Tuple, Union
 
 from fabric_tpu.utils import serde
@@ -71,7 +72,8 @@ class BlockView:
     """
 
     __slots__ = ("raw", "header", "n_data", "_data_off", "_data_end",
-                 "_spans", "_meta_off", "_data", "_metadata", "_dhash")
+                 "_spans", "_meta_off", "_data", "_metadata", "_dhash",
+                 "parsed")
 
     def __init__(self, raw: _Raw, number: int, previous_hash: bytes,
                  data_hash: bytes, data_off: int, data_end: int,
@@ -155,11 +157,18 @@ def parse_block(raw: _Raw) -> Union[BlockView, Block]:
     Raises exactly what Block.deserialize raises for bytes neither
     accepts; never raises for bytes Block.deserialize accepts.
     """
+    t0 = time.perf_counter()
+    block = None
     if _fastparse is not None:
         r = _fastparse.parse_block(raw)
         if r is not None:
-            return BlockView(raw, *r)
-    return Block.deserialize(raw)
+            block = BlockView(raw, *r)
+    if block is None:
+        block = Block.deserialize(raw)
+    # when the parse ran, on perf_counter: the committer records it as
+    # the `wire.parse_block` span of the block's trace
+    block.parsed = (t0, time.perf_counter())
+    return block
 
 
 def n_txs(block) -> int:

@@ -27,7 +27,12 @@ import sys
 import time
 
 
-def replay(cfg: dict, block_paths, warm_rows=()) -> dict:
+def replay(cfg: dict, block_paths, warm_rows=(), on_block=None) -> dict:
+    """`on_block(node, i, store)`, when given, stands around each block:
+    it is called with the node, the block's index and `store()`, which
+    parses and commits that block and returns its record — so a caller
+    can put a clock, a profiler or a trace around whole blocks, or stop
+    early by raising StopIteration, without a copy of this loop."""
     from fabric_tpu.node.peer import PeerNode
     from fabric_tpu.protocol import wire
     from fabric_tpu.protocol.types import META_TXFLAGS
@@ -39,7 +44,8 @@ def replay(cfg: dict, block_paths, warm_rows=()) -> dict:
         warm = node.provider.warm(rows=warm_rows) if warm_rows else {}
         warm_s = time.perf_counter() - t0 - init_s
         blocks = []
-        for path in block_paths:
+
+        def store(path) -> dict:
             with open(path, "rb") as f:
                 block = wire.parse_block(f.read())
             t1 = time.perf_counter()
@@ -47,10 +53,19 @@ def replay(cfg: dict, block_paths, warm_rows=()) -> dict:
             seconds = time.perf_counter() - t1
             number = int(block.header.number)
             stored = node.ledger.blockstore.get_by_number(number)
-            blocks.append({
+            return {
                 "number": number,
                 "flags": bytes(stored.metadata.items[META_TXFLAGS]).hex(),
-                "seconds": round(seconds, 3)})
+                "seconds": round(seconds, 3)}
+
+        for i, path in enumerate(block_paths):
+            if on_block is None:
+                blocks.append(store(path))
+                continue
+            try:
+                blocks.append(on_block(node, i, lambda p=path: store(p)))
+            except StopIteration:
+                break
         return {"mspid": node.mspid,
                 "init_s": round(init_s, 3),
                 "warm": warm, "warm_s": round(warm_s, 3),

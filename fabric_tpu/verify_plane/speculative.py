@@ -26,6 +26,7 @@ from collections import deque
 from typing import Dict, List, Optional, Tuple
 
 from fabric_tpu.bccsp import SCHEME_P256, VerifyItem
+from fabric_tpu.bccsp.provider import dispatch_site
 from fabric_tpu.committer import collect_py
 from fabric_tpu.ops_plane import tracing
 
@@ -221,7 +222,9 @@ class SpeculativeVerifier:
                     batches.setdefault(cid, []).extend(items)
             for cid, batch in batches.items():
                 try:
-                    self._verify_batch(batch, stage="overlap", scope=cid)
+                    with dispatch_site("speculative"):
+                        self._verify_batch(batch, stage="overlap",
+                                           scope=cid)
                 except Exception:
                     logger.exception("speculative verify batch failed")
 

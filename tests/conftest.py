@@ -13,6 +13,8 @@ if "xla_force_host_platform_device_count" not in _flags:
 
 # Persistent XLA compilation cache, where JAX_COMPILATION_CACHE_DIR says
 # or at <checkout>/.cache/jax: a later run loads what this one compiled.
+import pytest
+
 from fabric_tpu.bccsp.factory import enable_compile_cache
 
 enable_compile_cache()
@@ -22,3 +24,21 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: multi-process topology tests excluded from the "
         "tier-1 'not slow' gate")
+
+
+@pytest.fixture
+def same_exposition():
+    """check(earlier, later): two /metrics texts of one registry differ
+    in nothing but the clock each exposition reads itself
+    (`process_uptime_seconds`), and that one has not run backwards."""
+    stamp = "process_uptime_seconds "
+
+    def check(earlier: str, later: str) -> None:
+        a, b = earlier.splitlines(), later.splitlines()
+        assert len(a) == len(b), (earlier, later)
+        differing = [(x, y) for x, y in zip(a, b) if x != y]
+        assert all(x.startswith(stamp) and y.startswith(stamp)
+                   for x, y in differing), differing
+        for x, y in differing:
+            assert float(y[len(stamp):]) >= float(x[len(stamp):])
+    return check

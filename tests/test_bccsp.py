@@ -148,7 +148,7 @@ def test_fail_stop_resolve_error_reaches_the_caller(monkeypatch):
         raise RuntimeError("injected resolve failure")
 
     def lane(items, idxs, pending):
-        pending.append((idxs, raising))
+        pending.append((idxs, raising, None, None))
 
     p = JaxTpuProvider()
     monkeypatch.setattr(p, "_verify_p256", lane)

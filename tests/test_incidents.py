@@ -329,7 +329,7 @@ def test_detach_on_stop(tmp_path):
 # zero-overhead guard
 # ---------------------------------------------------------------------------
 
-def test_zero_overhead_when_disabled():
+def test_zero_overhead_when_disabled(same_exposition):
     """No recorder constructed -> no /incidents routes and no
     incidents_* series; /metrics byte-identical."""
     reg = MetricsRegistry()
@@ -343,7 +343,7 @@ def test_zero_overhead_when_disabled():
                 _get(ops.addr, path)
             assert ei.value.code == 404
         text = _get(ops.addr, "/metrics").read().decode()
-        assert text == before
+        same_exposition(before, text)
         assert "incidents_" not in text
     finally:
         ops.stop()

@@ -239,7 +239,7 @@ def test_route_json_and_folded():
 # zero-overhead guard
 # ---------------------------------------------------------------------------
 
-def test_zero_overhead_when_disabled():
+def test_zero_overhead_when_disabled(same_exposition):
     """The acceptance guard: no profiler constructed -> no
     /profile/sampled route, no profiler_* series, /metrics
     byte-identical to a registry that never heard of this PR."""
@@ -253,7 +253,7 @@ def test_zero_overhead_when_disabled():
             _get(ops.addr, "/profile/sampled")
         assert ei.value.code == 404
         text = _get(ops.addr, "/metrics").read().decode()
-        assert text == before
+        same_exposition(before, text)
         assert "profiler_" not in text
     finally:
         ops.stop()

@@ -197,7 +197,7 @@ def _get(addr, path):
                                   timeout=5)
 
 
-def test_history_route_serves_series_and_404s_unknown():
+def test_history_route_serves_series_and_404s_unknown(same_exposition):
     reg = MetricsRegistry()
     clk = FakeClock()
     st = TimeSeriesStore({"interval_s": 1.0}, registry=reg, clock=clk)
@@ -221,12 +221,12 @@ def test_history_route_serves_series_and_404s_unknown():
         assert ei.value.code == 404
         # the built-in exposition is untouched by the prefix route
         text = _get(ops.addr, "/metrics").read().decode()
-        assert text == reg.expose_text()
+        same_exposition(text, reg.expose_text())
     finally:
         ops.stop()
 
 
-def test_zero_overhead_when_disabled():
+def test_zero_overhead_when_disabled(same_exposition):
     """The acceptance guard: a node that leaves timeseries/resources
     disabled serves a /metrics surface with NO resource series and NO
     /metrics/history route — byte-identical exposition to a registry
@@ -241,7 +241,7 @@ def test_zero_overhead_when_disabled():
             _get(ops.addr, "/metrics/history")
         assert ei.value.code == 404
         text = _get(ops.addr, "/metrics").read().decode()
-        assert text == before
+        same_exposition(before, text)
         for name in ("process_resident_memory_bytes", "process_open_fds",
                      "process_threads", "native_arena_pool_free"):
             assert name not in text
@@ -250,7 +250,7 @@ def test_zero_overhead_when_disabled():
     # constructing a store never mutates the registry either
     st = TimeSeriesStore(registry=reg, clock=FakeClock())
     st.sample()
-    assert reg.expose_text() == before
+    same_exposition(before, reg.expose_text())
 
 
 # ---------------------------------------------------------------------------

@@ -425,3 +425,123 @@ def test_live_sampling_zero_drops_new_traces(net):
         assert recorded_ids() <= before, "unsampled tx left a trace"
     finally:
         tracing.tracer.sample_rate = 1.0
+
+
+# -- spans recorded without the client's help --------------------------------
+
+def _wait_trace(root_name, known, deadline_s=10.0):
+    """The newest finished trace rooted at `root_name` not in `known`."""
+    deadline = time.time() + deadline_s
+    while time.time() < deadline:
+        for r in tracing.tracer.recorder.list()["recent"]:
+            if r["root"] == root_name and r["trace_id"] not in known:
+                return tracing.tracer.recorder.get(r["trace_id"])
+        time.sleep(0.05)
+    return None
+
+
+def _ids():
+    return {r["trace_id"] for r in tracing.tracer.recorder.list()["recent"]}
+
+
+def test_gateway_verb_without_traceparent_roots_its_trace(net):
+    """A plain client (no span of its own, so no `tp` in its frames):
+    the gateway roots the request's trace, and the layers under it
+    record — the local endorser, one fan-out per target peer with its
+    dial's handshake below it, the remote endorser through the
+    fan-out's frame."""
+    assert tracing.tracer.enabled
+    time.sleep(0.3)
+    known = _ids()
+    gw = _client(net)
+    try:
+        assert tracing.tracer.current_context() is None
+        gw.endorse("assets", "create", [b"rooted1", b"bob"])
+    finally:
+        gw.close()
+    rec = _wait_trace("rpc.gateway.endorse", known)
+    assert rec is not None, tracing.tracer.recorder.list()["recent"][:5]
+    deadline = time.time() + 5
+    while time.time() < deadline and not any(
+            s["name"] == "rpc.endorse" for s in rec["spans"]):
+        time.sleep(0.05)              # the remote fragment merges late
+    spans = rec["spans"]
+    by_id = {s["span_id"]: s for s in spans}
+    names = [s["name"] for s in spans]
+    root = next(s for s in spans if s["name"] == "rpc.gateway.endorse")
+    assert root["parent_id"] is None
+    for required in ("endorser.validate", "endorser.simulate",
+                     "endorser.sign", "gateway.fanout", "comm.handshake",
+                     "rpc.endorse"):
+        assert required in names, (required, sorted(set(names)))
+    fanout = next(s for s in spans if s["name"] == "gateway.fanout")
+    assert fanout["parent_id"] == root["span_id"]
+    shake = next(s for s in spans if s["name"] == "comm.handshake"
+                 and s["parent_id"] == fanout["span_id"])
+    assert shake["attributes"]["role"] == "initiator"
+    # the remote peer's endorser, continued through the fan-out's frame
+    remote = next(s for s in spans if s["name"] == "rpc.endorse")
+    assert by_id[remote["parent_id"]]["name"] == "gateway.fanout"
+    # the other end of that dial had no context: it roots no trace
+    assert _wait_trace("comm.handshake", known, deadline_s=0.5) is None
+    # and the layer's metric has something to read
+    stats = tracing.tracer.span_stats()
+    assert stats["comm.handshake"]["count"] >= 1
+    assert stats["gateway.fanout"]["count"] >= 1
+
+
+def test_tracer_off_records_nothing_and_stays_a_noop(net):
+    time.sleep(0.5)
+    before, stats = _ids(), tracing.tracer.span_stats()
+    tracing.tracer.enabled = False
+    try:
+        # every site gets the shared no-op after one attribute load
+        assert tracing.tracer.start_span("rpc.gateway.endorse") \
+            is tracing.NOOP_SPAN
+        assert tracing.tracer.context_from("00-" + "1" * 32 + "-"
+                                           + "2" * 16 + "-01") is None
+        gw = _client(net)
+        try:
+            gw.endorse("assets", "create", [b"dark1", b"eve"])
+        finally:
+            gw.close()
+        time.sleep(0.5)
+        assert _ids() == before
+        assert tracing.tracer.span_stats() == stats
+    finally:
+        tracing.tracer.enabled = True
+
+
+def test_root_trace_is_only_for_verbs_that_ask():
+    """comm/rpc.py: a method served with root_trace roots a trace when
+    its frame brought none; any other stays untraced."""
+    from fabric_tpu.comm.rpc import RpcServer, connect
+    from fabric_tpu.msp import CachedMSP
+    from fabric_tpu.msp.ca import DevOrg
+    org = DevOrg("RootOrg")
+    msps = {"RootOrg": CachedMSP(org.msp())}
+    server = RpcServer("127.0.0.1", 0, org.new_identity("srv"), msps)
+    server.serve("front.door", lambda body, peer: {"ok": 1},
+                 root_trace=True)
+    server.serve("inner", lambda body, peer: {"ok": 1})
+    server.start()
+    was = tracing.tracer.enabled
+    tracing.tracer.enabled = True
+    try:
+        known = _ids()
+        conn = connect(server.addr, org.new_identity("cli"), msps)
+        try:
+            conn.call("inner", {})
+            conn.call("front.door", {})
+        finally:
+            conn.close()
+        rec = _wait_trace("rpc.front.door", known)
+        assert rec is not None
+        assert [s["name"] for s in rec["spans"]] == ["rpc.front.door"]
+        time.sleep(0.2)
+        roots = {r["root"] for r in tracing.tracer.recorder.list()["recent"]
+                 if r["trace_id"] not in known}
+        assert "rpc.inner" not in roots
+    finally:
+        tracing.tracer.enabled = was
+        server.stop()
