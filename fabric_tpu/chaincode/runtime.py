@@ -35,6 +35,11 @@ class Contract:
     def invoke(self, stub: ChaincodeStub, fn: str, args: List[bytes]) -> bytes:
         raise NotImplementedError
 
+    def functions(self) -> Tuple[str, ...]:
+        """The function names this contract declares; () when it cannot
+        say (an out-of-process contract)."""
+        return ()
+
 
 class ExternalContract(Contract):
     """Out-of-process contract hook (externalbuilder run-style): executes a
@@ -81,6 +86,12 @@ class ChaincodeRegistry:
     def names(self) -> List[str]:
         return sorted(self._contracts)
 
+    def function_label(self, name: str, fn: str) -> str:
+        """`fn` as a metric label: itself where the contract declares
+        it, "other" for anything else a client may send."""
+        entry = self._contracts.get(name)
+        return fn if entry and fn in entry[1].functions() else "other"
+
     def execute(self, stub: ChaincodeStub, name: str, fn: str,
                 args: List[bytes]) -> Tuple[int, bytes]:
         """Run one invocation; returns (status, payload). 500 on contract
@@ -114,6 +125,9 @@ class FuncContract(Contract):
 
     def __init__(self, **handlers: Callable):
         self._handlers = handlers
+
+    def functions(self) -> Tuple[str, ...]:
+        return tuple(self._handlers)
 
     def invoke(self, stub: ChaincodeStub, fn: str, args: List[bytes]) -> bytes:
         if fn not in self._handlers:

@@ -25,7 +25,7 @@ from fabric_tpu.endorser.proposal import (
 )
 from fabric_tpu.ledger.statedb import StateDB
 from fabric_tpu.msp import SigningIdentity, deserialize_from_msps
-from fabric_tpu.ops_plane import tracing
+from fabric_tpu.ops_plane import registry as metrics_registry, tracing
 from fabric_tpu.policy import PolicyEvaluator, SignaturePolicy, SignedData
 from fabric_tpu.protocol.build import compute_txid
 from fabric_tpu.protocol.types import (ChaincodeAction, Endorsement,
@@ -158,8 +158,18 @@ class Endorser:
                              txid=txid,
                              creator=creator, registry=self.registry,
                              pvt_store=self.pvt_store)
-        _, payload = self.registry.execute(
-            stub, prop.chaincode_id, prop.fn, list(prop.args))
+        status = "500"
+        try:
+            _, payload = self.registry.execute(
+                stub, prop.chaincode_id, prop.fn, list(prop.args))
+            status = "200"
+        finally:
+            metrics_registry.counter(
+                "chaincode_invoke_total", "contract invocations simulated "
+                "by the endorser, by outcome").add(
+                    1, chaincode=prop.chaincode_id, status=status,
+                    function=self.registry.function_label(
+                        prop.chaincode_id, prop.fn))
         pvt_sets = stub.private_sets()
         if pvt_sets:
             if self.transient_store is not None:

@@ -14,6 +14,7 @@ self-heal on open (recoverDBs).
 
 from __future__ import annotations
 
+import collections
 import hashlib
 import logging
 import os
@@ -29,7 +30,7 @@ from fabric_tpu.protocol.types import META_COMMIT_HASH, META_TXFLAGS
 
 from .blkstorage import BlockStore
 from .historydb import HistoryDB
-from .mvcc import validate_and_prepare_batch
+from .mvcc import MvccTally, validate_and_prepare_batch
 from .statedb import StateDB
 
 logger = logging.getLogger("fabric_tpu.ledger")
@@ -278,14 +279,48 @@ class KVLedger:
                              "falling back to host MVCC")
             return None
 
-    def _validate_and_prepare(self, num: int, envelopes, flags: TxFlags):
+    def _validate_and_prepare(self, num: int, envelopes, flags: TxFlags,
+                              tally: Optional[MvccTally] = None):
         """MVCC pass: the wavefront scheduler when parallel_commit is
-        on, the serial oracle otherwise — identical output either way."""
+        on, the serial oracle otherwise — identical output either way.
+        Only the oracle fills `tally`."""
         if self._commit_scheduler is not None:
             return self._commit_scheduler.validate_and_prepare_batch(
                 self.statedb, num, envelopes, flags)
         return validate_and_prepare_batch(self.statedb, num,
-                                          envelopes, flags)
+                                          envelopes, flags, tally)
+
+    def _count_block(self, flags: TxFlags, tally: Optional[MvccTally],
+                     writes: int) -> None:
+        """One committed block into the always-on counters: its
+        transactions by final code, the writes of its valid txs and,
+        where the serial walk validated it (`tally`), the reads it
+        checked and the conflicts it found.  The default-off commit
+        paths do not walk read by read: their blocks move no
+        `path="serial"` series, so those never read as "no conflicts"."""
+        from fabric_tpu.ops_plane import registry
+        ch = self.channel_id
+        txs = registry.counter(
+            "ledger_tx_total", "transactions committed, by final "
+            "validation code")
+        for code, n in collections.Counter(flags.codes()).items():
+            txs.add(n, channel=ch, code=ValidationCode(code).name)
+        registry.counter(
+            "ledger_state_writes_total", "writes of valid transactions "
+            "applied to state and history").add(writes, channel=ch)
+        if tally is None:
+            return
+        registry.counter(
+            "ledger_mvcc_reads_total", "reads validated, by the commit "
+            "path that counts them (the serial MVCC walk)").add(
+                tally.reads, channel=ch, path="serial")
+        conflicts = registry.counter(
+            "ledger_mvcc_conflicts_total", "reads that no longer held, by "
+            "who answered: an earlier valid tx of the block, or the state")
+        conflicts.add(tally.conflicts_block, channel=ch, path="serial",
+                      against="block")
+        conflicts.add(tally.conflicts_state, channel=ch, path="serial",
+                      against="state")
 
     _APPLY_BUCKETS = (1.0, 4.0, 16.0, 64.0, 256.0, 1024.0, 4096.0,
                       16384.0, float("inf"))
@@ -335,6 +370,7 @@ class KVLedger:
                             total_txs=len(block.data))
 
         t0 = time.perf_counter()
+        tally = None                 # only the serial walk has one
         prepared = self._take_prepared(block)
         if prepared is not None:
             # fused device validation already ran MVCC in the
@@ -345,8 +381,10 @@ class KVLedger:
         else:
             flags = TxFlags.from_bytes(block.metadata.items[META_TXFLAGS])
             envelopes = _safe_envelopes(block)
+            if self._commit_scheduler is None:
+                tally = MvccTally()
             batch, history = self._validate_and_prepare(
-                block.header.number, envelopes, flags)
+                block.header.number, envelopes, flags, tally)
         # split the batch by shard before the apply takes shard locks
         # (the parallel-commit / device-validate planes do the same)
         batch.preshard(getattr(self.statedb, "n_shards", 1))
@@ -376,6 +414,7 @@ class KVLedger:
             stats.phase("ledger.history_commit", "history_commit_s", t0)
 
         self._observe_apply(len(batch), len(history))
+        self._count_block(flags, tally, len(history))
         self.last_stats = stats
         logger.info(
             "[%s] committed block %d: %d/%d valid | validation=%.1fms "
@@ -491,6 +530,7 @@ class KVLedger:
             self._commit_window.retire(entry)
 
             self._observe_apply(len(batch), len(history))
+            self._count_block(flags, None, len(history))
             self.last_stats = stats
             logger.info(
                 "[%s] committed block %d (windowed, %d early / %d "

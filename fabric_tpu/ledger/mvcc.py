@@ -33,28 +33,40 @@ from fabric_tpu.protocol import (
 from fabric_tpu.protocol.txflags import TxFlags, ValidationCode
 from fabric_tpu.protocol.types import RangeQueryInfo, TX_ENDORSER
 
-from .statedb import StateDB, UpdateBatch, VersionedValue
+from .statedb import StateDB, UpdateBatch
 
 
-def _batch_merged_get(db: StateDB, batch: UpdateBatch, ns: str, key: str
-                      ) -> Optional[VersionedValue]:
-    found, vv = batch.get(ns, key)
-    if found:
-        return vv  # None here means staged delete
-    return db.get(ns, key)
+def _read_conflict(db: StateDB, batch: UpdateBatch, ns: str,
+                   read: KVRead) -> Optional[str]:
+    """validateKVRead (validator.go:175): version equality, nil-safe.
+    -> None when the read still holds, else who answered otherwise:
+    "block" (a write an earlier valid tx of this block staged) or
+    "state" (the committed state)."""
+    found, vv = batch.get(ns, read.key)
+    if not found:
+        vv = db.get(ns, read.key)
+    committed = None if vv is None else vv.version  # None: absent or deleted
+    if committed is None and read.version is None:
+        return None
+    if (committed is None or read.version is None
+            or committed.block_num != read.version.block_num
+            or committed.tx_num != read.version.tx_num):
+        return "block" if found else "state"
+    return None
 
 
 def _validate_read(db: StateDB, batch: UpdateBatch, ns: str,
                    read: KVRead) -> bool:
-    """validateKVRead (validator.go:175): version equality, nil-safe."""
-    vv = _batch_merged_get(db, batch, ns, read.key)
-    committed = None if vv is None else vv.version
-    if committed is None and read.version is None:
-        return True
-    if committed is None or read.version is None:
-        return False
-    return (committed.block_num == read.version.block_num
-            and committed.tx_num == read.version.tx_num)
+    return _read_conflict(db, batch, ns, read) is None
+
+
+class MvccTally:
+    """What one block's serial walk did, in plain ints: the ledger adds
+    them to its counters once per block."""
+    __slots__ = ("reads", "conflicts_block", "conflicts_state")
+
+    def __init__(self):
+        self.reads = self.conflicts_block = self.conflicts_state = 0
 
 
 def _merged_range(db: StateDB, batch: UpdateBatch, ns: str,
@@ -119,14 +131,17 @@ def extract_rwset(env: Envelope) -> Optional[TxRwSet]:
 def validate_and_prepare_batch(
         db: StateDB, block_num: int,
         envelopes: List[Envelope], flags: TxFlags,
+        tally: Optional[MvccTally] = None,
 ) -> Tuple[UpdateBatch, List[Tuple[int, str, str, str, bytes, bool]]]:
     """validateAndPrepareBatch (validator.go:83).
 
     Mutates `flags` (MVCC_READ_CONFLICT / PHANTOM_READ_CONFLICT /
     BAD_RWSET) and returns (update_batch, history_writes) where
     history_writes = (tx_num, txid, ns, key, value, is_delete) of VALID txs.
+    `tally`, when given, takes the reads validated and the conflicts.
     """
     batch = UpdateBatch()
+    reads = against_block = against_state = 0
     history: List[Tuple[int, str, str, str, bytes, bool]] = []
     for tx_num, env in enumerate(envelopes):
         if not flags.is_valid(tx_num):
@@ -142,8 +157,14 @@ def validate_and_prepare_batch(
         ok = True
         for ns_rw in rwset.ns_rwsets:
             for read in ns_rw.reads:
-                if not _validate_read(db, batch, ns_rw.namespace, read):
+                reads += 1
+                against = _read_conflict(db, batch, ns_rw.namespace, read)
+                if against is not None:
                     flags.set(tx_num, ValidationCode.MVCC_READ_CONFLICT)
+                    if against == "block":
+                        against_block += 1
+                    else:
+                        against_state += 1
                     ok = False
                     break
             if not ok:
@@ -166,4 +187,8 @@ def validate_and_prepare_batch(
                     batch.put(ns_rw.namespace, w.key, w.value, version)
                 history.append((tx_num, txid, ns_rw.namespace, w.key,
                                 w.value, w.is_delete))
+    if tally is not None:
+        tally.reads += reads
+        tally.conflicts_block += against_block
+        tally.conflicts_state += against_state
     return batch, history
