@@ -19,6 +19,7 @@ from fabric_tpu.ops_plane import tracing
 from fabric_tpu.ops_plane.logging import jlog
 from fabric_tpu.protocol import Block
 from fabric_tpu.protocol.wire import n_txs
+from fabric_tpu.utils import heap
 
 from .txvalidator import TxValidator, ValidationResult
 
@@ -250,6 +251,11 @@ class Committer:
                 # must not make the caller believe the commit failed
                 logger.exception("config application failed for block %d",
                                  block.header.number)
+        # the block boundary: what is alive now is the ledger, which
+        # the collector's full passes need not walk again
+        with tracing.tracer.start_span("committer.heap_boundary",
+                                       require_parent=True):
+            heap.block_boundary()
         return BlockCommitResult(vr, stats, final)
 
     def _check_replay(self, block: Block) -> Optional[BlockCommitResult]:

@@ -950,6 +950,11 @@ class PeerNode:
         # FABRIC_TPU_PEER_TRACING__SAMPLE_RATE=0.1
         from fabric_tpu.ops_plane import tracing as _tracing
         _tracing.configure(cfg.get("tracing", {}))
+        # the collector's account (full passes, frozen objects, thaws):
+        # installed here and not by the tracer, whose locks its hook
+        # must never meet
+        from fabric_tpu.utils import heap as _heap
+        _heap.install()
 
         self.ops = None
         if cfg.get("ops_port") is not None:

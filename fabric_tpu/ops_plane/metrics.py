@@ -13,6 +13,8 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
+from fabric_tpu.utils import heap
+
 _T0 = time.perf_counter()
 
 _DEFAULT_BUCKETS = (0.001, 0.005, 0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
@@ -286,11 +288,17 @@ class MetricsRegistry:
         """Prometheus text exposition format (system.go:183 /metrics).
         Each exposition stamps `process_uptime_seconds`: two of them
         give the seconds between on `perf_counter`, the clock the spans
-        and the dispatch account use."""
+        and the dispatch account use.  The process default registry
+        stamps the collector's account (`utils/heap.py`) the same way,
+        in a process whose node installed it."""
         self.gauge("process_uptime_seconds",
                    "perf_counter seconds since this registry's module "
                    "was loaded, set at each exposition").set(
                        time.perf_counter() - _T0)
+        if self is registry:
+            for name, (value, help_) in heap.account().items():
+                self.gauge(name, help_ + ", set at each exposition").set(
+                    value)
         with self._lock:
             metrics = list(self._metrics.values())
         lines: List[str] = []

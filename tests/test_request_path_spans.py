@@ -74,7 +74,7 @@ def test_store_blocks_direct_children_leave_no_unnamed_stretch(
                      "committer.config_check", "ledger.mvcc",
                      "ledger.block_commit", "ledger.state_commit",
                      "ledger.history_commit", "committer.observe",
-                     "committer.notify"):
+                     "committer.notify", "committer.heap_boundary"):
         assert required in names, (required, names)
     # the parse ran before the hand-off; everything else inside the root
     assert kids[0]["name"] == "wire.parse_block"
