@@ -557,8 +557,8 @@ class JaxTpuProvider(prov.Provider):
     def _observe_lane(self, lane: str, real: int, padded: int,
                       per_device=None) -> None:
         """Per-dispatch batching-economics telemetry: lane fill fraction
-        and padded-slot waste into the ops_plane registry (the live
-        counterpart of bench.py's one-shot occupancy numbers), broken
+        and padded-slot waste into the ops_plane registry (what the
+        benchmark's `provider.lane_fill.*` metrics read), broken
         out per device tile so a chip running empty shards is visible.
         Guarded: observability must never break the dispatch hot path."""
         try:
@@ -1091,8 +1091,8 @@ class JaxTpuProvider(prov.Provider):
     def idemix_pair_probe(self, batch: int = None):
         """(fn, green_args, red_args) for the BN254 dual-pairing lane:
         green checks e(G1,g2)*e(-G1,g2)==1, red e(G1,g2)^2==1 (both
-        on-curve).  One shared probe for warmup and bench — the callers
-        must not each reach into the kernel privates."""
+        on-curve).  The probe `node/warmup.py` dispatches — callers
+        must not reach into the kernel privates."""
         from fabric_tpu.idemix import bn254 as hbn
         from fabric_tpu.ops import bignum as bnmod
         b = batch or self.IDEMIX_MIN_BUCKET

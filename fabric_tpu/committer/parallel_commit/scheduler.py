@@ -113,7 +113,8 @@ class ParallelCommitScheduler:
         # serial fallback: on a 1-core host (or when the adaptive pool
         # would provision a single worker anyway) the wave machinery can
         # only ever add coordination overhead on top of the oracle's
-        # walk — BENCH_r12 measured it at 0.73x — so the scheduler runs
+        # walk (slower than serial on one CPU core; not measured on the
+        # chip's host, ROADMAP D2), so the scheduler runs
         # the serial oracle directly and counts the fallback.  Tests
         # that hold the wave path to bit-identity pass False to keep
         # exercising it regardless of the host.
@@ -198,8 +199,8 @@ class ParallelCommitScheduler:
 
         if self.serial_fallback and self.host_cores <= 1:
             # a 1-core host can never validate two txs concurrently:
-            # graph building + pool map are pure overhead (BENCH_r12's
-            # 0.73x commit_parallel_speedup), so skip them wholesale
+            # graph building + pool map are pure overhead, so skip them
+            # wholesale
             return self._serial(db, block_num, envelopes, flags,
                                 "one_core")
 

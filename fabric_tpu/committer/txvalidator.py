@@ -45,6 +45,7 @@ from fabric_tpu.protocol import Block
 from fabric_tpu.protocol.txflags import TxFlags, ValidationCode
 from fabric_tpu.protocol.types import META_TXFLAGS
 from fabric_tpu.protocol.wire import n_txs
+from fabric_tpu.verify_plane.cache import all_miss
 
 logger = logging.getLogger("fabric_tpu.committer")
 
@@ -132,10 +133,10 @@ def _intersection_s(u1, u2) -> float:
 class _PipelineEconomics:
     """Live collect-under-verify overlap over a rolling block window.
 
-    The bench-only measurement (bench.py `_window_trace_detail`) derives
-    the same fraction post-hoc from tracer spans; this tracks it on the
-    node itself so the SLO plane can watch the overlap floor without a
-    bench run.  Collect intervals come from validate_begin, verify
+    Tracked on the node itself so the SLO plane can watch the overlap
+    floor from the live process (gauge
+    `pipeline_collect_under_verify_frac`; no benchmark cell reads it).
+    Collect intervals come from validate_begin, verify
     intervals span device enqueue -> resolve return (the
     bccsp.batch_verify window).  All timestamps share perf_counter."""
 
@@ -196,15 +197,15 @@ class TxValidator:
         self._static_msps = msps
         self._provider = provider
         # verify-once plane (verify_plane.VerdictCache) — None keeps the
-        # classic always-verify behaviour.  When wired, each flush
-        # partitions its dispatch batch against the cache: MAC-verified
+        # classic always-verify behaviour.  When wired, each block's
+        # dispatch batch is partitioned against the cache: MAC-verified
         # hits skip the device, misses verify and backfill.  Identity
         # validity and policy evaluation are NEVER cached — the gate
         # always runs live; only the pure signature bit is reused.
         self.verify_cache = verify_cache
         # per-channel device placement hook:
         # provider_source(channel_id, demand) -> Provider | None.  When
-        # wired (bccsp_placement), each flush re-resolves the provider
+        # wired (bccsp_placement), each dispatch re-resolves the provider
         # and reports its batch size so the placement scheduler can
         # resize this channel's device span from observed queue depth.
         self.provider_source = provider_source
@@ -604,18 +605,89 @@ class TxValidator:
         finally:
             self._msps_snapshot = None
 
-    @property
-    def overlap_chunk(self) -> int:
-        """Pass-1 sub-block chunk size: every CHUNK txs the newly-collected
-        unique items are dispatched to the device asynchronously, so host
-        collection of the NEXT chunk overlaps device verification of the
-        previous one (SURVEY.md §7 hard-part #3 double-buffering).  The
-        default is one flush per block; FABRIC_TPU_VALIDATE_CHUNK (read
-        per validate call) lowers it.  Whether splitting wins on a
-        directly attached chip is not measured (ROADMAP D3)."""
-        import os
-        return int(os.environ.get("FABRIC_TPU_VALIDATE_CHUNK",
-                                  "1000000000"))
+    # -- the one verify step (both tails) -----------------------------------
+
+    def _dispatch(self, items: list) -> tuple:
+        """Partition a block's unique items against the node's verdict
+        cache and enqueue the misses on the device, ONE dispatch a
+        block.  Never waits for the device; `_await` does.  MAC-verified
+        cached verdicts skip the device entirely; anything else — miss,
+        MAC failure, stale epoch — is dispatched (the partition's home:
+        verify_plane/cache.py)."""
+        cache = self.verify_cache
+        if cache is None or not items:
+            part = all_miss(items)
+        else:
+            t0 = time.perf_counter()
+            part = cache.partition(items)
+            tracing.tracer.record_span(
+                "validator.cache_filter", t0, time.perf_counter(),
+                attributes={"items": len(items)})
+        if not part.misses:
+            return part, None, {}
+        # items are their OWN dedup keys (VerifyItem NamedTuple)
+        with dispatch_site("validator"):
+            resolve = self._resolve_provider(
+                len(part.misses)).batch_verify_async(part.misses)
+        # EAGER background resolution: a thread blocks on the results
+        # the moment the dispatch is enqueued.  The provider's dispatch
+        # account times the device by when a waiter that was ALREADY
+        # blocked saw the output (`t_ready`, bccsp/dispatch_account.py),
+        # and a driver that begins block N+1 before finishing block N
+        # keeps this fetch ahead of the later dispatch.
+        holder: dict = {}
+        t_disp = time.perf_counter()
+        econ = self._econ
+
+        def run():
+            try:
+                holder["out"] = resolve()
+                econ.note_verify(t_disp, time.perf_counter())
+            except BaseException as exc:   # re-raised in _await
+                holder["err"] = exc
+
+        th = threading.Thread(target=run, daemon=True)
+        th.start()
+        return part, th, holder
+
+    def _await(self, handle: tuple) -> np.ndarray:
+        """Wait for `_dispatch`'s results, store them in the cache and
+        return the block's verdicts as one bool array aligned with the
+        dispatched items.  The store — a digest and a MAC per item — is
+        inside the caller's dispatch-wait clock, under its own span so
+        that the wait is told from it."""
+        part, th, holder = handle
+        if th is not None:
+            th.join()
+            if "err" in holder:
+                raise holder["err"]
+        t0 = time.perf_counter()
+        verdicts = part.settle(holder.get("out"), site="commit",
+                               scope=self.channel_id)
+        if th is not None and self.verify_cache is not None:
+            tracing.tracer.record_span(
+                "validator.cache_store", t0, time.perf_counter(),
+                attributes={"items": part.n_misses})
+        self._note_coverage(part)
+        return verdicts
+
+    def _collected(self, t0: float, num: int, n: int, part) -> float:
+        """Close pass 1: the collect interval into the overlap window
+        and the `validator.collect` span; returns its seconds."""
+        collect_s = time.perf_counter() - t0
+        self._econ.note_collect(t0, t0 + collect_s)
+        n_unique = part.n_hits + part.n_misses
+        attrs = {"block": int(num), "txs": n, "unique_items": n_unique}
+        if self.verify_cache is not None and n_unique:
+            attrs["cache_hits"] = part.n_hits
+            attrs["cache_misses"] = part.n_misses
+        if part.links:
+            # stitch the block trace to the speculative spans whose
+            # verdicts it consumed
+            attrs["links"] = sorted(part.links)[:8]
+        tracing.tracer.record_span(
+            "validator.collect", t0, t0 + collect_s, attributes=attrs)
+        return collect_s
 
     def _begin_inner(self, block: Block) -> dict:
         n = n_txs(block)
@@ -647,76 +719,6 @@ class TxValidator:
         seen_txids: Dict[str, int] = {}
         items: Dict[VerifyItem, None] = {}   # insertion-ordered dedup set
         works: List[_TxWork] = []
-        # (result-or-None, dispatched keys, [(key, verdict, trace)])
-        resolvers: List[Tuple] = []
-        flushed = 0
-        hit_n = miss_n = 0
-        spec_links: set = set()
-        cache = self.verify_cache
-        chunk = self.overlap_chunk
-
-        def flush():
-            nonlocal flushed, hit_n, miss_n
-            keys = list(items.keys())
-            new = keys[flushed:]
-            if new:
-                # verify-once: MAC-verified cached verdicts skip the
-                # device entirely; anything else — miss, MAC failure,
-                # stale epoch — goes through the full dispatch below
-                hits: list = []
-                if cache is not None:
-                    t_filter = time.perf_counter()
-                    miss_pos, raw_hits = cache.filter(new)
-                    tracing.tracer.record_span(
-                        "validator.cache_filter", t_filter,
-                        time.perf_counter(), attributes={"items": len(new)})
-                    hits = [(new[i], v, tr) for i, v, tr in raw_hits]
-                    new = [new[i] for i in miss_pos]
-                    hit_n += len(hits)
-                    miss_n += len(new)
-                    for _, _, tr in hits:
-                        if tr:
-                            spec_links.add(tr)
-                if not new:
-                    if hits:
-                        resolvers.append((None, [], hits))
-                    flushed = len(keys)
-                    return
-                # items are their OWN dedup keys (VerifyItem NamedTuple)
-                with dispatch_site("validator"):
-                    resolve = self._resolve_provider(
-                        len(new)).batch_verify_async(new)
-                # EAGER background resolution: start fetching results
-                # the moment the dispatch is enqueued.  Relayed device
-                # transports serialize a result read behind any LATER
-                # dispatch's transfers+compute (measured +0.25 s per
-                # block in the streamed window when the next block's
-                # dispatch was enqueued first); a thread that is already
-                # blocked on the results keeps the fetch ahead of them.
-                holder: dict = {}
-                t_disp = time.perf_counter()
-                econ = self._econ
-
-                def run(resolve=resolve, holder=holder, t_disp=t_disp,
-                        econ=econ):
-                    try:
-                        holder["out"] = resolve()
-                        econ.note_verify(t_disp, time.perf_counter())
-                    except BaseException as exc:   # re-raised at join
-                        holder["err"] = exc
-
-                th = threading.Thread(target=run, daemon=True)
-                th.start()
-
-                def result(th=th, holder=holder):
-                    th.join()
-                    if "err" in holder:
-                        raise holder["err"]
-                    return holder["out"]
-
-                resolvers.append((result, new, hits))
-                flushed = len(keys)
-
         if use_fast:
             recs = _fastcollect.collect(block.data, self.channel_id)
         else:
@@ -738,28 +740,14 @@ class TxValidator:
                 n_aborted += 1
             if work is not None:
                 works.append(work)
-            if (tx_num + 1) % chunk == 0:
-                flush()
-        flush()
+        verify = self._dispatch(list(items))
         self._note_early_aborts(n_aborted)
         self._inflight_txids.append((num, seen_txids))
-        collect_s = time.perf_counter() - t0
-        self._econ.note_collect(t0, t0 + collect_s)
-        attrs = {"block": int(num), "txs": n, "unique_items": len(items)}
-        if hit_n or miss_n:
-            attrs["cache_hits"] = hit_n
-            attrs["cache_misses"] = miss_n
-        if spec_links:
-            # stitch the block trace to the speculative spans whose
-            # verdicts it consumed
-            attrs["links"] = sorted(spec_links)[:8]
-        tracing.tracer.record_span(
-            "validator.collect", t0, t0 + collect_s, attributes=attrs)
+        collect_s = self._collected(t0, num, n, verify[0])
         return {"block": block, "flags": flags, "items": items,
-                "works": works, "resolvers": resolvers,
+                "works": works, "verify": verify,
                 "msps": self._msps_snapshot, "seen_txids": seen_txids,
-                "collect_s": collect_s, "cache_hits": hit_n,
-                "cache_misses": miss_n}
+                "collect_s": collect_s}
 
     def _begin_deep(self, block: Block, num: int, carry: list,
                     doomed=None) -> dict:
@@ -768,9 +756,8 @@ class TxValidator:
         memo slot assignment, and flat dispatch-ordered VerifyItem
         interning all run without per-tx Python bytecode.  Python's
         per-block work shrinks to resolving each UNIQUE identity once
-        and launching the async device dispatches, which is what lets
-        collect-under-verify overlap approach the device-bound limit in
-        the streamed window.  Flag parity with the classic tail and the
+        and launching the block's async device dispatch (`_dispatch`).
+        Flag parity with the classic tail and the
         pure-Python mirror is enforced differentially
         (tests/test_committer.py)."""
         n = n_txs(block)
@@ -818,96 +805,16 @@ class TxValidator:
         index: Dict[VerifyItem, int] = {}   # item -> dispatch position
         plans: list = []
         pol_cache: dict = {}
-        # (result, verdict positions, dispatched items)
-        resolvers: List[Tuple] = []
-        flushed = 0
-        n_refs = 0
-        hit_n = miss_n = 0
-        hit_fills: list = []       # (verdict position, cached verdict)
-        spec_links: set = set()
-        cache = self.verify_cache
-
-        def flush():
-            nonlocal flushed, hit_n, miss_n
-            keys = list(index.keys())
-            new = keys[flushed:]
-            if new:
-                # verify-once partition — same contract as the classic
-                # flush: only MAC-verified fresh hits skip the device
-                if cache is not None:
-                    t_filter = time.perf_counter()
-                    miss_pos, raw_hits = cache.filter(new)
-                    tracing.tracer.record_span(
-                        "validator.cache_filter", t_filter,
-                        time.perf_counter(), attributes={"items": len(new)})
-                    positions = [flushed + i for i in miss_pos]
-                    for i, v, tr in raw_hits:
-                        hit_fills.append((flushed + i, v))
-                        if tr:
-                            spec_links.add(tr)
-                    new = [new[i] for i in miss_pos]
-                    hit_n += len(raw_hits)
-                    miss_n += len(new)
-                    if not new:
-                        flushed = len(keys)
-                        return
-                else:
-                    positions = list(range(flushed, flushed + len(new)))
-                with dispatch_site("validator"):
-                    resolve = self._resolve_provider(
-                        len(new)).batch_verify_async(new)
-                # eager background resolution — same rationale as the
-                # classic path's flush(): keep the result fetch ahead of
-                # any later dispatch on relayed transports
-                holder: dict = {}
-                t_disp = time.perf_counter()
-                econ = self._econ
-
-                def run(resolve=resolve, holder=holder, t_disp=t_disp,
-                        econ=econ):
-                    try:
-                        holder["out"] = resolve()
-                        econ.note_verify(t_disp, time.perf_counter())
-                    except BaseException as exc:   # re-raised at join
-                        holder["err"] = exc
-
-                th = threading.Thread(target=run, daemon=True)
-                th.start()
-
-                def result(th=th, holder=holder):
-                    th.join()
-                    if "err" in holder:
-                        raise holder["err"]
-                    return holder["out"]
-
-                resolvers.append((result, positions, new))
-                flushed = len(keys)
-
-        chunk = self.overlap_chunk
-        policy_for = self.policies.policy_for
-        for start in range(0, len(works), chunk):
-            n_refs += _fastcollect.assemble(
-                works[start:start + chunk], c_ents, e_ents, endorsers,
-                codes, index, plans, VerifyItem, SCHEME_P256,
-                policy_for, pol_cache)
-            flush()
+        n_refs = _fastcollect.assemble(
+            works, c_ents, e_ents, endorsers, codes, index, plans,
+            VerifyItem, SCHEME_P256, self.policies.policy_for, pol_cache)
+        verify = self._dispatch(list(index))
         self._inflight_txids.append((num, seen_txids))
-        collect_s = time.perf_counter() - t0
-        self._econ.note_collect(t0, t0 + collect_s)
-        attrs = {"block": int(num), "txs": n, "unique_items": len(index)}
-        if hit_n or miss_n:
-            attrs["cache_hits"] = hit_n
-            attrs["cache_misses"] = miss_n
-        if spec_links:
-            attrs["links"] = sorted(spec_links)[:8]
-        tracing.tracer.record_span(
-            "validator.collect", t0, t0 + collect_s, attributes=attrs)
+        collect_s = self._collected(t0, num, n, verify[0])
         return {"deep": True, "block": block, "codes": codes,
-                "plans": plans, "items": index, "resolvers": resolvers,
+                "plans": plans, "items": index, "verify": verify,
                 "msps": self._msps_snapshot, "seen_txids": seen_txids,
-                "collect_s": collect_s, "n_refs": n_refs,
-                "cache_hits": hit_n, "cache_misses": miss_n,
-                "hit_fills": hit_fills}
+                "collect_s": collect_s, "n_refs": n_refs}
 
     # per-block stage SLIs + live overlap gauge (the SLO plane's inputs;
     # the "commit" stage lands next door in committer._observe_metrics)
@@ -933,7 +840,7 @@ class TxValidator:
         except Exception:
             pass
 
-    def _note_coverage(self, state: dict) -> None:
+    def _note_coverage(self, part) -> None:
         """Verify-once economics for one block: feed the rolling
         coverage window and, on a node whose cache is speculatively
         filled (the gateway host), publish speculative_coverage_frac —
@@ -942,9 +849,7 @@ class TxValidator:
         cache = self.verify_cache
         if cache is None:
             return
-        hits = state.get("cache_hits", 0)
-        total = hits + state.get("cache_misses", 0)
-        cache.coverage.note(hits, total)
+        cache.coverage.note(part.n_hits, part.n_hits + part.n_misses)
         if not cache.speculative_attached:
             return
         try:
@@ -962,34 +867,14 @@ class TxValidator:
         except Exception:
             pass
 
-    def _store_verdicts(self, cache, items, verdicts) -> None:
-        """The dispatch's verdicts into the node's verdict cache — a
-        digest and a MAC per item, inside the dispatch-wait clock: its
-        own span, so that wait is told from this."""
-        t0 = time.perf_counter()
-        cache.store(items, verdicts, site="commit", scope=self.channel_id)
-        tracing.tracer.record_span(
-            "validator.cache_store", t0, time.perf_counter(),
-            attributes={"items": len(items)})
-
     def _finish_deep(self, state: dict) -> ValidationResult:
         block = state["block"]
         codes = state["codes"]
         index = state["items"]
-        collect_s = state["collect_s"]
 
         t0 = time.perf_counter()
-        verdict = np.zeros(len(index), dtype=np.uint8)
-        for pos, v in state.get("hit_fills", ()):
-            verdict[pos] = 1 if v else 0
-        cache = self.verify_cache
-        for resolve, positions, sub in state["resolvers"]:
-            out = resolve()
-            if cache is not None:
-                self._store_verdicts(cache, sub, out)
-            verdict[np.asarray(positions, dtype=np.intp)] = \
-                np.asarray(out, dtype=bool)
-        self._note_coverage(state)
+        # positional over `index`, as gate and the fused path read it
+        verdict = self._await(state["verify"]).view(np.uint8)
         dispatch_s = time.perf_counter() - t0
         tracing.tracer.record_span(
             "validator.dispatch_wait", t0, t0 + dispatch_s,
@@ -1008,27 +893,8 @@ class TxValidator:
             _fastcollect.gate(state["plans"], verdict, codes,
                               self.validation_plugin, self.evaluator, {})
             flags = TxFlags.from_bytes(bytes(codes))
-        gate_s = time.perf_counter() - t0
-        tracing.tracer.record_span(
-            "validator.gate", t0, t0 + gate_s,
-            attributes={"block": int(block.header.number),
-                        "txs": len(state["plans"])})
-
-        t0 += gate_s
-        block.metadata.items[META_TXFLAGS] = flags.to_bytes()
-        self._observe_block(collect_s, dispatch_s, gate_s)
-        logger.info(
-            "[%s] validated block %d: %d/%d valid | collect=%.1fms "
-            "dispatch=%.1fms (%d uniq sigs) gate=%.1fms",
-            self.channel_id, block.header.number, flags.valid_count(),
-            n_txs(block), collect_s * 1e3, dispatch_s * 1e3,
-            len(index), gate_s * 1e3)
-        # the flags' way into the block's metadata (a BlockView decodes
-        # its metadata here), the stage metrics, the log line
-        tracing.tracer.record_span("validator.finish", t0,
-                                   time.perf_counter())
-        return ValidationResult(flags, collect_s, dispatch_s, gate_s,
-                                state["n_refs"], len(index))
+        return self._finished(state, flags, t0, dispatch_s,
+                              len(state["plans"]))
 
     def _finish_inner(self, state: dict) -> ValidationResult:
         if state.get("deep"):
@@ -1037,23 +903,11 @@ class TxValidator:
         flags = state["flags"]
         items = state["items"]
         works = state["works"]
-        collect_s = state["collect_s"]
 
         t0 = time.perf_counter()
         keys = list(items.keys())
-        verdict: Dict[Tuple, bool] = {}
-        cache = self.verify_cache
-        for resolve, chunk_keys, hits in state["resolvers"]:
-            for k, v, _tr in hits:
-                verdict[k] = bool(v)
-            if resolve is None:
-                continue
-            out = resolve()
-            if cache is not None:
-                self._store_verdicts(cache, chunk_keys, out)
-            verdict.update(
-                (k, bool(v)) for k, v in zip(chunk_keys, out))
-        self._note_coverage(state)
+        verdict: Dict[Tuple, bool] = dict(
+            zip(keys, self._await(state["verify"]).tolist()))
         dispatch_s = time.perf_counter() - t0
         tracing.tracer.record_span(
             "validator.dispatch_wait", t0, t0 + dispatch_s,
@@ -1076,13 +930,25 @@ class TxValidator:
         plugin = self._memoized_plugin({})
         for work in works:
             self._gate_tx(work, flags, verdict, overlay, plugin=plugin)
+        return self._finished(state, flags, t0, dispatch_s, len(works))
+
+    def _finished(self, state: dict, flags: TxFlags, t0: float,
+                  dispatch_s: float, n_gated: int) -> ValidationResult:
+        """Close pass 2 for either tail: the gate's span, then the
+        flags' way into the block's metadata (a BlockView decodes its
+        metadata here), the stage metrics and the log line."""
         gate_s = time.perf_counter() - t0
+        block = state["block"]
+        collect_s = state["collect_s"]
+        n_unique = len(state["items"])
         tracing.tracer.record_span(
             "validator.gate", t0, t0 + gate_s,
             attributes={"block": int(block.header.number),
-                        "txs": len(works)})
-
-        n_refs = sum(1 + sum(len(s) for _, _, s in w.namespaces) for w in works)
+                        "txs": n_gated})
+        n_refs = state.get("n_refs")
+        if n_refs is None:       # the classic tail counts its own here
+            n_refs = sum(1 + sum(len(s) for _, _, s in w.namespaces)
+                         for w in state["works"])
         t0 += gate_s
         block.metadata.items[META_TXFLAGS] = flags.to_bytes()
         self._observe_block(collect_s, dispatch_s, gate_s)
@@ -1090,12 +956,12 @@ class TxValidator:
             "[%s] validated block %d: %d/%d valid | collect=%.1fms "
             "dispatch=%.1fms (%d uniq sigs) gate=%.1fms",
             self.channel_id, block.header.number, flags.valid_count(),
-            len(block.data), collect_s * 1e3, dispatch_s * 1e3, len(keys),
+            n_txs(block), collect_s * 1e3, dispatch_s * 1e3, n_unique,
             gate_s * 1e3)
         tracing.tracer.record_span("validator.finish", t0,
                                    time.perf_counter())
         return ValidationResult(flags, collect_s, dispatch_s, gate_s,
-                                n_refs, len(keys))
+                                n_refs, n_unique)
 
 
 def _false_oracle(_txid: str) -> bool:

@@ -945,7 +945,7 @@ class PeerNode:
                 self._channel_msps, epoch_source=self._channel_epoch)
 
         # tx tracing + flight recorder: on by default for nodes (the
-        # import-time default stays off so libraries/bench pay nothing);
+        # import-time default stays off so libraries pay nothing);
         # sample rate and recorder capacity ride localconfig, e.g.
         # FABRIC_TPU_PEER_TRACING__SAMPLE_RATE=0.1
         from fabric_tpu.ops_plane import tracing as _tracing

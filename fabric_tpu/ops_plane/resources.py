@@ -34,8 +34,8 @@ every probe degrades to "metric absent" off-Linux or when a source is
 missing, never to an exception on the sampling thread.
 
 `provenance()` also lives here: the {platform, device_kind, n_devices,
-hostname} stamp bench.py records in every BENCH/MULTICHIP JSON, making
-the ROADMAP's "cpu-virtual caveat" machine-readable.
+hostname} stamp `__graft_entry__.dryrun_multichip` puts in its record,
+so that a virtual CPU mesh is never read as a chip.
 """
 
 from __future__ import annotations

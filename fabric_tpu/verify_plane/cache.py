@@ -48,6 +48,8 @@ import threading
 from collections import OrderedDict, deque
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import numpy as np
+
 REASON_MAC = "mac"
 REASON_STALE = "stale"
 
@@ -273,30 +275,37 @@ class VerdictCache:
                 pass
         return dup
 
-    def filter(self, items: Sequence) -> Tuple[List[int], List[Tuple]]:
-        """Partition a dispatch batch against the cache.
-
-        Returns (miss_positions, hits) where `hits` is a list of
-        (position, verdict, trace_id).  Positions index into `items`.
-        """
-        miss: List[int] = []
-        hits: List[Tuple[int, bool, str]] = []
+    def partition(self, items: Sequence) -> "_Partition":
+        """Split a dispatch batch against the cache: one `lookup` per
+        item.  The caller dispatches `.misses` however it likes and
+        hands their verdicts to `.settle`."""
+        misses: List = []
+        miss_pos: List[int] = []
+        hit_pos: List[int] = []
+        hit_verdicts: List[bool] = []
+        links = set()
+        lookup = self.lookup
         for i, it in enumerate(items):
-            v, trace = self.lookup(it)
+            v, trace = lookup(it)
             if v is None:
-                miss.append(i)
+                miss_pos.append(i)
+                misses.append(it)
             else:
-                hits.append((i, v, trace))
-        return miss, hits
+                hit_pos.append(i)
+                hit_verdicts.append(v)
+                if trace:
+                    links.add(trace)
+        return _Partition(self, len(items), misses, miss_pos, hit_pos,
+                          hit_verdicts, links)
 
-    def store(self, items: Sequence, verdicts, site: str,
-              trace_id: str = "", scope: str = "") -> None:
+    def _store(self, items: Sequence, verdicts, site: str,
+               trace_id: str = "", scope: str = "") -> None:
         """Record a device dispatch's results and its economics: `items`
         aligned with `verdicts`, all freshly verified at `site` on
         behalf of channel `scope`."""
         dupes = 0
         for it, v in zip(items, verdicts):
-            if self.put(it, bool(v), trace_id=trace_id, scope=scope):
+            if self.put(it, v, trace_id=trace_id, scope=scope):
                 dupes += 1
         note_device_verifications(len(items), site)
         if dupes:
@@ -337,6 +346,54 @@ class VerdictCache:
                 "evictions_total": total("evictions")}
 
 
+class _Partition:
+    """One batch split against a verdict cache, between its lookup and
+    the return of its dispatch.  The only place that knows how hits and
+    fresh verdicts are kept and put back in the batch's order; it knows
+    no provider, no thread and no span."""
+
+    __slots__ = ("misses", "links", "n_hits", "n_misses", "_cache",
+                 "_miss_pos", "_hit_pos", "_hit_verdicts")
+
+    def __init__(self, cache: Optional[VerdictCache], n: int, misses: List,
+                 miss_pos, hit_pos, hit_verdicts, links: set):
+        self.misses = misses       # the items to dispatch, in batch order
+        self.links = links         # speculative trace ids of the hits
+        self.n_misses = len(misses)
+        self.n_hits = n - len(misses)
+        self._cache = cache
+        self._miss_pos = np.asarray(miss_pos, dtype=np.intp)
+        self._hit_pos = np.asarray(hit_pos, dtype=np.intp)
+        self._hit_verdicts = np.asarray(hit_verdicts, dtype=bool)
+
+    def settle(self, out=None, *, site: str, scope: str = "",
+               trace_id: str = "") -> np.ndarray:
+        """Store the misses' verdicts `out` (aligned with `.misses`,
+        freshly verified at `site` for channel `scope`) and return the
+        whole batch's verdicts, aligned with the partitioned items.
+        With nothing missed `out` is not looked at."""
+        verdicts = np.zeros(self.n_hits + self.n_misses, dtype=bool)
+        verdicts[self._hit_pos] = self._hit_verdicts
+        if self.misses:
+            fresh = np.asarray(out, dtype=bool)
+            if fresh.shape != (self.n_misses,):
+                raise ValueError(
+                    f"{self.n_misses} items dispatched, verdicts of "
+                    f"shape {fresh.shape} returned")
+            if self._cache is not None:
+                self._cache._store(self.misses, fresh.tolist(), site,
+                                   trace_id=trace_id, scope=scope)
+            verdicts[self._miss_pos] = fresh
+        return verdicts
+
+
+def all_miss(items: List) -> _Partition:
+    """The partition of a site with no cache wired: every item is
+    dispatched and `settle` stores nothing."""
+    return _Partition(None, len(items), items, range(len(items)), (), (),
+                      set())
+
+
 class CachingProvider:
     """Provider wrapper that consults/extends a VerdictCache around
     `batch_verify` — drops in wherever a Provider goes (the orderer's
@@ -358,44 +415,16 @@ class CachingProvider:
         return bool(self.batch_verify([item])[0])
 
     def batch_verify(self, items):
-        import numpy as np
-        items = list(items)
-        out = np.zeros(len(items), dtype=bool)
-        miss, hits = self._cache.filter(items)
-        for pos, v, _ in hits:
-            out[pos] = v
-        if miss:
-            sub = [items[i] for i in miss]
-            res = self._inner.batch_verify(sub)
-            self._cache.store(sub, res, self._site, scope=self._scope)
-            for i, v in zip(miss, res):
-                out[i] = bool(v)
-        return out
+        part = self._cache.partition(list(items))
+        out = self._inner.batch_verify(part.misses) if part.misses else None
+        return part.settle(out, site=self._site, scope=self._scope)
 
     def batch_verify_async(self, items):
-        import numpy as np
-        items = list(items)
-        miss, hits = self._cache.filter(items)
-        if not miss:
-            out = np.zeros(len(items), dtype=bool)
-            for pos, v, _ in hits:
-                out[pos] = v
-            return lambda: out
-        sub = [items[i] for i in miss]
-        resolve = self._inner.batch_verify_async(sub)
-        cache, site, scope = self._cache, self._site, self._scope
-
-        def resolved():
-            res = resolve()
-            cache.store(sub, res, site, scope=scope)
-            out = np.zeros(len(items), dtype=bool)
-            for pos, v, _ in hits:
-                out[pos] = v
-            for i, v in zip(miss, res):
-                out[i] = bool(v)
-            return out
-
-        return resolved
+        part = self._cache.partition(list(items))
+        resolve = (self._inner.batch_verify_async(part.misses)
+                   if part.misses else lambda: None)
+        return lambda: part.settle(resolve(), site=self._site,
+                                   scope=self._scope)
 
     def __getattr__(self, name):
         return getattr(self._inner, name)

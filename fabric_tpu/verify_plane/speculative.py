@@ -233,13 +233,12 @@ class SpeculativeVerifier:
         under a span whose trace id rides into the cache entries so the
         commit-time block trace can link back to the speculative work.
         Returns that trace id ("" when nothing was dispatched)."""
-        miss, _hits = self.cache.filter(items)
-        if not miss:
+        part = self.cache.partition(items)
+        if not part.misses:
             return ""
-        sub = [items[i] for i in miss]
         span = tracing.tracer.start_span(
             "verify_plane.speculative",
-            attributes={"stage": stage, "items": len(sub)})
+            attributes={"stage": stage, "items": len(part.misses)})
         trace_id = span.context.trace_id if span.recording else ""
         # enter the span so the provider's bccsp.batch_verify child
         # (require_parent) attaches — this worker thread has no other
@@ -248,8 +247,8 @@ class SpeculativeVerifier:
             # async-dispatch API: same result as batch_verify, but it
             # is the instrumented path (bccsp.batch_verify child span
             # with device wall time)
-            out = self.provider_source().batch_verify_async(sub)()
-            self.cache.store(sub, out, site="speculative",
-                             trace_id=trace_id, scope=scope)
-            self.dispatched += len(sub)
+            out = self.provider_source().batch_verify_async(part.misses)()
+            part.settle(out, site="speculative", trace_id=trace_id,
+                        scope=scope)
+            self.dispatched += len(part.misses)
         return trace_id

@@ -1,8 +1,8 @@
 """Test configuration: run everything on a virtual 8-device CPU mesh.
 
 Must set env vars BEFORE jax is imported anywhere (mirrors the driver's
-dryrun_multichip environment).  Real-TPU benchmarking happens in bench.py,
-not under pytest.
+dryrun_multichip environment).  Measurement on the chip is the benchmark's
+(`BENCHMARK.json`, `benchmark/run.py`), not pytest's.
 """
 import os
 

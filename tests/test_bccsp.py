@@ -246,7 +246,7 @@ def test_degrading_provider_delegates_primary_attributes():
     from fabric_tpu.bccsp.degrade import DegradingProvider
     primary = JaxTpuProvider()
     deg = DegradingProvider(primary, SoftwareProvider())
-    assert deg.stats is primary.stats       # bench reads provider.stats
+    assert deg.stats is primary.stats       # /state and the benchmark read provider.stats
 
 
 def test_empty_batch(tpu):

@@ -1,7 +1,7 @@
 """Provider dispatch-economics regression tests.
 
 Round 4 shipped a fast lane that re-uploaded ~124 MB of key tables per
-dispatch; the driver bench caught it, CI did not.  These tests pin the
+dispatch; a timed run on the device caught it, CI did not.  These tests pin the
 economics the bank redesign (ops/device_bank.py) guarantees:
 
   * tables cross host->device ONCE per key (h2d_bytes accounting);
@@ -256,13 +256,12 @@ def _overlap(win, busy):
     return total
 
 
-def test_window_collect_under_verify(monkeypatch):
+def test_window_collect_under_verify():
     """Streamed-window economics regression (the config-5 pipeline):
 
-    * validate_begin must NEVER synchronize with the device — not per
-      block and not per chunk (FABRIC_TPU_VALIDATE_CHUNK forces several
-      intra-block flushes here); any hidden resolve() on the begin path
-      would cost >= one injected 0.25 s device delay per block;
+    * validate_begin must NEVER synchronize with the device; any hidden
+      resolve() on the begin path would cost >= one injected 0.25 s
+      device delay per block;
     * the measured collect-under-verify fraction for steady-state blocks
       (every begin after the pipeline fills) must clear a floor — the
       depth-2 window drives collect of block N+1 entirely under the
@@ -274,7 +273,6 @@ def test_window_collect_under_verify(monkeypatch):
     from fabric_tpu.policy import parse_policy
     from fabric_tpu.protocol import KVWrite, NsRwSet, TxRwSet, build
 
-    monkeypatch.setenv("FABRIC_TPU_VALIDATE_CHUNK", "10")
     org = DevOrg("Org1")
     msps = {org.mspid: CachedMSP(org.msp())}
     policies = PolicyRegistry(parse_policy("OR('Org1.member')"))
@@ -306,7 +304,7 @@ def test_window_collect_under_verify(monkeypatch):
         res = validator.validate_finish(pending.pop(0))
         assert res.flags.valid_count() == 40
 
-    # 1: begin never blocked on the device (per block or per chunk)
+    # 1: begin never blocked on the device
     slowest = max(e - s for s, e in begins)
     assert slowest < prov.delay * 0.5, (slowest, begins)
     # 2: steady-state collects ran under an in-flight device verify

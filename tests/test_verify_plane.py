@@ -305,6 +305,189 @@ def test_caching_provider_async_all_hit_path(orgs, sw_provider):
     assert resolve().all()
 
 
+# -- the one primitive: partition / settle ------------------------------------
+
+
+def _reference_filter_store(cache, items, verify, site, scope, trace_id=""):
+    """The partition as its callers wrote it out by hand before it had one
+    home (`filter` + `store`): the plain reference, on the cache's public
+    `lookup` and `put` only."""
+    out = [None] * len(items)
+    missed = []
+    for i, it in enumerate(items):
+        v, _ = cache.lookup(it)
+        if v is None:
+            missed.append(i)
+        else:
+            out[i] = v
+    if missed:
+        dupes = 0
+        for i, v in zip(missed, verify([items[i] for i in missed])):
+            dupes += cache.put(items[i], bool(v), trace_id=trace_id,
+                               scope=scope)
+            out[i] = bool(v)
+        _m()["device"].add(len(missed), site=site)
+        if dupes:
+            _m()["dupes"].add(dupes, site=site)
+    return out, len(missed)
+
+
+def _broken(env):
+    return Envelope(env.payload, env.signature[:-2] + b"\x00\x01")
+
+
+def _prepare(case, cache, items, truth):
+    """Bring `cache` into the state `case` names; returns how many of
+    `items` must be dispatched."""
+    n = len(items)
+    cached = {"all_miss": 0, "all_hit": n}.get(case, n - 2)
+    for it, v in zip(items[:cached], truth[:cached]):
+        cache.put(it, v, scope="ch", trace_id="spec-1")
+    if case == "mac_tampered":
+        # items[1] is the broken signature: flip its cached False to True
+        d = item_digest(items[1])
+        mac, verdict, scope, epoch, trace = cache._data[d]
+        assert verdict is False
+        cache._data[d] = (mac, True, scope, epoch, trace)
+        return n - cached + 1
+    if case == "stale_epoch":
+        cache.put(items[0], truth[0], scope="other")
+        cache.set_epoch(3, scope="other")
+        return n - cached + 1
+    return n - cached
+
+
+@pytest.mark.parametrize("case", ["all_hit", "all_miss", "mixed",
+                                  "mac_tampered", "stale_epoch"])
+def test_partition_settle_equals_filter_then_store(orgs, sw_provider, case):
+    """One pair of calls, five cache states: the answer is aligned with
+    the input, and verdicts, counters and the cache's contents are what
+    the hand-written filter + store gave."""
+    org1, org2 = orgs
+    msps = _msps(org1, org2)
+    envs = [make_tx(org1, org2) for _ in range(6)]
+    envs[1] = _broken(envs[1])
+    items = [creator_item(e, msps) for e in envs]
+    truth = [bool(v) for v in sw_provider.batch_verify(items)]
+    assert truth == [True, False, True, True, True, True]
+
+    secret = b"k" * 32
+    ref, new = (VerdictCache(capacity=64, secret=secret) for _ in range(2))
+    want_missed = _prepare(case, ref, items, truth)
+    assert _prepare(case, new, items, truth) == want_missed
+
+    before = counts()
+    ref_out, ref_missed = _reference_filter_store(
+        ref, items, sw_provider.batch_verify, "commit", "ch", "t-9")
+    ref_moved = delta(before, counts())
+    assert ref_missed == want_missed and ref_out == truth
+
+    before = counts()
+    part = new.partition(items)
+    assert (part.n_hits, part.n_misses) == (len(items) - want_missed,
+                                            want_missed)
+    missed = set(part.misses)
+    assert part.misses == [it for it in items if it in missed]  # in order
+    assert part.links == ({"spec-1"} if part.n_hits else set())
+    out = part.settle(
+        sw_provider.batch_verify(part.misses) if part.misses else None,
+        site="commit", scope="ch", trace_id="t-9")
+    moved = delta(before, counts())
+
+    assert out.dtype == bool and out.tolist() == truth
+    assert moved == ref_moved
+    assert moved["mac"] == (case == "mac_tampered")
+    assert moved["stale"] == (case == "stale_epoch")
+    assert moved["device"] == want_missed and moved["dupes"] == 0
+    assert dict(new._data) == dict(ref._data)            # MACs included
+    assert list(new._data) == list(ref._data)            # and LRU order
+    for it, v in zip(items, truth):                      # re-stored too
+        assert new.peek(it) is v
+
+
+def test_settle_refuses_a_misaligned_answer(orgs, sw_provider):
+    org1, org2 = orgs
+    msps = _msps(org1, org2)
+    items = [creator_item(make_tx(org1, org2), msps) for _ in range(3)]
+    cache = VerdictCache(capacity=8)
+    part = cache.partition(items)
+    with pytest.raises(ValueError):
+        part.settle([True, True], site="commit")
+    assert len(cache) == 0                               # nothing stored
+
+
+def _block_items(envs, msps):
+    items = {}
+    for e in envs:
+        creators, endorsements = derive_items(e.serialize(), "ch", msps)
+        items.update(dict.fromkeys(creators + endorsements))
+    return list(items)
+
+
+@pytest.mark.parametrize("caller", ["provider_sync", "provider_async",
+                                    "speculative", "validator_classic",
+                                    "validator_deep"])
+def test_five_callers_one_partition(orgs, sw_provider, caller):
+    """Every site that consults the cache goes through the same pair of
+    calls: same verdicts in the cache, one device verification per
+    unique item, booked under the caller's own site and scope; a second
+    pass dispatches nothing."""
+    org1, org2 = orgs
+    msps = _msps(org1, org2)
+    shared = [org1.new_identity("e1"), org2.new_identity("e2")]
+    envs = [make_tx(org1, org2, endorsers=shared) for _ in range(5)]
+    envs[2] = _broken(envs[2])
+    items = _block_items(envs, msps)
+    truth = [bool(v) for v in sw_provider.batch_verify(items)]
+    assert truth.count(False) == 1 and len(items) == 5 * 3
+
+    inner = CountingProvider(sw_provider)
+    cache = VerdictCache(capacity=256)
+    site, scope = "commit", "ch"
+    if caller.startswith("provider"):
+        site, scope = "orderer", "sys"
+        p = CachingProvider(inner, cache, site=site, scope=scope)
+        if caller == "provider_sync":
+            run = lambda: p.batch_verify(items)
+        else:
+            run = lambda: p.batch_verify_async(items)()
+    elif caller == "speculative":
+        site = "speculative"
+        spec = SpeculativeVerifier(cache, lambda: inner, lambda cid: msps)
+        run = lambda: spec._verify_batch(items, stage="overlap", scope="ch")
+    else:
+        codes = [int(ValidationCode.VALID)] * 5
+        codes[2] = int(ValidationCode.BAD_CREATOR_SIGNATURE)
+
+        def run():
+            # a validator per pass: the second is a peer's replay, not a
+            # block of duplicate txids
+            v = TxValidator("ch", msps, inner, _policies(),
+                            verify_cache=cache)
+            if caller == "validator_classic":
+                v.sbe_lookup = lambda ns, key: None      # keeps the classic tail
+            state = v.validate_begin(make_block(envs))
+            assert bool(state.get("deep")) == (caller == "validator_deep")
+            assert v.validate_finish(state).flags.codes() == codes
+
+    before = counts()
+    site_before = _m()["device"].value(site=site)
+    first = run()
+    assert inner.dispatched == len(items)
+    assert sorted(inner.batches[0]) == sorted(items)     # one dispatch
+    second = run()
+    assert inner.dispatched == len(items) and len(inner.batches) == 1
+    moved = delta(before, counts())
+    assert moved["device"] == len(items) and moved["dupes"] == 0
+    assert _m()["device"].value(site=site) - site_before == len(items)
+    assert moved["misses"] == len(items) and moved["hits"] == len(items)
+    for it, want in zip(items, truth):
+        assert cache.peek(it) is want
+        assert cache._data[item_digest(it)][2] == scope
+    if caller.startswith("provider"):
+        assert first.tolist() == truth == second.tolist()
+
+
 # -- differential fuzz: cache-on == cache-off --------------------------------
 
 
