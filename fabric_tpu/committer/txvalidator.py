@@ -45,7 +45,8 @@ from fabric_tpu.protocol import Block
 from fabric_tpu.protocol.txflags import TxFlags, ValidationCode
 from fabric_tpu.protocol.types import META_TXFLAGS
 from fabric_tpu.protocol.wire import n_txs
-from fabric_tpu.verify_plane.cache import all_miss
+from fabric_tpu.verify_plane.cache import (all_miss,
+                                           note_device_verifications)
 
 logger = logging.getLogger("fabric_tpu.committer")
 
@@ -181,6 +182,36 @@ class ValidationResult:
     @property
     def total_s(self) -> float:
         return self.collect_s + self.dispatch_s + self.gate_s
+
+
+# How many of a block's unique verify items `TxValidator._dispatch` asks
+# the verdict cache for before it asks for the rest.  A verdict is in the
+# cache only if an earlier verify site of THIS node (gateway ingress,
+# speculative verifier, a trusted attestation) saw the item; a replayed
+# block passed through none of them, and then a lookup (digest, lock,
+# dictionary) and a store (digest, two MACs, an eviction) per item is
+# 0.46 s of a 10,000-tx block for nothing (PERF.md §6, PR 31).  256 is
+# large enough that a block with 2% of its items cached escapes the
+# probe with probability < 0.6% (0.98**256), small enough that the probe
+# is ~1 ms, and above every block a served peer cuts at the deployments'
+# rates (~92 items), which therefore take the whole path unprobed.  A
+# wrong guess costs device time, never a verdict.  A constant: the rule
+# reads the block's own items and keeps nothing between blocks.
+PROBE = 256
+_PROBE_RUN = 4      # consecutive items a run: a transaction's creator
+                    # and endorsement signatures sit side by side
+
+
+def probe_positions(n: int) -> List[int]:
+    """The PROBE positions `_dispatch` looks up first in a block of
+    `n` > PROBE unique items: PROBE / 4 runs of 4 consecutive items,
+    the runs evenly spaced from the first item to the last.  A function
+    of `n` only; ascending, distinct, and not aliased with a
+    transaction's period in the dispatch order."""
+    runs = PROBE // _PROBE_RUN
+    last = n - _PROBE_RUN
+    return [r * last // (runs - 1) + j
+            for r in range(runs) for j in range(_PROBE_RUN)]
 
 
 class TxValidator:
@@ -613,16 +644,23 @@ class TxValidator:
         block.  Never waits for the device; `_await` does.  MAC-verified
         cached verdicts skip the device entirely; anything else — miss,
         MAC failure, stale epoch — is dispatched (the partition's home:
-        verify_plane/cache.py)."""
+        verify_plane/cache.py).  A block of more than PROBE items is
+        probed first, and where the probe finds the cache silent the
+        rest is dispatched unasked and nothing is stored."""
         cache = self.verify_cache
+        n = len(items)
         if cache is None or not items:
             part = all_miss(items)
         else:
             t0 = time.perf_counter()
-            part = cache.partition(items)
+            if n <= PROBE:
+                part = cache.partition(items)
+            else:
+                part = cache.partition_probed(items, probe_positions(n),
+                                              site="commit")
             tracing.tracer.record_span(
                 "validator.cache_filter", t0, time.perf_counter(),
-                attributes={"items": len(items)})
+                attributes={"items": n - part.n_bypassed})
         if not part.misses:
             return part, None, {}
         # items are their OWN dedup keys (VerifyItem NamedTuple)
@@ -655,7 +693,8 @@ class TxValidator:
         return the block's verdicts as one bool array aligned with the
         dispatched items.  The store — a digest and a MAC per item — is
         inside the caller's dispatch-wait clock, under its own span so
-        that the wait is told from it."""
+        that the wait is told from it.  A bypassed block stores nothing:
+        only the device's work is booked."""
         part, th, holder = handle
         if th is not None:
             th.join()
@@ -664,7 +703,9 @@ class TxValidator:
         t0 = time.perf_counter()
         verdicts = part.settle(holder.get("out"), site="commit",
                                scope=self.channel_id)
-        if th is not None and self.verify_cache is not None:
+        if part.n_bypassed:
+            note_device_verifications(part.n_misses, "commit")
+        elif th is not None and self.verify_cache is not None:
             tracing.tracer.record_span(
                 "validator.cache_store", t0, time.perf_counter(),
                 attributes={"items": part.n_misses})
@@ -680,7 +721,9 @@ class TxValidator:
         attrs = {"block": int(num), "txs": n, "unique_items": n_unique}
         if self.verify_cache is not None and n_unique:
             attrs["cache_hits"] = part.n_hits
-            attrs["cache_misses"] = part.n_misses
+            attrs["cache_misses"] = part.n_misses - part.n_bypassed
+            if part.n_bypassed:
+                attrs["cache_bypassed"] = part.n_bypassed
         if part.links:
             # stitch the block trace to the speculative spans whose
             # verdicts it consumed

@@ -95,6 +95,11 @@ def _m():
                 "evictions": registry.counter(
                     "verify_cache_evictions_total",
                     "entries dropped by the LRU bound"),
+                "bypassed": registry.counter(
+                    "verify_cache_bypassed_total",
+                    "items dispatched with no lookup and stored nowhere: "
+                    "the rest of a batch whose probe found no cached "
+                    "verdict, by verify site"),
                 "device": registry.counter(
                     "verify_plane_device_verifications_total",
                     "signatures actually dispatched to the provider, by "
@@ -298,6 +303,58 @@ class VerdictCache:
         return _Partition(self, len(items), misses, miss_pos, hit_pos,
                           hit_verdicts, links)
 
+    def partition_probed(self, items: Sequence, positions: Sequence[int],
+                         *, site: str) -> "_Partition":
+        """`partition` for a batch that may hold nothing this node has
+        seen: `positions` are looked up first.  Where one of them is
+        answered the whole batch is partitioned as `partition` would —
+        the probe's answers are used, not asked for again, and the LRU
+        order is one in-order walk's.  Where none is, the rest is not
+        asked: every item is dispatched, `settle` stores nothing, and
+        the items never looked up are counted once under
+        verify_cache_bypassed_total{site}.  A wrong guess costs device
+        time, never a verdict: an unasked item is verified afresh."""
+        lookup = self.lookup
+        probed = {i: lookup(items[i]) for i in positions}
+        if all(v is None for v, _ in probed.values()):
+            bypassed = len(items) - len(probed)
+            try:
+                _m()["bypassed"].add(bypassed, site=site)
+            except Exception:
+                pass
+            return all_miss(items, bypassed=bypassed)
+        misses: List = []
+        miss_pos: List[int] = []
+        hit_pos: List[int] = []
+        hit_verdicts: List[bool] = []
+        links = set()
+        for i, it in enumerate(items):
+            if i in probed:
+                v, trace = probed[i]
+                if v is not None:
+                    self._touch(it)
+            else:
+                v, trace = lookup(it)
+            if v is None:
+                miss_pos.append(i)
+                misses.append(it)
+            else:
+                hit_pos.append(i)
+                hit_verdicts.append(v)
+                if trace:
+                    links.add(trace)
+        return _Partition(self, len(items), misses, miss_pos, hit_pos,
+                          hit_verdicts, links)
+
+    def _touch(self, item) -> None:
+        """Make a live entry the most recently used, as a hit does, with
+        no lookup counted: a probed hit takes its place in the batch's
+        order."""
+        d = item_digest(item)
+        with self._lock:
+            if d in self._data:
+                self._data.move_to_end(d)
+
     def _store(self, items: Sequence, verdicts, site: str,
                trace_id: str = "", scope: str = "") -> None:
         """Record a device dispatch's results and its economics: `items`
@@ -352,15 +409,17 @@ class _Partition:
     fresh verdicts are kept and put back in the batch's order; it knows
     no provider, no thread and no span."""
 
-    __slots__ = ("misses", "links", "n_hits", "n_misses", "_cache",
-                 "_miss_pos", "_hit_pos", "_hit_verdicts")
+    __slots__ = ("misses", "links", "n_hits", "n_misses", "n_bypassed",
+                 "_cache", "_miss_pos", "_hit_pos", "_hit_verdicts")
 
     def __init__(self, cache: Optional[VerdictCache], n: int, misses: List,
-                 miss_pos, hit_pos, hit_verdicts, links: set):
+                 miss_pos, hit_pos, hit_verdicts, links: set,
+                 n_bypassed: int = 0):
         self.misses = misses       # the items to dispatch, in batch order
         self.links = links         # speculative trace ids of the hits
         self.n_misses = len(misses)
         self.n_hits = n - len(misses)
+        self.n_bypassed = n_bypassed   # of the misses, those never asked
         self._cache = cache
         self._miss_pos = np.asarray(miss_pos, dtype=np.intp)
         self._hit_pos = np.asarray(hit_pos, dtype=np.intp)
@@ -387,11 +446,12 @@ class _Partition:
         return verdicts
 
 
-def all_miss(items: List) -> _Partition:
-    """The partition of a site with no cache wired: every item is
-    dispatched and `settle` stores nothing."""
+def all_miss(items: List, bypassed: int = 0) -> _Partition:
+    """The partition of a site with no cache wired, or of a batch whose
+    probe found the cache silent (`bypassed` of its items never asked):
+    every item is dispatched and `settle` stores nothing."""
     return _Partition(None, len(items), items, range(len(items)), (), (),
-                      set())
+                      set(), n_bypassed=bypassed)
 
 
 class CachingProvider:

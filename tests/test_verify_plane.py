@@ -17,6 +17,7 @@ import pytest
 
 from fabric_tpu.bccsp.factory import init_factories, FactoryOpts
 from fabric_tpu.committer import Committer, PolicyRegistry, TxValidator
+from fabric_tpu.committer.txvalidator import PROBE, probe_positions
 from fabric_tpu.ledger import KVLedger, LedgerConfig
 from fabric_tpu.msp import CachedMSP
 from fabric_tpu.msp.ca import DevOrg
@@ -75,6 +76,7 @@ def counts():
             "mac": m["rejects"].value(reason="mac"),
             "stale": m["rejects"].value(reason="stale"),
             "evictions": m["evictions"].total(),
+            "bypassed": m["bypassed"].total(),
             "device": m["device"].total(), "dupes": m["dupes"].total(),
             "attested": m["attested"].total()}
 
@@ -488,6 +490,301 @@ def test_five_callers_one_partition(orgs, sw_provider, caller):
         assert first.tolist() == truth == second.tolist()
 
 
+# -- the validator's probe: a block the cache is silent on goes round it -------
+
+TAILS = ["validator_classic", "validator_deep"]
+
+
+def _sized_envs(org1, org2, n_items):
+    """Envelopes of one block with exactly `n_items` unique verify items:
+    every tx its own creator and its own endorsers, three items a tx, two
+    for a tx that Org1 alone endorsed."""
+    full, rest = divmod(n_items, 3)
+    if rest == 1:
+        full, rest = full - 1, 4
+    solo = rest // 2
+    envs = [make_tx(org1, org2, creator=org1.new_identity(f"c{i}"))
+            for i in range(full)]
+    envs += [make_tx(org1, org2, creator=org1.new_identity(f"s{i}"),
+                     endorsers=[org1.new_identity("e")])
+             for i in range(solo)]
+    return envs
+
+
+def _broken_endorsement(env, signer):
+    """`env`'s transaction with its second endorsement's signature
+    altered, signed anew by `signer`: only the endorsement is unsound."""
+    tx = env.payload_dict()["data"]
+    e2 = tx["actions"][0]["endorsements"][1]
+    e2["signature"] = e2["signature"][:-2] + b"\x00\x01"
+    return build.signed_envelope("endorser_transaction", "ch", tx, signer)
+
+
+def _validate(tail, msps, provider, cache, envs):
+    """One block through a fresh validator on the named tail: (the
+    block's unique items in dispatch order, its flags)."""
+    v = TxValidator("ch", msps, provider, _policies(), verify_cache=cache)
+    if tail == "validator_classic":
+        v.sbe_lookup = lambda ns, key: None          # keeps the classic tail
+    state = v.validate_begin(make_block(envs))
+    assert bool(state.get("deep")) == (tail == "validator_deep")
+    order = list(state["items"])
+    return order, v.validate_finish(state).flags.codes()
+
+
+def _record_spans(monkeypatch):
+    from fabric_tpu.ops_plane import tracing
+    spans = []
+    monkeypatch.setattr(
+        tracing.tracer, "record_span",
+        lambda name, start, end, attributes=None, parent=None:
+        spans.append((name, attributes or {})))
+    return spans
+
+
+def _commit_site():
+    return (_m()["device"].value(site="commit"),
+            _m()["bypassed"].value(site="commit"))
+
+
+def _off_probe_tx(order, envs, msps, width=3):
+    """Index of a full tx none of whose items sits at a probe position."""
+    probed = set(probe_positions(len(order)))
+    for t, env in enumerate(envs):
+        at = order.index(creator_item(env, msps))
+        if not probed & set(range(at, at + width)):
+            return t
+    raise AssertionError("every tx is probed")
+
+
+def test_probe_positions_are_spread_and_unaliased():
+    for n in (PROBE + 1, PROBE + 2, 300, 1023, 39_999, 40_000):
+        pos = probe_positions(n)
+        assert len(pos) == PROBE == len(set(pos)) and pos == sorted(pos)
+        assert pos[0] == 0 and pos[-1] == n - 1
+        assert pos == probe_positions(n)                 # of n only
+        runs = [pos[i:i + 4] for i in range(0, PROBE, 4)]
+        assert all(r == list(range(r[0], r[0] + 4)) for r in runs)
+        starts = [r[0] for r in runs]
+        gaps = {b - a for a, b in zip(starts, starts[1:])}
+        assert max(gaps) - min(gaps) <= 1                # evenly spaced
+    # the runs do not start on one phase of a four-item transaction
+    assert len({p % 4 for p in probe_positions(40_000)[::4]}) == 4
+
+
+@pytest.mark.parametrize("tail", TAILS)
+def test_probe_empty_cache_bypasses_a_big_block(orgs, sw_provider, tail,
+                                                monkeypatch):
+    """(a) Nothing cached: one dispatch of all n items, PROBE lookups,
+    nothing stored, the device's work still booked under `commit`."""
+    org1, org2 = orgs
+    msps = _msps(org1, org2)
+    envs = _sized_envs(org1, org2, 300)
+    envs[7] = _broken(envs[7])
+    order, off = _validate(tail, msps, sw_provider, None, envs)
+    n = len(order)
+    assert n == 300 > PROBE
+    assert off.count(int(ValidationCode.BAD_CREATOR_SIGNATURE)) == 1
+
+    inner = CountingProvider(sw_provider)
+    cache = VerdictCache(capacity=4096)
+    spans = _record_spans(monkeypatch)
+    before, site_before = counts(), _commit_site()
+    got, on = _validate(tail, msps, inner, cache, envs)
+    moved = delta(before, counts())
+    device, bypassed = (a - b for a, b in zip(_commit_site(), site_before))
+
+    assert on == off and got == order
+    assert inner.batches == [order]                      # one dispatch
+    assert (moved["hits"], moved["misses"], moved["bypassed"]) == (
+        0, PROBE, n - PROBE)
+    assert (device, bypassed) == (n, n - PROBE)
+    assert moved["device"] == n and moved["dupes"] == 0
+    assert moved["rejects"] == 0 and moved["evictions"] == 0
+    assert len(cache) == 0                               # nothing stored
+    names = [name for name, _ in spans]
+    assert "validator.cache_store" not in names
+    assert names.count("validator.cache_filter") == 1
+    (collect,) = [a for name, a in spans if name == "validator.collect"]
+    assert (collect["unique_items"], collect["cache_hits"],
+            collect["cache_misses"], collect["cache_bypassed"]) == (
+        n, 0, PROBE, n - PROBE)
+    assert cache.coverage.frac() == 0.0 and len(cache.coverage._blocks) == 1
+
+
+@pytest.mark.parametrize("tail", TAILS)
+def test_probe_hit_keeps_a_big_block_on_the_cache_path(orgs, sw_provider,
+                                                       tail, monkeypatch):
+    """(b) One cached item at a probe position (and a few elsewhere):
+    verdicts, counters, contents and LRU order are the hand-written
+    lookup + put reference's, and no item is looked up twice."""
+    org1, org2 = orgs
+    msps = _msps(org1, org2)
+    envs = _sized_envs(org1, org2, 300)
+    envs[11] = _broken(envs[11])
+    order, off = _validate(tail, msps, sw_provider, None, envs)
+    n = len(order)
+    truth = [bool(v) for v in sw_provider.batch_verify(order)]
+    pos = probe_positions(n)
+    probed = set(pos)
+    off_probe = [i for i in range(n) if i not in probed]
+    # one probed item alone decides; the rest scrambles the LRU order
+    for cached in ([pos[41]],
+                   [pos[200], off_probe[30], pos[3], off_probe[2], pos[90]]):
+        secret = b"k" * 32
+        ref, new = (VerdictCache(capacity=4096, secret=secret)
+                    for _ in range(2))
+        for c in (ref, new):
+            for i in cached:
+                c.put(order[i], truth[i], scope="ch", trace_id="spec-1")
+
+        before = counts()
+        ref_out, ref_missed = _reference_filter_store(
+            ref, order, sw_provider.batch_verify, "commit", "ch")
+        ref_moved = delta(before, counts())
+        assert ref_out == truth and ref_missed == n - len(cached)
+
+        asked = []
+        lookup = new.lookup
+        new.lookup = lambda it: (asked.append(it), lookup(it))[1]
+        inner = CountingProvider(sw_provider)
+        spans = _record_spans(monkeypatch)
+        before = counts()
+        got, on = _validate(tail, msps, inner, new, envs)
+        moved = delta(before, counts())
+
+        assert on == off and got == order
+        assert moved == ref_moved and moved["bypassed"] == 0
+        assert moved["hits"] == len(cached) and moved["dupes"] == 0
+        assert sorted(asked) == sorted(order)            # each item once
+        assert inner.batches == [[it for i, it in enumerate(order)
+                                  if i not in cached]]
+        assert dict(new._data) == dict(ref._data)        # MACs included
+        assert list(new._data) == list(ref._data)        # and LRU order
+        (collect,) = [a for name, a in spans if name == "validator.collect"]
+        assert "cache_bypassed" not in collect
+        assert collect["cache_hits"] == len(cached)
+        assert collect["links"] == ["spec-1"]
+        assert [a["items"] for name, a in spans
+                if name == "validator.cache_store"] == [n - len(cached)]
+
+
+@pytest.mark.parametrize("tail", TAILS)
+def test_probe_miss_never_trusts_what_it_did_not_ask(orgs, sw_provider,
+                                                     tail):
+    """(c) Cached items only off the probe positions, the two unsound
+    signatures' among them: the block is bypassed, the device verifies
+    every item itself, and the flags are the cache-off validator's."""
+    org1, org2 = orgs
+    msps = _msps(org1, org2)
+    envs = _sized_envs(org1, org2, 900)      # room between the probe's runs
+    order, _ = _validate(tail, msps, sw_provider, None, envs)
+    t = _off_probe_tx(order, envs, msps)
+    envs[t] = _broken(envs[t])
+    u = _off_probe_tx(order, envs[t + 1:], msps) + t + 1
+    envs[u] = _broken_endorsement(envs[u], org1.new_identity("fresh"))
+    order, off = _validate(tail, msps, sw_provider, None, envs)
+    n = len(order)
+    assert off[t] == int(ValidationCode.BAD_CREATOR_SIGNATURE)
+    assert off[u] == int(ValidationCode.ENDORSEMENT_POLICY_FAILURE)
+    truth = [bool(v) for v in sw_provider.batch_verify(order)]
+    assert truth.count(False) == 2
+
+    probed = set(probe_positions(n))
+    cached = [i for i in range(n)
+              if i not in probed and (i % 5 == 0 or not truth[i])]
+    cache = VerdictCache(capacity=4096)
+    for i in cached:
+        # were the cache asked, these would read as sound
+        cache.put(order[i], True, scope="ch")
+    held = list(cache._data.items())
+
+    inner = CountingProvider(sw_provider)
+    before = counts()
+    got, on = _validate(tail, msps, inner, cache, envs)
+    moved = delta(before, counts())
+    assert on == off and got == order
+    assert inner.batches == [order]                      # the unsound two too
+    assert (moved["hits"], moved["misses"], moved["bypassed"]) == (
+        0, PROBE, n - PROBE)
+    assert moved["device"] == n and moved["dupes"] == 0
+    assert list(cache._data.items()) == held             # neither read nor fed
+
+
+@pytest.mark.parametrize("tail", TAILS)
+def test_probe_rejects_are_misses_not_hits(orgs, sw_provider, tail):
+    """(d) A MAC-tampered and a stale-epoch entry at probe positions are
+    dropped and counted as rejects + misses: neither keeps the block on
+    the cache path, neither skips a verification."""
+    org1, org2 = orgs
+    msps = _msps(org1, org2)
+    envs = _sized_envs(org1, org2, 300)
+    order, _ = _validate(tail, msps, sw_provider, None, envs)
+    pos = probe_positions(len(order))
+    # the tx whose creator signature sits at a probe position, broken
+    t = next(t for t, e in enumerate(envs)
+             if order.index(creator_item(e, msps)) in pos[40:])
+    envs[t] = _broken(envs[t])
+    order, off = _validate(tail, msps, sw_provider, None, envs)
+    n = len(order)
+    bad = order.index(creator_item(envs[t], msps))
+    assert bad in pos and off[t] == int(ValidationCode.BAD_CREATOR_SIGNATURE)
+    stale = next(p for p in pos if p != bad)
+
+    cache = VerdictCache(capacity=4096)
+    cache.put(order[bad], False, scope="ch")
+    d = item_digest(order[bad])
+    mac, verdict, scope, epoch, trace = cache._data[d]
+    cache._data[d] = (mac, True, scope, epoch, trace)    # flipped, MAC kept
+    cache.put(order[stale], True, scope="other")
+    cache.set_epoch(3, scope="other")
+
+    inner = CountingProvider(sw_provider)
+    before = counts()
+    got, on = _validate(tail, msps, inner, cache, envs)
+    moved = delta(before, counts())
+    assert on == off and got == order
+    assert inner.batches == [order]
+    assert (moved["mac"], moved["stale"], moved["rejects"]) == (1, 1, 2)
+    assert (moved["hits"], moved["misses"], moved["bypassed"]) == (
+        0, PROBE, n - PROBE)
+    assert moved["device"] == n and len(cache) == 0      # both dropped
+
+
+@pytest.mark.parametrize("tail", TAILS)
+def test_probe_leaves_a_block_of_probe_items_alone(orgs, sw_provider, tail,
+                                                   monkeypatch):
+    """(e) Exactly PROBE items: the unprobed path — every item looked up
+    and stored; one item more and the empty cache is gone round."""
+    org1, org2 = orgs
+    msps = _msps(org1, org2)
+    probes = []
+    probed = VerdictCache.partition_probed
+    monkeypatch.setattr(
+        VerdictCache, "partition_probed",
+        lambda self, items, positions, *, site:
+        (probes.append(len(items)),
+         probed(self, items, positions, site=site))[1])
+    for n, bypassed in ((PROBE, 0), (PROBE + 1, 1)):
+        envs = _sized_envs(org1, org2, n)
+        cache = VerdictCache(capacity=4096)
+        inner = CountingProvider(sw_provider)
+        spans = _record_spans(monkeypatch)
+        before = counts()
+        order, on = _validate(tail, msps, inner, cache, envs)
+        moved = delta(before, counts())
+        assert len(order) == n and inner.batches == [order]
+        assert on.count(int(ValidationCode.VALID)) >= n // 3 - 1
+        assert probes == [PROBE + 1] * bypassed
+        assert (moved["hits"], moved["misses"], moved["bypassed"]) == (
+            0, PROBE, bypassed)
+        assert moved["device"] == n
+        assert len(cache) == (0 if bypassed else PROBE)
+        stored = [a["items"] for name, a in spans
+                  if name == "validator.cache_store"]
+        assert stored == ([] if bypassed else [PROBE])
+
+
 # -- differential fuzz: cache-on == cache-off --------------------------------
 
 
@@ -543,10 +840,12 @@ def _mode(validator, mode):
 @pytest.mark.parametrize("mode", ["native", "python"])
 def test_differential_fuzz_cache_on_equals_cache_off(orgs, sw_provider,
                                                      mode):
-    """Same corpora, same blocks, three runs: cache-off, cache-on, and
-    cache-on with a 3-entry cache (evictions mid-block).  All three
-    must produce bit-identical TxFlags, on the native and pure-Python
-    collect paths."""
+    """Same corpora, same blocks, four runs: cache-off, cache-on,
+    cache-on with a 3-entry cache (evictions mid-block), and cache-on
+    warmed with every other item's verdict (the last block, above
+    PROBE items, goes round the first two caches and through the
+    fourth).  All must produce bit-identical TxFlags, on the native and
+    pure-Python collect paths."""
     org1, org2 = orgs
     msps = _msps(org1, org2)
     for seed in (7, 19, 40):
@@ -554,16 +853,28 @@ def test_differential_fuzz_cache_on_equals_cache_off(orgs, sw_provider,
         blocks = [_adversarial_corpus(org1, org2, rng) for _ in range(3)]
         # the same envelope appears in two different blocks too
         blocks[2] = blocks[2] + [blocks[0][0]]
+        blocks.append(_adversarial_corpus(org1, org2, rng, n=200))
 
-        def run(cache):
-            v = _mode(TxValidator("ch", msps, sw_provider, _policies(),
+        def run(cache, provider=sw_provider):
+            v = _mode(TxValidator("ch", msps, provider, _policies(),
                                   verify_cache=cache), mode)
             return _run_blocks(v, blocks)
 
-        off = run(None)
+        counting = CountingProvider(sw_provider)
+        off = run(None, counting)
+        assert len(counting.batches[-1]) > PROBE
         on = run(VerdictCache(capacity=4096))
         tiny = run(VerdictCache(capacity=3))
-        assert off == on == tiny, f"verdict fork at seed {seed} ({mode})"
+        warm = VerdictCache(capacity=4096)
+        seen = [it for b in counting.batches for it in b][::2]
+        for it, v in zip(seen, sw_provider.batch_verify(seen)):
+            warm.put(it, bool(v), scope="ch")
+        before = counts()
+        warmed = run(warm)
+        moved = delta(before, counts())
+        assert moved["hits"] >= len(set(seen)) and moved["bypassed"] == 0
+        assert off == on == tiny == warmed, \
+            f"verdict fork at seed {seed} ({mode})"
 
 
 def test_cached_verdict_cannot_vouch_for_revoked_identity(orgs,
