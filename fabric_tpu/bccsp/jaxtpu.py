@@ -52,6 +52,7 @@ MIN_BUCKET = 128
 MAX_BUCKET = 1 << 17
 
 _ZERO32 = b"\x00" * 32
+_ZERO64 = b"\x00" * 64
 
 _DER_PARSE = []
 
@@ -603,12 +604,13 @@ class JaxTpuProvider(prov.Provider):
         pending.t_mark = t_enq1
         return rec
 
-    def _dispatch(self, fn, keep, arrays, pending, kind="generic"):
+    def _dispatch(self, fn, keep, arrays, pending, lane="generic"):
         """Pad to buckets, chunk beyond MAX_BUCKET (bounds the compiled-
         program set while arbitrarily large blocks still use the device),
         ENQUEUE the device calls (jax dispatch is async), and record
-        (keep, out, post, record) for the resolve step.  `kind` names
-        the program family (`generic@128`, `ed25519@128`)."""
+        (keep, out, post, record) for the resolve step.  `lane` names
+        the kernel, in the account and in the program (`generic@128` is
+        the P-256 ladder, `ed25519@128` the Ed25519 one)."""
         for lo in range(0, len(keep), MAX_BUCKET):
             hi = min(lo + MAX_BUCKET, len(keep))
             chunk = [a[..., lo:hi] for a in arrays]
@@ -616,11 +618,11 @@ class JaxTpuProvider(prov.Provider):
             bucket = int(np.asarray(padded[0]).shape[-1])
             t_enq0 = self._clock()
             out = fn(*padded)
-            rec = self._dispatched(pending, "generic", f"{kind}@{bucket}",
+            rec = self._dispatched(pending, lane, f"{lane}@{bucket}",
                                    hi - lo, t_enq0)
             self.stats["h2d_bytes"] += sum(
                 np.asarray(a).nbytes for a in padded)
-            self._observe_lane("generic", hi - lo, bucket)
+            self._observe_lane(lane, hi - lo, bucket)
             pending.append((keep[lo:hi], out, None, rec))
 
     # Row-grid geometry for the fast lane (ops/p256_fixed.verify_words_
@@ -707,26 +709,10 @@ class JaxTpuProvider(prov.Provider):
         idxs_np = np.asarray(idxs, np.int64)
         counts = np.bincount(key_ids[valid], minlength=G)
         slots = np.full(G, -1, np.int64)
-        # biggest groups claim slots first; each claimed slot is PINNED
-        # in the bank until the rows dispatch has captured the bank
-        # array — a later build (this batch or a concurrent one on
-        # another thread) must not evict it, or its rows would verify
-        # against the wrong table
         pinned = set()
-        builds = _TableBuilds(self.key_tables, self._clock)
         try:
-            for g in np.argsort(-counts, kind="stable"):
-                g = int(g)
-                if not pk_ok[g] or not counts[g]:
-                    continue
-                pk = pks[g]
-                slot = self.key_tables.lookup(pk, pin=True)
-                if slot is None and counts[g] >= self.fast_key_threshold:
-                    slot = builds.get_or_build(pk)
-                if slot is not None:
-                    pinned.add(slot)
-                    slots[g] = slot
-            builds.record(pending)
+            self._claim_slots(self.key_tables, pks, pk_ok, counts, slots,
+                              pinned, pending)
             fsel = np.nonzero(valid & (slots[key_ids] >= 0))[0]
             if fsel.size:
                 self._dispatch_rows_vec(fsel, key_ids, slots, rsw, ew,
@@ -753,11 +739,38 @@ class JaxTpuProvider(prov.Provider):
             self._dispatch(self._get_fn(SCHEME_P256), idxs_np[gsel],
                            arrays, pending)
 
-    def _dispatch_rows_vec(self, sel, key_ids, slots, rsw, ew, idxs_np,
-                           pending):
-        """Vectorized rows-lane packing: key-major (R, C) grid built by
-        numpy gathers over the batch word arrays; chunked by
-        ROWS_CHUNK/ROW_BUCKETS like the rec path."""
+    def _claim_slots(self, bank, pks, pk_ok, counts, slots, pinned,
+                     pending) -> None:
+        """Fill `slots[g]` with the bank slot of each key group that
+        rides the fixed-comb lane: a resident key always, a new one
+        when this batch brings `fast_key_threshold` signatures under
+        it.  Biggest groups claim first; each claimed slot is PINNED
+        (and added to `pinned`, for the caller's `finally`) until the
+        rows dispatch has captured the bank array — a later build (this
+        batch or a concurrent one on another thread) must not evict it,
+        or its rows would verify against the wrong table."""
+        builds = _TableBuilds(bank, self._clock)
+        for g in np.argsort(-counts, kind="stable"):
+            g = int(g)
+            if not pk_ok[g] or not counts[g]:
+                continue
+            pk = pks[g]
+            slot = bank.lookup(pk, pin=True)
+            if slot is None and counts[g] >= self.fast_key_threshold:
+                slot = builds.get_or_build(pk)
+            if slot is not None:
+                pinned.add(slot)
+                slots[g] = slot
+        builds.record(pending)
+
+    def _row_grids(self, sel, key_ids, slots, idxs_np):
+        """The key-major (R, C) grids of one scheme's fast-lane items,
+        built by numpy gathers and chunked by ROWS_CHUNK / ROW_BUCKETS
+        like the rec path.  Yields (flat, row_key, positions, Rb) a
+        dispatch: `flat` indexes the batch's word arrays cell by cell
+        (padding repeats a real signature), `row_key` is each row's
+        bank slot, `positions` the cells' batch positions with -1 for
+        padding.  Shared by both curves' rows lanes."""
         C = self.fast_row_c
         order = sel[np.argsort(key_ids[sel], kind="stable")]
         gids, starts, ngs = np.unique(key_ids[order], return_index=True,
@@ -782,8 +795,6 @@ class JaxTpuProvider(prov.Provider):
         slot_grid = np.concatenate(slot_rows)
         row_key = np.asarray(row_key, np.int32)
         R = sel_grid.shape[0]
-        fn = self._get_fn("p256-rows")
-        bank = self.key_tables.array()
         max_rows = min(self.ROW_BUCKETS[-1], max(self.rows_chunk, 1))
         for lo in range(0, R, max_rows):
             hi = min(lo + max_rows, R)
@@ -799,7 +810,16 @@ class JaxTpuProvider(prov.Provider):
                 rk = np.concatenate([rk, np.repeat(rk[:1], padrows)])
                 og = np.concatenate(
                     [og, np.full((padrows, C), -1, np.int64)])
-            flat = sg.reshape(-1)
+            yield sg.reshape(-1), rk, og.reshape(-1), Rb
+
+    def _dispatch_rows_vec(self, sel, key_ids, slots, rsw, ew, idxs_np,
+                           pending):
+        """Vectorized P-256 rows-lane packing and dispatch."""
+        C = self.fast_row_c
+        fn = self._get_fn("p256-rows")
+        bank = self.key_tables.array()
+        for flat, rk, positions, Rb in self._row_grids(sel, key_ids, slots,
+                                                       idxs_np):
             words = [
                 np.ascontiguousarray(rsw[flat, :8].T).reshape(8, Rb, C),
                 np.ascontiguousarray(rsw[flat, 8:].T).reshape(8, Rb, C),
@@ -808,8 +828,8 @@ class JaxTpuProvider(prov.Provider):
             out = fn(bank, rk, *words)
             self.stats["h2d_bytes"] += (
                 sum(w.nbytes for w in words) + rk.nbytes)
-            self._enqueue_rows_out(out, og.reshape(-1), pending,
-                                   f"rows@{Rb}", t_enq0)
+            self._enqueue_rows_out(out, positions, pending, "rows", Rb,
+                                   t_enq0)
 
     def _verify_p256_recs(self, items, idxs, pending):
         """Rec-based fallback lane split (no C extension)."""
@@ -893,14 +913,15 @@ class JaxTpuProvider(prov.Provider):
             out.append((row_key, frecs, slots, Rb))
         return out
 
-    def _enqueue_rows_out(self, out, slots, pending, program, t_enq0):
-        """One row-grid dispatch, just called: `slots` are the batch
-        positions of the grid's cells, -1 for padding (dropped at
-        resolve)."""
+    def _enqueue_rows_out(self, out, slots, pending, lane, rows, t_enq0):
+        """One row-grid dispatch of `rows` rows, just called on `lane`
+        (`rows`: P-256, `ed25519-rows`): `slots` are the batch positions
+        of the grid's cells, -1 for padding (dropped at resolve)."""
         slots_np = np.asarray(slots)
         valid = slots_np >= 0
         keep = slots_np[valid]
-        rec = self._dispatched(pending, "rows", program, len(keep), t_enq0)
+        rec = self._dispatched(pending, lane, f"{lane}@{rows}", len(keep),
+                               t_enq0)
         self.stats["fast_key_sigs"] += len(keep)
         # rows-lane pad slots interleave (within-row pad + pad rows), so
         # the per-device split counts the valid mask over each device's
@@ -912,7 +933,7 @@ class JaxTpuProvider(prov.Provider):
             per_device = [
                 (dev, int(valid[i * chunk:(i + 1) * chunk].sum()), chunk)
                 for i, dev in enumerate(self.device_labels)]
-        self._observe_lane("rows", len(keep), len(slots_np),
+        self._observe_lane(lane, len(keep), len(slots_np),
                            per_device=per_device)
         pending.append(
             (keep, out, lambda a, valid=valid: a.reshape(-1)[valid], rec))
@@ -934,74 +955,91 @@ class JaxTpuProvider(prov.Provider):
             out = fn(bank, rk, *words)
             self.stats["h2d_bytes"] += (
                 sum(w.nbytes for w in words) + rk.nbytes)
-            self._enqueue_rows_out(out, slots, pending, f"rows@{Rb}",
-                                   t_enq0)
-
-    def _dispatch_ed_rows(self, fast, pending):
-        """ed25519 row-grid dispatches (fast: [(bank_slot, recs)], recs:
-        (idx, pk, sig, msg))."""
-        from fabric_tpu.ops import ed25519 as edmod
-        C = self.fast_row_c
-        fn = self._get_fn("ed25519-rows")
-        bank = self.ed_key_tables.array()
-        for row_key, frecs, slots, Rb in self._row_chunks(fast):
-            ay, a_sign, ry, r_sign, s, k = edmod.pack_verify_inputs(
-                [rec[1] for rec in frecs], [rec[2] for rec in frecs],
-                [rec[3] for rec in frecs])
-            rk = np.asarray(row_key, dtype=np.int32)
-            args = (ry.reshape(8, Rb, C),
-                    r_sign.reshape(Rb, C).astype(np.int32),
-                    s.reshape(8, Rb, C), k.reshape(8, Rb, C))
-            t_enq0 = self._clock()
-            out = fn(bank, rk, *args)
-            self.stats["h2d_bytes"] += (
-                sum(np.asarray(a).nbytes for a in args) + rk.nbytes)
-            self._enqueue_rows_out(out, slots, pending,
-                                   f"ed25519-rows@{Rb}", t_enq0)
+            self._enqueue_rows_out(out, slots, pending, "rows", Rb, t_enq0)
 
     def _verify_ed25519(self, items, idxs, pending):
-        """Two-lane ed25519 dispatch (the P-256 design): cached-A keys
-        ride the all-comb row kernel; the rest decompress A on device
-        and take the comb+ladder generic kernel."""
-        recs = []
-        for i in idxs:
+        """Two-lane Ed25519 dispatch (the P-256 design): signatures
+        under a device-resident (or residency-worthy) key ride the
+        all-comb row kernel, lane `ed25519-rows`, in one merged
+        dispatch; the rest decompress A on device and take the
+        comb+ladder kernel, lane `ed25519`.
+
+        Packing is numpy over the whole scheme's items, as for P-256,
+        but for k = SHA-512(R || A || M) mod L: the hash runs over each
+        whole message and the reduction is exact, one signature at a
+        time (ops/ed25519.challenge_words) — the lanes' host cost, 13k
+        messages of 1-3 KB in a mixed 10,000-tx block."""
+        from fabric_tpu.ops import ed25519 as edmod
+        n = len(idxs)
+        sigs = [None] * n
+        msgs = [None] * n
+        pk_of = [None] * n
+        key_ids = np.empty(n, np.int64)
+        sig_ok = np.empty(n, bool)
+        pk_map = {}
+        pks = []
+        for j, i in enumerate(idxs):
             it = items[i]
-            if len(it.pubkey) != 32 or len(it.signature) != 64:
-                self.stats["host_rejects"] += 1
-                continue
-            recs.append((i, it.pubkey, it.signature, it.payload))
-        groups = {}
-        for rec in recs:
-            groups.setdefault(rec[1], []).append(rec)
-        fast, generic = [], []
+            pk_of[j] = it.pubkey
+            sig = it.signature
+            if len(sig) == 64:
+                sigs[j] = sig
+                sig_ok[j] = True
+            else:
+                sigs[j] = _ZERO64
+                sig_ok[j] = False
+            msgs[j] = it.payload
+            gid = pk_map.get(pk_of[j])
+            if gid is None:
+                gid = pk_map[pk_of[j]] = len(pks)
+                pks.append(pk_of[j])
+            key_ids[j] = gid
+        G = len(pks)
+        pk_ok = np.fromiter((len(pk) == 32 for pk in pks), bool, G)
+        valid = sig_ok & pk_ok[key_ids]
+        self.stats["host_rejects"] += n - int(valid.sum())
+        if not valid.any():
+            return
+        ry, r_sign, sw = edmod.sig_words(sigs)
+        kw = edmod.challenge_words(pk_of, sigs, msgs)
+        idxs_np = np.asarray(idxs, np.int64)
+        counts = np.bincount(key_ids[valid], minlength=G)
+        slots = np.full(G, -1, np.int64)
         pinned = set()
-        builds = _TableBuilds(self.ed_key_tables, self._clock)
         try:
-            for pk, g in sorted(groups.items(),
-                                key=lambda kv: -len(kv[1])):
-                slot = self.ed_key_tables.lookup(pk, pin=True)
-                if slot is None and len(g) >= self.fast_key_threshold:
-                    slot = builds.get_or_build(pk)
-                if slot is None:
-                    generic.extend(g)
-                else:
-                    pinned.add(slot)
-                    fast.append((slot, g))
-            builds.record(pending)
-            fast.sort(key=lambda t: -len(t[1]))
-            if fast:
-                self._dispatch_ed_rows(fast, pending)
+            self._claim_slots(self.ed_key_tables, pks, pk_ok, counts, slots,
+                              pinned, pending)
+            fsel = np.nonzero(valid & (slots[key_ids] >= 0))[0]
+            if fsel.size:
+                C = self.fast_row_c
+                fn = self._get_fn("ed25519-rows")
+                bank = self.ed_key_tables.array()
+                for flat, rk, positions, Rb in self._row_grids(
+                        fsel, key_ids, slots, idxs_np):
+                    args = (
+                        np.ascontiguousarray(ry[flat].T).reshape(8, Rb, C),
+                        r_sign[flat].reshape(Rb, C),
+                        np.ascontiguousarray(sw[flat].T).reshape(8, Rb, C),
+                        np.ascontiguousarray(kw[flat].T).reshape(8, Rb, C))
+                    t_enq0 = self._clock()
+                    out = fn(bank, rk, *args)
+                    self.stats["h2d_bytes"] += (
+                        sum(a.nbytes for a in args) + rk.nbytes)
+                    self._enqueue_rows_out(out, positions, pending,
+                                           "ed25519-rows", Rb, t_enq0)
         finally:
             self.ed_key_tables.unpin(pinned)
-        generic.sort(key=lambda rec: rec[0])
-        if generic:
-            from fabric_tpu.ops import ed25519 as edmod
-            keep = [rec[0] for rec in generic]
-            arrays = list(edmod.pack_verify_inputs(
-                [rec[1] for rec in generic], [rec[2] for rec in generic],
-                [rec[3] for rec in generic]))
-            self._dispatch(self._get_fn(SCHEME_ED25519), keep, arrays,
-                           pending, kind="ed25519")
+        gsel = np.nonzero(valid & (slots[key_ids] < 0))[0]
+        if gsel.size:
+            ay, a_sign = edmod.key_words(
+                [pks[g] if pk_ok[g] else _ZERO32 for g in range(G)])
+            gk = key_ids[gsel]
+            arrays = [np.ascontiguousarray(ay[gk].T), a_sign[gk],
+                      np.ascontiguousarray(ry[gsel].T), r_sign[gsel],
+                      np.ascontiguousarray(sw[gsel].T),
+                      np.ascontiguousarray(kw[gsel].T)]
+            self._dispatch(self._get_fn(SCHEME_ED25519), idxs_np[gsel],
+                           arrays, pending, lane="ed25519")
 
     # -- idemix: batched BN254 pairing checks (BASELINE config 4) -----------
 
@@ -1106,12 +1144,15 @@ class JaxTpuProvider(prov.Provider):
                 packed["A"], packed["B"], x1, y1, x1)
         return fn, base + (y2,), base + (y1,)
 
-    def warm(self, generic=(), rows=()) -> dict:
-        """One P-256 dispatch at exactly each named program shape, so a
-        serving process compiles nothing later: `generic` are generic-
-        ladder buckets (powers of two from MIN_BUCKET), `rows` are
-        fixed-comb row buckets (members of ROW_BUCKETS).  Returns
-        seconds per shape; a wrong verdict raises.
+    def warm(self, generic=(), rows=(), ed25519=(), ed25519_rows=()) -> dict:
+        """One dispatch at exactly each named program shape, so a
+        serving process compiles nothing later.  `generic` / `ed25519`
+        are the buckets of the P-256 / Ed25519 ladder lanes (powers of
+        two from MIN_BUCKET), `rows` / `ed25519_rows` the row buckets
+        of the two fixed-comb lanes (members of ROW_BUCKETS).  Returns
+        seconds per shape, named as the account names the programs
+        (`generic@128`, `rows@256`, `ed25519@128`, `ed25519-rows@128`);
+        a wrong verdict raises.
 
         The shapes go out on one thread each: tracing a program holds
         the interpreter lock, but XLA compiles (and loads from the
@@ -1120,43 +1161,53 @@ class JaxTpuProvider(prov.Provider):
         import hashlib
         from concurrent.futures import ThreadPoolExecutor
 
-        def signed(n_keys: int) -> list:
+        def signed(scheme: str, n_keys: int) -> list:
             out = []
             for i in range(n_keys):
-                key = self.fallback.key_gen(SCHEME_P256)
-                digest = hashlib.sha256(b"warm %d" % i).digest()
-                out.append(VerifyItem(SCHEME_P256, key.public_bytes(),
-                                      self.fallback.sign(key, digest),
-                                      digest))
+                key = self.fallback.key_gen(scheme)
+                payload = b"warm %d" % i     # Ed25519 signs the message
+                if scheme == SCHEME_P256:
+                    payload = hashlib.sha256(payload).digest()
+                out.append(VerifyItem(scheme, key.public_bytes(),
+                                      self.fallback.sign(key, payload),
+                                      payload))
             return out
 
         jobs = []
-        if generic:
+        for lane, scheme, buckets in (("generic", SCHEME_P256, generic),
+                                      ("ed25519", SCHEME_ED25519, ed25519)):
+            if not buckets:
+                continue
             # 128 keys, each far under fast_key_threshold per batch:
-            # these stay on the generic ladder whatever the bucket
-            spread = signed(MIN_BUCKET)
-            for bucket in generic:
+            # these stay on the ladder lane whatever the bucket
+            spread = signed(scheme, MIN_BUCKET)
+            for bucket in buckets:
                 n = bucket if bucket == MIN_BUCKET else bucket // 2 + 1
                 if (bucket != _bucket(n)
                         or -(-n // len(spread)) >= self.fast_key_threshold):
-                    raise ValueError(f"generic bucket {bucket} cannot be "
+                    raise ValueError(f"{lane} bucket {bucket} cannot be "
                                      "warmed")
-                jobs.append((f"generic@{bucket}",
+                jobs.append((f"{lane}@{bucket}",
                              (spread * -(-n // len(spread)))[:n]))
             # one jitted function per lane, made before the threads
             # race for it
-            self._get_fn(SCHEME_P256)
-        if rows:
+            self._get_fn(scheme)
+        for lane, scheme, bank, fn_key, buckets in (
+                ("rows", SCHEME_P256, self.key_tables, "p256-rows", rows),
+                ("ed25519-rows", SCHEME_ED25519, self.ed_key_tables,
+                 "ed25519-rows", ed25519_rows)):
+            if not buckets:
+                continue
             # one resident key filling exactly `bucket` rows
-            hot = signed(1)
-            self.key_tables.get_or_build(hot[0].pubkey)
-            for bucket in rows:
+            hot = signed(scheme, 1)
+            bank.get_or_build(hot[0].pubkey)
+            for bucket in buckets:
                 if bucket not in self.ROW_BUCKETS:
-                    raise ValueError(f"rows bucket {bucket} not in "
+                    raise ValueError(f"{lane} bucket {bucket} not in "
                                      "ROW_BUCKETS")
-                jobs.append((f"rows@{bucket}",
+                jobs.append((f"{lane}@{bucket}",
                              hot * (bucket * self.fast_row_c)))
-            self._get_fn("p256-rows")
+            self._get_fn(fn_key)
 
         def one(job):
             name, items = job
@@ -1173,6 +1224,20 @@ class JaxTpuProvider(prov.Provider):
             return dict(pool.map(one, jobs))
 
     # -- the batch verbs ----------------------------------------------------
+
+    # The order a batch's schemes are packed and enqueued in — a
+    # decision, not the scheme of whichever item came first.  One chip
+    # runs its programs in order, and while one runs the host packs the
+    # next, so a batch of two programs costs
+    #     pack(1st) + max(device(1st), pack(2nd)) + device(2nd)
+    # and is shortest with the cheaper pack first and the longer program
+    # under the dearer pack.  P-256 goes first on both counts: its pack
+    # is numpy over 32-byte digests the collector already made (~1
+    # us/sig), Ed25519's hashes every whole message (SHA-512(R||A||M),
+    # ~5 us/sig at 2 KB), and in a mixed block of one org in three on
+    # Ed25519 the P-256 program is the longer one.  Idemix last: its
+    # host-side checks are the dearest of all.
+    SCHEME_ORDER = (SCHEME_P256, SCHEME_ED25519, SCHEME_IDEMIX)
 
     def batch_verify_async(self, items: Sequence[VerifyItem]):
         """Enqueue device verification and return resolve() -> bool[N].
@@ -1199,15 +1264,15 @@ class JaxTpuProvider(prov.Provider):
             by_scheme = {}
             for i, it in enumerate(items):
                 by_scheme.setdefault(it.scheme, []).append(i)
-            for scheme, idxs in by_scheme.items():
-                if scheme == SCHEME_P256:
-                    self._verify_p256(items, idxs, pending)
-                elif scheme == SCHEME_IDEMIX:
-                    self._verify_idemix(items, idxs, pending)
-                elif scheme == SCHEME_ED25519:
-                    self._verify_ed25519(items, idxs, pending)
-                else:
-                    self.stats["host_rejects"] += len(idxs)
+            verbs = {SCHEME_P256: self._verify_p256,
+                     SCHEME_ED25519: self._verify_ed25519,
+                     SCHEME_IDEMIX: self._verify_idemix}
+            for scheme in self.SCHEME_ORDER:
+                idxs = by_scheme.pop(scheme, None)
+                if idxs:
+                    verbs[scheme](items, idxs, pending)
+            for idxs in by_scheme.values():      # a scheme nobody verifies
+                self.stats["host_rejects"] += len(idxs)
         except Exception as exc:
             if not self.degrade:
                 span.end(status="ERROR")

@@ -36,7 +36,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from fabric_tpu.bccsp import SCHEME_P256, VerifyItem
+from fabric_tpu.bccsp import SCHEME_ED25519, SCHEME_P256, VerifyItem
 from fabric_tpu.bccsp.provider import dispatch_site
 from fabric_tpu.msp import Identity
 from fabric_tpu.ops_plane import tracing
@@ -375,25 +375,21 @@ class TxValidator:
         return deserialize_from_msps(self.msps, ident_bytes)
 
     def _resolve_creator(self, ident_bytes: bytes):
-        """Creator memo value: (identity, p256_pub_wire|None), or None
-        for identities the MSP rejects (deserialize + chain-validate —
+        """Creator memo value (`_memo_ent`), or None for identities
+        the MSP rejects (deserialize + chain-validate —
         the (0, creator) memo of the Python tail, resolved once per
         unique creator on the deep path)."""
         creator = self._deserialize(ident_bytes)
         if creator is not None and not _msp_validates(self.msps, creator):
             creator = None
-        return None if creator is None else (
-            creator, creator._pub_wire
-            if getattr(creator, "scheme", None) == SCHEME_P256 else None)
+        return None if creator is None else _memo_ent(creator)
 
     def _resolve_endorser(self, ident_bytes: bytes):
         """Endorser memo value — deserialize only, NO chain validation
         (the (1, endorser) memo: an unrecognized endorser merely weakens
         the policy, policy.go:390-393)."""
         ident = self._deserialize(ident_bytes)
-        return None if ident is None else (
-            ident, ident._pub_wire
-            if getattr(ident, "scheme", None) == SCHEME_P256 else None)
+        return None if ident is None else _memo_ent(ident)
 
     def _collect_tx_fast(self, tx_num: int, rec, flags: TxFlags,
                          seen_txids: Dict[str, int],
@@ -410,8 +406,8 @@ class TxValidator:
         This loop runs ~10k times per block on one core (the slot of
         the reference's per-tx goroutine fan-out), so it is written for
         bytecode economy: VerifyItems are their own dedup keys
-        (NamedTuple), per-identity facts are memoized as (identity,
-        p256_pub_wire) pairs, and attribute lookups are hoisted."""
+        (NamedTuple), per-identity facts are memoized (`_memo_ent`),
+        and attribute lookups are hoisted."""
         if isinstance(rec, int):
             # pre-registration structural failure: the txid never
             # entered seen_txids on the Python path either
@@ -451,25 +447,20 @@ class TxValidator:
 
         # creator identity: deserialize + chain-validate, memoized per
         # block (the msp/cache role for this hot loop).  memo value:
-        # (identity, p256 pub_wire or None), or None for invalid.
+        # `_memo_ent`'s, or None for invalid.
         ckey = (0, creator_bytes)
         ent = memo.get(ckey, memo)
         if ent is memo:
-            creator = self._deserialize(creator_bytes)
-            if creator is not None and not _msp_validates(self.msps, creator):
-                creator = None
-            ent = None if creator is None else (
-                creator, creator._pub_wire
-                if getattr(creator, "scheme", None) == SCHEME_P256
-                else None)
-            memo[ckey] = ent
+            ent = memo[ckey] = self._resolve_creator(creator_bytes)
         if ent is None:
             flags.set(tx_num, ValidationCode.BAD_CREATOR_SIGNATURE)
             return None
-        creator, pub_wire = ent
-        if pub_wire is not None:
+        creator, pub_wire, scheme = ent
+        if scheme == SCHEME_P256:
             item = VerifyItem(SCHEME_P256, pub_wire, signature, pdigest)
-        else:      # ed25519 (raw message) or idemix (own item shape)
+        elif pub_wire is not None:       # ed25519 signs the message
+            item = VerifyItem(scheme, pub_wire, signature, payload)
+        else:                            # idemix: its own item shape
             item = creator.verify_item(payload, signature)
         if item not in items:
             items[item] = None
@@ -499,17 +490,14 @@ class TxValidator:
                 ekey = (1, endorser)
                 ent = memo.get(ekey, memo)
                 if ent is memo:
-                    ident = self._deserialize(endorser)
-                    ent = None if ident is None else (
-                        ident, ident._pub_wire
-                        if getattr(ident, "scheme", None) == SCHEME_P256
-                        else None)
-                    memo[ekey] = ent
+                    ent = memo[ekey] = self._resolve_endorser(endorser)
                 if ent is None:
                     continue
-                ident, e_wire = ent
-                if e_wire is not None:
+                ident, e_wire, scheme = ent
+                if scheme == SCHEME_P256:
                     it = VerifyItem(SCHEME_P256, e_wire, esig, edigest)
+                elif e_wire is not None:
+                    it = VerifyItem(scheme, e_wire, esig, endorsed + endorser)
                 else:
                     it = ident.verify_item(endorsed + endorser, esig)
                 if it not in items:
@@ -1005,6 +993,18 @@ class TxValidator:
                                    time.perf_counter())
         return ValidationResult(flags, collect_s, dispatch_s, gate_s,
                                 n_refs, n_unique)
+
+
+def _memo_ent(ident: Identity) -> tuple:
+    """What both tails memoize per identity: (identity, pub_wire,
+    scheme) where the identity's VerifyItem is its four plain fields —
+    P-256 over the walker's SHA-256 digest, Ed25519 over the message
+    itself — so that a tail interns it without a call per signature;
+    (identity, None, None) for one that shapes its own item (idemix)."""
+    scheme = getattr(ident, "scheme", None)
+    if scheme in (SCHEME_P256, SCHEME_ED25519):
+        return ident, ident._pub_wire, scheme
+    return ident, None, None
 
 
 def _false_oracle(_txid: str) -> bool:

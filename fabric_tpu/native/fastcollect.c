@@ -1487,16 +1487,19 @@ static PyObject *py_digest_spans(PyObject *self, PyObject *args)
 }
 
 /* VerifyItem interning.  index maps item -> dispatch position; for
- * P-256 items we probe with a plain 4-tuple FIRST (a tuple hashes and
- * compares equal to the NamedTuple with the same fields) so repeats —
- * the overwhelmingly common case on real blocks — never construct the
- * NamedTuple at all.  Stored keys must be real VerifyItems because the
- * dispatch path reads .scheme/.pubkey attributes off them. */
-static Py_ssize_t intern_p256(PyObject *index, PyObject *cls,
-                              PyObject *scheme, PyObject *wire,
-                              PyObject *sig, PyObject *dig)
+ * an item that is its four plain fields (P-256 over the walker's
+ * digest, Ed25519 over the message itself) we probe with a plain
+ * 4-tuple FIRST (a tuple hashes and compares equal to the NamedTuple
+ * with the same fields) so repeats — the overwhelmingly common case on
+ * real blocks — never construct the NamedTuple at all, and nothing is
+ * called in Python for one.  Stored keys must be real VerifyItems
+ * because the dispatch path reads .scheme/.pubkey attributes off
+ * them. */
+static Py_ssize_t intern_fields(PyObject *index, PyObject *cls,
+                                PyObject *scheme, PyObject *wire,
+                                PyObject *sig, PyObject *payload)
 {
-    PyObject *probe = PyTuple_Pack(4, scheme, wire, sig, dig);
+    PyObject *probe = PyTuple_Pack(4, scheme, wire, sig, payload);
     if (!probe)
         return -1;
     PyObject *v = PyDict_GetItemWithError(index, probe);
@@ -1517,7 +1520,7 @@ static Py_ssize_t intern_p256(PyObject *index, PyObject *cls,
     return rc < 0 ? -1 : idx;
 }
 
-/* non-P-256 item (already a VerifyItem/own item shape): plain intern */
+/* an identity that shapes its own item (idemix): plain intern */
 static Py_ssize_t intern_item(PyObject *index, PyObject *item)
 {
     PyObject *v = PyDict_GetItemWithError(index, item);
@@ -1537,8 +1540,13 @@ static Py_ssize_t intern_item(PyObject *index, PyObject *item)
 /* assemble(works, c_ents, e_ents, endorsers, codes, index, plans,
  *          verify_item_cls, scheme_p256, policy_for, pol_cache) -> n_refs
  *
- * c_ents/e_ents: per-slot (identity, p256_pub_wire|None) or None for
- * identities the MSP rejected.  Appends to `plans`
+ * c_ents/e_ents: per-slot (identity, pub_wire|None, scheme|None) or
+ * None for identities the MSP rejected.  With a pub_wire the item is
+ * its four plain fields and is interned here: over the walker's
+ * SHA-256 digest where the scheme is `scheme_p256`, over the message
+ * itself otherwise (Ed25519 signs the message: payload for a creator,
+ * endorsed || endorser for an endorsement).  Without one the identity
+ * is asked (`verify_item`: idemix).  Appends to `plans`
  * (tx_num, creator_idx, [(policy, [(item_idx, identity)...])...]) and
  * interns items into `index` in EXACTLY the Python tail's order:
  * creator first, then each action's endorsements, then that action's
@@ -1588,9 +1596,15 @@ static PyObject *py_assemble(PyObject *self, PyObject *args)
         PyObject *wire = PyTuple_GET_ITEM(ent, 1);
         Py_ssize_t cidx;
         if (wire != Py_None) {
-            cidx = intern_p256(index, cls, scheme, wire,
-                               PyTuple_GET_ITEM(work, 5),   /* signature */
-                               PyTuple_GET_ITEM(work, 4));  /* pdigest */
+            PyObject *cscheme = PyTuple_GET_ITEM(ent, 2);
+            int digested = PyObject_RichCompareBool(cscheme, scheme, Py_EQ);
+            if (digested < 0)
+                return NULL;
+            cidx = intern_fields(index, cls, cscheme, wire,
+                                 PyTuple_GET_ITEM(work, 5), /* signature */
+                                 PyTuple_GET_ITEM(work, digested
+                                                  ? 4       /* pdigest */
+                                                  : 3));    /* payload */
         } else {
             PyObject *item = PyObject_CallMethodObjArgs(
                 creator, s_verify_item, PyTuple_GET_ITEM(work, 3),
@@ -1632,27 +1646,43 @@ static PyObject *py_assemble(PyObject *self, PyObject *args)
                     PyObject *ident = PyTuple_GET_ITEM(eent, 0);
                     PyObject *ewire = PyTuple_GET_ITEM(eent, 1);
                     Py_ssize_t eidx;
-                    if (ewire != Py_None) {
-                        eidx = intern_p256(index, cls, scheme, ewire,
-                                           PyTuple_GET_ITEM(end3, 1),
-                                           PyTuple_GET_ITEM(end3, 2));
-                    } else {
+                    PyObject *escheme =
+                        ewire != Py_None ? PyTuple_GET_ITEM(eent, 2) : NULL;
+                    int digested = escheme
+                        ? PyObject_RichCompareBool(escheme, scheme, Py_EQ)
+                        : 0;
+                    if (digested < 0) {
+                        Py_DECREF(sigset); Py_DECREF(entries);
+                        return NULL;
+                    }
+                    if (digested) {
+                        eidx = intern_fields(index, cls, escheme, ewire,
+                                             PyTuple_GET_ITEM(end3, 1),
+                                             PyTuple_GET_ITEM(end3, 2));
+                    } else {      /* over the message itself */
                         PyObject *msg = PySequence_Concat(
                             endorsed, PyList_GET_ITEM(endorsers, slot));
                         if (!msg) {
                             Py_DECREF(sigset); Py_DECREF(entries);
                             return NULL;
                         }
-                        PyObject *item = PyObject_CallMethodObjArgs(
-                            ident, s_verify_item, msg,
-                            PyTuple_GET_ITEM(end3, 1), NULL);
-                        Py_DECREF(msg);
-                        if (!item) {
-                            Py_DECREF(sigset); Py_DECREF(entries);
-                            return NULL;
+                        if (escheme) {
+                            eidx = intern_fields(index, cls, escheme, ewire,
+                                                 PyTuple_GET_ITEM(end3, 1),
+                                                 msg);
+                            Py_DECREF(msg);
+                        } else {
+                            PyObject *item = PyObject_CallMethodObjArgs(
+                                ident, s_verify_item, msg,
+                                PyTuple_GET_ITEM(end3, 1), NULL);
+                            Py_DECREF(msg);
+                            if (!item) {
+                                Py_DECREF(sigset); Py_DECREF(entries);
+                                return NULL;
+                            }
+                            eidx = intern_item(index, item);
+                            Py_DECREF(item);
                         }
-                        eidx = intern_item(index, item);
-                        Py_DECREF(item);
                     }
                     if (eidx < 0) {
                         Py_DECREF(sigset); Py_DECREF(entries);

@@ -1009,8 +1009,9 @@ class PeerNode:
             # GET /state: per-channel shard sizes, checkpoint generation/
             # savepoint, and how much the last reopen had to replay
             self.ops.register_route("GET", "/state", self._state_route)
-            # POST /bccsp/warmup {"generic": [buckets], "rows": [buckets]}:
-            # one dispatch at exactly each named shape, in this process,
+            # POST /bccsp/warmup {"generic": [buckets], "rows": [buckets],
+            # "ed25519": [buckets], "ed25519_rows": [buckets]}: one
+            # dispatch at exactly each named shape, in this process,
             # so nothing compiles inside a request's time-out later
             self.ops.register_route("POST", "/bccsp/warmup",
                                     self._warmup_route)
@@ -1448,8 +1449,8 @@ class PeerNode:
         req = json.loads(body or b"{}")
         t0 = time.perf_counter()
         timings = self.provider.warm(
-            generic=[int(b) for b in req.get("generic", [])],
-            rows=[int(b) for b in req.get("rows", [])])
+            **{lane: [int(b) for b in req.get(lane, [])]
+               for lane in ("generic", "rows", "ed25519", "ed25519_rows")})
         return 200, {"timings": timings,
                      "seconds": round(time.perf_counter() - t0, 3),
                      "provider": self._provider_status()}

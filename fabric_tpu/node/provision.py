@@ -142,7 +142,8 @@ def provision_network(base_dir: str, n_orderers: int = 3,
                       collections: List[dict] = None,
                       batch: BatchConfig = None,
                       spare_orderers: int = 0,
-                      clients_per_org: int = 1) -> dict:
+                      clients_per_org: int = 1,
+                      org_schemes: dict = None) -> dict:
     """Full dev network: orderer cluster + peer-org peers on one channel.
 
     The nwo-style harness (reference: integration/nwo/network.go:173) —
@@ -158,12 +159,23 @@ def provision_network(base_dir: str, n_orderers: int = 3,
     own "cert_fp" so a drill can build the add_consenter request
     without re-deriving it.
 
-    `clients_per_org`: enrolled P-256 client identities per peer org.
+    `clients_per_org`: enrolled client identities per peer org.
     "clients" keeps naming each org's first; all of them are listed
     under "client_pool" ({org: [cfg paths]}).
+
+    `org_schemes`: {org: signature scheme} for the peer orgs that have
+    re-enrolled — every peer and every pooled client of such an org
+    holds a key of that scheme (SCHEME_ED25519) under the org's own CA,
+    the state of a Fabric v3 channel (capability V3_0) whose orgs move
+    to Ed25519 one at a time.  An org not named signs P-256, as does
+    every org CA and admin: the channel's MSPs accept both either way.
     """
     from fabric_tpu.orderer.cluster import cert_fingerprint
 
+    org_schemes = dict(org_schemes or {})
+    unknown = set(org_schemes) - set(peer_orgs)
+    if unknown:
+        raise ValueError(f"org_schemes names no peer org: {sorted(unknown)}")
     ord_org = DevOrg("OrdererOrg")
     p_orgs = {name: DevOrg(name) for name in peer_orgs}
     all_orgs = {"OrdererOrg": ord_org, **p_orgs}
@@ -216,8 +228,8 @@ def provision_network(base_dir: str, n_orderers: int = 3,
         for j in range(peers_per_org):
             peer_list.append((org_name, j, peer_ports[idx]))
             idx += 1
-    peer_creds = {(o, j): p_orgs[o].issuer.issue(f"peer{j}@{o}")
-                  for o, j, _ in peer_list}
+    peer_creds = {(o, j): p_orgs[o].issuer.issue(
+        f"peer{j}@{o}", scheme=org_schemes.get(o)) for o, j, _ in peer_list}
     attestors = [{"mspid": o, "cert_fp": cert_fingerprint(c)}
                  for (o, _), (c, _k) in peer_creds.items()]
 
@@ -343,8 +355,9 @@ def provision_network(base_dir: str, n_orderers: int = 3,
         return path
 
     for org_name in p_orgs:
+        scheme = org_schemes.get(org_name)
         clients[org_name] = _write_client(
-            org_name, f"client@{org_name}", None,
+            org_name, f"client@{org_name}", scheme,
             f"client_{org_name}.json")
         clients_ed25519[org_name] = _write_client(
             org_name, f"client@{org_name}", SCHEME_ED25519,
@@ -352,7 +365,7 @@ def provision_network(base_dir: str, n_orderers: int = 3,
         client_pool[org_name].append(clients[org_name])
         for i in range(1, clients_per_org):
             client_pool[org_name].append(_write_client(
-                org_name, f"client{i}@{org_name}", None,
+                org_name, f"client{i}@{org_name}", scheme,
                 f"client_{org_name}_{i}.json"))
     # per-org ADMIN identities (channel-config admin certs): the admin
     # CLI's install/join verbs are Admins-gated

@@ -13,8 +13,9 @@ program this compiled instead of compiling it.
 
 P-256 is warmed by exact lane and bucket (`JaxTpuProvider.warm`, which
 a running peer also offers as POST /bccsp/warmup), at the shapes below;
-Ed25519 by total batch size (`--buckets`); Idemix at its first three
-batch buckets.
+Ed25519 here by total batch size (`--buckets`) — `JaxTpuProvider.warm`
+takes its exact shapes too (`ed25519=`, `ed25519_rows=`), which is what
+a serving peer is asked for; Idemix at its first three batch buckets.
 """
 
 from __future__ import annotations

@@ -107,15 +107,58 @@ def verify_words_rows(bank_f32, row_key, ry, r_sign, s, k) -> jnp.ndarray:
 # Host-side packing: RFC 8032 wire format -> kernel inputs
 # ---------------------------------------------------------------------------
 
+def key_words(pubkeys: list):
+    """32B public keys A -> (ay, a_sign): A's y-coordinate as (B, 8)
+    uint32 big-endian words, one row a key, and its x-parity bits (B,)
+    int32."""
+    pkw = np.frombuffer(b"".join(pubkeys), "<u4").reshape(len(pubkeys), 8)
+    a_sign = (pkw[:, 7] >> 31).astype(np.int32)
+    ay = np.ascontiguousarray(pkw[:, ::-1]).astype(np.uint32)
+    ay[:, 0] &= 0x7FFFFFFF
+    return ay, a_sign
+
+
+def sig_words(sigs: list):
+    """64B signatures R || S -> (ry, r_sign, s): R's y-coordinate and S
+    as (B, 8) uint32 big-endian words of the integer values, one row a
+    signature, and R's x-parity bits (B,) int32.  Numpy end to end."""
+    B = len(sigs)
+    sgw = np.frombuffer(b"".join(sigs), "<u4").reshape(B, 16)
+    r_sign = (sgw[:, 7] >> 31).astype(np.int32)
+    ry = np.ascontiguousarray(sgw[:, 7::-1]).astype(np.uint32)
+    ry[:, 0] &= 0x7FFFFFFF
+    s = np.ascontiguousarray(sgw[:, :7:-1]).astype(np.uint32)
+    return ry, r_sign, s
+
+
+def challenge_words(pubkeys: list, sigs: list, msgs: list) -> np.ndarray:
+    """k = SHA-512(R || A || M) mod L of each signature, exact, as
+    (B, 8) uint32 big-endian words, one row a signature.
+
+    The hash runs over the whole message (RFC 8032 signs the message,
+    not a digest), so this is the Ed25519 lanes' host cost: OpenSSL's
+    SHA-512 fed in three pieces (no concatenated copy of the message)
+    and one Python-int reduction a signature."""
+    sha512 = hashlib.sha512
+    from_bytes = int.from_bytes
+    Lmod = ed.L
+    kb = bytearray(32 * len(sigs))
+    at = 0
+    for pk, sig, msg in zip(pubkeys, sigs, msgs):
+        h = sha512(sig[:32])
+        h.update(pk)
+        h.update(msg)
+        kb[at:at + 32] = (from_bytes(h.digest(), "little")
+                          % Lmod).to_bytes(32, "big")
+        at += 32
+    return np.frombuffer(kb, ">u4").reshape(-1, 8).astype(np.uint32)
+
+
 def pack_verify_inputs(pubkeys: list, sigs: list, msgs: list):
     """(32B pubkey, 64B sig, message) triples -> kernel input arrays.
 
     Returns (ay, a_sign, ry, r_sign, s, k) ready for verify_words.
     Malformed-length inputs raise ValueError (callers pre-screen).
-
-    Numpy-vectorized except the SHA-512 + mod-L fold, which is
-    per-signature by nature; the previous per-word python packing was
-    ~10 us/sig — most of the ed25519 lane's host time.
     """
     B = len(pubkeys)
     if B == 0:
@@ -125,29 +168,8 @@ def pack_verify_inputs(pubkeys: list, sigs: list, msgs: list):
     for pk, sig in zip(pubkeys, sigs):
         if len(pk) != 32 or len(sig) != 64:
             raise ValueError("ed25519: bad pubkey/signature length")
-    pkw = np.frombuffer(b"".join(pubkeys), "<u4").reshape(B, 8)
-    sgw = np.frombuffer(b"".join(sigs), "<u4").reshape(B, 16)
-    rw, sw_le = sgw[:, :8], sgw[:, 8:]
-    a_sign = (pkw[:, 7] >> 31).astype(np.int32)
-    r_sign = (rw[:, 7] >> 31).astype(np.int32)
-
-    def be_words(lew, mask_top=False):
-        # LE 32B value -> (8, B) big-endian word order (native uint32)
-        w = np.ascontiguousarray(lew[:, ::-1].T).astype(np.uint32)
-        if mask_top:
-            w[0] &= 0x7FFFFFFF
-        return w
-
-    ay = be_words(pkw, True)
-    ry = be_words(rw, True)
-    sw = be_words(sw_le)
-    sha512 = hashlib.sha512
-    Lmod = ed.L
-    kb = bytearray()
-    for pk, sig, msg in zip(pubkeys, sigs, msgs):
-        k = int.from_bytes(sha512(sig[:32] + pk + msg).digest(),
-                           "little") % Lmod
-        kb += k.to_bytes(32, "big")
-    kw = np.ascontiguousarray(
-        np.frombuffer(bytes(kb), ">u4").reshape(B, 8).T).astype(np.uint32)
-    return ay, a_sign, ry, r_sign, sw, kw
+    ay, a_sign = key_words(pubkeys)
+    ry, r_sign, s = sig_words(sigs)
+    k = challenge_words(pubkeys, sigs, msgs)
+    return (np.ascontiguousarray(ay.T), a_sign, np.ascontiguousarray(ry.T),
+            r_sign, np.ascontiguousarray(s.T), np.ascontiguousarray(k.T))

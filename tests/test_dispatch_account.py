@@ -266,3 +266,97 @@ def test_table_build_span_under_the_batchs_span(rig):
     assert record["pack_ms"] == 0.0
     from fabric_tpu.ops_plane import registry
     assert registry.get("provider_table_build_seconds") is not None
+
+
+# -- two curves, four lanes (PR 32) ---------------------------------------------
+
+def signed_ed25519(n_keys: int, per_key: int = 1) -> list:
+    from fabric_tpu.bccsp.provider import SCHEME_ED25519
+    items = []
+    for i in range(n_keys):
+        key = SW.key_gen(SCHEME_ED25519)
+        for j in range(per_key):
+            msg = b"message %d %d " % (i, j) * (1 + 40 * j)
+            items.append(VerifyItem(SCHEME_ED25519, key.public_bytes(),
+                                    SW.sign(key, msg), msg))
+    return items
+
+
+@pytest.fixture
+def mixed_rig(rig):
+    """The rig with stand-ins on the Ed25519 lanes too, each call noted
+    in the order the chip got it."""
+    from fabric_tpu.bccsp.provider import SCHEME_ED25519
+    p, clock, chip, reg = rig
+    calls = []
+
+    def noting(name, fn):
+        def call(*args):
+            calls.append(name)
+            return fn(*args)
+        return call
+
+    p._fns[SCHEME_P256] = noting("generic", chip.generic)
+    p._fns["p256-rows"] = noting("rows", chip.rows)
+    p._fns[SCHEME_ED25519] = noting("ed25519", chip.generic)
+    p._fns["ed25519-rows"] = noting("ed25519-rows", chip.rows)
+    return p, reg, calls
+
+
+def lanes_of(reg, name) -> dict:
+    out = {}
+    for labels, n in series(reg, name).items():
+        lane = dict(labels)["lane"]
+        out[lane] = out.get(lane, 0) + n
+    return out
+
+
+def test_a_mixed_batch_moves_one_lane_a_kernel(mixed_rig):
+    """A block of two curves is two programs, and the account tells them
+    apart: `rows` and `ed25519-rows` move by one each, under their own
+    program names; the sums are still the provider's own counts."""
+    p, reg, calls = mixed_rig
+    # the Ed25519 items first: the order is the provider's decision
+    # (P-256 first), not the scheme of whichever item came first
+    items = signed_ed25519(1, 70) + signed(1, 70)
+    with dispatch_site("validator"):
+        assert p.batch_verify(items).all()
+    assert calls == ["rows", "ed25519-rows"]
+    assert lanes_of(reg, "provider_dispatch_total") == {
+        "rows": 1, "ed25519-rows": 1}
+    assert lanes_of(reg, "provider_dispatch_sigs_total") == {
+        "rows": 70, "ed25519-rows": 70}
+    programs = {dict(k)["program"]
+                for k in series(reg, "provider_dispatch_total")}
+    assert programs == {"rows@4", "ed25519-rows@4"}
+    assert sum(series(reg, "provider_dispatch_total").values()) \
+        == p.stats["dispatches"] == 2
+    assert sum(series(reg, "provider_dispatch_sigs_total").values()) \
+        == p.stats["device_sigs"] == 140
+    held = reg.get("provider_dispatch_held_seconds")
+    assert {dict(k)["lane"] for k in held._sum} == {"rows", "ed25519-rows"}
+    pack = reg.get("provider_dispatch_pack_seconds")
+    assert {dict(k)["lane"] for k in pack._sum} == {"rows", "ed25519-rows"}
+
+
+def test_the_ladder_lanes_are_told_apart_too(mixed_rig):
+    p, reg, calls = mixed_rig
+    assert p.batch_verify(signed_ed25519(2) + signed(3)).all()
+    assert calls == ["generic", "ed25519"]
+    assert lanes_of(reg, "provider_dispatch_sigs_total") == {
+        "generic": 3, "ed25519": 2}
+    programs = {dict(k)["program"]
+                for k in series(reg, "provider_dispatch_total")}
+    assert programs == {"generic@128", "ed25519@128"}
+
+
+def test_a_p256_only_batch_leaves_no_ed25519_series(mixed_rig):
+    """Nothing changes for a P-256-only window: its exposition carries
+    no series of the Ed25519 lanes."""
+    p, reg, calls = mixed_rig
+    with dispatch_site("validator"):
+        assert p.batch_verify(signed(1, 70) + signed(3)).all()
+    assert calls == ["rows", "generic"]
+    assert "ed25519" not in reg.expose_text()
+    for name in ("provider_dispatch_total", "provider_dispatch_sigs_total"):
+        assert set(lanes_of(reg, name)) == {"rows", "generic"}
