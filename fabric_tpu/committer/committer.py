@@ -31,6 +31,10 @@ class BlockCommitResult:
     validation: ValidationResult  # flags as of the sig/policy gate
     commit_stats: object          # ledger CommitStats
     final_flags: object           # TxFlags after MVCC (what the block stores)
+    # the block trace's root (SpanContext), None when it is not recorded:
+    # what runs after store_block returns (the private-data
+    # coordinator's tail) hangs its span here
+    trace: object = None
 
 
 class Committer:
@@ -74,6 +78,7 @@ class Committer:
             if parsed is not None:
                 tracing.tracer.record_span("wire.parse_block", *parsed)
             result = self._store_block_inner(block)
+            result.trace = span.context
             if span.recording:
                 span.set_attribute("valid",
                                    result.final_flags.valid_count())
@@ -297,7 +302,8 @@ class Committer:
         """Retroactive child spans for the ledger commit phases, each
         where it really ran (kvledger stamps the intervals)."""
         for name, start, end in stats.phase_spans:
-            tracing.tracer.record_span(name, start, end)
+            tracing.tracer.record_span(name, start, end,
+                                       stats.span_attrs.get(name))
 
     def _observe_metrics(self, block, vr, stats) -> None:
         """Per-phase commit metrics (metric parity: the reference's

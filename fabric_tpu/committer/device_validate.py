@@ -43,11 +43,12 @@ from __future__ import annotations
 import logging
 import struct
 import threading
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional
 
 import numpy as np
 
-from fabric_tpu.protocol import Version
+from fabric_tpu.ledger.mvcc import committed_versions, prepared_from_lanes
+from fabric_tpu.protocol import wire
 from fabric_tpu.protocol.txflags import TxFlags
 
 logger = logging.getLogger("fabric_tpu.committer.device_validate")
@@ -161,7 +162,6 @@ class DeviceValidator:
         lanes = getattr(block, "rwset_lanes", None)
         if lanes is not None:
             return lanes, block.raw
-        from fabric_tpu.protocol import wire
         parts: List[bytes] = []
         spans = bytearray()
         off = 0
@@ -242,19 +242,14 @@ class DeviceValidator:
         lanes, base = self._lanes_of(block)
         if lanes is None:
             raise _Demote("extract")
-        lflags, lt, lk, lr, lw, arena = lanes
-        if lflags:
+        if lanes[0]:
             raise _Demote("hash_collision")
-        if lt != T:
+        if lanes[1] != T:
             raise _Demote("extract")
-
-        arr = np.frombuffer(arena, dtype=np.uint64)
-        o = 0
-        tx_sec = arr[o:o + 3 * lt].reshape(lt, 3); o += 3 * lt
-        rd = arr[o:o + 5 * lr].reshape(lr, 5); o += 5 * lr
-        wr = arr[o:o + 5 * lw].reshape(lw, 5); o += 5 * lw
-        ky = arr[o:o + 5 * lk].reshape(lk, 5)
-        status = tx_sec[:, 0].astype(np.int32)
+        table = wire.LaneTable(base, lanes)
+        lr, lw, lk = len(table.reads), len(table.writes), lanes[2]
+        rd, wr = table.reads, table.writes
+        status = table.status.astype(np.int32)
 
         plans = state["plans"]
         for plan in plans:
@@ -265,13 +260,15 @@ class DeviceValidator:
                 raise _Demote("inexpressible")
 
         gate_in = self._build_gate(plans, verdict, plugin, evaluator, T)
-        key_strs, c_arrs = self._gather_committed(db, ky, base, lk)
+        c_arrs = self._gather_committed(db, table)
 
         gate_bytes, final = self._dispatch(
             pre, status, gate_in, rd, wr, c_arrs, num, lr, lw, lk)
 
-        batch, history = self._rebuild(final, tx_sec, wr, key_strs,
-                                       base, num, lw)
+        # the write lanes of final-valid txs, replayed as the serial
+        # walk stages them: its UpdateBatch order, its history rows
+        batch, history = prepared_from_lanes(
+            table, TxFlags.from_bytes(bytes(final)), num)
         # pre-split by state shard off the commit lock path; the
         # ledger's apply_updates consumes the cached split
         batch.preshard(getattr(self.statedb, "n_shards", 1))
@@ -349,37 +346,34 @@ class DeviceValidator:
     # -- committed-state gather ---------------------------------------------
 
     @staticmethod
-    def _gather_committed(db, ky, base, K):
-        """Decode each interned key slot once and snapshot its committed
-        version as i32 lanes; out-of-range versions demote."""
-        key_strs: List[Tuple[str, str]] = []
+    def _gather_committed(db, table):
+        """Snapshot each interned key slot's committed version as i32
+        lanes (ledger/mvcc.committed_versions: one look-up a slot, as
+        the serial walk's lane source does); out-of-range versions
+        demote."""
+        K = len(table.key_strs)
         c_has = np.zeros(K, dtype=np.int32)
         c_blk = np.zeros(K, dtype=np.int32)
         c_txn = np.zeros(K, dtype=np.int32)
-        for s in range(K):
-            _h, no, nn, ko, kn = (int(x) for x in ky[s])
-            ns = bytes(base[no:no + nn]).decode("utf-8")
-            key = bytes(base[ko:ko + kn]).decode("utf-8")
-            key_strs.append((ns, key))
-            vv = db.get(ns, key)
-            if vv is None:
+        for s, pair in enumerate(committed_versions(db, table.key_strs)):
+            if pair is None:
                 continue
-            bn, tn = vv.version.block_num, vv.version.tx_num
+            bn, tn = pair
             if not (_I32_MIN <= bn <= _I32_MAX
                     and _I32_MIN <= tn <= _I32_MAX):
                 raise _Demote("version_range")
             c_has[s] = 1
             c_blk[s] = bn
             c_txn[s] = tn
-        return key_strs, (c_has, c_blk, c_txn)
+        return c_has, c_blk, c_txn
 
     # -- the fused program ---------------------------------------------------
 
     @staticmethod
     def _i32(col: np.ndarray) -> np.ndarray:
-        # u64 lane -> i32 (two's complement; walkers enforce i32 range
-        # for version fields, and offsets/slots are small positives)
-        return col.astype(np.int64).astype(np.int32)
+        # i64 lane -> i32 (walkers enforce i32 range for version
+        # fields, and offsets/slots are small positives)
+        return col.astype(np.int32)
 
     def _dispatch(self, pre, status, g, rd, wr, c_arrs, num, R, W, K):
         floor = self._mesh_floor()
@@ -523,37 +517,3 @@ class DeviceValidator:
         prog = jax.jit(fn)
         _PROGRAMS[ckey] = prog
         return prog
-
-    # -- batch / history rebuild (oracle insertion order) --------------------
-
-    @staticmethod
-    def _rebuild(final, tx_sec, wr, key_strs, base, num, W):
-        """Replay the write lanes of final-valid txs in global lane
-        order: identical put/delete call sequence (and therefore
-        identical UpdateBatch dict order) and identical history rows to
-        validate_and_prepare_batch."""
-        from fabric_tpu.ledger.statedb import UpdateBatch
-        batch = UpdateBatch()
-        history: List[tuple] = []
-        txids: Dict[int, str] = {}
-        for j in range(W):
-            t = int(wr[j, 0])
-            if final[t] != 0:
-                continue
-            txid = txids.get(t)
-            if txid is None:
-                toff, tlen = int(tx_sec[t, 1]), int(tx_sec[t, 2])
-                txid = bytes(base[toff:toff + tlen]).decode("utf-8")
-                txids[t] = txid
-            slot = int(wr[j, 1])
-            is_del = bool(wr[j, 2])
-            voff, vlen = int(wr[j, 3]), int(wr[j, 4])
-            ns, key = key_strs[slot]
-            value = bytes(base[voff:voff + vlen])
-            version = Version(num, t)
-            if is_del:
-                batch.delete(ns, key, version)
-            else:
-                batch.put(ns, key, value, version)
-            history.append((t, txid, ns, key, value, is_del))
-        return batch, history

@@ -1,8 +1,11 @@
 """Commit-status notifier: txid -> validation code, push not poll.
 
 Rides the committer's post-commit listener hook (committer.py calls
-fn(block, final_flags) after every ledger commit), decodes each
-envelope's txid once, and wakes any blocked commit_status waiters.
+fn(block, final_flags) after every ledger commit), reads each tx's txid
+off the block's lane table (`wire.lane_table`: the one the ledger's MVCC
+walk and the block store's index read; a tx the table does not speak
+for, and a block without a table, is decoded envelope by envelope), and
+wakes any blocked commit_status waiters.
 This is the event plane the reference builds from peer/deliveryservice
 block events + gateway/commit.go — here it is in-process because the
 gateway is peer-co-located.
@@ -14,13 +17,14 @@ path (blkstorage keeps the authoritative record forever).
 
 from __future__ import annotations
 
+import itertools
 import logging
 import threading
 from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
 
 from fabric_tpu.ops_plane import tracing
-from fabric_tpu.protocol import Envelope
+from fabric_tpu.protocol import Envelope, wire
 
 logger = logging.getLogger("fabric_tpu.gateway")
 
@@ -43,23 +47,40 @@ class CommitNotifier:
         # ambient trace id here IS the block trace — remember it so
         # commit_status can link the request trace to the block trace
         block_trace = tracing.tracer.current_trace_id()
+        number = int(block.header.number)
+        txids = wire.lane_txids(block)
+        # what this block adds past the history: txid -> entry, ordered
+        # by first appearance, the last appearance's entry (a dict's
+        # rule, and `_history`'s)
+        fresh: Dict[str, Tuple[int, int, Optional[str]]] = {}
         with self._lock:
-            for i, env_bytes in enumerate(block.data):
-                try:
-                    txid = Envelope.deserialize(
-                        env_bytes).header().channel_header.txid
-                except Exception:
-                    continue
+            history = self._history
+            for i, (txid, code) in enumerate(zip(txids, flags.codes())):
+                if txid is None:
+                    try:
+                        txid = Envelope.deserialize(
+                            block.data[i]).header().channel_header.txid
+                    except Exception:
+                        continue
                 if not txid:
                     continue
-                self._history[txid] = (int(flags.flag(i)),
-                                       int(block.header.number),
-                                       block_trace)
-                evs = self._waiters.pop(txid, None)
-                if evs:
-                    notified.extend(evs)
-            while len(self._history) > self.window:
-                self._history.popitem(last=False)
+                if txid in history:
+                    history[txid] = (code, number, block_trace)
+                else:
+                    fresh[txid] = (code, number, block_trace)
+                if self._waiters:
+                    evs = self._waiters.pop(txid, None)
+                    if evs:
+                        notified.extend(evs)
+            # the window evicts from the front: first what was there,
+            # then the head of what this block brings, which is never
+            # inserted
+            over = len(history) + len(fresh) - self.window
+            gone = max(0, min(over, len(history)))
+            for _ in range(gone):
+                history.popitem(last=False)
+            history.update(itertools.islice(
+                fresh.items(), max(0, over - gone), None))
         for ev in notified:
             ev.set()
 

@@ -167,17 +167,21 @@ class BlockStore:
         self._by_hash[h] = num
         self._prev_hash = block.header.previous_hash
         self._cur_hash = h
-        for i, env_bytes in enumerate(block.data):
-            # native header peek; full decode only when it rejects
-            summary = wire.envelope_summary(env_bytes)
-            if summary is not None:
-                txid = summary[2]
-            else:
-                try:
-                    txid = Envelope.deserialize(
-                        env_bytes).header().channel_header.txid
-                except Exception:
-                    continue
+        # the block's lane table speaks for its OK txs; every other tx,
+        # and a block without a table, is read envelope by envelope
+        for i, txid in enumerate(wire.lane_txids(block)):
+            if txid is None:
+                env_bytes = block.data[i]
+                # native header peek; full decode only when it rejects
+                summary = wire.envelope_summary(env_bytes)
+                if summary is not None:
+                    txid = summary[2]
+                else:
+                    try:
+                        txid = Envelope.deserialize(
+                            env_bytes).header().channel_header.txid
+                    except Exception:
+                        continue
             # first writer wins: duplicate txids keep the earliest location
             self._by_txid.setdefault(txid, (num, i))
 

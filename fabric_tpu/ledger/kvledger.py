@@ -25,12 +25,13 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 from fabric_tpu.protocol import Block
+from fabric_tpu.protocol.wire import n_txs
 from fabric_tpu.protocol.txflags import TxFlags, ValidationCode
 from fabric_tpu.protocol.types import META_COMMIT_HASH, META_TXFLAGS
 
 from .blkstorage import BlockStore
 from .historydb import HistoryDB
-from .mvcc import MvccTally, validate_and_prepare_batch
+from .mvcc import MvccTally, lane_source_of, validate_and_prepare_batch
 from .statedb import StateDB
 
 logger = logging.getLogger("fabric_tpu.ledger")
@@ -123,15 +124,20 @@ class CommitStats:
     valid_txs: int = 0
     total_txs: int = 0
     # (span name, start, end) of each phase as it really ran, on
-    # perf_counter: what the committer records as the ledger.* spans
+    # perf_counter: what the committer records as the ledger.* spans,
+    # with the attributes `span_attrs` holds under the span's name
     phase_spans: list = field(default_factory=list)
+    span_attrs: dict = field(default_factory=dict)
 
-    def phase(self, name: str, attr: str, t0: float) -> None:
+    def phase(self, name: str, attr: str, t0: float,
+              attributes: Optional[dict] = None) -> None:
         """Close a phase that began at `t0`: its seconds into `attr`,
         its real interval into `phase_spans`."""
         t1 = time.perf_counter()
         setattr(self, attr, getattr(self, attr) + t1 - t0)
         self.phase_spans.append((name, t0, t1))
+        if attributes:
+            self.span_attrs[name] = attributes
 
 
 class KVLedger:
@@ -279,25 +285,27 @@ class KVLedger:
                              "falling back to host MVCC")
             return None
 
-    def _validate_and_prepare(self, num: int, envelopes, flags: TxFlags,
+    def _validate_and_prepare(self, num: int, source, flags: TxFlags,
                               tally: Optional[MvccTally] = None):
         """MVCC pass: the wavefront scheduler when parallel_commit is
-        on, the serial oracle otherwise — identical output either way.
-        Only the oracle fills `tally`."""
+        on (over envelopes), the serial oracle otherwise (over either
+        of its sources) — identical output either way.  Only the oracle
+        fills `tally`."""
         if self._commit_scheduler is not None:
             return self._commit_scheduler.validate_and_prepare_batch(
-                self.statedb, num, envelopes, flags)
+                self.statedb, num, source, flags)
         return validate_and_prepare_batch(self.statedb, num,
-                                          envelopes, flags, tally)
+                                          source, flags, tally)
 
     def _count_block(self, flags: TxFlags, tally: Optional[MvccTally],
-                     writes: int) -> None:
+                     writes: int, source: Optional[str] = None) -> None:
         """One committed block into the always-on counters: its
         transactions by final code, the writes of its valid txs and,
         where the serial walk validated it (`tally`), the reads it
-        checked and the conflicts it found.  The default-off commit
-        paths do not walk read by read: their blocks move no
-        `path="serial"` series, so those never read as "no conflicts"."""
+        checked, the conflicts it found and which `source` supplied its
+        rw-sets.  The default-off commit paths do not walk read by
+        read: their blocks move no `path="serial"` series, so those
+        never read as "no conflicts", and no source."""
         from fabric_tpu.ops_plane import registry
         ch = self.channel_id
         txs = registry.counter(
@@ -310,6 +318,11 @@ class KVLedger:
             "applied to state and history").add(writes, channel=ch)
         if tally is None:
             return
+        registry.counter(
+            "ledger_commit_source_total", "transactions of the blocks the "
+            "serial MVCC walk validated, by what supplied their rw-sets: "
+            "the block's lane table, or its envelopes decoded again").add(
+                len(flags), channel=ch, source=source)
         registry.counter(
             "ledger_mvcc_reads_total", "reads validated, by the commit "
             "path that counts them (the serial MVCC walk)").add(
@@ -367,7 +380,7 @@ class KVLedger:
             raise ValueError(
                 f"block {block.header.number} previous_hash mismatch")
         stats = CommitStats(block_num=block.header.number,
-                            total_txs=len(block.data))
+                            total_txs=n_txs(block))
 
         t0 = time.perf_counter()
         tally = None                 # only the serial walk has one
@@ -378,17 +391,26 @@ class KVLedger:
             # no envelope materialization, no host MVCC walk
             final_bytes, batch, history = prepared
             flags = TxFlags.from_bytes(final_bytes)
+            mvcc_attrs = {"source": "prepared"}
         else:
             flags = TxFlags.from_bytes(block.metadata.items[META_TXFLAGS])
-            envelopes = _safe_envelopes(block)
+            # the serial walk reads the block's lane table where the
+            # block allows it, else its envelopes, decoded again
+            source, reason = None, "scheduler"
             if self._commit_scheduler is None:
                 tally = MvccTally()
+                source, reason = lane_source_of(block, flags)
+            if source is not None:
+                mvcc_attrs = {"source": "lanes"}
+            else:
+                source = _safe_envelopes(block)
+                mvcc_attrs = {"source": "envelopes", "reason": reason}
             batch, history = self._validate_and_prepare(
-                block.header.number, envelopes, flags, tally)
+                block.header.number, source, flags, tally)
         # split the batch by shard before the apply takes shard locks
         # (the parallel-commit / device-validate planes do the same)
         batch.preshard(getattr(self.statedb, "n_shards", 1))
-        stats.phase("ledger.mvcc", "state_validation_s", t0)
+        stats.phase("ledger.mvcc", "state_validation_s", t0, mvcc_attrs)
         stats.valid_txs = flags.valid_count()
         # MVCC may have flipped more flags — write the final bitmap back
         block.metadata.items[META_TXFLAGS] = flags.to_bytes()
@@ -414,7 +436,7 @@ class KVLedger:
             stats.phase("ledger.history_commit", "history_commit_s", t0)
 
         self._observe_apply(len(batch), len(history))
-        self._count_block(flags, tally, len(history))
+        self._count_block(flags, tally, len(history), mvcc_attrs["source"])
         self.last_stats = stats
         logger.info(
             "[%s] committed block %d: %d/%d valid | validation=%.1fms "

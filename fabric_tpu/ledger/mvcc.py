@@ -16,12 +16,34 @@ rangequery_validator.go.  Semantics preserved exactly:
 
 The verify-then-gate restructure (SURVEY.md §7) does not touch this pass:
 it runs after the TPU verdict bitmap has been folded into the flags.
+
+One walk, two sources.  `validate_and_prepare_batch` is the one serial
+walk and the oracle; its per-tx records come from one of two suppliers:
+
+- the *envelope source* decodes each still-VALID envelope
+  (`parse_endorser_tx`: the whole payload, endorsements and all) and
+  names a key by its (namespace, key) pair;
+- the *lane source* reads the block's `wire.LaneTable` — the C walker's
+  one pass over the block (`BlockView.rwset_lanes`) — and names a key by
+  its interned slot: keys are decoded and their committed versions
+  fetched once a slot (`committed_versions`), no Envelope, Transaction
+  or TxRwSet is built and `block.data` is not materialised.
+
+Which one a block takes is read off the block (`lane_source_of`), set by
+nobody: the lane source when the block is a `BlockView`, the extractor
+is native, no two keys of the block collide in the hash, the table's tx
+count is the flags', and no tx whose flag is still VALID has status
+RANGE or UNKNOWN (range replay stays host work over decoded reads);
+otherwise the whole block goes through the envelope source.
 """
 
 from __future__ import annotations
 
 from typing import List, Optional, Tuple
 
+import numpy as np
+
+from fabric_tpu.protocol import wire
 from fabric_tpu.protocol import (
     Envelope,
     KVRead,
@@ -36,28 +58,20 @@ from fabric_tpu.protocol.types import RangeQueryInfo, TX_ENDORSER
 from .statedb import StateDB, UpdateBatch
 
 
-def _read_conflict(db: StateDB, batch: UpdateBatch, ns: str,
-                   read: KVRead) -> Optional[str]:
-    """validateKVRead (validator.go:175): version equality, nil-safe.
-    -> None when the read still holds, else who answered otherwise:
-    "block" (a write an earlier valid tx of this block staged) or
-    "state" (the committed state)."""
+def _validate_read(db: StateDB, batch: UpdateBatch, ns: str,
+                   read: KVRead) -> bool:
+    """validateKVRead (validator.go:175): version equality, nil-safe
+    (the wavefront scheduler's per-read check; the serial walk below
+    compares the same way over its sources' records)."""
     found, vv = batch.get(ns, read.key)
     if not found:
         vv = db.get(ns, read.key)
     committed = None if vv is None else vv.version  # None: absent or deleted
     if committed is None and read.version is None:
-        return None
-    if (committed is None or read.version is None
-            or committed.block_num != read.version.block_num
-            or committed.tx_num != read.version.tx_num):
-        return "block" if found else "state"
-    return None
-
-
-def _validate_read(db: StateDB, batch: UpdateBatch, ns: str,
-                   read: KVRead) -> bool:
-    return _read_conflict(db, batch, ns, read) is None
+        return True
+    return not (committed is None or read.version is None
+                or committed.block_num != read.version.block_num
+                or committed.tx_num != read.version.tx_num)
 
 
 class MvccTally:
@@ -128,65 +142,193 @@ def extract_rwset(env: Envelope) -> Optional[TxRwSet]:
     return None if parsed is None else parsed[1]
 
 
+# -- the two sources ----------------------------------------------------------
+#
+# A source gives (records, committed).  `records` yields, for each tx whose
+# flag is VALID and that carries a kv rw-set, in block order,
+#   (tx_num, txid, groups, writes)   or   (tx_num, None, None, None)
+# the second where the rw-set does not decode (BAD_RWSET).  `groups` is one
+# (reads, range_queries) pair a namespace, reads being (ident, version) with
+# version None | (block_num, tx_num); `writes` yields (ident, ns, key, value,
+# is_delete).  `ident` names a key within the block, and `committed(ident)`
+# is the version the state holds for it, None when absent.
+
+
+def _version_pair(version: Optional[Version]):
+    return None if version is None else (version.block_num, version.tx_num)
+
+
+def _envelope_source(db: StateDB, envelopes: List[Envelope], flags: TxFlags):
+    def records():
+        for tx_num, env in enumerate(envelopes):
+            if not flags.is_valid(tx_num):
+                continue
+            try:
+                parsed = parse_endorser_tx(env)
+            except Exception:
+                yield tx_num, None, None, None
+                continue
+            if parsed is None:
+                continue  # config txs etc. don't carry kv rwsets
+            txid, rwset = parsed
+            yield (tx_num, txid,
+                   [([((n.namespace, r.key), _version_pair(r.version))
+                      for r in n.reads],
+                     [(n.namespace, rq) for rq in n.range_queries])
+                    for n in rwset.ns_rwsets],
+                   [((n.namespace, w.key), n.namespace, w.key, w.value,
+                     w.is_delete)
+                    for n in rwset.ns_rwsets for w in n.writes])
+
+    def committed(ident):
+        vv = db.get(*ident)
+        return None if vv is None else _version_pair(vv.version)
+
+    return records(), committed
+
+
+def committed_versions(db: StateDB, key_strs) -> list:
+    """The version the state holds for each (namespace, key), None when
+    absent: one look-up a slot of a lane table."""
+    return [None if vv is None else _version_pair(vv.version)
+            for vv in (db.get(ns, key) for ns, key in key_strs)]
+
+
+def _lane_records(table: "wire.LaneTable", flags: TxFlags):
+    """The lane source's records.  Lanes ascend by tx and keep the
+    oracle's order within one (namespace by namespace, reads then
+    writes), and no tx that gets here has a range query: one group."""
+    txs = np.arange(table.n_tx)
+    rd, wr = table.reads, table.writes
+    r_end = np.searchsorted(rd[:, 0], txs, side="right").tolist()
+    w_end = np.searchsorted(wr[:, 0], txs, side="right").tolist()
+    reads = [(slot, (blk, txn) if has else None)
+             for slot, has, blk, txn in rd[:, 1:].tolist()]
+    w_rows = wr[:, 1:].tolist()
+    key_strs, txids, base = table.key_strs, table.txids, table.base
+    r0 = w0 = 0
+    for tx_num, status in enumerate(table.status.tolist()):
+        r1, w1 = r_end[tx_num], w_end[tx_num]
+        if status != wire.LANE_SKIP and flags.is_valid(tx_num):
+            if status == wire.LANE_BAD:
+                yield tx_num, None, None, None
+            else:
+                yield (tx_num, txids[tx_num], ((reads[r0:r1], ()),),
+                       ((slot, *key_strs[slot], bytes(base[off:off + n]),
+                         bool(is_delete))
+                        for slot, is_delete, off, n in w_rows[w0:w1]))
+        r0, w0 = r1, w1
+
+
+def _lane_source(db: StateDB, table: "wire.LaneTable", flags: TxFlags):
+    return (_lane_records(table, flags),
+            committed_versions(db, table.key_strs).__getitem__)
+
+
+def lane_source_of(block, flags: TxFlags):
+    """The rule, read off the block: (its LaneTable, None) when the lane
+    source may supply the walk, else (None, reason) — wire.lane_table's,
+    or "count" / "range" / "unknown" (see the head of this module)."""
+    table, reason = wire.lane_table(block)
+    if table is None:
+        return None, reason
+    if table.n_tx != len(flags):
+        return None, "count"
+    valid = np.frombuffer(flags.to_bytes(), dtype=np.uint8) == int(
+        ValidationCode.VALID)
+    status = table.status[valid]
+    if (status == wire.LANE_RANGE).any():
+        return None, "range"
+    if (status == wire.LANE_UNKNOWN).any():
+        return None, "unknown"
+    return table, None
+
+
+def _stage_writes(batch: UpdateBatch, history: list, staged: dict,
+                  block_num: int, tx_num: int, txid: str, writes) -> None:
+    """A valid tx's writes join the batch at Version(block_num, tx_num)."""
+    version = Version(block_num, tx_num)
+    pair = (block_num, tx_num)
+    for ident, ns, key, value, is_delete in writes:
+        if is_delete:
+            batch.delete(ns, key, version)
+            staged[ident] = None
+        else:
+            batch.put(ns, key, value, version)
+            staged[ident] = pair
+        history.append((tx_num, txid, ns, key, value, is_delete))
+
+
+def prepared_from_lanes(table: "wire.LaneTable", final: TxFlags,
+                        block_num: int):
+    """(update_batch, history_writes) of a block whose FINAL flags are
+    known (the fused device program's): the write lanes of its valid txs
+    replayed in lane order — the put/delete sequence, and therefore the
+    UpdateBatch's order and the history rows, of the walk below."""
+    batch, history = UpdateBatch(), []
+    for tx_num, txid, _groups, writes in _lane_records(table, final):
+        if writes is not None:
+            _stage_writes(batch, history, {}, block_num, tx_num, txid,
+                          writes)
+    return batch, history
+
+
 def validate_and_prepare_batch(
         db: StateDB, block_num: int,
-        envelopes: List[Envelope], flags: TxFlags,
+        source, flags: TxFlags,
         tally: Optional[MvccTally] = None,
 ) -> Tuple[UpdateBatch, List[Tuple[int, str, str, str, bytes, bool]]]:
     """validateAndPrepareBatch (validator.go:83).
 
+    `source` is the block's envelopes, in order (None for one that does
+    not decode), or its `wire.LaneTable` where `lane_source_of` gave one.
     Mutates `flags` (MVCC_READ_CONFLICT / PHANTOM_READ_CONFLICT /
     BAD_RWSET) and returns (update_batch, history_writes) where
     history_writes = (tx_num, txid, ns, key, value, is_delete) of VALID txs.
     `tally`, when given, takes the reads validated and the conflicts.
     """
+    if isinstance(source, wire.LaneTable):
+        records, committed = _lane_source(db, source, flags)
+    else:
+        records, committed = _envelope_source(db, source, flags)
     batch = UpdateBatch()
+    # ident -> version of the writes that valid txs of this block staged
+    # so far (None: a staged delete); what `batch` holds, by ident
+    staged: dict = {}
     reads = against_block = against_state = 0
     history: List[Tuple[int, str, str, str, bytes, bool]] = []
-    for tx_num, env in enumerate(envelopes):
-        if not flags.is_valid(tx_num):
-            continue
-        try:
-            parsed = parse_endorser_tx(env)
-        except Exception:
+    for tx_num, txid, groups, writes in records:
+        if groups is None:
             flags.set(tx_num, ValidationCode.BAD_RWSET)
             continue
-        if parsed is None:
-            continue  # config txs etc. don't carry kv rwsets
-        txid, rwset = parsed
         ok = True
-        for ns_rw in rwset.ns_rwsets:
-            for read in ns_rw.reads:
+        for ns_reads, range_queries in groups:
+            for ident, version in ns_reads:
                 reads += 1
-                against = _read_conflict(db, batch, ns_rw.namespace, read)
-                if against is not None:
-                    flags.set(tx_num, ValidationCode.MVCC_READ_CONFLICT)
-                    if against == "block":
+                # validateKVRead (validator.go:175): version equality,
+                # nil-safe, against the block's own writes first
+                if ident in staged:
+                    if staged[ident] != version:
                         against_block += 1
-                    else:
-                        against_state += 1
+                        ok = False
+                elif committed(ident) != version:
+                    against_state += 1
                     ok = False
+                if not ok:
+                    flags.set(tx_num, ValidationCode.MVCC_READ_CONFLICT)
                     break
             if not ok:
                 break
-            for rq in ns_rw.range_queries:
-                if not _validate_range_query(db, batch, ns_rw.namespace, rq):
+            for ns, rq in range_queries:
+                if not _validate_range_query(db, batch, ns, rq):
                     flags.set(tx_num, ValidationCode.PHANTOM_READ_CONFLICT)
                     ok = False
                     break
             if not ok:
                 break
-        if not ok:
-            continue
-        version = Version(block_num, tx_num)
-        for ns_rw in rwset.ns_rwsets:
-            for w in ns_rw.writes:
-                if w.is_delete:
-                    batch.delete(ns_rw.namespace, w.key, version)
-                else:
-                    batch.put(ns_rw.namespace, w.key, w.value, version)
-                history.append((tx_num, txid, ns_rw.namespace, w.key,
-                                w.value, w.is_delete))
+        if ok:
+            _stage_writes(batch, history, staged, block_num, tx_num, txid,
+                          writes)
     if tally is not None:
         tally.reads += reads
         tally.conflicts_block += against_block

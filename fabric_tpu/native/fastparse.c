@@ -636,7 +636,8 @@ reject:
  *                     range_queries list (interval replay is host work)
  *   status 4 UNKNOWN  host outcome is deterministic but device-
  *                     inexpressible (non-str keys, bignum/odd version
- *                     shapes, non-bool is_delete, non-bytes payload…)
+ *                     shapes, non-bool is_delete, non-bytes payload…),
+ *                     or a header Envelope.header() would refuse
  *
  * RANGE/UNKNOWN txs that could pass the signature gate force the host
  * path for the block (demotion); BAD/SKIP never do.  rw-set keys are
@@ -1099,6 +1100,22 @@ static int walk_env(const uint8_t *base, const uint8_t *ep, size_t en,
         if (rd_str(&txid_v, &sp, &sn) < 0) return LN_BAD;
         *txid_off = (uint64_t)(sp - base);
         *txid_len = sn;
+    }
+    /* OK also tells the txid readers (block index, commit notifier,
+     * private-data coordinator) that Envelope.header() succeeds: it
+     * needs channel_id, and creator + nonce in a signature_header.
+     * parse_endorser_tx reads none of them, so a tx without them is not
+     * BAD; the table just cannot speak for it */
+    {
+        cur_t t = ch_v, v = {NULL, NULL}, sh_v = {NULL, NULL};
+        if (dict_find(&t, "channel_id", &v) != 1) return LN_UNKNOWN;
+        t = header_v;
+        if (dict_find(&t, "signature_header", &sh_v) != 1)
+            return LN_UNKNOWN;
+        t = sh_v;
+        if (dict_find(&t, "creator", &v) != 1) return LN_UNKNOWN;
+        t = sh_v;
+        if (dict_find(&t, "nonce", &v) != 1) return LN_UNKNOWN;
     }
     return LN_OK;
 }

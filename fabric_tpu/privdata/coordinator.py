@@ -15,7 +15,8 @@ import logging
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
-from fabric_tpu.protocol import Envelope
+from fabric_tpu.ops_plane import tracing
+from fabric_tpu.protocol import Envelope, wire
 from fabric_tpu.protocol.txflags import TxFlags, ValidationCode
 from fabric_tpu.protocol.types import META_TXFLAGS, TxRwSet
 
@@ -74,20 +75,37 @@ class Coordinator:
 
     def store_block(self, block):
         result = self.committer.store_block(block)
+        with tracing.tracer.start_span(
+                "privdata.store_block", require_parent=True,
+                parent=getattr(result, "trace", None)):
+            self._store_private(block)
+        return result
+
+    def _store_private(self, block) -> None:
+        """The private half of StoreBlock, after the block's commit.
+        Which VALID txs write to a collection is read off the block's
+        lane table (`wire.lane_table`) and only those are decoded; a tx
+        the table does not speak for, and a block without a table, is
+        decoded as before."""
         flags = TxFlags.from_bytes(block.metadata.items[META_TXFLAGS])
+        valid = [t for t, code in enumerate(flags.codes())
+                 if code == ValidationCode.VALID]
+        table, _ = wire.lane_table(block)
+        txids = wire.lane_txids(block)
+        known = {t: txids[t] for t in valid if txids[t] is not None}
+        private = (set(table.txs_writing_under(PVT_SEP))
+                   if table is not None else ())
+        decode = [t for t in valid if t not in known or t in private]
         writes: Dict[Tuple[str, str], Dict[str, object]] = {}
         btl: Dict[Tuple[str, str], int] = {}
-        txids = []
-        for tx_num, env_bytes in enumerate(block.data):
-            if not flags.is_valid(tx_num):
-                continue
+        for tx_num in decode:
             try:
-                env = Envelope.deserialize(env_bytes)
+                env = Envelope.deserialize(block.data[tx_num])
                 txid = env.header().channel_header.txid
                 rwset = _tx_rwset(env)
             except Exception:
                 continue
-            txids.append(txid)
+            known[tx_num] = txid
             if rwset is None:
                 continue
             for ns_set in rwset.ns_rwsets:
@@ -112,8 +130,10 @@ class Coordinator:
         if writes:
             self.pvt_store.commit(block.header.number, writes, btl)
         self.pvt_store.process_purges(block.header.number)
-        self.transient.purge_by_txids(txids)
-        return result
+        # the block's VALID txs that decode: nothing to purge them from
+        # while the transient store holds nothing
+        if len(self.transient):
+            self.transient.purge_by_txids(known.values())
 
     def _resolve(self, txid: str, ns: str, coll: str,
                  expected: Dict[str, object]) -> Optional[dict]:

@@ -11,7 +11,8 @@ metadata splice point — in one C walk, and this module wraps them:
   BlockView             duck-types Block for every consumer on the
                         covered path; materializes .data / .metadata
                         lazily only when a consumer truly needs Python
-                        objects (MVCC, config handling)
+                        objects (config handling, a tx its lane table
+                        does not speak for)
   envelope_summary(raw) -> (type, channel_id, txid) | None — the gateway
                         header peek, no Envelope/Header trees
   parse_block_py / envelope_summary_py
@@ -20,6 +21,10 @@ metadata splice point — in one C walk, and this module wraps them:
                         used by the differential fuzz suite
   n_txs(block)          len(block.data) without forcing a BlockView to
                         materialize its envelope list
+  lane_table(block)     -> LaneTable: the block's rw-sets and txids as
+                        fixed-width lanes, extracted once a block by the
+                        C walker; what the commit path reads after the
+                        validator instead of decoding envelopes again
 
 Fallback semantics: the native parser accepts EXACTLY the strict
 canonical block shape; anything else (including every malformed input)
@@ -42,6 +47,8 @@ import hashlib
 import struct
 import time
 from typing import Any, Dict, List, Optional, Tuple, Union
+
+import numpy as np
 
 from fabric_tpu.utils import serde
 from fabric_tpu.protocol.types import (
@@ -73,7 +80,7 @@ class BlockView:
 
     __slots__ = ("raw", "header", "n_data", "_data_off", "_data_end",
                  "_spans", "_meta_off", "_data", "_metadata", "_dhash",
-                 "parsed")
+                 "_lanes", "_table", "parsed")
 
     def __init__(self, raw: _Raw, number: int, previous_hash: bytes,
                  data_hash: bytes, data_off: int, data_end: int,
@@ -88,6 +95,8 @@ class BlockView:
         self._data: Optional[List[bytes]] = None
         self._metadata: Optional[BlockMetadata] = None
         self._dhash: Optional[bytes] = None
+        self._lanes: Optional[tuple] = None     # (rwset_lanes result,)
+        self._table: Optional["LaneTable"] = None
 
     # -- covered-path accessors (no per-tx objects) ---------------------
 
@@ -98,11 +107,18 @@ class BlockView:
 
     @property
     def rwset_lanes(self):
-        """Fixed-width uint64 validation lanes for the fused device
-        program: (flags, n_tx, n_keys, n_reads, n_writes, arena) —
-        see rwset_lanes() below.  Zero-copy like data_spans: no
-        per-tx Python objects are built."""
-        return rwset_lanes(self.raw, self._spans)
+        """Fixed-width uint64 lanes of the block's rw-sets and txids:
+        (flags, n_tx, n_keys, n_reads, n_writes, arena) — see
+        rwset_lanes() below.  Zero-copy like data_spans: no per-tx
+        Python objects are built.  Extracted at the first access and
+        kept: the fused device program (committer/device_validate.py)
+        reads the tuple, and everything after the validator reads it as
+        a LaneTable through lane_table() — the ledger's MVCC walk, the
+        block store's txid index, the commit notifier and the
+        private-data coordinator."""
+        if self._lanes is None:
+            self._lanes = (rwset_lanes(self.raw, self._spans),)
+        return self._lanes[0]
 
     @property
     def computed_data_hash(self) -> bytes:
@@ -260,6 +276,99 @@ def rwset_lanes(base: _Raw, spans) -> Optional[tuple]:
     if _fastparse is not None:
         return _fastparse.rwset_lanes(base, spans)
     return rwset_lanes_py(base, spans)
+
+
+class LaneTable:
+    """One block's rwset_lanes arena, opened for the host: what the
+    commit path reads instead of decoding envelopes again.  Rows keep
+    the arena's order (lanes ascend by tx); every offset indexes `base`.
+
+      status  [n_tx]  LANE_* of each tx
+      reads   (n_reads, 5) int64   [tx, slot, has_version, block, txnum]
+      writes  (n_writes, 5) int64  [tx, slot, is_delete, value_off, len]
+    """
+
+    __slots__ = ("base", "n_tx", "status", "reads", "writes", "_tx",
+                 "_keys", "_txids", "_key_strs")
+
+    def __init__(self, base: _Raw, lanes: tuple):
+        _flags, n_tx, n_keys, n_reads, n_writes, arena = lanes
+        # int64: versions are two's-complement i32 in u64 cells
+        cells = np.frombuffer(arena, dtype=np.int64)
+        o = 3 * n_tx
+        self.base = base
+        self.n_tx = n_tx
+        self._tx = cells[:o].reshape(n_tx, 3)
+        self.status = self._tx[:, 0]
+        self.reads = cells[o:o + 5 * n_reads].reshape(n_reads, 5)
+        o += 5 * n_reads
+        self.writes = cells[o:o + 5 * n_writes].reshape(n_writes, 5)
+        o += 5 * n_writes
+        self._keys = cells[o:o + 5 * n_keys].reshape(n_keys, 5)
+        self._txids: Optional[List[Optional[str]]] = None
+        self._key_strs: Optional[List[Tuple[str, str]]] = None
+
+    @property
+    def txids(self) -> List[Optional[str]]:
+        """Each tx's txid, None where the status is not OK (the table
+        does not speak for that tx: decode its envelope).  An OK txid
+        is what Envelope.header().channel_header.txid gives."""
+        if self._txids is None:
+            base = self.base
+            self._txids = [
+                str(base[off:off + n], "utf-8") if st == LANE_OK else None
+                for st, off, n in self._tx.tolist()]
+        return self._txids
+
+    @property
+    def key_strs(self) -> List[Tuple[str, str]]:
+        """(namespace, key) of each interned slot, decoded once."""
+        if self._key_strs is None:
+            base = self.base
+            self._key_strs = [
+                (str(base[no:no + nn], "utf-8"),
+                 str(base[ko:ko + kn], "utf-8"))
+                for _h, no, nn, ko, kn in self._keys.tolist()]
+        return self._key_strs
+
+    def txs_writing_under(self, marker: str) -> List[int]:
+        """The OK txs, ascending, with a write whose namespace holds
+        `marker`."""
+        slot_has = np.fromiter((marker in ns for ns, _key in self.key_strs),
+                               dtype=bool, count=len(self._keys))
+        if not slot_has.any():
+            return []
+        w = self.writes
+        return np.unique(w[slot_has[w[:, 1]], 0]).tolist()
+
+
+def lane_table(block) -> Tuple[Optional[LaneTable], Optional[str]]:
+    """(the block's LaneTable, None), or (None, why there is none):
+    "no_view" the block is not a BlockView, "no_native" the extractor is
+    the Python mirror (byte-by-byte hashing: never a fast path),
+    "collision" two keys of the block share a hash, "count" the span
+    table did not extract.  Opened once a block and kept on the view."""
+    if not isinstance(block, BlockView):
+        return None, "no_view"
+    if block._table is not None:
+        return block._table, None
+    if _fastparse is None:
+        return None, "no_native"
+    lanes = block.rwset_lanes
+    if lanes is None:
+        return None, "count"
+    if lanes[0]:
+        return None, "collision"
+    block._table = LaneTable(block.raw, lanes)
+    return block._table, None
+
+
+def lane_txids(block) -> List[Optional[str]]:
+    """A txid a tx of the block, off its lane table: None for a tx the
+    table does not speak for (status not OK), and for every tx of a
+    block without a table — the reader decodes those envelopes itself."""
+    table, _ = lane_table(block)
+    return table.txids if table is not None else [None] * n_txs(block)
 
 
 def envelope_summary_py(raw: _Raw) -> Optional[Tuple[str, str, str]]:
@@ -769,7 +878,21 @@ def _lane_env(base: bytes, off: int, ln: int, tx: int, st: _LaneState):
     xv = _LaneCur(base, *txid_v)
     if xv.p >= xv.end or base[xv.p] != 0x53:
         raise _LaneStat(LANE_UNKNOWN)
-    return _lane_str(xv)
+    txid = _lane_str(xv)
+    # OK also promises the txid readers that Envelope.header() succeeds
+    try:
+        sh_v = _lane_dict_find(_LaneCur(base, *header_v),
+                               b"signature_header")
+        strict = (sh_v is not None
+                  and None not in (
+                      _lane_dict_find(_LaneCur(base, *ch_v), b"channel_id"),
+                      _lane_dict_find(_LaneCur(base, *sh_v), b"creator"),
+                      _lane_dict_find(_LaneCur(base, *sh_v), b"nonce")))
+    except _LaneStat:
+        strict = False                         # signature_header no dict
+    if not strict:
+        raise _LaneStat(LANE_UNKNOWN)
+    return txid
 
 
 def rwset_lanes_py(base: _Raw, spans) -> Optional[tuple]:

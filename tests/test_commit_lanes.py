@@ -1,0 +1,728 @@
+"""The commit path's two sources: `mvcc.validate_and_prepare_batch` fed
+by the block's lane table against the same walk fed by the envelopes
+decoded again, on the same bytes — flags, UpdateBatch order, history
+rows, commit hash and MvccTally equal — the rule that picks one
+(`mvcc.lane_source_of`) and each of its demotions, and the three readers
+that take their txids from the same table: the block store's index, the
+commit notifier and the private-data coordinator.
+"""
+import os
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+import threading
+from collections import OrderedDict
+
+import pytest
+
+from fabric_tpu.bccsp.factory import FactoryOpts, init_factories
+from fabric_tpu.gateway.notifier import CommitNotifier
+from fabric_tpu.ledger import KVLedger, LedgerConfig
+from fabric_tpu.ledger import mvcc
+from fabric_tpu.ledger.blkstorage import BlockStore
+from fabric_tpu.ledger.kvledger import _safe_envelopes
+from fabric_tpu.msp.ca import DevOrg
+from fabric_tpu.ops_plane import registry, tracing
+from fabric_tpu.privdata import coordinator as coordinator_mod
+from fabric_tpu.protocol import (Block, Envelope, KVRead, KVWrite, NsRwSet,
+                                 RangeQueryInfo, TxFlags, TxRwSet,
+                                 ValidationCode, Version, build, wire)
+from fabric_tpu.protocol.types import META_TXFLAGS, TX_CONFIG, TX_ENDORSER
+from fabric_tpu.testing import smallbank_model as model
+from fabric_tpu.utils import serde
+
+V = int(ValidationCode.VALID)
+MVCC = int(ValidationCode.MVCC_READ_CONFLICT)
+POLICY = int(ValidationCode.ENDORSEMENT_POLICY_FAILURE)
+BADRW = int(ValidationCode.BAD_RWSET)
+GENESIS = b"\x00" * 32
+
+
+@pytest.fixture(scope="module", autouse=True)
+def sw_provider():
+    return init_factories(FactoryOpts(default="SW"))
+
+
+@pytest.fixture(scope="module")
+def ids():
+    """(creator, endorsers): MVCC and the readers verify no signature, so
+    one org's identities sign everything."""
+    org = DevOrg("Org1")
+    return org.new_identity("client"), [org.new_identity("e1"),
+                                        org.new_identity("e2")]
+
+
+def rw(reads=(), writes=(), ranges=(), ns="cc"):
+    return TxRwSet((NsRwSet(ns, reads=tuple(reads), writes=tuple(writes),
+                            range_queries=tuple(ranges)),))
+
+
+def tx(ids, rwset, **kw):
+    creator, endorsers = ids
+    return build.endorser_tx("ch", "cc", "1.0", rwset, creator, endorsers,
+                             **kw)
+
+
+def seed(ids, n=8):
+    """Block 0: put k00..k{n-1} = b"v0"."""
+    return [tx(ids, rw(writes=[KVWrite(f"k{i:02d}", b"v0")]))
+            for i in range(n)]
+
+
+def raw_block(number, prev, envelopes):
+    """(serialized block, its header hash)."""
+    block = build.new_block(number, prev, envelopes)
+    return block.serialize(), block.hash()
+
+
+def view_of(raw, gate=None):
+    view = wire.parse_block(raw)
+    assert isinstance(view, wire.BlockView)
+    if gate is not None:
+        view.metadata.items[META_TXFLAGS] = bytes(gate)
+    return view
+
+
+def plain_of(raw, gate=None):
+    block = Block.deserialize(raw)
+    if gate is not None:
+        block.metadata.items[META_TXFLAGS] = bytes(gate)
+    return block
+
+
+def state_of(ledger):
+    return sorted(
+        (k, None if vv is None else
+         (vv.value, vv.version.block_num, vv.version.tx_num))
+        for k, vv in ledger.statedb._data.items())
+
+
+def history_of(ledger):
+    h = ledger.historydb
+    return {k: [(m.block_num, m.tx_num, m.txid, m.value, m.is_delete)
+                for m in h.get_history(*k)]
+            for k in sorted(h._index)}
+
+
+def tally_of(t):
+    return (t.reads, t.conflicts_block, t.conflicts_state)
+
+
+def mvcc_span(ledger):
+    return ledger.last_stats.span_attrs["ledger.mvcc"]
+
+
+def through_both_sources(stream):
+    """Feed `stream` — [(envelopes, gate codes | None)] — to two ledgers:
+    one gets BlockViews (the lane source), one plain Blocks (the envelope
+    source).  Before each commit the two sources walk the same bytes over
+    the same state and every output is compared; after it, the ledgers.
+    -> (final codes per block, tally per block)."""
+    by_lanes, by_envs = KVLedger("ch", LedgerConfig()), KVLedger(
+        "ch", LedgerConfig())
+    prev, codes, tallies = GENESIS, [], []
+    for number, (envelopes, gate) in enumerate(stream):
+        raw, nxt = raw_block(number, prev, envelopes)
+        gate = bytes(gate if gate is not None else [V] * len(envelopes))
+        view = view_of(raw, gate)
+        f_l, f_e = TxFlags.from_bytes(gate), TxFlags.from_bytes(gate)
+        table, reason = mvcc.lane_source_of(view, f_l)
+        assert reason is None and isinstance(table, wire.LaneTable)
+        t_l, t_e = mvcc.MvccTally(), mvcc.MvccTally()
+        b_l, h_l = mvcc.validate_and_prepare_batch(
+            by_lanes.statedb, number, table, f_l, t_l)
+        b_e, h_e = mvcc.validate_and_prepare_batch(
+            by_lanes.statedb, number, _safe_envelopes(plain_of(raw)), f_e,
+            t_e)
+        assert f_l.to_bytes() == f_e.to_bytes()
+        assert list(b_l.items()) == list(b_e.items())      # in order
+        assert h_l == h_e
+        assert tally_of(t_l) == tally_of(t_e)
+        assert t_l.conflicts_block + t_l.conflicts_state == sum(
+            a != b and b == MVCC for a, b in zip(gate, f_l.to_bytes()))
+
+        by_lanes.commit(view)
+        assert mvcc_span(by_lanes) == {"source": "lanes"}
+        # no envelope list was built, unless the block store's index
+        # had a tx to read for which the table does not speak
+        assert (view._data is None) == all(
+            st == wire.LANE_OK for st in table.status.tolist())
+        by_envs.commit(plain_of(raw, gate))
+        assert mvcc_span(by_envs) == {"source": "envelopes",
+                                      "reason": "no_view"}
+        assert (bytes(view.metadata.items[META_TXFLAGS])
+                == f_l.to_bytes())
+        assert by_lanes.commit_hash == by_envs.commit_hash
+        codes.append(list(f_l.to_bytes()))
+        tallies.append(tally_of(t_l))
+        prev = nxt
+    assert state_of(by_lanes) == state_of(by_envs)
+    assert history_of(by_lanes) == history_of(by_envs)
+    for number in range(len(stream)):
+        assert (by_lanes.blockstore.get_by_number(number).serialize()
+                == by_envs.blockstore.get_by_number(number).serialize())
+    return codes, tallies
+
+
+# -- the lane source against the envelope source ------------------------------
+
+
+def case_bump_repeats(ids):
+    """`bump`: one versioned read + one write a tx; the second and third
+    tx on a key of the block conflict against the block."""
+    keys = [0, 1, 2, 1, 3, 1, 2, 4]
+    block = [tx(ids, rw(reads=[KVRead(f"k{k:02d}", Version(0, k))],
+                        writes=[KVWrite(f"k{k:02d}", b"v1")])) for k in keys]
+    stale = [tx(ids, rw(reads=[KVRead("k00", Version(0, 0))],
+                        writes=[KVWrite("k00", b"v2")]))]
+    stream = [(seed(ids), None), (block, None), (stale, None)]
+    want = [[V] * 8, [V, V, V, MVCC, V, MVCC, MVCC, V], [MVCC]]
+    return stream, want, [(0, 0, 0), (8, 3, 0), (1, 0, 1)]
+
+
+def case_deletes(ids):
+    """A delete in one block and stale / absent reads after it; a delete
+    inside a block and reads of that key after it in the same block."""
+    b1 = [tx(ids, rw(writes=[KVWrite("k01", b"", True)]))]
+    b2 = [
+        tx(ids, rw(reads=[KVRead("k01", Version(0, 1))])),     # stale
+        tx(ids, rw(reads=[KVRead("k01", None)],                # gone: holds
+                   writes=[KVWrite("k01", b"back")])),
+        tx(ids, rw(reads=[KVRead("k02", Version(0, 2))],
+                   writes=[KVWrite("k02", b"", True)])),       # deletes k02
+        tx(ids, rw(reads=[KVRead("k02", Version(0, 2))])),     # ... so: block
+        tx(ids, rw(reads=[KVRead("k02", None)],                # absent now
+                   writes=[KVWrite("k02", b"again")])),
+        tx(ids, rw(reads=[KVRead("k02", None)])),              # written: block
+    ]
+    stream = [(seed(ids), None), (b1, None), (b2, None)]
+    want = [[V] * 8, [V], [MVCC, V, V, MVCC, V, MVCC]]
+    return stream, want, [(0, 0, 0), (0, 0, 0), (6, 2, 1)]
+
+
+def case_absent_keys(ids):
+    """A key the state never held, read with no version (holds) and with
+    a recorded one (conflicts against the state); two namespaces in one
+    tx, the second one's read failing after the first one's counted."""
+    two_ns = TxRwSet((
+        NsRwSet("cc", reads=(KVRead("k00", Version(0, 0)),),
+                writes=(KVWrite("k00", b"x"),)),
+        NsRwSet("dd", reads=(KVRead("nokey", Version(3, 3)),
+                             KVRead("never-counted", None)),
+                writes=(KVWrite("w", b"y"),))))
+    b1 = [tx(ids, rw(reads=[KVRead("nokey", None)],
+                     writes=[KVWrite("nokey2", b"1")])),
+          tx(ids, rw(reads=[KVRead("nokey", Version(0, 3))])),
+          tx(ids, two_ns),
+          tx(ids, rw(reads=[KVRead("k00", Version(0, 0))]))]
+    stream = [(seed(ids), None), (b1, None)]
+    return stream, [[V] * 8, [V, MVCC, MVCC, V]], [(0, 0, 0), (5, 0, 2)]
+
+
+def case_garbage_bad_and_config(ids):
+    """A gate-invalid tx whose rw-set is garbage stays as the gate left
+    it; the same bytes gate-valid are BAD_RWSET; a config tx among
+    endorser txs is skipped; an endorser tx without actions too."""
+    creator, _ = ids
+    junk = build.signed_envelope(TX_ENDORSER, "ch", {"not": "a tx"}, creator)
+    config = build.signed_envelope(TX_CONFIG, "ch", {"config": 1}, creator)
+    empty = build.signed_envelope(TX_ENDORSER, "ch", {"actions": []},
+                                  creator)
+    good = [tx(ids, rw(reads=[KVRead("k07", Version(0, 7))],
+                       writes=[KVWrite("k07", b"g")])),
+            tx(ids, rw(reads=[KVRead("k07", Version(0, 7))]))]
+    b1 = [junk, good[0], junk, config, empty, good[1]]
+    stream = [(seed(ids), None), (b1, [POLICY, V, V, V, V, V])]
+    want = [[V] * 8, [POLICY, V, BADRW, V, V, MVCC]]
+    return stream, want, [(0, 0, 0), (2, 1, 0)]
+
+
+def case_smallbank_chains(ids):
+    """SmallBank at s = 1.0 over 40 accounts: chains of conflicts on the
+    hot ones, rw-sets of 1-3 reads and 0-3 writes, one envelope in 9
+    tampered (what the gate stamped stays)."""
+    creator, endorsers = ids
+    plan = model.plan_chain(2**31 + 34, 40, 4, 64, 6, 9)
+    stream, want = [], []
+    for block in plan:
+        raw, _ = model.build_block(block, GENESIS, "ch", "smallbank",
+                                   endorsers, [creator] * 6)
+        envelopes = [Envelope.deserialize(b)
+                     for b in Block.deserialize(raw).data]
+        stream.append((envelopes, [V if c == MVCC else c
+                                   for c in block["codes"]]))
+        want.append(list(block["codes"]))
+    assert sum(c == MVCC for codes in want for c in codes) > 20
+    return stream, want, None
+
+
+@pytest.mark.parametrize("case", [
+    case_bump_repeats, case_deletes, case_absent_keys,
+    case_garbage_bad_and_config, case_smallbank_chains],
+    ids=lambda c: c.__name__[5:])
+def test_the_lane_source_gives_the_envelope_sources_answers(ids, case):
+    stream, want_codes, want_tallies = case(ids)
+    codes, tallies = through_both_sources(stream)
+    assert codes == want_codes
+    if want_tallies is not None:
+        assert tallies == want_tallies
+
+
+def test_two_ledgers_one_stream_end_at_the_same_hash_and_state(ids):
+    """The durable pair: ledgers on disk, one fed views and one plain
+    blocks, reopened, agree with each other and with themselves."""
+    stream, want, _ = case_bump_repeats(ids)
+    import tempfile
+    with tempfile.TemporaryDirectory() as tmp:
+        roots = [os.path.join(tmp, "lanes"), os.path.join(tmp, "envs")]
+        ledgers = [KVLedger("ch", LedgerConfig(root=r)) for r in roots]
+        prev = GENESIS
+        for number, (envelopes, _gate) in enumerate(stream):
+            raw, prev = raw_block(number, prev, envelopes)
+            gate = [V] * len(envelopes)
+            ledgers[0].commit(view_of(raw, gate))
+            ledgers[1].commit(plain_of(raw, gate))
+        assert mvcc_span(ledgers[0])["source"] == "lanes"
+        assert mvcc_span(ledgers[1])["source"] == "envelopes"
+        reopened = [KVLedger("ch", LedgerConfig(root=r)) for r in roots]
+        for a, b in ((ledgers[0], ledgers[1]), (reopened[0], reopened[1]),
+                     (ledgers[0], reopened[0])):
+            assert a.commit_hash == b.commit_hash
+            assert state_of(a) == state_of(b)
+            assert history_of(a) == history_of(b)
+        assert [list(reopened[0].blockstore.get_by_number(n)
+                     .metadata.items[META_TXFLAGS])
+                for n in range(3)] == want
+
+
+# -- the rule and its demotions -----------------------------------------------
+
+
+def without_nonce(ids):
+    """An endorser tx whose signature header lacks its nonce: the rw-set
+    decodes (`parse_endorser_tx` reads no signature header), the header
+    does not (`Envelope.header()` raises).  Lane status UNKNOWN."""
+    good = tx(ids, rw(writes=[KVWrite("k06", b"u")]))
+    payload = serde.decode(good.payload)
+    del payload["header"]["signature_header"]["nonce"]
+    return Envelope(serde.encode(payload), good.signature)
+
+
+def demotion_plain_block(ids, monkeypatch):
+    block = [tx(ids, rw(writes=[KVWrite("k05", b"x")]))]
+    return block, None, plain_of, "no_view"
+
+
+def demotion_no_native(ids, monkeypatch):
+    """What FABRIC_TPU_NO_NATIVE=1 leaves behind: `wire._fastparse` is
+    None, so the extractor would be the Python mirror."""
+    block = [tx(ids, rw(writes=[KVWrite("k05", b"x")]))]
+
+    def parse(raw, gate):
+        view = view_of(raw, gate)
+        monkeypatch.setattr(wire, "_fastparse", None)
+        return view
+    return block, None, parse, "no_native"
+
+
+def demotion_collision(ids, monkeypatch):
+    """djb2-64("ab") == djb2-64("bA"): the table's flag is 1."""
+    block = [tx(ids, rw(writes=[KVWrite("ab", b"1")])),
+             tx(ids, rw(writes=[KVWrite("bA", b"2")]))]
+    return block, None, view_of, "collision"
+
+
+def demotion_valid_range(ids, monkeypatch):
+    held = tuple(KVRead(f"k{i:02d}", Version(0, i)) for i in range(3))
+    block = [tx(ids, rw(ranges=[RangeQueryInfo("k00", "k03", True, held)])),
+             tx(ids, rw(ranges=[RangeQueryInfo("k00", "k03", True,
+                                               held[:2])]))]
+    return block, None, view_of, "range"
+
+
+def demotion_unknown(ids, monkeypatch):
+    block = [tx(ids, rw(writes=[KVWrite("k05", b"x")])), without_nonce(ids)]
+    return block, None, view_of, "unknown"
+
+
+def demotion_count(ids, monkeypatch):
+    """One flag more than the block has txs."""
+    block = [tx(ids, rw(writes=[KVWrite("k05", b"x")]))]
+    return block, [V, V], view_of, "count"
+
+
+@pytest.mark.parametrize("demotion", [
+    demotion_plain_block, demotion_no_native, demotion_collision,
+    demotion_valid_range, demotion_unknown, demotion_count],
+    ids=lambda d: d.__name__[9:])
+def test_a_demoted_block_commits_through_the_envelope_source(
+        ids, monkeypatch, demotion):
+    envelopes, gate, parse, reason = demotion(ids, monkeypatch)
+    channel = "ch-" + reason
+    ledger, oracle = (KVLedger(channel, LedgerConfig()) for _ in range(2))
+    raw0, prev = raw_block(0, GENESIS, seed(ids))
+    ledger.commit(view_of(raw0, [V] * 8))
+    oracle.commit(plain_of(raw0, [V] * 8))
+    before = source_counts(channel)
+    raw, _ = raw_block(1, prev, envelopes)
+    gate = gate or [V] * len(envelopes)
+    ledger.commit(parse(raw, gate))
+    assert mvcc_span(ledger) == {"source": "envelopes", "reason": reason}
+    oracle.commit(plain_of(raw, gate))
+    assert ledger.commit_hash == oracle.commit_hash
+    assert state_of(ledger) == state_of(oracle)
+    assert history_of(ledger) == history_of(oracle)
+    after = source_counts(channel)
+    # the oracle shares the channel's series: two blocks, both by envelopes
+    assert after["lanes"] == before["lanes"]
+    assert after["envelopes"] - before["envelopes"] == 2 * len(gate)
+
+
+def test_a_gate_invalid_range_or_unknown_tx_does_not_demote(ids):
+    held = tuple(KVRead(f"k{i:02d}", Version(0, i)) for i in range(3))
+    envelopes = [tx(ids, rw(ranges=[RangeQueryInfo("k00", "k03", True,
+                                                   held[:2])])),
+                 without_nonce(ids),
+                 tx(ids, rw(reads=[KVRead("k00", Version(0, 0))],
+                            writes=[KVWrite("k00", b"y")]))]
+    codes, tallies = through_both_sources(
+        [(seed(ids), None), (envelopes, [POLICY, POLICY, V])])
+    assert codes[1] == [POLICY, POLICY, V]
+    assert tallies[1] == (1, 0, 0)
+
+
+def test_the_mvcc_span_carries_its_source(ids):
+    from fabric_tpu.committer.committer import Committer
+    from fabric_tpu.committer.txvalidator import PolicyRegistry, TxValidator
+    from fabric_tpu.msp import CachedMSP
+    from fabric_tpu.policy import parse_policy
+    org = DevOrg("Org1")
+    creator, endorser = org.new_identity("c"), org.new_identity("e")
+    validator = TxValidator(
+        "ch-span", {"Org1": CachedMSP(org.msp())},
+        init_factories(FactoryOpts(default="SW")),
+        PolicyRegistry(parse_policy("OR('Org1.member')")))
+    committer = Committer(KVLedger("ch-span", LedgerConfig()), validator)
+    raw, prev = raw_block(0, GENESIS, [build.endorser_tx(
+        "ch-span", "cc", "1.0", rw(writes=[KVWrite("a", b"1")]), creator,
+        [endorser])])
+    raw1, _ = raw_block(1, prev, [build.endorser_tx(
+        "ch-span", "cc", "1.0", rw(writes=[KVWrite("b", b"1")]), creator,
+        [endorser])])
+    t = tracing.tracer
+    was = t.enabled
+    t.configure({"enabled": True})
+    try:
+        committer.store_block(view_of(raw))
+        committer.store_block(plain_of(raw1))
+        got = {}
+        for rec in t.recorder.list()["recent"]:
+            for s in t.recorder.get(rec["trace_id"])["spans"]:
+                if (s["name"] == "ledger.mvcc"
+                        and rec["root"] == "committer.store_block"):
+                    got[s["attributes"]["source"]] = s["attributes"]
+    finally:
+        t.enabled = was
+    assert got["lanes"] == {"source": "lanes"}
+    assert got["envelopes"] == {"source": "envelopes", "reason": "no_view"}
+
+
+def source_counts(channel):
+    c = registry.counter("ledger_commit_source_total")
+    return {s: c.value(channel=channel, source=s)
+            for s in ("lanes", "envelopes")}
+
+
+def test_the_source_counter_adds_up_to_the_blocks_txs(ids):
+    """Per block, lanes + envelopes = the block's tx count."""
+    ledger = KVLedger("ch-count", LedgerConfig())
+    prev = GENESIS
+    blocks = [(seed(ids, 5), view_of), (seed(ids, 3), plain_of),
+              ([tx(ids, rw(writes=[KVWrite("ab", b"1")])),
+                tx(ids, rw(writes=[KVWrite("bA", b"2")]))], view_of),
+              (seed(ids, 7), view_of)]
+    want = [("lanes", 5), ("envelopes", 3), ("envelopes", 2), ("lanes", 7)]
+    for number, ((envelopes, parse), (source, n)) in enumerate(
+            zip(blocks, want)):
+        raw, prev = raw_block(number, prev, envelopes)
+        before = source_counts("ch-count")
+        ledger.commit(parse(raw, [V] * len(envelopes)))
+        after = source_counts("ch-count")
+        moved = {s: after[s] - before[s] for s in after}
+        assert moved == {"lanes": 0, "envelopes": 0, source: n}
+        assert sum(moved.values()) == len(envelopes)
+
+
+# -- the extractor: OK promises a header that decodes -------------------------
+
+
+def test_a_header_that_does_not_decode_is_unknown_in_c_and_in_the_mirror(ids):
+    good = tx(ids, rw(writes=[KVWrite("k", b"v")]))
+
+    def cut(path):
+        payload = serde.decode(good.payload)
+        node = payload["header"]
+        for key in path[:-1]:
+            node = node[key]
+        del node[path[-1]]
+        return Envelope(serde.encode(payload), good.signature)
+
+    broken = [cut(("signature_header", "nonce")),
+              cut(("signature_header", "creator")),
+              cut(("signature_header",)),
+              cut(("channel_header", "channel_id"))]
+    payload = serde.decode(good.payload)
+    payload["header"]["signature_header"] = [1, 2]
+    broken.append(Envelope(serde.encode(payload), good.signature))
+    raw, _ = raw_block(0, GENESIS, [good] + broken)
+    view = view_of(raw)
+    native = wire._fastparse.rwset_lanes(*view.data_spans)
+    mirror = wire.rwset_lanes_py(*view.data_spans)
+    assert bytes(native[5]) == bytes(mirror[5]) and native[:5] == mirror[:5]
+    table, _ = wire.lane_table(view)
+    assert table.status.tolist() == [wire.LANE_OK] + [wire.LANE_UNKNOWN] * 5
+    assert table.txids == [good.header().channel_header.txid] + [None] * 5
+    for env in broken:
+        with pytest.raises(Exception):
+            env.header()
+        assert mvcc.parse_endorser_tx(env) is not None
+
+
+# -- the three txid readers ---------------------------------------------------
+
+
+def reader_block(ids, n, window_dupes=True):
+    """`n` endorser txs with a config tx, a tx whose header does not
+    decode and an envelope that is no envelope among them, and txids that
+    repeat: inside the block, next to each other and far apart."""
+    creator, _ = ids
+    envelopes = [tx(ids, rw(writes=[KVWrite(f"w{i:04d}", b"v")]))
+                 for i in range(n)]
+    envelopes[3] = build.signed_envelope(TX_CONFIG, "ch", {"config": 1},
+                                         creator)
+    envelopes[5] = without_nonce(ids)
+    envelopes[8] = envelopes[7]
+    envelopes[n - 2] = envelopes[1]
+    envelopes[n // 2] = envelopes[n // 2 - 9]
+    data = [e.serialize() for e in envelopes]
+    data[11] = b"not an envelope"
+    codes = [(V, MVCC, POLICY)[i % 3] for i in range(n)]
+    return data, codes
+
+
+def block_of(number, prev, data):
+    from fabric_tpu.protocol.types import (BlockHeader, BlockMetadata,
+                                           block_data_hash)
+    block = Block(BlockHeader(number, prev, block_data_hash(data)), data,
+                  BlockMetadata())
+    return block.serialize(), block.hash()
+
+
+def history_as_it_was(window, blocks):
+    """`CommitNotifier.on_block` before the table: every envelope
+    decoded, every txid inserted, then the window trimmed."""
+    history = OrderedDict()
+    for block, codes in blocks:
+        for i, env_bytes in enumerate(block.data):
+            try:
+                txid = Envelope.deserialize(
+                    env_bytes).header().channel_header.txid
+            except Exception:
+                continue
+            if not txid:
+                continue
+            history[txid] = (codes[i], int(block.header.number), None)
+        while len(history) > window:
+            history.popitem(last=False)
+    return history
+
+
+@pytest.mark.parametrize("window,sizes", [(16, (48, 48)), (16, (5, 30, 7)),
+                                          (64, (48, 20, 48)), (4096, (40,))],
+                         ids=["3x-window", "small-large-small",
+                              "under-window", "default-window"])
+def test_the_notifier_keeps_the_history_it_kept(ids, window, sizes):
+    sizes = [max(s, 24) for s in sizes]
+    notifier = CommitNotifier("ch", window=window)
+    prev, fed = GENESIS, []
+    datas = [reader_block(ids, n) for n in sizes]
+    # a txid of the last block that an earlier block already holds: the
+    # entry is updated where it stands
+    datas[-1][0][2] = datas[0][0][sizes[0] - 1]
+    waited = Envelope.deserialize(datas[0][0][20]).header().channel_header.txid
+    got = []
+    waiter = threading.Thread(
+        target=lambda: got.append(notifier.wait(waited, 30.0)))
+    waiter.start()
+    while waited not in notifier._waiters:
+        pass
+    for number, (data, codes) in enumerate(datas):
+        raw, prev = block_of(number, prev, data)
+        view = view_of(raw)
+        notifier.on_block(view, TxFlags.from_codes(codes))
+        assert view._data is not None       # tx 11 and tx 5: decoded
+        fed.append((plain_of(raw), codes))
+        want = history_as_it_was(window, fed)
+        assert list(notifier._history.items()) == list(want.items())
+        if number == 0:
+            # the waiter registered before the block is woken by it, and
+            # reads what the window still holds
+            waiter.join(30.0)
+            assert not waiter.is_alive() and not notifier._waiters
+            assert got == [want.get(waited)]
+        # a plain block takes the per-envelope path to the same history
+        again = CommitNotifier("ch", window=window)
+        for block, block_codes in fed:
+            again.on_block(block, TxFlags.from_codes(block_codes))
+        assert list(again._history.items()) == list(want.items())
+
+
+def test_the_notifier_decodes_nothing_of_a_block_the_table_speaks_for(ids):
+    raw, _ = raw_block(0, GENESIS, seed(ids, 40))
+    view = view_of(raw)
+    notifier = CommitNotifier("ch", window=16)
+    notifier.on_block(view, TxFlags.from_codes([V] * 40))
+    assert view._data is None
+    txids = [Envelope.deserialize(b).header().channel_header.txid
+             for b in plain_of(raw).data]
+    assert list(notifier._history) == txids[-16:]
+    assert notifier.peek(txids[-1]) == (V, 0, None)
+
+
+def test_the_block_store_finds_every_txid_where_it_found_it(ids, tmp_path):
+    stores = {"views": BlockStore(None), "plain": BlockStore(None),
+              "disk": BlockStore(str(tmp_path / "blocks"))}
+    prev = GENESIS
+    datas = [reader_block(ids, 30), reader_block(ids, 26)]
+    datas[1][0][4] = datas[0][0][0]         # first writer wins: block 0
+    for number, (data, _codes) in enumerate(datas):
+        raw, prev = block_of(number, prev, data)
+        stores["views"].add_block(view_of(raw))
+        stores["disk"].add_block(view_of(raw))
+        stores["plain"].add_block(plain_of(raw))
+    want = stores["plain"]._by_txid
+    assert len(want) > 40
+    assert stores["views"]._by_txid == want
+    assert stores["disk"]._by_txid == want
+    first = Envelope.deserialize(datas[0][0][0]).header().channel_header.txid
+    assert want[first] == (0, 0)
+    twice = Envelope.deserialize(datas[0][0][7]).header().channel_header.txid
+    assert want[twice] == (0, 7)
+    for txid in want:
+        assert stores["views"].has_txid(txid)
+    # what the recovery scan rebuilds from the file is the same index
+    assert BlockStore(str(tmp_path / "blocks"))._by_txid == want
+
+
+# -- the private-data coordinator ---------------------------------------------
+
+
+def private_peers(provider, tmp_path, org):
+    """Two coordinators over their own ledgers: one is fed views, the
+    other plain blocks."""
+    from test_privdata import make_peer
+    from fabric_tpu.privdata import CollectionConfig
+    peers = []
+    for name in ("views", "plain"):
+        coord, transient, pvt, ledger = make_peer(
+            org, provider, tmp=str(tmp_path / name))
+        coord.registry.define("cc", CollectionConfig(
+            "others", member_orgs=("Org9",), block_to_live=0))
+        peers.append((coord, transient, pvt, ledger))
+    return peers
+
+
+def private_tx(org, i, collection, transients=(), public=True):
+    from fabric_tpu.chaincode.stub import ChaincodeStub
+    from fabric_tpu.ledger.statedb import StateDB
+    stub = ChaincodeStub(StateDB(), "cc", channel_id="ch", txid="")
+    if public:
+        stub.put_state(f"pub{i}", b"open")
+    if collection:
+        stub.put_private_data(collection, f"sec{i}", b"classified%d" % i)
+    env = build.endorser_tx("ch", "cc", "1.0", stub.rwset(),
+                            org.new_identity("client"),
+                            [org.new_identity("e")])
+    for transient in transients:
+        transient.persist(env.header().channel_header.txid, 0,
+                          stub.private_sets())
+    return env
+
+
+def test_the_coordinator_decodes_only_what_writes_to_a_collection(
+        sw_provider, tmp_path, monkeypatch):
+    org = DevOrg("Org1")
+    (c_v, tr_v, pvt_v, lg_v), (c_p, tr_p, pvt_p, lg_p) = private_peers(
+        sw_provider, tmp_path, org)
+    both = (tr_v, tr_p)
+    decoded = []
+    real = coordinator_mod._tx_rwset
+    monkeypatch.setattr(coordinator_mod, "_tx_rwset",
+                        lambda env: decoded.append(env) or real(env))
+
+    def store(envelopes):
+        raw, _ = raw_block(lg_v.height, lg_v.blockstore.chain_info()
+                           .current_hash if lg_v.height else GENESIS,
+                           envelopes)
+        del decoded[:]
+        c_v.store_block(view_of(raw))
+        by_view = len(decoded)
+        c_p.store_block(plain_of(raw))
+        return by_view, len(decoded) - by_view
+
+    # block 0: the transient stores hold nothing, no tx is private
+    assert store([private_tx(org, i, None) for i in range(6)]) == (0, 6)
+    # block 1: public txs, two writing to the member collection (the
+    # cleartext of one was never staged), one to a collection of others,
+    # one that writes to the collection and nothing public, and an
+    # invalid tx (its creator signature is cut) that writes to it too
+    stale = private_tx(org, 90, "secrets", both)       # never committed
+    cut = private_tx(org, 7, "secrets", both)
+    cut = Envelope(cut.payload, cut.signature[:-2] + b"\x00\x01")
+    envelopes = [private_tx(org, 0, None), private_tx(org, 1, "secrets", both),
+                 private_tx(org, 2, None), private_tx(org, 3, "secrets"),
+                 private_tx(org, 4, "others", both), private_tx(org, 5, None),
+                 private_tx(org, 6, "secrets", both, public=False), cut]
+    assert len(tr_v) == 5
+    assert store(envelopes) == (4, 7)
+    for pvt in (pvt_v, pvt_p):
+        assert pvt.get("cc", "secrets", "sec1") == b"classified1"
+        assert pvt.get("cc", "secrets", "sec6") == b"classified6"
+        assert pvt.get("cc", "secrets", "sec3") is None
+        assert pvt.get("cc", "secrets", "sec7") is None
+        assert pvt.get("cc", "others", "sec4") is None
+    assert pvt_v._state == pvt_p._state and pvt_v._by_txid == pvt_p._by_txid
+    assert [(m.block_num, m.txid, m.namespace, m.collection, m.expected)
+            for m in c_v.missing] == [
+        (m.block_num, m.txid, m.namespace, m.collection, m.expected)
+        for m in c_p.missing]
+    assert [m.collection for m in c_v.missing] == ["secrets"]
+    # purged by txid: every VALID tx of the block; the invalid one's and
+    # the never-committed one's cleartext stays
+    kept = {stale.header().channel_header.txid,
+            cut.header().channel_header.txid}
+    assert set(tr_v._by_txid) == set(tr_p._by_txid) == kept
+    assert lg_v.commit_hash == lg_p.commit_hash
+    assert state_of(lg_v) == state_of(lg_p)
+
+
+def test_the_coordinators_tail_has_a_span_in_the_blocks_trace(
+        sw_provider, tmp_path):
+    org = DevOrg("Org1")
+    (coord, _tr, _pvt, _lg), _ = private_peers(sw_provider, tmp_path, org)
+    raw, _ = raw_block(0, GENESIS, [private_tx(org, 0, None)])
+    t = tracing.tracer
+    was = t.enabled
+    t.configure({"enabled": True})
+    try:
+        coord.store_block(view_of(raw))
+        rec = t.recorder.get(next(
+            r["trace_id"] for r in t.recorder.list()["recent"]
+            if r["root"] == "committer.store_block"))
+    finally:
+        t.enabled = was
+    root = next(s for s in rec["spans"] if s["parent_id"] is None)
+    tail = next(s for s in rec["spans"]
+                if s["name"] == "privdata.store_block")
+    assert tail["parent_id"] == root["span_id"]
+    assert tail["start"] >= root["start"] + root["duration_s"]
