@@ -32,6 +32,7 @@ import threading
 from dataclasses import dataclass
 from typing import Dict, Iterator, List, Optional, Tuple
 
+from fabric_tpu.ledger.fsync import flush_and_sync
 from fabric_tpu.protocol import Block, Envelope, block_header_hash
 from fabric_tpu.protocol import wire
 from fabric_tpu.protocol.types import META_TXFLAGS
@@ -208,8 +209,7 @@ class BlockStore:
             with open(path, "ab") as f:
                 f.write(_LEN.pack(len(payload)))
                 f.write(payload)
-                f.flush()
-                os.fsync(f.fileno())
+                flush_and_sync(f, "blocks")
             self._index_block(
                 block, _Loc(self._open_segment_no, offset,
                             _LEN.size + len(payload)))

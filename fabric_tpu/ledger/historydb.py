@@ -24,6 +24,7 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
 from fabric_tpu.ledger import checkpoint as ckpt
+from fabric_tpu.ledger.fsync import flush_and_sync
 from fabric_tpu.ledger.statedb import shard_of
 from fabric_tpu.utils import serde
 
@@ -87,8 +88,7 @@ class HistoryDB:
                 with open(self._wal_path(), "ab") as f:
                     f.write(_LEN.pack(len(payload)))
                     f.write(payload)
-                    f.flush()
-                    os.fsync(f.fileno())
+                    flush_and_sync(f, "history")
             self._apply(block_num, writes)
             if self.root is not None:
                 self._blocks_since_ckpt += 1
@@ -213,15 +213,32 @@ class HistoryDB:
         ckpt.gc_generations(self.root, {gen, gen - 1} | self._live_pins())
         self._ckpt_gen = gen
         self._blocks_since_ckpt = 0
+        self._observe_checkpoint(t0, time.perf_counter(), gen)
+        return manifest
+
+    def _observe_checkpoint(self, t0: float, t1: float, gen: int) -> None:
+        """The span, and beside the state store's `state_checkpoint_*`
+        the count and the seconds of this store's."""
         try:
             from fabric_tpu.ops_plane import tracing
             tracing.tracer.record_span(
-                "history.checkpoint", t0, time.perf_counter(),
+                "history.checkpoint", t0, t1,
                 attributes={"channel": self.channel, "gen": gen,
                             "savepoint": self._savepoint})
         except Exception:
             pass
-        return manifest
+        if not self.channel:
+            return
+        try:
+            from fabric_tpu.ops_plane.metrics import registry
+            registry.counter("history_checkpoint_total",
+                             "History checkpoints written").add(
+                                 1, channel=self.channel)
+            registry.histogram("history_checkpoint_seconds",
+                               "Wall time per history checkpoint").observe(
+                                   t1 - t0, channel=self.channel)
+        except Exception:
+            pass
 
     def _recover(self) -> None:
         source = "empty"

@@ -298,14 +298,15 @@ class KVLedger:
                                           source, flags, tally)
 
     def _count_block(self, flags: TxFlags, tally: Optional[MvccTally],
-                     writes: int, source: Optional[str] = None) -> None:
+                     history: list, source: Optional[str] = None) -> None:
         """One committed block into the always-on counters: its
-        transactions by final code, the writes of its valid txs and,
-        where the serial walk validated it (`tally`), the reads it
-        checked, the conflicts it found and which `source` supplied its
-        rw-sets.  The default-off commit paths do not walk read by
-        read: their blocks move no `path="serial"` series, so those
-        never read as "no conflicts", and no source."""
+        transactions by final code, the writes of its valid txs
+        (`history`: how many, and their key + value bytes) and, where
+        the serial walk validated it (`tally`), the reads it checked,
+        the conflicts it found and which `source` supplied its rw-sets.
+        The default-off commit paths do not walk read by read: their
+        blocks move no `path="serial"` series, so those never read as
+        "no conflicts", and no source."""
         from fabric_tpu.ops_plane import registry
         ch = self.channel_id
         txs = registry.counter(
@@ -315,7 +316,11 @@ class KVLedger:
             txs.add(n, channel=ch, code=ValidationCode(code).name)
         registry.counter(
             "ledger_state_writes_total", "writes of valid transactions "
-            "applied to state and history").add(writes, channel=ch)
+            "applied to state and history").add(len(history), channel=ch)
+        registry.counter(
+            "ledger_state_write_bytes_total", "key + value bytes of those "
+            "writes").add(sum(len(w[3].encode()) + len(w[4])
+                              for w in history), channel=ch)
         if tally is None:
             return
         registry.counter(
@@ -436,7 +441,7 @@ class KVLedger:
             stats.phase("ledger.history_commit", "history_commit_s", t0)
 
         self._observe_apply(len(batch), len(history))
-        self._count_block(flags, tally, len(history), mvcc_attrs["source"])
+        self._count_block(flags, tally, history, mvcc_attrs["source"])
         self.last_stats = stats
         logger.info(
             "[%s] committed block %d: %d/%d valid | validation=%.1fms "
@@ -552,7 +557,7 @@ class KVLedger:
             self._commit_window.retire(entry)
 
             self._observe_apply(len(batch), len(history))
-            self._count_block(flags, None, len(history))
+            self._count_block(flags, None, history)
             self.last_stats = stats
             logger.info(
                 "[%s] committed block %d (windowed, %d early / %d "

@@ -51,6 +51,7 @@ from dataclasses import dataclass
 from typing import Dict, Iterator, List, Optional, Tuple
 
 from fabric_tpu.ledger import checkpoint as ckpt
+from fabric_tpu.ledger.fsync import flush_and_sync
 from fabric_tpu.protocol import Version
 from fabric_tpu.utils import serde
 
@@ -677,8 +678,7 @@ class StateDB:
         with open(self._wal_path(), "ab") as f:
             f.write(_LEN.pack(len(payload)))
             f.write(payload)
-            f.flush()
-            os.fsync(f.fileno())
+            flush_and_sync(f, "state")
 
     def checkpoint(self) -> Optional[dict]:
         """Flush every shard + flip the manifest; returns the manifest
