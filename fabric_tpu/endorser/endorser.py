@@ -7,7 +7,9 @@ core/endorser/msgvalidation.go and the ACL check from core/aclmgmt.
 
 Signing stays host-side (private keys never touch the TPU); the single
 proposal-creator verify here is immediate, not batched — endorsement is a
-low-volume interactive path, unlike commit-side block validation.
+low-volume interactive path, unlike commit-side block validation.  It is
+made once, by the node's provider: the signature check and the ACL's
+signature half both gate on that one verdict.
 """
 
 from __future__ import annotations
@@ -131,7 +133,12 @@ class Endorser:
         creator = deserialize_from_msps(self.msps, sh.creator, validate=True)
         if creator is None:
             raise EndorserError("unknown or invalid creator identity")
-        if not creator.verify(sp.proposal_bytes, sp.signature):
+        # collect -> one verify -> gate: the proposal's one item goes to
+        # the node's provider once, and its verdict answers the ACL's
+        # evaluator below for the item it collects from the same bytes
+        item = creator.verify_item(sp.proposal_bytes, sp.signature)
+        verified = {item: self.evaluator.provider.verify(item)}
+        if not verified[item]:
             raise EndorserError("bad proposal signature")
         for flt in self.auth_filters:       # core/handlers/auth chain
             try:
@@ -141,11 +148,12 @@ class Endorser:
         sd = SignedData(sp.proposal_bytes, sh.creator, sp.signature)
         if self.acl is not None:
             try:
-                self.acl.check_acl("peer/Propose", sd)
+                self.acl.check_acl("peer/Propose", sd, verified)
             except PermissionError as e:
                 raise EndorserError(str(e)) from e
         elif self.proposal_acl is not None:
-            if not self.evaluator.evaluate_signed_data(self.proposal_acl, [sd]):
+            if not self.evaluator.evaluate_signed_data(
+                    self.proposal_acl, [sd], verified):
                 raise EndorserError("creator fails proposal ACL policy")
         return prop, sh.creator
 

@@ -14,8 +14,9 @@ call site with no code change.
 
 from __future__ import annotations
 
-from typing import Dict, Optional
+from typing import Dict, Mapping, Optional
 
+from fabric_tpu.bccsp import VerifyItem
 from .evaluator import PolicyEvaluator
 from .policy import SignedData
 
@@ -75,16 +76,21 @@ class ACLProvider:
             raise ACLError(f"{resource}: policy {name!r} not defined")
         return bundle, policy, name
 
-    def check_acl(self, resource: str, sd: Optional[SignedData]) -> None:
+    def check_acl(self, resource: str, sd: Optional[SignedData],
+                  verified: Optional[Mapping[VerifyItem, bool]] = None
+                  ) -> None:
         """Raises ACLError unless `sd` satisfies the resource's policy.
 
         Unknown resources and unresolvable policy names DENY (the
-        reference fails closed, aclmgmt resource checks)."""
+        reference fails closed, aclmgmt resource checks).  `verified`
+        is `PolicyEvaluator.evaluate_signed_data`'s: verdicts the caller
+        holds already; the policy, the MSPs and the creator's validation
+        are the current bundle's either way."""
         if sd is None:
             raise ACLError(f"{resource}: no signed data")
         bundle, policy, name = self._policy(resource)
         evaluator = PolicyEvaluator(bundle.msps, self.provider)
-        if not evaluator.evaluate_signed_data(policy, [sd]):
+        if not evaluator.evaluate_signed_data(policy, [sd], verified):
             raise ACLError(f"{resource}: signed data does not satisfy "
                            f"policy {name!r}")
 

@@ -14,15 +14,16 @@ Here the same decision logic is split into:
   [provider.batch_verify over an entire block — ONE TPU dispatch],
   gate()     : keep identities whose verdict bit is set,
   evaluate() : the exact cauthdsl greedy semantics over valid identities.
-`evaluate_signed_data` composes all three for single-policy use.
+`evaluate_signed_data` composes all three for single-policy use; a caller
+that has already verified some of the set's items (the endorser: the
+proposal's creator signature) hands their verdicts in and only the rest
+is dispatched.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence
-
-import numpy as np
+from typing import Dict, List, Mapping, Optional, Sequence
 
 from fabric_tpu.bccsp import VerifyItem
 from fabric_tpu.msp import Identity, Principal
@@ -80,7 +81,8 @@ class PolicyEvaluator:
     # -- pass 3: gate + evaluate --------------------------------------------
 
     @staticmethod
-    def gate(collected: CollectResult, verdicts: np.ndarray) -> List[Identity]:
+    def gate(collected: CollectResult,
+             verdicts: Sequence[bool]) -> List[Identity]:
         """Identities whose signatures verified (policy.go:390-393: invalid
         signatures only exclude, never fail the set)."""
         return [ident for ident, ok in zip(collected.identities, verdicts) if ok]
@@ -122,10 +124,23 @@ class PolicyEvaluator:
 
     # -- one-shot composition ----------------------------------------------
 
-    def evaluate_signed_data(self, policy: SignaturePolicy,
-                             signed_data: Sequence[SignedData]) -> bool:
+    def evaluate_signed_data(
+            self, policy: SignaturePolicy,
+            signed_data: Sequence[SignedData],
+            verified: Optional[Mapping[VerifyItem, bool]] = None) -> bool:
+        """collect -> verify -> gate -> evaluate for one signature set.
+
+        `verified` holds verdicts the caller already has from this
+        node's provider, keyed by the item verified.  A collected item
+        is answered from it only when equal in all four fields (scheme,
+        public key, signature, payload: Verify is a pure function of
+        them); every other item is dispatched here, as without it."""
         collected = self.collect(signed_data)
         if not collected.items:
             return self.evaluate(policy, [])
-        verdicts = self.provider.batch_verify(collected.items)
-        return self.evaluate(policy, self.gate(collected, verdicts))
+        verdicts = dict(verified or {})
+        todo = [it for it in collected.items if it not in verdicts]
+        if todo:
+            verdicts.update(zip(todo, self.provider.batch_verify(todo)))
+        return self.evaluate(policy, self.gate(
+            collected, [verdicts[it] for it in collected.items]))

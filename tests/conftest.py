@@ -42,3 +42,28 @@ def same_exposition():
         for x, y in differing:
             assert float(y[len(stamp):]) >= float(x[len(stamp):])
     return check
+
+
+@pytest.fixture
+def counting():
+    """counting(inner) -> a provider that hands everything to `inner` and
+    keeps what it was asked to verify: `.items`, every item in order, and
+    `.calls`, the number of dispatches (`verify` is one of one item)."""
+    class Counting:
+        def __init__(self, inner):
+            self.inner = inner
+            self.items = []
+            self.calls = 0
+
+        def verify(self, item):
+            return bool(self.batch_verify([item])[0])
+
+        def batch_verify(self, items):
+            items = list(items)
+            self.items.extend(items)
+            self.calls += 1
+            return self.inner.batch_verify(items)
+
+        def __getattr__(self, name):
+            return getattr(self.inner, name)
+    return Counting

@@ -110,3 +110,71 @@ def test_qscc_consumes_acl(world):
         qscc.get_block_by_number(0, member)         # Readers ok, but
                                                     # empty store raises
     qscc.get_chain_info(org.admin)
+
+
+def _signed(ident, payload=b"query", signed=None):
+    return SignedData(payload, ident.serialize(),
+                      ident.sign(payload if signed is None else signed))
+
+
+@pytest.mark.parametrize("case", ["valid", "invalid", "no_signed_data"])
+def test_check_acl_with_held_verdicts_dispatches_nothing(world, counting,
+                                                         case):
+    """`check_acl(resource, sd, verified)` decides as `check_acl(resource,
+    sd)` does, from the caller's verdict and without the provider."""
+    provider, org, orgs = world
+    member = org.new_identity("h1")
+    sd = {"valid": _signed(member),
+          "invalid": _signed(member, signed=b"other bytes"),
+          "no_signed_data": None}[case]
+    src = _bundle_source(org, orgs)
+    own = ACLProvider(src, provider)
+    counted = counting(provider)
+    acl = ACLProvider(src, counted)
+    held = {}
+    if sd is not None:
+        item = src.current().msps["Org1"].deserialize_identity(
+            sd.identity).verify_item(sd.data, sd.signature)
+        held[item] = provider.verify(item)
+
+    def outcome(check):
+        try:
+            check()
+        except ACLError as e:
+            return str(e)
+        return "allowed"
+
+    for resource in ("qscc/GetBlockByNumber", "cscc/JoinChain",
+                     "no/SuchResource"):
+        assert outcome(lambda: acl.check_acl(resource, sd, held)) \
+            == outcome(lambda: own.check_acl(resource, sd))
+    assert outcome(lambda: acl.check_acl("qscc/GetBlockByNumber", sd, held)) \
+        == {"valid": "allowed",
+            "invalid": "qscc/GetBlockByNumber: signed data does not "
+                       "satisfy policy 'Readers'",
+            "no_signed_data": "qscc/GetBlockByNumber: no signed data"}[case]
+    assert counted.calls == 0
+
+
+def test_check_acl_verifies_what_the_held_verdicts_do_not_cover(world,
+                                                                counting):
+    provider, org, orgs = world
+    src = _bundle_source(org, orgs)
+    counted = counting(provider)
+    acl = ACLProvider(src, counted)
+    member = org.new_identity("h2")
+    sd, other = _signed(member), _signed(member, b"another query")
+    msp = src.current().msps["Org1"]
+    item = msp.deserialize_identity(sd.identity).verify_item(
+        sd.data, sd.signature)
+    # a verdict for this signer's other message does not answer this one
+    acl.check_acl("qscc/GetBlockByNumber", other, {item: True})
+    assert counted.calls == 1 and counted.items[0] != item
+    # nor does it let a forged signature through
+    with pytest.raises(ACLError):
+        acl.check_acl("qscc/GetBlockByNumber",
+                      _signed(member, signed=b"x"), {item: True})
+    assert counted.calls == 2
+    # no verdicts at all: today's call, one verify
+    acl.check_acl("qscc/GetBlockByNumber", sd)
+    assert counted.calls == 3 and counted.items[-1] == item

@@ -3,7 +3,8 @@ order -> validate -> commit (reference: core/endorser, core/chaincode,
 core/chaincode/lifecycle)."""
 import pytest
 
-from fabric_tpu.bccsp.factory import init_factories, FactoryOpts
+from fabric_tpu.bccsp.factory import (FactoryOpts, init_factories,
+                                      set_default)
 from fabric_tpu.chaincode import (
     ChaincodeDefinition,
     ChaincodeRegistry,
@@ -15,6 +16,8 @@ from fabric_tpu.chaincode import (
 )
 from fabric_tpu.chaincode.runtime import FuncContract
 from fabric_tpu.committer import Committer, TxValidator
+from fabric_tpu.config import (Bundle, BundleSource, ChannelConfig,
+                               OrgConfig, default_policies)
 from fabric_tpu.endorser import (
     Endorser,
     ProposalResponse,
@@ -25,7 +28,7 @@ from fabric_tpu.endorser import (
 from fabric_tpu.ledger import KVLedger, LedgerConfig
 from fabric_tpu.msp import CachedMSP
 from fabric_tpu.msp.ca import DevOrg
-from fabric_tpu.policy import parse_policy
+from fabric_tpu.policy import ACLProvider, parse_policy
 from fabric_tpu.protocol import ValidationCode, build
 
 
@@ -323,3 +326,154 @@ def test_all_endorsers_must_succeed(world):
     assert bad.status == 500
     with pytest.raises(ResponseMismatchError):
         assemble_transaction(sp, [good, bad], world.client)
+
+
+# -- one verify per proposal: collect -> one verify -> gate -----------------
+
+WIRINGS = ("acl", "proposal_acl", "neither")
+BAD_SIGNATURE = "bad proposal signature"
+
+
+@pytest.fixture()
+def counted(sw_provider, counting):
+    """The node's provider, counting — and the process default too, so
+    that a verify made through `get_default()` would be counted as well."""
+    provider = counting(sw_provider)
+    set_default(provider)
+    yield provider
+    set_default(sw_provider)
+
+
+def _bundle(world, propose=None, orgs=None, sequence=0):
+    """The channel's config over the world's orgs (or `orgs` of them);
+    `propose`: a policy of its own for `peer/Propose`, named "Propose"
+    (the default is Writers: any member)."""
+    orgs = world.orgs if orgs is None else orgs
+    cfgs = []
+    for o in orgs:
+        mc = o.msp_config()
+        cfgs.append(OrgConfig(mspid=o.mspid,
+                              root_certs=tuple(mc.root_certs_pem),
+                              admins=tuple(mc.admin_certs_pem)))
+    policies = default_policies([o.mspid for o in orgs])
+    if propose:
+        policies["Propose"] = parse_policy(propose)
+    return Bundle(ChannelConfig(
+        channel_id="ch", sequence=sequence, orgs=tuple(cfgs),
+        policies=policies,
+        acls={"peer/Propose": "Propose"} if propose else {}))
+
+
+def _wired(world, provider, wiring, admits_org1=True):
+    """Org1's endorser on `provider` under one of the three wirings of
+    the proposal gate; -> (endorser, the bundle source or None)."""
+    src = None
+    kw = {}
+    if wiring == "acl":
+        src = BundleSource(_bundle(
+            world, None if admits_org1 else "OR('Org2.member')"))
+        kw["acl"] = ACLProvider(src, provider)
+    elif wiring == "proposal_acl":
+        kw["proposal_acl"] = parse_policy(
+            "OR('Org1.member')" if admits_org1 else "OR('Org2.member')")
+    endorser = Endorser("ch", world.ledger.statedb, world.registry,
+                        world.msps, provider,
+                        world.orgs[0].new_identity("peerOrg1"), **kw)
+    return endorser, src
+
+
+def _put(world, signer=None, channel="ch"):
+    return signed_proposal(channel, "cc", "put", [b"k", b"v"],
+                           signer or world.client)
+
+
+def _tamper(sp):
+    return type(sp)(sp.proposal_bytes, sp.signature[:-2] + b"\x00\x01")
+
+
+@pytest.mark.parametrize("wiring", WIRINGS)
+def test_valid_proposal_costs_exactly_one_verify(world, counted, wiring):
+    endorser, _ = _wired(world, counted, wiring)
+    sp = _put(world)
+    resp = endorser.process_proposal(sp)
+    assert (resp.status, resp.message) == (200, "")
+    creator = world.msps["Org1"].deserialize_identity(world.client.serialize())
+    assert counted.items == [
+        creator.verify_item(sp.proposal_bytes, sp.signature)]
+    assert counted.calls == 1
+
+
+@pytest.mark.parametrize("wiring", WIRINGS)
+def test_tampered_signature_stops_before_filters_and_acl(
+        world, counted, wiring):
+    endorser, _ = _wired(world, counted, wiring)
+    ran = []
+    endorser.auth_filters = [lambda prop, creator: ran.append("filter")]
+    if endorser.acl is not None:
+        real = endorser.acl.check_acl
+        endorser.acl.check_acl = lambda *a, **kw: (ran.append("acl"),
+                                                   real(*a, **kw))
+    resp = endorser.process_proposal(_tamper(_put(world)))
+    assert (resp.status, resp.message) == (500, BAD_SIGNATURE)
+    assert resp.endorsement is None and ran == []
+    assert len(counted.items) == 1 and counted.calls == 1
+    # the same endorser, a sound proposal: filter, then the gate
+    assert endorser.process_proposal(_put(world)).status == 200
+    assert ran == ["filter"] + ["acl"] * (endorser.acl is not None)
+
+
+@pytest.mark.parametrize("wiring,message", [
+    ("acl", "peer/Propose: signed data does not satisfy policy 'Propose'"),
+    ("proposal_acl", "creator fails proposal ACL policy"),
+])
+def test_well_signed_creator_outside_the_policy(world, counted, wiring,
+                                                message):
+    endorser, _ = _wired(world, counted, wiring, admits_org1=False)
+    resp = endorser.process_proposal(_put(world))
+    assert (resp.status, resp.message) == (500, message)
+    assert resp.endorsement is None
+    # its signature was sound, verified once, and not asked about again
+    assert len(counted.items) == 1 and counted.calls == 1
+
+
+@pytest.mark.parametrize("case,message", [
+    ("bad_signature", BAD_SIGNATURE),
+    ("unknown_creator", "unknown or invalid creator identity"),
+    ("wrong_channel", "proposal for channel 'other', serving 'ch'"),
+])
+@pytest.mark.parametrize("wiring", WIRINGS)
+def test_rejections_a_client_sees_are_unchanged(world, counted, wiring, case,
+                                                message):
+    endorser, _ = _wired(world, counted, wiring)
+    if case == "bad_signature":
+        sp = _tamper(_put(world))
+    elif case == "unknown_creator":
+        sp = _put(world, DevOrg("Org1").new_identity("stranger"))
+    else:
+        sp = _put(world, channel="other")
+    resp = endorser.process_proposal(sp)
+    assert (resp.status, resp.message, resp.payload, resp.endorsement) == \
+        (500, message, b"", None)
+    # only a proposal that reached the signature check cost a verify
+    assert counted.calls == (1 if case == "bad_signature" else 0)
+
+
+def test_config_update_judges_the_next_proposal(world, counted):
+    """`peer/Propose` tightened, then an org taken off the channel: each
+    takes effect on the next proposal, and each proposal still costs one
+    verify — the verdict is carried, the policy and the MSPs are not."""
+    endorser, src = _wired(world, counted, "acl")
+    admin = world.orgs[0].admin
+    assert endorser.process_proposal(_put(world)).status == 200
+    src.update(_bundle(world, "OR('Org1.admin')", sequence=1))
+    resp = endorser.process_proposal(_put(world))
+    assert (resp.status, resp.message) == (
+        500, "peer/Propose: signed data does not satisfy policy 'Propose'")
+    assert endorser.process_proposal(_put(world, admin)).status == 200
+    # Org1 leaves the bundle; the endorser's own MSP table still knows it,
+    # so the signature check passes and the bundle's MSPs decide
+    src.update(_bundle(world, orgs=world.orgs[1:], sequence=2))
+    resp = endorser.process_proposal(_put(world))
+    assert (resp.status, resp.message) == (
+        500, "peer/Propose: signed data does not satisfy policy 'Writers'")
+    assert counted.calls == len(counted.items) == 4

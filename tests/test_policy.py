@@ -120,3 +120,71 @@ def test_or_consumes_all_branches_like_reference(world):
     # with a second Org2 member it passes
     u2b = org2.new_identity("x3")
     assert ev.evaluate_signed_data(p, [sd(u1), sd(u2), sd(u2b)])
+
+
+# -- verdicts the caller already holds ---------------------------------------
+
+def _held_cases(org1, org2):
+    u1, u2 = org1.new_identity("h1"), org2.new_identity("h2")
+    forged = SignedData(b"payload", u1.serialize(), u1.sign(b"other data"))
+    return {
+        "valid": [sd(u1), sd(u2)],
+        "invalid": [forged, sd(u2)],
+        "duplicated": [sd(u1), sd(u1), sd(u2)],
+        "empty": [],
+    }
+
+
+@pytest.mark.parametrize("case", ["valid", "invalid", "duplicated", "empty"])
+def test_held_verdicts_dispatch_nothing_and_gate_the_same(world, counting,
+                                                          case):
+    """`evaluate_signed_data(..., verified)` with a verdict for every
+    collected item asks the provider nothing and decides as its own
+    verify does, under a policy that needs every signer and one that
+    needs any."""
+    org1, org2, _, ev = world
+    sds = _held_cases(org1, org2)[case]
+    collected = ev.collect(sds)
+    held = dict(zip(collected.items,
+                    map(bool, ev.provider.batch_verify(collected.items))
+                    if collected.items else ()))
+    assert list(held.values()) == {"valid": [True, True],
+                                   "invalid": [False, True],
+                                   "duplicated": [True, True],
+                                   "empty": []}[case]
+    counted = counting(ev.provider)
+    ev2 = PolicyEvaluator(ev.msps, counted)
+    for expr in ("AND('Org1.member', 'Org2.member')",
+                 "OR('Org1.member', 'Org2.member')"):
+        p = parse_policy(expr)
+        assert ev2.evaluate_signed_data(p, sds, held) \
+            == ev.evaluate_signed_data(p, sds)
+    assert counted.calls == 0 and counted.items == []
+
+
+def test_item_the_held_verdicts_do_not_cover_is_verified(world, counting):
+    """A held verdict answers only the item equal to it in scheme, key,
+    signature and payload; any other collected item is dispatched, alone,
+    and a held verdict of True for some other item admits nobody."""
+    org1, org2, _, ev = world
+    u1, u2 = org1.new_identity("p1"), org2.new_identity("p2")
+    forged = SignedData(b"payload", u2.serialize(), u2.sign(b"other data"))
+    and_p = parse_policy("AND('Org1.member', 'Org2.member')")
+    counted = counting(ev.provider)
+    ev2 = PolicyEvaluator(ev.msps, counted)
+    s1, s2 = sd(u1), sd(u2)     # ECDSA signs with fresh randomness: sign once
+    item1, item2 = ev.collect([s1, s2]).items
+    assert ev2.evaluate_signed_data(and_p, [s1, s2], {item1: True})
+    assert counted.items == [item2] and counted.calls == 1
+    # the same signer over other bytes: another payload, so not covered
+    other = ev.collect([sd(u1, b"other")]).items[0]
+    assert other.pubkey == item1.pubkey and other != item1
+    assert ev2.evaluate_signed_data(and_p, [s1, s2], {other: True})
+    assert counted.items == [item2, item1, item2] and counted.calls == 2
+    # a forged signature is found out here even beside a held True
+    assert not ev2.evaluate_signed_data(and_p, [s1, forged], {item1: True})
+    assert counted.calls == 3 and counted.items[-1].pubkey == item2.pubkey
+    # and a held False is believed: nothing is asked, nobody admitted
+    assert not ev2.evaluate_signed_data(and_p, [s1, s2],
+                                        {item1: False, item2: True})
+    assert counted.calls == 3
