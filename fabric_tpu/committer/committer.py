@@ -65,6 +65,14 @@ class Committer:
         boundary), so the config tx itself is validated under the previous
         configuration — matching configtx/validator.go sequencing.
         """
+        # the block's two intake stamps, for the commit listeners (the
+        # gateway's stage account): when its frame was received — its
+        # parse began — where a parser stamped that, and when the
+        # committer took it.  They travel on the block, not in a global
+        t_store = time.perf_counter()
+        parsed = getattr(block, "parsed", None)
+        block.intake = (parsed[0] if parsed is not None else t_store,
+                        t_store)
         # root of the block-domain trace: everything downstream (VSCC
         # batch verify, MVCC, ledger append, commit notification) hangs
         # off this span, and commit_status links request traces to it
@@ -316,20 +324,10 @@ class Committer:
                 "validation_duration_seconds",
                 "txvalidator.Validate wall time").observe(
                     vr.total_s, channel=ch)
-            registry.histogram(
-                "validation_dispatch_seconds",
-                "batched signature dispatch time").observe(
-                    vr.dispatch_s, channel=ch)
-            commit_s = 0.0
-            for phase in ("state_validation_s", "block_commit_s",
-                          "state_commit_s", "history_commit_s"):
-                v = getattr(stats, phase, None)
-                if v is not None:
-                    commit_s += v
-                    registry.histogram(
-                        "commit_phase_seconds",
-                        "per-phase ledger commit time").observe(
-                            v, channel=ch, phase=phase[:-2])
+            commit_s = sum(
+                getattr(stats, phase, None) or 0.0
+                for phase in ("state_validation_s", "block_commit_s",
+                              "state_commit_s", "history_commit_s"))
             # the "commit" stage of the validator_stage_seconds family
             # (collect/dispatch/gate land in txvalidator._observe_block)
             registry.histogram(

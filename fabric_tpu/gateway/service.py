@@ -13,6 +13,15 @@ recently-finished one replays its recorded outcome.
 Every verb records per-verb latency; the queue depth gauge, batch-size
 histogram, retry/dedup/backpressure counters land in the same
 ops_plane registry the /metrics endpoint exposes.
+
+Every answered `commit_status` books the request's wait since the
+orderer's 200 by stage (`gateway_commit_stage_seconds{channel,stage}`,
+always on; `_account_wait`): `ordered` (the 200 -> the block holding the
+tx received by this peer), `intake` (-> `committer.store_block`
+begins), `commit` (-> the notifier holds the tx's code), `answer`
+(-> the reply).  The boundaries are plain `perf_counter` readings: the
+200's is kept beside the status in the dedup window, the block's three
+come with the notifier's answer.
 """
 
 from __future__ import annotations
@@ -28,7 +37,7 @@ from fabric_tpu.comm import connect
 from fabric_tpu.endorser.proposal import SignedProposal
 from fabric_tpu.gateway import admission as _admission
 from fabric_tpu.gateway.broadcaster import BatchBroadcaster
-from fabric_tpu.gateway.notifier import CommitNotifier
+from fabric_tpu.gateway.notifier import CommitNotifier, Committed
 from fabric_tpu.ops_plane import registry, tracing
 from fabric_tpu.ops_plane.logging import jlog
 from fabric_tpu.protocol import Envelope
@@ -98,7 +107,8 @@ class GatewayService:
         self.lifecycle = "serving"
         self._queue: List[_Pending] = []
         self._inflight: Dict[str, _Pending] = {}
-        # txid -> (status, info) of finished submissions (dedup window)
+        # txid -> (status, info, perf_counter at the orderer's answer) of
+        # finished submissions (dedup window)
         self._recent: "OrderedDict[str, tuple]" = OrderedDict()
         self._stop = threading.Event()
         self._thread = threading.Thread(
@@ -118,6 +128,10 @@ class GatewayService:
         self._m_backpressure = registry.counter(
             "gateway_backpressure_total",
             "submissions rejected on a full admission queue")
+        self._m_stage = registry.histogram(
+            "gateway_commit_stage_seconds",
+            "an answered transaction's wait since the orderer's 200, by "
+            "stage: ordered, intake, commit, answer")
         # SLO-driven admission control: typed shed verdicts BEFORE the
         # queue-full cliff.  The burn source reads the node's
         # SloEvaluator lazily (peer wiring creates slo after the
@@ -336,7 +350,7 @@ class GatewayService:
                 pending = self._inflight.get(txid)
                 deduped = pending is not None
                 if pending is None and txid in self._recent:
-                    st, info = self._recent[txid]
+                    st, info = self._recent[txid][:2]
                     self._m_dedup.add(1)
                     return {"txid": txid, "status": st, "info": info,
                             "deduped": True}
@@ -377,6 +391,10 @@ class GatewayService:
                             "gateway admission queue full "
                             f"({self.max_queue}): backpressure, retry later")
                     pending = _Pending(raw, txid, channel_id)
+                    if ch is not None:
+                        # before ordering can commit it: the notifier
+                        # keeps the outcome of what it was told to watch
+                        ch.commit_notifier.watch(txid)
                     self._inflight[txid] = pending
                     self._queue.append(pending)
                     self._m_depth.set(len(self._queue))
@@ -400,37 +418,41 @@ class GatewayService:
         """Block until the committer records the txid's validation code
         (VALID / MVCC_READ_CONFLICT / ...), no ledger polling."""
         t0 = time.monotonic()
+        t_arrival = time.perf_counter()
         try:
             ch = self.node._chan(body)
             txid = str(body["txid"])
             timeout = min(int(body.get("timeout_ms", 15000)) / 1000.0, 120.0)
             notifier = self._notifier(ch)
+
+            def from_block_store() -> Optional[Committed]:
+                # committed unwatched (another gateway's, or before this
+                # one attached, or long ago): the block store is
+                # authoritative, and names no block
+                try:
+                    store = ch.ledger.blockstore
+                    if store.has_txid(txid):
+                        return Committed(
+                            int(store.get_tx_validation_code(txid)), -1)
+                except Exception:
+                    pass
+                return None
+
             with tracing.tracer.start_span(
                     "gateway.commit_wait", require_parent=True,
                     attributes={"txid": txid}) as span:
-                got = notifier.peek(txid)
-                if got is None:
-                    # committed before this gateway attached its notifier
-                    # (or long ago): the block store is authoritative
-                    try:
-                        if ch.ledger.blockstore.has_txid(txid):
-                            code = \
-                                ch.ledger.blockstore.get_tx_validation_code(
-                                    txid)
-                            got = (int(code), -1, None)
-                    except Exception:
-                        got = None
-                if got is None:
-                    got = notifier.wait(txid, timeout)
+                got = notifier.peek(txid) or from_block_store() \
+                    or notifier.wait(txid, timeout, recheck=from_block_store)
                 if got is None:
                     span.set_attribute("found", False)
                     return {"found": False, "txid": txid}
-                code, block_num, block_trace = got
+                code, block_num, block_trace = got.code, got.block, got.trace
                 span.set_attribute("found", True)
                 span.set_attribute("code", int(code))
                 span.set_attribute("block", block_num)
                 # stitch the request trace to the block's pipeline trace
                 span.add_link(block_trace)
+                self._account_wait(ch.channel_id, txid, got, t_arrival, span)
             try:
                 name = ValidationCode(code).name
             except ValueError:
@@ -442,6 +464,46 @@ class GatewayService:
             return out
         finally:
             self._observe("commit_status", t0)
+
+    # the request's wait, by stage, and the spans that show it
+    _STAGES = (("ordered", "gateway.ordered_wait"),
+               ("intake", "gateway.block_intake"),
+               ("commit", "gateway.block_commit"),
+               ("answer", "gateway.answer"))
+
+    def _account_wait(self, channel_id: str, txid: str, got: Committed,
+                      t_arrival: float, span) -> None:
+        """Book an answered transaction's wait by stage, once.  The
+        boundaries — the orderer's 200, the block's frame received,
+        `committer.store_block` begun, the code held, the reply (now) —
+        are forced into that order, so the stages booked always sum to
+        reply − 200.  A call that arrived after the code was held did
+        not wait through the block's life: it books `answer` alone,
+        from its arrival; so does one whose 200 or whose block's stamps
+        this gateway does not hold (another gateway's submit; an answer
+        from the block store)."""
+        now = time.perf_counter()
+        with self._lock:
+            recent = self._recent.get(txid)
+        t_200 = recent[2] if recent is not None and recent[0] == 200 \
+            else None
+        stamps = got.stamps
+        if stamps is not None and t_200 is not None \
+                and t_arrival <= stamps[2]:
+            stages = self._STAGES
+            edges = [t_200, *stamps, now]
+            for i in range(1, len(edges)):
+                edges[i] = max(edges[i], edges[i - 1])
+        else:
+            stages = self._STAGES[-1:]
+            held = stamps[2] if stamps is not None else t_arrival
+            edges = [max(held, t_arrival), now]
+        for (stage, span_name), start, end in zip(stages, edges, edges[1:]):
+            self._m_stage.observe(end - start, channel=channel_id,
+                                  stage=stage)
+            if span.recording:
+                tracing.tracer.record_span(span_name, start, end,
+                                           parent=span.context)
 
     # batcher -----------------------------------------------------------
 
@@ -511,13 +573,16 @@ class GatewayService:
                      txids=[p.txid for p in batch[:8]])
                 results = [(500, f"gateway broadcast error: {exc}")] \
                     * len(batch)
+            # the orderer's answer to every tx of the batch: where the
+            # stage account's `ordered` begins
+            t_answer = time.perf_counter()
             with self._cv:
                 for p, sp, (st, info) in zip(batch, spans_order, results):
                     p.status, p.info = int(st), str(info)
                     sp.set_attribute("status", p.status)
                     sp.end("OK" if p.status == 200 else "ERROR")
                     self._inflight.pop(p.txid, None)
-                    self._recent[p.txid] = (p.status, p.info)
+                    self._recent[p.txid] = (p.status, p.info, t_answer)
                 while len(self._recent) > self.recent_window:
                     self._recent.popitem(last=False)
             # feed per-tx gateway sojourn (queue wait + broadcast) into

@@ -24,7 +24,6 @@ from fabric_tpu.orderer.deliver import (
     NotReadyError,
     SeekInfo,
 )
-from fabric_tpu.ops_plane import tracing
 
 logger = logging.getLogger("fabric_tpu.gossip.blocksprovider")
 
@@ -61,95 +60,71 @@ class BlocksProvider:
         """Fetch + batch-verify + hand over up to `window` blocks.
         Returns how many blocks were accepted.
 
-        The whole pull runs under a `gossip.pull_window` root span, so
-        the deliver req frame carries a traceparent (comm/rpc.py attaches
-        "tp" from the ambient context) and the orderer's `orderer.deliver`
-        span lands in the SAME trace — one /traces/<id> export covers
-        seek, stream, window sig-verify and handover.  These traces are
-        high-frequency (one per poll); cap them with the recorder's
-        per-root retention policy (tracing config `retention`)."""
+        No node runs this puller (a node's deliver client is
+        `PeerNode._deliver_loop`, where the intake's spans and counters
+        are); in-process topologies and the chaos harness do."""
         height = self.state.committer.height
-        with tracing.tracer.start_span(
-                "gossip.pull_window",
-                attributes={"channel": self.channel_id, "height": height,
-                            "window": self.window}) as span:
-            blocks: List = []
-            sender = None
-            try:
-                for item in self.deliver.deliver(
-                        self.channel_id,
-                        SeekInfo(start=height, stop=height + self.window - 1,
-                                 behavior=BEHAVIOR_FAIL_IF_NOT_READY),
-                        signed=self.signed):
-                    # deliver handlers yield bare blocks; standing-aware
-                    # clients yield (block, attests, sender)
-                    if isinstance(item, tuple):
-                        block, sender = item[0], item[2]
-                    else:
-                        block = item
-                    blocks.append(block)
-            except NotReadyError:
-                pass  # reached the orderer tip mid-window: fine
-            except DeliverError as e:
+        blocks: List = []
+        sender = None
+        try:
+            for item in self.deliver.deliver(
+                    self.channel_id,
+                    SeekInfo(start=height, stop=height + self.window - 1,
+                             behavior=BEHAVIOR_FAIL_IF_NOT_READY),
+                    signed=self.signed):
+                # deliver handlers yield bare blocks; standing-aware
+                # clients yield (block, attests, sender, tp)
+                if isinstance(item, tuple):
+                    block, sender = item[0], item[2]
+                else:
+                    block = item
+                blocks.append(block)
+        except NotReadyError:
+            pass  # reached the orderer tip mid-window: fine
+        except DeliverError as e:
+            self._failures += 1
+            logger.warning("[%s] deliver failed (%d): %s",
+                           self.channel_id, self._failures, e)
+            return 0
+        except Exception as e:
+            # transport-level death (RpcClosed/RpcTimeout/ConnectionError
+            # — a severed channel or partitioned orderer), not a deliver
+            # protocol error: same retry treatment, the loop()'s backoff
+            # + re-pull IS the catch-up path once the partition heals
+            self._failures += 1
+            logger.warning("[%s] deliver transport failed (%d): %r",
+                           self.channel_id, self._failures, e)
+            return 0
+        if not blocks:
+            if self._failures:
+                self._mark_healed(0)   # reachable again, already at tip
+            return 0
+        if (self.standing is not None and sender is not None
+                and self.standing(sender)):
+            self.last_resort_windows += 1
+            logger.warning(
+                "[%s] window served by a QUARANTINED source (last "
+                "resort; every healthy endpoint failed)",
+                self.channel_id)
+        if self.mcs is not None:
+            verdicts = self.mcs.verify_window(blocks)  # ONE dispatch
+        else:
+            verdicts = [True] * len(blocks)
+        accepted = 0
+        for block, ok in zip(blocks, verdicts):
+            if not ok:
                 self._failures += 1
-                logger.warning("[%s] deliver failed (%d): %s",
-                               self.channel_id, self._failures, e)
-                span.set_attribute("error", str(e))
-                return 0
-            except Exception as e:
-                # transport-level death (RpcClosed/RpcTimeout/ConnectionError
-                # — a severed channel or partitioned orderer), not a deliver
-                # protocol error: same retry treatment, the loop()'s backoff
-                # + re-pull IS the catch-up path once the partition heals
-                self._failures += 1
-                logger.warning("[%s] deliver transport failed (%d): %r",
-                               self.channel_id, self._failures, e)
-                span.set_attribute("error", repr(e))
-                return 0
-            if not blocks:
-                if self._failures:
-                    self._mark_healed(0)   # reachable again, already at tip
-                return 0
-            if (self.standing is not None and sender is not None
-                    and self.standing(sender)):
-                self.last_resort_windows += 1
-                logger.warning(
-                    "[%s] window served by a QUARANTINED source (last "
-                    "resort; every healthy endpoint failed)",
-                    self.channel_id)
-                span.set_attribute("last_resort", True)
-                try:
-                    from fabric_tpu.ops_plane import registry
-                    registry.counter(
-                        "gossip_deliver_last_resort_total",
-                        "deliver windows pulled from a quarantined "
-                        "source").add(1, channel=self.channel_id)
-                except Exception:
-                    pass
-            if self.mcs is not None:
-                with tracing.tracer.start_span(
-                        "gossip.verify_window",
-                        attributes={"blocks": len(blocks)}):
-                    verdicts = self.mcs.verify_window(blocks)  # ONE dispatch
-            else:
-                verdicts = [True] * len(blocks)
-            accepted = 0
-            for block, ok in zip(blocks, verdicts):
-                if not ok:
-                    self._failures += 1
-                    logger.error("[%s] block %d failed orderer-sig verify; "
-                                 "dropping rest of window", self.channel_id,
-                                 block.header.number)
-                    break  # later blocks chain off the bad one
-                self.state.add_block(block)
-                accepted += 1
-            span.set_attribute("blocks", len(blocks))
-            span.set_attribute("accepted", accepted)
-            if accepted:
-                if self._failures:
-                    self._mark_healed(accepted)
-                self._failures = 0
-            return accepted
+                logger.error("[%s] block %d failed orderer-sig verify; "
+                             "dropping rest of window", self.channel_id,
+                             block.header.number)
+                break  # later blocks chain off the bad one
+            self.state.add_block(block)
+            accepted += 1
+        if accepted:
+            if self._failures:
+                self._mark_healed(accepted)
+            self._failures = 0
+        return accepted
 
     def _mark_healed(self, accepted: int) -> None:
         """First successful deliver contact after a failure streak."""
@@ -158,14 +133,6 @@ class BlocksProvider:
              failures=self._failures, accepted=accepted,
              height=self.state.committer.height)
         self._failures = 0
-        try:
-            from fabric_tpu.ops_plane import registry
-            registry.counter(
-                "gossip_deliver_recoveries_total",
-                "deliver reconnects after a failure streak").add(
-                    1, channel=self.channel_id)
-        except Exception:
-            pass
 
     def catch_up(self, max_windows: int = 1000) -> int:
         """Drain to the orderer tip NOW: pull windows until one comes

@@ -15,6 +15,7 @@ import threading
 from typing import Callable, Dict, List, Optional
 
 from fabric_tpu.bccsp.provider import DeviceError
+from fabric_tpu.ops_plane import tracing
 from fabric_tpu.protocol import Block
 from fabric_tpu.protocol import wire
 
@@ -136,9 +137,17 @@ class GossipState:
         self._buffer[num] = block
 
     def _gossip_block(self, block: Block) -> None:
-        raw = block.serialize()
-        for to in self.discovery.alive_ids()[:self.fanout]:
-            self.endpoint.send(to, MSG_BLOCK, {"block": raw})
+        # on the deliver thread, before the block reaches the committer:
+        # a child of the block's intake trace (`peer.block_intake`)
+        with tracing.tracer.start_span("gossip.forward",
+                                       require_parent=True) as span:
+            raw = block.serialize()
+            targets = self.discovery.alive_ids()[:self.fanout]
+            for to in targets:
+                self.endpoint.send(to, MSG_BLOCK, {"block": raw})
+            if span.recording:
+                span.set_attribute("peers", len(targets))
+                span.set_attribute("bytes", len(raw))
 
     # -- ordered drain into the committer (deliverPayloads) ------------------
 

@@ -791,8 +791,8 @@ class OrdererNode:
         cid = body["channel"]
         attesting = (self._attest_deliver and self.verify_cache is not None)
         msps = None
+        support = self.registrar.get(cid)
         if attesting:
-            support = self.registrar.get(cid)
             src = (getattr(support, "bundle_source", None)
                    or self.bundle_source) if support is not None \
                 else self.bundle_source
@@ -803,6 +803,13 @@ class OrdererNode:
         for block in self.deliver.deliver(cid, seek, sd,
                                           timeout_s=body.get("timeout_s", 30)):
             out = {"block": block.serialize()}
+            # the block's trace context rides beside the block, as the
+            # broadcast frame's `tps` ride beside the envelopes: the
+            # peer's block trace links this orderer's.  A context only;
+            # absent when this orderer wrote the block untraced
+            tp = support.chain.block_traceparent(int(block.header.number))
+            if tp is not None:
+                out["tp"] = tp
             if attesting and msps is not None:
                 from fabric_tpu.verify_plane import attest_block
                 try:

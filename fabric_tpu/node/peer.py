@@ -48,6 +48,7 @@ from fabric_tpu.endorser import Endorser
 from fabric_tpu.endorser.proposal import SignedProposal
 from fabric_tpu.ledger import KVLedger, LedgerConfig
 from fabric_tpu.node.orderer import load_signing_identity
+from fabric_tpu.ops_plane import registry, tracing
 from fabric_tpu.orderer import block_signature_items
 from fabric_tpu.policy import SignedData, parse_policy
 from fabric_tpu.privdata import (
@@ -59,6 +60,7 @@ from fabric_tpu.privdata import (
 )
 from fabric_tpu.protocol import wire
 from fabric_tpu.protocol.types import Block
+from fabric_tpu.protocol.wire import n_txs
 from fabric_tpu.scc.cscc import Cscc
 from fabric_tpu.scc.discovery import DiscoveryService
 from fabric_tpu.scc.qscc import Qscc
@@ -139,11 +141,12 @@ class RemoteDeliver:
             self._rr = (self._rr + 1) % len(self.orderers)
 
     def deliver(self, channel_id, seek, signed=None, timeout_s: int = 10):
-        """Yields (block, attests, sender) — `attests` is the orderer's
-        optional per-envelope verdict-attestation list (verify_plane/
-        attest.py) and `sender` the handshake-verified identity of the
-        orderer connection it rode in on; both None when the orderer
-        sends plain blocks.
+        """Yields (block, attests, sender, tp) — `attests` is the
+        orderer's optional per-envelope verdict-attestation list
+        (verify_plane/attest.py), `sender` the handshake-verified
+        identity of the orderer connection it rode in on, and `tp` the
+        block's trace context at that orderer (a traceparent: the peer's
+        block trace links it); each None when the orderer sends none.
 
         Standing-aware source selection is two-pass: quarantined
         endpoints are SKIPPED while any healthy endpoint remains
@@ -179,7 +182,8 @@ class RemoteDeliver:
                             "timeout_s": int(timeout_s),
                             "signed_data": sd}):
                         yield (wire.parse_block(item["block"]),
-                               item.get("attests"), sender)
+                               item.get("attests"), sender,
+                               item.get("tp"))
                     self._rr = idx
                     return
                 finally:
@@ -203,7 +207,8 @@ class RemoteDeliver:
                             "timeout_s": int(timeout_s),
                             "signed_data": sd}):
                         yield (wire.parse_block(item["block"]),
-                               item.get("attests"), sender)
+                               item.get("attests"), sender,
+                               item.get("tp"))
                     # _rr stays put: the next pull tries healthy
                     # endpoints first again
                     return
@@ -648,6 +653,76 @@ class PeerChannel:
         except Exception:
             logger.debug("attestation seeding failed", exc_info=True)
 
+    def _intake_block(self, block, attests, sender, tp) -> bool:
+        """One delivered block, from its frame to the committer: the
+        orderer's signature (one device dispatch), the byzantine
+        monitor's verdict and the attestation seeding, then the gossip
+        state plane, which forwards the block to the fan-out and drains
+        strictly in block order into `committer.store_block`.  -> False
+        when the window ends here (a bad signature, a disputed height);
+        True for a block taken, and for a stale duplicate passed over.
+
+        The block's trace is rooted here and begins where the frame was
+        received (the parser's stamp); it links the orderer's trace of
+        the same block, whose context rode beside it.  What the
+        committer does falls under it through the ambient context."""
+        parsed = getattr(block, "parsed", None)
+        with tracing.tracer.start_span(
+                "peer.block_intake", parent=None,
+                start=parsed[0] if parsed is not None else None,
+                attributes={"channel": self.channel_id,
+                            "block": int(block.header.number),
+                            "txs": n_txs(block)}) as root:
+            if root.recording:
+                raw = getattr(block, "raw", None)
+                if raw is not None:
+                    root.set_attribute("bytes", len(raw))
+                ctx = tracing.parse_traceparent(tp)
+                if ctx is not None:
+                    root.add_link(ctx.trace_id)
+            with tracing.tracer.start_span("deliver.block_sig"):
+                items = block_signature_items(block, self.msps)
+                with dispatch_site("block_sig"):
+                    signed = bool(items) and bool(
+                        self.node.provider.batch_verify(items).all())
+            if not signed:
+                logger.warning("block %d failed orderer-signature "
+                               "verification; dropping window",
+                               block.header.number)
+                # a KNOWN signer with an invalid signature is an
+                # offense (honest orderers cannot produce it — the
+                # authenticated transport rules out frame corruption);
+                # unknown signers may be config lag and are never scored
+                if self.byz_monitor is not None and items:
+                    src = self._byz_source(sender)
+                    if src is not None:
+                        self.byz_monitor.offense(src, "bad_sig")
+                return False
+            with tracing.tracer.start_span("deliver.admit") as admit:
+                if self.byz_monitor is not None:
+                    from fabric_tpu.byzantine.monitor import (
+                        VERDICT_ADMIT, VERDICT_STALE)
+                    verdict = self.byz_monitor.check_block(
+                        block, self._byz_source(sender))
+                    if verdict != VERDICT_ADMIT:
+                        admit.set_attribute("verdict", str(verdict))
+                    if verdict == VERDICT_STALE:
+                        return True
+                    if verdict != VERDICT_ADMIT:
+                        # hold: disputed height awaiting quorum;
+                        # reject: this stream served crime evidence.
+                        # Either way re-source from the next
+                        # consenter — re-seek from committed height
+                        # keeps exactly-once (replay guard dedups)
+                        self.deliver_client.advance()
+                        return False
+                if attests:
+                    self._seed_attestations(block, attests, sender)
+            # through the gossip state plane: fans out to peers
+            # and drains strictly in block order
+            self.gossip.state.add_block(block)
+            return True
+
     def _deliver_loop(self) -> None:
         from fabric_tpu.orderer.deliver import SeekInfo
         backoff = 0.2
@@ -656,50 +731,14 @@ class PeerChannel:
             height = self.ledger.height
             try:
                 got = 0
-                for block, attests, sender in self.deliver_client.deliver(
-                        self.channel_id,
-                        SeekInfo(start=height, stop=height + 31,
-                                 behavior="block_until_ready"),
-                        timeout_s=5):
-                    items = block_signature_items(block, self.msps)
-                    with dispatch_site("block_sig"):
-                        signed = bool(items) and bool(
-                            self.node.provider.batch_verify(items).all())
-                    if not signed:
-                        logger.warning("block %d failed orderer-signature "
-                                       "verification; dropping window",
-                                       block.header.number)
-                        # a KNOWN signer with an invalid signature is an
-                        # offense (honest orderers cannot produce it —
-                        # the authenticated transport rules out frame
-                        # corruption); unknown signers may be config lag
-                        # and are never scored
-                        if self.byz_monitor is not None and items:
-                            src = self._byz_source(sender)
-                            if src is not None:
-                                self.byz_monitor.offense(src, "bad_sig")
+                for block, attests, sender, tp in \
+                        self.deliver_client.deliver(
+                            self.channel_id,
+                            SeekInfo(start=height, stop=height + 31,
+                                     behavior="block_until_ready"),
+                            timeout_s=5):
+                    if not self._intake_block(block, attests, sender, tp):
                         break
-                    if self.byz_monitor is not None:
-                        from fabric_tpu.byzantine.monitor import (
-                            VERDICT_ADMIT, VERDICT_STALE)
-                        verdict = self.byz_monitor.check_block(
-                            block, self._byz_source(sender))
-                        if verdict == VERDICT_STALE:
-                            got += 1
-                            continue
-                        if verdict != VERDICT_ADMIT:
-                            # hold: disputed height awaiting quorum;
-                            # reject: this stream served crime evidence.
-                            # Either way re-source from the next
-                            # consenter — re-seek from committed height
-                            # keeps exactly-once (replay guard dedups)
-                            self.deliver_client.advance()
-                            break
-                    if attests:
-                        self._seed_attestations(block, attests, sender)
-                    # through the gossip state plane: fans out to peers
-                    # and drains strictly in block order
-                    self.gossip.state.add_block(block)
                     got += 1
                 if got and self.byz_monitor is not None:
                     self.byz_monitor.on_committed(self.ledger.height)
@@ -717,6 +756,12 @@ class PeerChannel:
             except Exception:
                 self.deliver_healthy = False
                 logger.debug("deliver pull failed; retrying", exc_info=True)
+                # `deliver_healthy` shows on /healthz only: the failure
+                # also moves a counter an operator can alert on
+                registry.counter(
+                    "deliver_client_failures_total",
+                    "deliver pulls from the ordering service that failed"
+                ).add(1, channel=self.channel_id)
                 time.sleep(backoff)
                 backoff = min(backoff * 2, 3.0)
             try:
@@ -1404,7 +1449,6 @@ class PeerNode:
             str(body["file"]), int(body["offset"]))
 
     def _state_route(self, path, body):
-        from fabric_tpu.ops_plane import registry
         demotions = registry.counter(
             "validator_device_demotions_total",
             "device-validation demotions to the host path, by reason")

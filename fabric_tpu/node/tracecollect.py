@@ -1,9 +1,11 @@
 """Cross-node trace assembly: one Chrome trace spanning the cluster.
 
 A transaction's spans are scattered: the gateway peer records the
-request trace, the orderer records its `orderer.deliver` children, the
-committing peers record block traces linked from the request's
-`commit_wait` span.  Each node's `GET /traces/<id>` only exports what
+request trace and its own block trace (`peer.block_intake`, linked from
+the request's `commit_wait` span), the orderers record the request's
+`orderer.broadcast` fragment and the block's `orderer.block` trace
+(linked from the peer's block trace: its context rode beside the block),
+the other peers their own block traces.  Each node's `GET /traces/<id>` only exports what
 its own flight recorder holds — this module fans out to every
 configured ops endpoint, follows links TRANSITIVELY across nodes (node
 A's spans can link a trace that only node B recorded), and merges the
@@ -64,6 +66,7 @@ def collect_cluster_trace(trace_id: str, endpoints: Sequence[str],
     when NO node knows the root trace id.
     """
     from fabric_tpu.ops_plane.metrics import registry as _metrics_registry
+    from fabric_tpu.ops_plane.tracing import links_to_follow
 
     nodes: List[Tuple[str, object]] = []
     if local_tracer is not None:
@@ -112,7 +115,9 @@ def collect_cluster_trace(trace_id: str, endpoints: Sequence[str],
                 merged["args"] = dict(args, node=name)
                 events.append(merged)
                 node_spans[name] = node_spans.get(name, 0) + 1
-                for linked in args.get("links", ()) or ():
+                # back links (a block -> its requests) only from the
+                # trace asked for, as in export_chrome
+                for linked in links_to_follow(args, tid == str(trace_id)):
                     if linked not in fetched and linked not in pending:
                         pending.append(linked)
             # thread lanes, namespaced per node

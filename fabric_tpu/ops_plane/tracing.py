@@ -4,19 +4,40 @@ Dependency-free (stdlib only) tracing in the OpenTelemetry shape —
 trace_id/span_id/parent, monotonic timestamps, attributes — with W3C
 `traceparent`-style context propagation carried inside the RPC plane's
 req/cast frames (comm/rpc.py adds a "tp" field when an ambient span is
-active).  Two trace families exist:
+active).  Three trace families exist:
 
   * request traces — rooted at an opted-in client (GatewayClient /
-    examples/gateway_load.py) and continued across processes by the
-    RPC server, covering gateway admission, endorsement and ordering;
-  * block traces — rooted at `committer.store_block`, covering VSCC
-    batch verify (device time), MVCC, ledger append and commit
-    notification.
+    examples/gateway_load.py) or at the gateway verb itself, and
+    continued across processes by the RPC server, covering gateway
+    admission, endorsement, the broadcast (`orderer.broadcast` on the
+    orderer) and, under `gateway.commit_wait`, the request's wait by
+    stage (`gateway.ordered_wait`, `.block_intake`, `.block_commit`,
+    `.answer`);
+  * orderer block traces — rooted at every cut as `orderer.block`
+    (timer, count, bytes, oversize, config alike), covering
+    `orderer.batch_fill`, `orderer.cut_propose`, `orderer.consensus`
+    and `orderer.write`; a follower's `orderer.write` is a fragment of
+    the leader's trace, whose context rides in the raft entry;
+  * peer block traces — rooted at `peer.block_intake` where a deliver
+    frame is received (`deliver.block_sig`, `deliver.admit`,
+    `gossip.forward`, then `committer.store_block` and everything
+    under it), or at `committer.store_block` where a block is handed
+    straight to the committer (gossiped blocks, replay, catch-up).
 
-The two are stitched by **links**: the commit notifier remembers each
-block's trace id, and the gateway's commit_status span links to it, so
-`GET /traces/<request-id>` exports the request's spans *and* the linked
-block's spans in one Chrome trace-event JSON (Perfetto-loadable).
+The three are stitched by **links**, both ways.  Down the pipeline:
+the commit notifier remembers each block's trace id and the gateway's
+`commit_wait` span links to that; the orderer's block context rides
+beside the block on the deliver frame (`tp`, next to `attests`) and the
+peer's block trace links it.  So `GET /traces/<request-id>` — with
+`?cluster=1`, across nodes (node/tracecollect.py) — exports the
+request, the peer's block and the orderer's block as one Chrome
+trace-event JSON (Perfetto-loadable).  Back up: an orderer block trace
+back-links the request traces whose envelopes it cut (the broadcast
+frame's `tps`; at most 32); an export follows back links only from the
+trace it was asked for, so `GET /traces/<orderer-block-id>` shows the
+block with its requests, and a request's export is not filled with its
+block's other requests.  Only contexts travel on the wire, never a time
+or a duration.
 
 A gateway verb whose frame carried no context roots a request trace
 itself (comm/rpc.py `serve(..., root_trace=True)`), so a plain client
@@ -93,7 +114,7 @@ class _NoopSpan:
     def set_attribute(self, key, value):
         return self
 
-    def add_link(self, trace_id):
+    def add_link(self, trace_id, back=False):
         return self
 
     def add_event(self, name, **attributes):
@@ -142,10 +163,17 @@ class Span:
         self.attributes[key] = value
         return self
 
-    def add_link(self, trace_id: Optional[str]):
-        """Record a pointer to another trace (request <-> block stitch)."""
+    def add_link(self, trace_id: Optional[str], back: bool = False):
+        """Record a pointer to another trace (request <-> block stitch).
+        Links point down the pipeline (request -> peer block -> orderer
+        block) and an export follows them transitively; a `back` link
+        points up (a block -> the requests it carried) and is followed
+        only from the trace an export was asked for, so that a
+        request's picture does not fill with its block's other
+        requests."""
         if trace_id:
-            self.attributes.setdefault("links", []).append(trace_id)
+            self.attributes.setdefault(
+                "back_links" if back else "links", []).append(trace_id)
         return self
 
     def add_event(self, name: str, **attributes):
@@ -207,23 +235,23 @@ class _Activation:
         return False
 
 
+def links_to_follow(attributes: dict, asked_for: bool):
+    """The trace ids an export follows from a span: its links, and its
+    back links too where the span is of the trace asked for."""
+    links = attributes.get("links") or ()
+    if asked_for:
+        return list(links) + list(attributes.get("back_links") or ())
+    return links
+
+
 class FlightRecorder:
     """Bounded store of finished traces: last `max_traces` complete ones
     plus the `max_slow` slowest ever seen (so a tail-latency outlier
-    survives long after ring eviction — the flight-recorder property).
+    survives long after ring eviction — the flight-recorder property)."""
 
-    `retention` adds a per-root-span-name cap on top of the global ring:
-    ``{"gossip.pull_window": 8}`` keeps only the newest 8 pull-window
-    traces, so a high-frequency poller can't flush the rarer (and more
-    interesting) request/block traces out of the recorder.  Configured
-    via the tracing localconfig sub-dict, e.g.
-    ``FABRIC_TPU_PEER_TRACING__RETENTION='{"gossip.pull_window": 8}'``."""
-
-    def __init__(self, max_traces: int = 256, max_slow: int = 32,
-                 retention: Optional[Dict[str, int]] = None):
+    def __init__(self, max_traces: int = 256, max_slow: int = 32):
         self.max_traces = int(max_traces)
         self.max_slow = int(max_slow)
-        self.retention = dict(retention or {})   # root span name -> max kept
         self._lock = threading.Lock()
         self._recent: "OrderedDict[str, dict]" = OrderedDict()
         self._slow: List[dict] = []          # sorted by duration desc
@@ -238,14 +266,6 @@ class FlightRecorder:
                                         record["duration_s"])
                 record = old
             self._recent[tid] = record
-            root = record.get("root_name")
-            cap = self.retention.get(root) if self.retention else None
-            if cap is not None:
-                # oldest-first: OrderedDict keeps insertion order
-                same = [k for k, r in self._recent.items()
-                        if r.get("root_name") == root]
-                for k in same[:max(0, len(same) - int(cap))]:
-                    self._maybe_keep_slow(self._recent.pop(k))
             while len(self._recent) > self.max_traces:
                 evicted_id, evicted = self._recent.popitem(last=False)
                 self._maybe_keep_slow(evicted)
@@ -339,10 +359,6 @@ class Tracer:
             cfg.get("max_traces", self.recorder.max_traces))
         self.recorder.max_slow = int(
             cfg.get("max_slow", self.recorder.max_slow))
-        retention = cfg.get("retention")
-        if retention is not None:
-            self.recorder.retention = {str(k): int(v)
-                                       for k, v in dict(retention).items()}
         return self
 
     # -- context ------------------------------------------------------------
@@ -374,11 +390,15 @@ class Tracer:
 
     def start_span(self, name: str, parent="ambient",
                    attributes: Optional[dict] = None,
-                   require_parent: bool = False):
+                   require_parent: bool = False,
+                   start: Optional[float] = None):
         """Create a span.  parent: "ambient" (default, thread-local),
         a SpanContext, or None to force a new root.  require_parent=True
         yields a no-op when there is no ambient/explicit parent — used by
-        mid-pipeline stages so untraced traffic records nothing."""
+        mid-pipeline stages so untraced traffic records nothing.
+        `start` is the perf_counter() reading at which the work began,
+        where that was before the code could open the span (a frame is
+        received, then parsed, then known to be a block)."""
         if not self.enabled:
             return NOOP_SPAN
         if parent == "ambient":
@@ -391,12 +411,16 @@ class Tracer:
             ctx = SpanContext(os.urandom(16).hex(), os.urandom(8).hex(),
                               sampled)
             span = Span(self, name, ctx, None, attributes)
+            if start is not None:
+                span.start = start
             if sampled:
                 self._register_root(span)
             return span
         ctx = SpanContext(parent.trace_id, os.urandom(8).hex(),
                           parent.sampled)
         span = Span(self, name, ctx, parent.span_id, attributes)
+        if start is not None:
+            span.start = start
         if parent.sampled and parent.remote:
             # continuing a trace whose root lives in another process:
             # this span anchors the local fragment
@@ -535,7 +559,8 @@ class Tracer:
                 nxt = []
                 for r in frontier:
                     for span in r["spans"]:
-                        for linked in span["attributes"].get("links", ()):
+                        for linked in links_to_follow(span["attributes"],
+                                                r is rec):
                             if linked in seen:
                                 continue
                             if len(records) >= max_traces:

@@ -10,10 +10,12 @@ way each stream iteration does.
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 from typing import List, Optional, Sequence
 
 from fabric_tpu.ops_plane import tracing
+from fabric_tpu.orderer import metrics
 from fabric_tpu.orderer.consensus import ChainHaltedError
 from fabric_tpu.orderer.msgprocessor import MsgClass, MsgProcessorError
 from fabric_tpu.orderer.raft import NotLeaderError
@@ -24,6 +26,11 @@ STATUS_BAD_REQUEST = 400
 STATUS_FORBIDDEN = 403
 STATUS_NOT_FOUND = 404
 STATUS_UNAVAILABLE = 503
+
+# the `status` label of the broadcast series, as upstream spells it
+_STATUS_NAMES = {STATUS_SUCCESS: "SUCCESS", STATUS_BAD_REQUEST: "BAD_REQUEST",
+                 STATUS_FORBIDDEN: "FORBIDDEN", STATUS_NOT_FOUND: "NOT_FOUND",
+                 STATUS_UNAVAILABLE: "SERVICE_UNAVAILABLE"}
 
 
 @dataclass(frozen=True)
@@ -64,25 +71,46 @@ class BroadcastHandler:
             span.set_attribute("channel", channel_id)
         support = self.registrar.get(channel_id)
         if support is None:
-            return BroadcastResponse(STATUS_NOT_FOUND,
+            resp = BroadcastResponse(STATUS_NOT_FOUND,
                                      f"unknown channel {channel_id!r}")
+        else:
+            resp = self._process(support, channel_id, env, attest, attestor)
+        metrics.processed.add(1, channel=channel_id,
+                              status=_STATUS_NAMES[resp.status])
+        return resp
+
+    @staticmethod
+    def _process(support, channel_id: str, env: Envelope, attest,
+                 attestor) -> BroadcastResponse:
+        """Validate, then enqueue; each timed once, under its outcome."""
+        t0 = time.perf_counter()
         try:
             cls = support.processor.process(env, attest=attest,
                                             attestor=attestor)
+            resp = None
         except MsgProcessorError as e:
-            return BroadcastResponse(STATUS_FORBIDDEN, str(e))
+            resp = BroadcastResponse(STATUS_FORBIDDEN, str(e))
+        t1 = time.perf_counter()
+        metrics.validate.observe(
+            t1 - t0, channel=channel_id,
+            status=_STATUS_NAMES[resp.status if resp else STATUS_SUCCESS])
+        if resp is not None:
+            return resp
         try:
             if cls is MsgClass.CONFIG:
                 support.chain.configure(env)
             else:
                 support.chain.order(env)
+            resp = BroadcastResponse(STATUS_SUCCESS)
         except NotLeaderError as e:
             # SERVICE_UNAVAILABLE + leader hint so clients re-submit there
-            return BroadcastResponse(STATUS_UNAVAILABLE, str(e),
+            resp = BroadcastResponse(STATUS_UNAVAILABLE, str(e),
                                      leader_hint=e.leader_id or 0)
         except ChainHaltedError as e:
-            return BroadcastResponse(STATUS_UNAVAILABLE, str(e))
-        return BroadcastResponse(STATUS_SUCCESS)
+            resp = BroadcastResponse(STATUS_UNAVAILABLE, str(e))
+        metrics.enqueue.observe(time.perf_counter() - t1, channel=channel_id,
+                                status=_STATUS_NAMES[resp.status])
+        return resp
 
     def handle_batch(
             self, envs: Sequence[Envelope],

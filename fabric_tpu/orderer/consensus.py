@@ -9,14 +9,28 @@ fabric_tpu/orderer/raft.py + RaftChain below it in registrar wiring.
 
 from __future__ import annotations
 
+import logging
 import threading
 import time
-from typing import Callable, List, Optional
+from collections import OrderedDict
+from typing import Callable, Dict, List, NamedTuple, Optional
 
 from fabric_tpu.ops_plane import tracing
-from fabric_tpu.orderer.blockcutter import BatchConfig, BlockCutter
+from fabric_tpu.ops_plane.logging import jlog
+from fabric_tpu.orderer import metrics
+from fabric_tpu.orderer.blockcutter import (
+    CUT_CONFIG,
+    Batch,
+    BatchConfig,
+    BlockCutter,
+)
 from fabric_tpu.orderer.blockwriter import BlockWriter
 from fabric_tpu.protocol import Envelope
+
+logger = logging.getLogger("fabric_tpu.orderer.consensus")
+
+# blocks whose trace context a chain remembers for its deliver streams
+BLOCK_TRACES = 64
 
 
 class ChainHaltedError(Exception):
@@ -38,6 +52,72 @@ class Chain:
     def halt(self) -> None:
         pass
 
+    # -- the block's trace ---------------------------------------------------
+    # Every cut roots a trace, `orderer.block`, under the tracer's
+    # sampling: the cut has no caller whose trace it could join (the
+    # batch timer has none at all, and a count or bytes cut closes many
+    # requests' envelopes, not the one that happened to fill it).
+
+    def _cut_made(self, batch: Batch, is_config: bool):
+        """Count a cut and open its block's trace: -> the root span (the
+        shared no-op with the tracer off), from the batch's first
+        enqueue, holding `orderer.batch_fill` and back-linking the
+        request traces the envelopes came in under."""
+        channel = self.writer.channel_id
+        metrics.cuts.add(1, channel=channel, reason=batch.reason)
+        metrics.block_fill.observe(batch.t_cut - batch.t_first,
+                                   channel=channel)
+        root = tracing.tracer.start_span(
+            "orderer.block", parent=None, start=batch.t_first,
+            attributes={"channel": channel, "reason": batch.reason,
+                        "txs": len(batch), "is_config": is_config})
+        if root.recording:
+            for link in batch.links:
+                root.add_link(link, back=True)
+            tracing.tracer.record_span(
+                "orderer.batch_fill", batch.t_first, batch.t_cut,
+                attributes={"reason": batch.reason, "txs": len(batch),
+                            "bytes": batch.nbytes},
+                parent=root.context)
+        return root
+
+    def _write_block(self, batch, is_config: bool, parent, fields=None):
+        """Create, sign and write the next block and tell the channel,
+        under `orderer.write`: a child of `parent` (a span context; one
+        parsed off a raft entry makes this node's fragment of the
+        leader's trace), a root of its own without one.  What a deliver
+        stream sends beside the block is remembered before the block can
+        be read."""
+        channel = self.writer.channel_id
+        with tracing.tracer.start_span(
+                "orderer.write", parent=parent,
+                attributes={"channel": channel, "txs": len(batch),
+                            "is_config": is_config}) as span:
+            t0 = time.perf_counter()
+            block = self.writer.create_next_block(batch)
+            number = int(block.header.number)
+            if fields:
+                block.metadata.items.update(fields)
+            if span.recording:
+                span.set_attribute("block", number)
+                if span.context.sampled:
+                    traces = self._block_traces
+                    traces[number] = tracing.format_traceparent(span.context)
+                    while len(traces) > BLOCK_TRACES:
+                        traces.popitem(last=False)
+            self.writer.write_block(block, is_config=is_config)
+            metrics.block_write.observe(time.perf_counter() - t0,
+                                        channel=channel)
+            metrics.committed_block.set(number, channel=channel)
+            self.on_block(block)
+        return block
+
+    def block_traceparent(self, number: int) -> Optional[str]:
+        """The context a deliver frame carries beside block `number`:
+        of this node's `orderer.write` span in the block's trace.  None
+        for a block written untraced, or long ago."""
+        return self._block_traces.get(number)
+
 
 class SoloChain(Chain):
     """Single-consenter dev chain (orderer/consensus/solo/consensus.go).
@@ -58,6 +138,7 @@ class SoloChain(Chain):
         self._halted = False
         self._timer: Optional[threading.Thread] = None
         self._batch_deadline: Optional[float] = None
+        self._block_traces: "OrderedDict[int, str]" = OrderedDict()
 
     # -- Chain interface ----------------------------------------------------
 
@@ -72,10 +153,10 @@ class SoloChain(Chain):
     def configure(self, env: Envelope) -> None:
         with self._lock:
             self._check_running()
-            pending = self.cutter.cut()
+            pending = self.cutter.cut(CUT_CONFIG)
             if pending:
                 self._write(pending)
-            self._write([env.serialize()], is_config=True)
+            self._write(_config_batch(env), is_config=True)
             self._batch_deadline = None
 
     def tick(self, now: Optional[float] = None) -> bool:
@@ -129,18 +210,16 @@ class SoloChain(Chain):
             self._batch_deadline = (time.monotonic()
                                     + self.cutter.config.batch_timeout_s)
 
-    def _write(self, batch: List[bytes], is_config: bool = False) -> None:
-        # consensus cut: spans only when ordered under a traced broadcast
-        # (timer-thread cuts have no ambient context and record nothing)
-        with tracing.tracer.start_span(
-                "orderer.cut_block", require_parent=True,
-                attributes={"batch_size": len(batch),
-                            "is_config": is_config}) as span:
-            block = self.writer.create_next_block(batch)
-            if span.recording:
-                span.set_attribute("block", int(block.header.number))
-            self.writer.write_block(block, is_config=is_config)
-            self.on_block(block)
+    def _write(self, batch: Batch, is_config: bool = False) -> None:
+        with self._cut_made(batch, is_config) as root:
+            self._write_block(batch, is_config, root.context)
+
+
+def _config_batch(env: Envelope) -> Batch:
+    """A config envelope is always a batch of its own."""
+    raw = env.serialize()
+    link = tracing.tracer.current_trace_id()
+    return Batch([raw], CUT_CONFIG, len(raw), links=[link] if link else ())
 
 
 # ---------------------------------------------------------------------------
@@ -161,6 +240,15 @@ def make_entry_signer(signer):
             raftmod.entry_signed_bytes(term, index, data, kind))
 
     return sign
+
+
+class _Proposal(NamedTuple):
+    """A batch the leader proposed and has not seen applied."""
+    term: int               # the term it was proposed in
+    txs: int
+    t_propose: float        # perf_counter
+    root: object            # its block trace's open spans: `orderer.block`
+    consensus: object       # and `orderer.consensus`
 
 
 class RaftChain(Chain):
@@ -202,6 +290,11 @@ class RaftChain(Chain):
         self._lock = threading.RLock()
         self._halted = False
         self._batch_deadline: Optional[float] = None
+        self._block_traces: "OrderedDict[int, str]" = OrderedDict()
+        # raft index -> what this node proposed there as leader
+        self._open: Dict[int, _Proposal] = {}
+        self._was_leader = False
+        self._seen_leader: Optional[int] = None
         self._last_applied = self._recover_applied_index()
         self.catchup_target: Optional[dict] = None  # set on snapshot install
         self._held_entries: List = []  # entries arriving while catching up
@@ -234,6 +327,7 @@ class RaftChain(Chain):
         with self._lock:
             self._check_running()
             self._check_leader()  # followers redirect Submit (chain.go:378)
+            metrics.proposals_received.add(1, channel=self.writer.channel_id)
             batches, pending = self.cutter.ordered(env)
             for batch in batches:
                 self._propose(batch, is_config=False)
@@ -243,10 +337,10 @@ class RaftChain(Chain):
         with self._lock:
             self._check_running()
             self._check_leader()
-            pending = self.cutter.cut()
+            pending = self.cutter.cut(CUT_CONFIG)
             if pending:
                 self._propose(pending, is_config=False)
-            self._propose([env.serialize()], is_config=True)
+            self._propose(_config_batch(env), is_config=True)
             self._batch_deadline = None
 
     def _check_leader(self) -> None:
@@ -283,11 +377,22 @@ class RaftChain(Chain):
             from fabric_tpu.orderer import raft as raftmod
             try:
                 self._propose(batch, is_config=False)
-            except raftmod.NotLeaderError:
+            except raftmod.NotLeaderError as exc:
                 # deposed between the deadline being set and firing: the
                 # batch is discarded (clients retry against the new leader)
+                self._batch_lost("not_leader", len(batch),
+                                 leader=exc.leader_id or 0)
                 return False
             return True
+
+    def _batch_lost(self, why: str, txs: int, **fields) -> None:
+        """A cut batch that will never be a block: counted, and logged
+        once per event."""
+        channel = self.writer.channel_id
+        metrics.proposal_failures.add(1, channel=channel)
+        jlog(logger, "orderer.batch_lost", level=logging.WARNING,
+             channel=channel, why=why, txs=txs, term=self.node.term,
+             **fields)
 
     def halt(self) -> None:
         with self._lock:
@@ -314,13 +419,70 @@ class RaftChain(Chain):
         with self._lock:
             self.node.tick()
 
-    def _propose(self, batch, is_config: bool) -> None:
-        with tracing.tracer.start_span(
-                "orderer.cut_propose", require_parent=True,
-                attributes={"batch_size": len(batch),
-                            "is_config": is_config}):
-            self.node.propose(self._serde.encode(
-                {"cfg": is_config, "batch": list(batch)}))
+    def _propose(self, batch: Batch, is_config: bool) -> None:
+        """Hand a cut batch to raft.  The block's trace is rooted here,
+        at the cut, and its context rides in the entry (`tp`; a context,
+        never a time), so that the followers' writes join it; the root
+        and `orderer.consensus` stay open until the entry is applied on
+        this node (`_settle`)."""
+        root = self._cut_made(batch, is_config)
+        payload = {"cfg": is_config, "batch": list(batch)}
+        if root.recording and root.context.sampled:
+            payload["tp"] = tracing.format_traceparent(root.context)
+        try:
+            with tracing.tracer.start_span(
+                    "orderer.cut_propose", parent=root.context,
+                    attributes={"batch_size": len(batch),
+                                "is_config": is_config}):
+                index = self.node.propose(self._serde.encode(payload))
+        except BaseException:
+            root.end(status="ERROR")
+            raise
+        self._open[index] = _Proposal(
+            self.node.term, len(batch), time.perf_counter(), root,
+            tracing.tracer.start_span("orderer.consensus",
+                                      parent=root.context,
+                                      attributes={"index": index}))
+
+    def _settle(self, entry) -> Optional[_Proposal]:
+        """-> what this node proposed at the index of a committed entry,
+        if this is that entry.  One of another term took the slot: the
+        proposal was lost to a leader change."""
+        p = self._open.pop(entry.index, None)
+        if p is None:
+            return None
+        if p.term == entry.term:
+            return p
+        p.consensus.end(status="ERROR")
+        p.root.set_attribute("lost_to_term", entry.term)
+        p.root.end(status="ERROR")
+        self._batch_lost("overwritten", p.txs, index=entry.index,
+                         proposed_in=p.term, committed_in=entry.term)
+        return None
+
+    def _observe_ready(self, r) -> None:
+        """The raft node's drain, counted: who leads, what persisting
+        cost, what went to each follower."""
+        from fabric_tpu.orderer import raft as raftmod
+        channel = self.writer.channel_id
+        leading = self.node.role == raftmod.LEADER
+        if leading != self._was_leader:
+            self._was_leader = leading
+            metrics.is_leader.set(1.0 if leading else 0.0, channel=channel)
+        # as upstream counts: a leader known after another, or after
+        # none — so the same node elected again in a later term counts
+        leader = self.node.leader_id
+        if leader != self._seen_leader:
+            self._seen_leader = leader
+            if leader is not None:
+                metrics.leader_changes.add(1, channel=channel)
+        if r.persist_s is not None:
+            metrics.persist.observe(r.persist_s, channel=channel)
+        for m in r.messages:
+            if m.entries and m.type == raftmod.MSG_APP:
+                metrics.append_bytes.add(
+                    sum(len(e.data) for e in m.entries),
+                    channel=channel, to=str(m.to))
 
     def process_ready(self):
         """Drain the raft node: apply committed entries to the ledger and
@@ -328,18 +490,22 @@ class RaftChain(Chain):
         from fabric_tpu.orderer import raft as raftmod
         with self._lock:
             r = self.node.take_ready()
+            self._observe_ready(r)
             if r.lost_leadership:
                 # discard the pending batch and stop the batch timer
                 # (reference etcdraft chain.go:604-607 becomeFollower):
                 # stale envelopes must not be proposed if leadership is
                 # later regained, and the timer path must not fire.
-                self.cutter.cut()
+                dropped = self.cutter.cut()
+                if dropped:
+                    self._batch_lost("lost_leadership", len(dropped))
                 self._batch_deadline = None
             for e in r.committed:
+                proposal = self._settle(e) if self._open else None
                 if e.kind == raftmod.ENTRY_SNAPSHOT:
                     self._on_snapshot_entry(e)
                 elif e.kind == raftmod.ENTRY_NORMAL:
-                    self._apply(e)
+                    self._apply(e, proposal)
                 elif e.kind == raftmod.ENTRY_CONF:
                     # the raft-internal effect (node set change) already
                     # ran inside take_ready; surface the full payload so
@@ -348,10 +514,7 @@ class RaftChain(Chain):
                     try:
                         self.on_conf(self._serde.decode(e.data))
                     except Exception:
-                        import logging
-                        logging.getLogger(
-                            "fabric_tpu.orderer.consensus").exception(
-                            "membership conf hook failed")
+                        logger.exception("membership conf hook failed")
             # compact only after the entries above hit the ledger — and
             # never while catching up, when _last_applied/height lag the
             # raft applied index and would bake stale state into the snap
@@ -359,7 +522,22 @@ class RaftChain(Chain):
                 self.node.maybe_compact()
         return r
 
-    def _apply(self, entry) -> None:
+    def _apply(self, entry, proposal: Optional[_Proposal] = None) -> None:
+        """Write the block of a committed entry.  `proposal`: this node
+        proposed it as leader, and its block trace is still open."""
+        if proposal is not None:
+            # consensus ends where the entry is handed over to be applied
+            now = time.perf_counter()
+            metrics.commit.observe(now - proposal.t_propose,
+                                   channel=self.writer.channel_id)
+            proposal.consensus.end(end_time=now)
+        try:
+            self._apply_entry(entry, proposal)
+        finally:
+            if proposal is not None:
+                proposal.root.end()
+
+    def _apply_entry(self, entry, proposal: Optional[_Proposal]) -> None:
         if self.catchup_target is not None:
             # ledger is behind the snapshot: hold entries until the missing
             # blocks arrive (replication), else block numbers would skew
@@ -372,11 +550,15 @@ class RaftChain(Chain):
             self._last_applied = entry.index
             return
         d = self._serde.decode(entry.data)
-        block = self.writer.create_next_block(d["batch"])
-        block.metadata.items[META_RAFT_INDEX] = entry.index
-        self.writer.write_block(block, is_config=d["cfg"])
+        if proposal is not None:
+            parent = proposal.root.context
+        else:
+            # a follower (or a leader that restarted): the context the
+            # leader put in the entry, if its tracer did
+            parent = tracing.tracer.context_from(d.get("tp"))
+        self._write_block(d["batch"], d["cfg"], parent,
+                          {META_RAFT_INDEX: entry.index})
         self._last_applied = entry.index
-        self.on_block(block)
 
     def _on_snapshot_entry(self, e) -> None:
         """A snapshot was installed: this node is behind the compacted log

@@ -13,6 +13,7 @@ import threading
 from dataclasses import dataclass
 from typing import Iterator, Optional
 
+from fabric_tpu.orderer import metrics
 from fabric_tpu.policy import SignedData
 from fabric_tpu.protocol import Block
 
@@ -53,17 +54,14 @@ class DeliverHandler:
         `signed` is the deliver request's creator triple, checked against
         the channel Readers policy when the channel enforces one.
 
-        When the request rode in on a traced RPC (the req frame carried
-        a traceparent — e.g. a leader peer's gossip.pull_window), the
-        stream is timed under an `orderer.deliver` child span; untraced
-        traffic records nothing (require_parent).
+        A stream is counted, not traced: a peer's deliver loop asks from
+        a thread that has no trace, and each block's own trace context
+        rides beside it on the frame (node/orderer.py `_rpc_deliver`).
+        A request that ends at the tip, by its stop or by its time-out
+        is a success; one refused or cut short is not.
         """
-        from fabric_tpu.ops_plane import tracing
-        span = tracing.tracer.start_span(
-            "orderer.deliver", require_parent=True,
-            attributes={"channel": channel_id})
-        sent = 0
-        status = "OK"
+        metrics.deliver_received.add(1, channel=channel_id)
+        success = "true"
         try:
             support = self.registrar.get(channel_id)
             if support is None:
@@ -76,7 +74,6 @@ class DeliverHandler:
                     if seek.stop is not None else None)
             if stop is not None and stop < start:
                 raise DeliverError(f"seek stop {stop} < start {start}")
-            span.set_attribute("start", start)
 
             num = start
             while stop is None or num <= stop:
@@ -87,17 +84,16 @@ class DeliverHandler:
                     if not support.wait_for_height(num + 1, timeout_s):
                         return  # timed out waiting at the tip
                 yield support.ledger.get_by_number(num)
-                sent += 1
+                metrics.deliver_sent.add(1, channel=channel_id)
                 num += 1
         except NotReadyError:
             raise    # at-tip is the normal end of a window pull
-        except BaseException as e:   # incl. GeneratorExit on client cancel
-            span.set_attribute("error", repr(e))
-            status = "ERROR"
+        except BaseException:        # incl. GeneratorExit on client cancel
+            success = "false"
             raise
         finally:
-            span.set_attribute("blocks", sent)
-            span.end(status=status)
+            metrics.deliver_completed.add(1, channel=channel_id,
+                                          success=success)
 
     @staticmethod
     def _resolve(pos, height: int) -> int:

@@ -21,6 +21,7 @@ from __future__ import annotations
 import os
 import random
 import struct
+import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -100,6 +101,9 @@ class Ready:
     committed: List[Entry] = field(default_factory=list)
     became_leader: bool = False
     lost_leadership: bool = False
+    # seconds the WAL spent appending and syncing what this drain made
+    # durable; None when nothing was written since the last drain
+    persist_s: Optional[float] = None
 
 
 # ---------------------------------------------------------------------------
@@ -121,6 +125,8 @@ class WAL:
     def __init__(self, path: Optional[str]):
         self.path = path
         self._f = None
+        # seconds spent in append() since the last sync(); None = clean
+        self._append_s: Optional[float] = None
         if path is not None:
             os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
             self._f = open(path, "ab")
@@ -128,13 +134,24 @@ class WAL:
     def append(self, rec: dict) -> None:
         if self._f is None:
             return
+        t0 = time.perf_counter()
         raw = serde.encode(rec)
         self._f.write(_REC.pack(len(raw)) + raw)
+        self._append_s = (self._append_s or 0.0) + time.perf_counter() - t0
 
-    def sync(self) -> None:
-        if self._f is not None:
-            self._f.flush()
-            os.fsync(self._f.fileno())
+    def sync(self) -> Optional[float]:
+        """Flush + fsync.  -> the seconds the records appended since the
+        last sync cost to write and make durable, None if there were
+        none."""
+        if self._f is None:
+            return None
+        t0 = time.perf_counter()
+        self._f.flush()
+        os.fsync(self._f.fileno())
+        appended, self._append_s = self._append_s, None
+        if appended is None:
+            return None
+        return appended + time.perf_counter() - t0
 
     def rewrite(self, records: Sequence[dict]) -> None:
         """Atomically replace the WAL with `records` (post-compaction)."""
@@ -321,8 +338,10 @@ class RaftNode:
     # -- public API ----------------------------------------------------------
 
     def take_ready(self) -> Ready:
-        self._wal.sync()  # nothing leaves the node before the WAL is durable
+        # nothing leaves the node before the WAL is durable
+        persist_s = self._wal.sync()
         r, self._ready = self._ready, Ready()
+        r.persist_s = persist_s
         # hand out committed-but-unapplied entries
         while self.applied_index < self.commit_index:
             self.applied_index += 1

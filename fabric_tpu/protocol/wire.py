@@ -80,7 +80,7 @@ class BlockView:
 
     __slots__ = ("raw", "header", "n_data", "_data_off", "_data_end",
                  "_spans", "_meta_off", "_data", "_metadata", "_dhash",
-                 "_lanes", "_table", "parsed")
+                 "_lanes", "_table", "parsed", "intake")
 
     def __init__(self, raw: _Raw, number: int, previous_hash: bytes,
                  data_hash: bytes, data_off: int, data_end: int,

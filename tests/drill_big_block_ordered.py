@@ -20,9 +20,12 @@ envelopes with status 200, the Raft term rose while the leader was busy
 with them, another orderer leads afterwards with a log that never held
 the entry, and no height moves again.  (A term change does not always
 lose it: one run went from term 1 to 3 and committed.)  The drill prints
-the term before and after.  Exit 0: the block committed everywhere with
-the expected flags.  Exit 1: it did not; the status of every node and
-the tail of each orderer's log go to stderr.
+the term before and after, and, whatever the outcome, what each
+orderer's Raft counters read after it (`/metrics`, PR 37): leader
+changes, lost proposals, the bytes appended to each follower against the
+entry's own, what persisting and committing took.  Exit 0: the block
+committed everywhere with the expected flags.  Exit 1: it did not; the
+status of every node and the tail of each orderer's log go to stderr.
 """
 
 from __future__ import annotations
@@ -82,6 +85,30 @@ def broadcast_all(nw: cs.Network, leader, envs: list) -> None:
         conn.close()
 
 
+D9_SERIES = ("consensus_etcdraft_is_leader",
+             "consensus_etcdraft_leader_changes",
+             "consensus_etcdraft_proposal_failures",
+             "consensus_etcdraft_append_bytes_total",
+             "consensus_etcdraft_data_persist_duration",
+             "consensus_etcdraft_commit_duration",
+             "blockcutter_cut_total", "deliver_blocks_sent")
+
+
+def say_raft_counters(orderer_ops: dict) -> None:
+    """Each orderer's D9 series, as its `/metrics` has them now."""
+    import urllib.request
+    for name, url in sorted(orderer_ops.items()):
+        try:
+            with urllib.request.urlopen(url + "/metrics", timeout=10) as r:
+                text = r.read().decode()
+        except OSError as exc:
+            cs.say(f"{name}: no exposition ({exc!r})")
+            continue
+        for line in text.splitlines():
+            if line.startswith(D9_SERIES) and "_bucket" not in line:
+                cs.say(f"{name}: {line}")
+
+
 def run() -> None:
     from fabric_tpu.bccsp.factory import FactoryOpts, init_factories
     from fabric_tpu.testing.procnet import (node_status, wait_orderer_leader,
@@ -94,6 +121,14 @@ def run() -> None:
         cfg["bccsp"] = "SW"
         cfg.pop("bccsp_degrade", None)
         cs.write_json(path, cfg)
+    from fabric_tpu.node.provision import free_ports
+    orderer_ops = {}
+    for path, port in zip(nw.net["orderers"],
+                          free_ports(len(nw.net["orderers"]))):
+        cfg = cs.read_json(path)
+        cfg["ops_port"] = port
+        cs.write_json(path, cfg)
+        orderer_ops[os.path.basename(path)[:-5]] = f"http://127.0.0.1:{port}"
     try:
         nw.start()
         leader = wait_orderer_leader(nw.orderers, nw.signer, nw.msps,
@@ -119,6 +154,7 @@ def run() -> None:
         try:
             nw.wait_heights(h0 + 2, COMMIT_DEADLINE_S)
         except Exception as exc:
+            say_raft_counters(orderer_ops)
             sys.stderr.write(f"peers: {nw.statuses()}\n")
             for addr in nw.orderers:
                 sys.stderr.write(f"orderer {addr}: "
@@ -134,6 +170,7 @@ def run() -> None:
         cs.say(f"every peer committed it {time.monotonic() - t1:.1f} s after "
                "the first broadcast (raft term now "
                f"{node_status(leader, nw.signer, nw.msps)['term']})")
+        say_raft_counters(orderer_ops)
         want = [(txid, cs.POLICY_FAILURE if tampered else cs.VALID)
                 for _, txid, tampered in envs]
         for org in cs.PEER_ORGS:

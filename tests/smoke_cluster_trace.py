@@ -37,6 +37,16 @@ from fabric_tpu.testing.procnet import (
 )
 
 
+# the three trace families one request's picture holds (PR 37): its own
+# wait by stage, the peer's block trace from the frame's receipt, the
+# orderer's from the cut
+PICTURE = {"gateway.commit_wait", "gateway.ordered_wait",
+           "gateway.block_intake", "gateway.block_commit", "gateway.answer",
+           "orderer.broadcast", "orderer.block", "orderer.batch_fill",
+           "orderer.consensus", "orderer.write", "peer.block_intake",
+           "deliver.block_sig", "gossip.forward", "committer.store_block"}
+
+
 def main() -> int:
     init_factories(FactoryOpts(default="SW"))
     with tempfile.TemporaryDirectory() as base:
@@ -109,7 +119,8 @@ def main() -> int:
                         doc = json.loads(r.read())
                 except (urllib.error.URLError, OSError):
                     doc = None
-                if doc and doc["otherData"]["n_nodes"] >= 3:
+                if doc and doc["otherData"]["n_nodes"] >= 3 and PICTURE <= {
+                        e["name"] for e in doc["traceEvents"]}:
                     break
                 time.sleep(0.3)
             if not doc:
@@ -126,10 +137,8 @@ def main() -> int:
                   and other["n_nodes"] >= 3
                   and len(pids) >= 3
                   and not other["truncated"]
-                  and other["n_traces_merged"] >= 2
-                  and any(n.startswith("gateway.") for n in names)
-                  and any(n.startswith("orderer.") for n in names)
-                  and "committer.store_block" in names)
+                  and other["n_traces_merged"] >= 3
+                  and PICTURE <= names)
             if not ok:
                 print(f"FAIL: merged trace malformed: nodes={nodes} "
                       f"names={sorted(names)} other={other}",

@@ -11,7 +11,6 @@ import os
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import threading
-from collections import OrderedDict
 
 import pytest
 
@@ -518,22 +517,24 @@ def block_of(number, prev, data):
     return block.serialize(), block.hash()
 
 
-def history_as_it_was(window, blocks):
-    """`CommitNotifier.on_block` before the table: every envelope
-    decoded, every txid inserted, then the window trimmed."""
-    history = OrderedDict()
+def outcomes_of(watched, blocks):
+    """What `CommitNotifier` should hold after `blocks` for the txids
+    someone waits for: every envelope decoded in order, the outcome of a
+    watched txid kept where its block brings it (the last appearance
+    inside that block), and a txid already told ignored afterwards."""
+    history, told = {}, set()
     for block, codes in blocks:
+        here = {}
         for i, env_bytes in enumerate(block.data):
             try:
                 txid = Envelope.deserialize(
                     env_bytes).header().channel_header.txid
             except Exception:
                 continue
-            if not txid:
-                continue
-            history[txid] = (codes[i], int(block.header.number), None)
-        while len(history) > window:
-            history.popitem(last=False)
+            if txid and txid in watched and txid not in told:
+                here[txid] = (codes[i], int(block.header.number))
+        history.update(here)
+        told.update(here)
     return history
 
 
@@ -541,15 +542,27 @@ def history_as_it_was(window, blocks):
                                           (64, (48, 20, 48)), (4096, (40,))],
                          ids=["3x-window", "small-large-small",
                               "under-window", "default-window"])
-def test_the_notifier_keeps_the_history_it_kept(ids, window, sizes):
+def test_the_notifier_keeps_what_someone_waits_for(ids, window, sizes):
     sizes = [max(s, 24) for s in sizes]
     notifier = CommitNotifier("ch", window=window)
     prev, fed = GENESIS, []
     datas = [reader_block(ids, n) for n in sizes]
-    # a txid of the last block that an earlier block already holds: the
-    # entry is updated where it stands
+    # a txid of the last block that an earlier block already holds: told
+    # once, where it first came
     datas[-1][0][2] = datas[0][0][sizes[0] - 1]
-    waited = Envelope.deserialize(datas[0][0][20]).header().channel_header.txid
+
+    def txid_at(b, i):
+        return Envelope.deserialize(datas[b][0][i]).header() \
+            .channel_header.txid
+    # watched: one plain tx a block, the repeated txids of block 0 (next
+    # to each other, far apart), the txid two blocks hold, and one no
+    # block brings; tx 5's header does not decode and is nobody's
+    watched = {txid_at(b, 13) for b in range(len(sizes))}
+    watched |= {txid_at(0, 7), txid_at(0, 1), txid_at(0, sizes[0] - 1),
+                "never-ordered"}
+    for txid in sorted(watched):
+        notifier.watch(txid)
+    waited = txid_at(0, 20)
     got = []
     waiter = threading.Thread(
         target=lambda: got.append(notifier.wait(waited, 30.0)))
@@ -562,31 +575,52 @@ def test_the_notifier_keeps_the_history_it_kept(ids, window, sizes):
         notifier.on_block(view, TxFlags.from_codes(codes))
         assert view._data is not None       # tx 11 and tx 5: decoded
         fed.append((plain_of(raw), codes))
-        want = history_as_it_was(window, fed)
-        assert list(notifier._history.items()) == list(want.items())
+        want = outcomes_of(watched | {waited}, fed)
+        held = {txid: (c.code, c.block)
+                for txid, c in notifier._history.items()}
+        assert held == want
+        assert len(notifier._history) <= window
+        # what came is no longer watched; what did not still is
+        assert set(notifier._watched) == watched - set(want)
         if number == 0:
-            # the waiter registered before the block is woken by it, and
-            # reads what the window still holds
+            # the waiter registered before the block is woken by it
             waiter.join(30.0)
             assert not waiter.is_alive() and not notifier._waiters
-            assert got == [want.get(waited)]
-        # a plain block takes the per-envelope path to the same history
+            assert got == [notifier.peek(waited)]
+            assert got[0][:3] == (codes[20], 0, None)
+        # a plain block takes the per-envelope path to the same outcomes
         again = CommitNotifier("ch", window=window)
+        for txid in sorted(watched | {waited}):
+            again.watch(txid)
         for block, block_codes in fed:
             again.on_block(block, TxFlags.from_codes(block_codes))
-        assert list(again._history.items()) == list(want.items())
+        assert {txid: (c.code, c.block)
+                for txid, c in again._history.items()} == want
+    assert "never-ordered" in notifier._watched
 
 
 def test_the_notifier_decodes_nothing_of_a_block_the_table_speaks_for(ids):
     raw, _ = raw_block(0, GENESIS, seed(ids, 40))
     view = view_of(raw)
-    notifier = CommitNotifier("ch", window=16)
-    notifier.on_block(view, TxFlags.from_codes([V] * 40))
-    assert view._data is None
     txids = [Envelope.deserialize(b).header().channel_header.txid
              for b in plain_of(raw).data]
+    notifier = CommitNotifier("ch", window=16)
+    for txid in txids[-20:]:
+        notifier.watch(txid)                # the window drops the first 4
+    notifier.on_block(view, TxFlags.from_codes([V] * 40))
+    assert view._data is None
     assert list(notifier._history) == txids[-16:]
-    assert notifier.peek(txids[-1]) == (V, 0, None)
+    assert notifier.peek(txids[-1])[:3] == (V, 0, None)
+    assert notifier.peek(txids[0]) is None      # nobody waited for it
+    # the block's stamps come with the outcome: none on a block no
+    # committer took, three in order on one that a committer did
+    assert notifier.peek(txids[-1]).stamps is None
+    view2 = view_of(raw)
+    view2.intake = (view2.parsed[0], view2.parsed[1])
+    notifier.watch(txids[0])
+    notifier.on_block(view2, TxFlags.from_codes([V] * 40))
+    received, taken, held = notifier.peek(txids[0]).stamps
+    assert received <= taken <= held
 
 
 def test_the_block_store_finds_every_txid_where_it_found_it(ids, tmp_path):

@@ -426,7 +426,7 @@ def test_peer_fail_stops_on_device_error_from_gossip(tmp_path):
             conn.close()
         assert out["status"] == 200, out
         height = peer.ledger.height
-        block, _, _ = next(iter(ch.deliver_client.deliver(
+        block, _, _, _ = next(iter(ch.deliver_client.deliver(
             "ch", SeekInfo(start=height, stop=height,
                            behavior="block_until_ready"), timeout_s=10)))
         ch.mcs.provider = SickDevice(ch.mcs.provider)

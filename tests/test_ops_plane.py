@@ -95,7 +95,12 @@ def test_commit_pipeline_metrics(tmp_path):
     assert 'committed_blocks_total{channel="met"} 1' in text
     assert 'ledger_height{channel="met"} 1' in text
     assert 'validation_duration_seconds_count{channel="met"} 1' in text
-    assert 'commit_phase_seconds' in text
+    # the per-phase twins went (PR 37): the stage family carries the
+    # commit's seconds, the `ledger.*` spans its phases
+    assert 'validator_stage_seconds_count{channel="met",stage="commit"} 1' \
+        in text
+    assert 'commit_phase_seconds' not in text
+    assert 'validation_dispatch_seconds' not in text
 
 
 def test_profiling_routes():

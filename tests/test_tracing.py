@@ -71,100 +71,29 @@ def test_recorder_bounds_eviction_and_slowest_retention():
     assert rec.list() == {"recent": [], "slowest": []}
 
 
-def test_recorder_retention_by_root_name_and_configure():
-    """Per-root retention: a high-frequency root (the gossip poller)
-    keeps only its newest N traces while other roots ride the normal
-    ring — the poller can't flush request/block traces out."""
-    rec = FlightRecorder(max_traces=64, max_slow=0,
-                         retention={"noisy": 3})
+def test_recorder_is_one_ring_and_configure():
+    """One ring for every root: a frequent root rides it like the rest
+    (the per-root `retention` cap went with its only user, the
+    in-process window puller's trace), and Tracer.configure wires the
+    ring's two bounds from the localconfig `tracing` sub-dict."""
+    rec = FlightRecorder(max_traces=12, max_slow=0)
     for i in range(8):
-        rec.add({"trace_id": f"n{i}", "root_name": "noisy",
+        rec.add({"trace_id": f"n{i}", "root_name": "orderer.block",
                  "start_wall": 0.0, "duration_s": 0.001,
-                 "spans": [{"name": "noisy"}]})
+                 "spans": [{"name": "orderer.block"}]})
         rec.add({"trace_id": f"q{i}", "root_name": "quiet",
                  "start_wall": 0.0, "duration_s": 0.001,
                  "spans": [{"name": "quiet"}]})
     listing = rec.list()["recent"]
-    noisy = [r["trace_id"] for r in listing if r["root"] == "noisy"]
-    quiet = [r["trace_id"] for r in listing if r["root"] == "quiet"]
-    assert noisy == ["n7", "n6", "n5"]      # capped, newest kept
-    assert len(quiet) == 8                  # uncapped root untouched
-    # Tracer.configure wires the policy from the localconfig tracing
-    # sub-dict (FABRIC_TPU_PEER_TRACING__RETENTION='{"root": n}')
+    assert [r["trace_id"] for r in listing] == [
+        "q7", "n7", "q6", "n6", "q5", "n5", "q4", "n4", "q3", "n3",
+        "q2", "n2"]                          # newest 12, whatever the root
+    assert not hasattr(rec, "retention")
     t = Tracer(FlightRecorder())
-    t.configure({"retention": {"gossip.pull_window": 2}})
-    assert t.recorder.retention == {"gossip.pull_window": 2}
-
-
-def test_pull_window_trace_covers_deliver():
-    """gossip.pull_window roots a trace and the orderer-side deliver
-    stream records an `orderer.deliver` child in the SAME trace (the
-    traceparent rides the ambient context / RPC req frame)."""
-    from fabric_tpu.gossip.blocksprovider import BlocksProvider
-    from fabric_tpu.orderer.deliver import DeliverHandler
-
-    class _Ledger:
-        def __init__(self, blocks):
-            self.blocks = blocks
-
-        @property
-        def height(self):
-            return len(self.blocks)
-
-        def get_by_number(self, n):
-            return self.blocks[n]
-
-    class _Support:
-        def __init__(self, blocks):
-            self.ledger = _Ledger(blocks)
-
-        def authorize_read(self, signed):
-            pass
-
-        def wait_for_height(self, h, timeout_s):
-            return False
-
-    class _Registrar:
-        def __init__(self, support):
-            self._s = support
-
-        def get(self, cid):
-            return self._s
-
-    class _Blk:
-        def __init__(self, n):
-            self.header = type("H", (), {"number": n})()
-
-    class _State:
-        def __init__(self):
-            self.committer = type("C", (), {"height": 0})()
-
-        def add_block(self, b):
-            self.committer.height += 1
-
-    blocks = [_Blk(i) for i in range(5)]
-    bp = BlocksProvider("ch", DeliverHandler(_Registrar(_Support(blocks))),
-                        _State(), window=8)
-    t = tracing.tracer
-    saved = (t.enabled, t.sample_rate, t.recorder)
-    t.enabled, t.sample_rate = True, 1.0
-    t.recorder = rec = FlightRecorder()
-    try:
-        assert bp.pull_window() == 5
-    finally:
-        t.enabled, t.sample_rate, t.recorder = saved
-    recent = rec.list()["recent"]
-    assert recent and recent[0]["root"] == "gossip.pull_window"
-    record = rec.get(recent[0]["trace_id"])
-    names = {s["name"] for s in record["spans"]}
-    assert {"gossip.pull_window", "orderer.deliver"} <= names
-    deliver = next(s for s in record["spans"]
-                   if s["name"] == "orderer.deliver")
-    assert deliver["attributes"]["blocks"] == 5
-    assert deliver["parent_id"] is not None      # child, not its own root
-    root = next(s for s in record["spans"]
-                if s["name"] == "gossip.pull_window")
-    assert root["attributes"]["accepted"] == 5
+    t.configure({"max_traces": 7, "max_slow": 3,
+                 "retention": {"gossip.pull_window": 2}})   # ignored
+    assert (t.recorder.max_traces, t.recorder.max_slow) == (7, 3)
+    assert t.enabled and not hasattr(t.recorder, "retention")
 
 
 def test_sampling_zero_records_nothing_but_propagates():
@@ -336,6 +265,66 @@ def test_live_tx_trace_covers_pipeline(net):
               if e.get("ph") == "X" and e["name"] == "bccsp.batch_verify")
     assert bv["args"]["batch_size"] >= 1
     assert bv["args"]["block_until_ready_s"] >= 0
+
+
+def test_block_intake_trace_covers_deliver(net):
+    """A delivered block roots `peer.block_intake` where its frame was
+    received; the signature check, the admission, the gossip forward and
+    the committer fall under it; and it links the orderer's trace of
+    the same block, whose context rode beside the block."""
+    gw = _client(net)
+    try:
+        code, number = gw.submit_transaction(
+            "assets", "create", [b"intake1", b"alice"],
+            commit_timeout_s=60.0)
+    finally:
+        gw.close()
+    assert code == int(ValidationCode.VALID)
+    rec, deadline = None, time.time() + 10
+    while rec is None and time.time() < deadline:
+        for r in tracing.tracer.recorder.list()["recent"]:
+            if r["root"] != "peer.block_intake":
+                continue
+            cand = tracing.tracer.recorder.get(r["trace_id"])
+            root = next(s for s in cand["spans"] if s["parent_id"] is None)
+            if root["attributes"]["block"] == number:
+                rec = cand
+                break
+        else:
+            time.sleep(0.05)
+    assert rec is not None
+    by_name = {s["name"]: s for s in rec["spans"]}
+    assert root["name"] == "peer.block_intake"
+    assert root["attributes"]["txs"] >= 1 and root["attributes"]["bytes"] > 0
+    for child in ("deliver.block_sig", "deliver.admit", "gossip.forward",
+                  "committer.store_block"):
+        assert by_name[child]["parent_id"] == root["span_id"], child
+        assert by_name[child]["start"] >= root["start"]
+    # (a device provider's `bccsp.batch_verify` falls under
+    # `deliver.block_sig` as its ambient child; the software provider's
+    # plain call opens no span)
+    # the frame was received before it was parsed, and the parse is there
+    parse = by_name["wire.parse_block"]
+    assert root["start"] == parse["start"]
+    # the orderer's trace of the block, linked; the followers' writes
+    # join it a beat after the leader's
+    (linked,) = root["attributes"]["links"]
+    deadline = time.time() + 10
+    while time.time() < deadline:
+        orderer = tracing.tracer.recorder.get(linked)
+        names = [s["name"] for s in orderer["spans"]] if orderer else []
+        if names.count("orderer.write") == 3:     # leader + two followers
+            break
+        time.sleep(0.05)
+    assert orderer["root_name"] == "orderer.block"
+    for required in ("orderer.block", "orderer.batch_fill",
+                     "orderer.cut_propose", "orderer.consensus"):
+        assert required in names, (required, names)
+    assert names.count("orderer.write") == 3
+    # and back up: the orderer's block names the request it carried
+    block_root = next(s for s in orderer["spans"]
+                      if s["name"] == "orderer.block")
+    assert len(block_root["attributes"]["back_links"]) >= 1
 
 
 def test_live_trace_over_ops_http(net):
