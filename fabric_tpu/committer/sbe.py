@@ -21,10 +21,9 @@ from __future__ import annotations
 
 from typing import Dict, Optional, Tuple
 
+from fabric_tpu.ledger.statedb import META_SUFFIX
 from fabric_tpu.policy import SignaturePolicy
 from fabric_tpu.utils import serde
-
-META_SUFFIX = "#meta"
 
 
 def meta_namespace(namespace: str) -> str:

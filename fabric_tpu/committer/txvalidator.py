@@ -56,6 +56,13 @@ logger = logging.getLogger("fabric_tpu.committer")
 # reference's per-tx goroutine fan-out (validator.go:194-209).  The
 # pure-Python path below stays as the no-compiler fallback and the
 # differential oracle (tests/test_committer.py).
+#
+# Two tails consume the walk and give the same flags: the DEEP tail
+# (`_begin_deep` / `_finish_deep`: digest -> assemble -> gate, all C, no
+# per-tx Python) and the CLASSIC tail (`_collect_tx_fast` / `_gate_tx`,
+# once a transaction), which alone knows key-level endorsement.  Which
+# one a block takes is `TxValidator._tail_of`'s rule, read off the state
+# and the block — not off how the validator was built.
 try:
     from fabric_tpu.native import load as _load_native
     _fastcollect = _load_native("_fastcollect")
@@ -220,7 +227,7 @@ class TxValidator:
     def __init__(self, channel_id: str, msps: Dict[str, object], provider,
                  policies: PolicyRegistry,
                  ledger_has_txid=None, bundle_source=None,
-                 sbe_lookup=None,
+                 sbe_lookup=None, sbe_state=None,
                  validation_plugin: str = "DefaultValidation",
                  provider_source=None, verify_cache=None,
                  early_abort=None, device_validate=None):
@@ -250,9 +257,18 @@ class TxValidator:
         # key-level endorsement: committed validation-parameter lookup
         # ((ns, key) -> policy bytes), usually sbe.statedb_lookup(statedb)
         self.sbe_lookup = sbe_lookup
+        # ... and the question `_tail_of` asks the same state before
+        # every block: () -> (savepoint, live validation parameters),
+        # usually `statedb.meta_keys`.  A lookup with no such question
+        # beside it may answer for any key: every block stays classic.
+        self.sbe_state = sbe_state
+        # newest block this validator saw write a `#meta` namespace and
+        # that the state's savepoint does not cover yet: until it does,
+        # the state's count cannot say "no parameter" for that block
+        self._meta_block: Optional[int] = None
         # blkstorage-backed duplicate-txid oracle (validator.go dedup vs
         # ledger).  The module-level sentinel (not a fresh lambda) lets
-        # the deep C path detect "unwired" and skip a per-tx Python call.
+        # the deep tail detect "unwired" and skip a per-tx Python call.
         self.ledger_has_txid = ledger_has_txid or _false_oracle
         # (block_number, txid-map) of blocks begun whose txids the
         # ledger oracle cannot see yet: a pipelined driver
@@ -271,12 +287,13 @@ class TxValidator:
         # on txs that lose MVCC anyway
         self.early_abort = early_abort
         # fused device validation (device_validate.DeviceValidator or
-        # None): on the deep path the gate fold AND MVCC run as one
+        # None): on the deep tail the gate fold AND MVCC run as one
         # device dispatch; the prepared UpdateBatch is stashed for the
         # ledger.  A demoted block (hash collision, range query, ...)
         # silently falls back to the host gate below — correctness
-        # never depends on the device path.  Requires sbe_lookup=None
-        # (key-level endorsement keeps the classic host tail).
+        # never depends on the device path.  It runs only on blocks that
+        # take the deep tail; node/peer.py builds its validator with
+        # sbe_lookup=None, so there every block does.
         self.device_validate = device_validate
         # live pipeline-economics window (overlap gauge for the SLO plane)
         self._econ = _PipelineEconomics()
@@ -700,13 +717,28 @@ class TxValidator:
         self._note_coverage(part)
         return verdicts
 
-    def _collected(self, t0: float, num: int, n: int, part) -> float:
-        """Close pass 1: the collect interval into the overlap window
-        and the `validator.collect` span; returns its seconds."""
+    def _collected(self, t0: float, num: int, n: int, part,
+                   tail: str, reason: str) -> float:
+        """Close pass 1: the collect interval into the overlap window,
+        the block's transactions into `validator_tail_total` and the
+        `validator.collect` span; returns its seconds."""
         collect_s = time.perf_counter() - t0
         self._econ.note_collect(t0, t0 + collect_s)
+        try:
+            from fabric_tpu.ops_plane import registry
+            registry.counter(
+                "validator_tail_total",
+                "transactions of the blocks validated, by the tail that "
+                "collected and gated them and why: deep (C, for a block "
+                "key-level endorsement cannot touch) or classic"
+            ).add(n, channel=self.channel_id, tail=tail, reason=reason)
+        except Exception:
+            pass
         n_unique = part.n_hits + part.n_misses
-        attrs = {"block": int(num), "txs": n, "unique_items": n_unique}
+        attrs = {"block": int(num), "txs": n, "unique_items": n_unique,
+                 "tail": tail}
+        if tail == "classic":
+            attrs["reason"] = reason
         if self.verify_cache is not None and n_unique:
             attrs["cache_hits"] = part.n_hits
             attrs["cache_misses"] = part.n_misses - part.n_bypassed
@@ -719,6 +751,54 @@ class TxValidator:
         tracing.tracer.record_span(
             "validator.collect", t0, t0 + collect_s, attributes=attrs)
         return collect_s
+
+    # -- which tail a block takes -------------------------------------------
+
+    def _sbe_enabled(self) -> bool:
+        """Key-level endorsement is a CHANNEL CAPABILITY
+        (common/capabilities/application.go KeyLevelEndorsement): on a
+        channel whose config lacks it, validation parameters are inert
+        and every key falls back to the namespace policy — peers that
+        disagreed on this would produce divergent validity bitmaps."""
+        if self.sbe_lookup is None:
+            return False
+        if self.bundle_source is None:
+            return True
+        from fabric_tpu.config import CAP_KEY_LEVEL_ENDORSEMENT
+        return self.bundle_source.current().has_capability(
+            CAP_KEY_LEVEL_ENDORSEMENT)
+
+    def _tail_of(self, use_sbe: bool) -> Tuple[str, str]:
+        """(tail, reason) for the block about to be collected.  Key-level
+        endorsement can change a flag only where a validation parameter
+        exists that a tx of the block could meet: in the committed state,
+        in a block validated and not yet committed, or in the block
+        itself.  Where none does, `SbeOverlay.policy_for` answers None
+        for every key, both tails evaluate the namespace policies alone,
+        and the deep one does it without per-tx Python.  This reads the
+        first two; the third is the C walk's own count (`_begin_deep`)."""
+        if _fastcollect is None or not hasattr(_fastcollect, "digest"):
+            return "classic", "no_native"
+        if getattr(self, "force_python_collect", False):
+            return "classic", "forced"
+        if not use_sbe:
+            return "deep", "no_sbe"
+        if self.sbe_state is None:
+            return "classic", "state_meta"      # cannot ask: assume some
+        savepoint, live = self.sbe_state()
+        if live:
+            return "classic", "state_meta"
+        pending = self._meta_block
+        if pending is not None:
+            if savepoint is None or savepoint < pending:
+                return "classic", "inflight_meta"
+            # committed, and the state's count held it when read
+            self._meta_block = None
+        return "deep", "no_sbe"
+
+    def _note_meta_block(self, num: int) -> None:
+        if self._meta_block is None or num > self._meta_block:
+            self._meta_block = num
 
     def _begin_inner(self, block: Block) -> dict:
         n = n_txs(block)
@@ -735,18 +815,21 @@ class TxValidator:
 
         doomed = self._doomed_txs(block)
 
-        use_fast = (_fastcollect is not None
-                    and not getattr(self, "force_python_collect", False))
-        if (use_fast and self.sbe_lookup is None
-                and hasattr(_fastcollect, "digest")):
-            # deep native tail: SBE needs the classic tail's per-tx
-            # written-keys bookkeeping, so key-level endorsement keeps
-            # the C-walker + Python-tail path
-            return self._begin_deep(block, num, carry, doomed)
+        t0 = time.perf_counter()
+        use_sbe = self._sbe_enabled()
+        tail, reason = self._tail_of(use_sbe)
+        if tail == "deep":
+            state = self._begin_deep(block, num, carry, doomed, t0, use_sbe)
+            if state is not None:
+                return state
+            # the block itself sets or deletes a parameter, and an
+            # earlier valid tx's governs the later ones: one wasted C
+            # walk, on a block that is rare by nature
+            tail, reason = "classic", "block_meta"
 
         flags = TxFlags(n)
-
-        t0 = time.perf_counter()
+        use_fast = (_fastcollect is not None
+                    and not getattr(self, "force_python_collect", False))
         seen_txids: Dict[str, int] = {}
         items: Dict[VerifyItem, None] = {}   # insertion-ordered dedup set
         works: List[_TxWork] = []
@@ -771,17 +854,19 @@ class TxValidator:
                 n_aborted += 1
             if work is not None:
                 works.append(work)
+        if use_sbe and any(w.meta_writes for w in works):
+            self._note_meta_block(num)
         verify = self._dispatch(list(items))
         self._note_early_aborts(n_aborted)
         self._inflight_txids.append((num, seen_txids))
-        collect_s = self._collected(t0, num, n, verify[0])
+        collect_s = self._collected(t0, num, n, verify[0], tail, reason)
         return {"block": block, "flags": flags, "items": items,
                 "works": works, "verify": verify,
                 "msps": self._msps_snapshot, "seen_txids": seen_txids,
-                "collect_s": collect_s}
+                "collect_s": collect_s, "use_sbe": use_sbe}
 
     def _begin_deep(self, block: Block, num: int, carry: list,
-                    doomed=None) -> dict:
+                    doomed, t0: float, use_sbe: bool) -> Optional[dict]:
         """Deep native pass 1: the C walker consumes its own tuples
         (fastcollect digest/assemble) — txid dedup, creator/endorser
         memo slot assignment, and flat dispatch-ordered VerifyItem
@@ -790,9 +875,10 @@ class TxValidator:
         and launching the block's async device dispatch (`_dispatch`).
         Flag parity with the classic tail and the
         pure-Python mirror is enforced differentially
-        (tests/test_committer.py)."""
+        (tests/test_committer.py).  None, with nothing of the block
+        kept, where the walk found a `#meta` write on a channel with
+        key-level endorsement: that block is the classic tail's."""
         n = n_txs(block)
-        t0 = time.perf_counter()
         oracle = self.ledger_has_txid
         if oracle is _false_oracle:
             oracle = None          # unwired: skip the per-tx call in C
@@ -801,13 +887,16 @@ class TxValidator:
             # zero-copy ingest: the envelopes are consumed as spans of
             # the block's raw wire bytes (protocol/wire.py BlockView) —
             # no per-tx bytes objects ever exist on this path
-            codes, seen_txids, works, creators, endorsers = \
+            codes, seen_txids, works, creators, endorsers, n_meta = \
                 _fastcollect.digest_spans(spans[0], spans[1],
                                           self.channel_id, carry, oracle)
         else:
-            codes, seen_txids, works, creators, endorsers = \
+            codes, seen_txids, works, creators, endorsers, n_meta = \
                 _fastcollect.digest(block.data, self.channel_id, carry,
                                     oracle)
+        if n_meta and use_sbe:
+            self._note_meta_block(num)
+            return None
         if doomed:
             # early abort on the deep path: DROP the work tuple (assemble
             # interns every work's items regardless of its code, and gate
@@ -841,7 +930,7 @@ class TxValidator:
             VerifyItem, SCHEME_P256, self.policies.policy_for, pol_cache)
         verify = self._dispatch(list(index))
         self._inflight_txids.append((num, seen_txids))
-        collect_s = self._collected(t0, num, n, verify[0])
+        collect_s = self._collected(t0, num, n, verify[0], "deep", "no_sbe")
         return {"deep": True, "block": block, "codes": codes,
                 "plans": plans, "items": index, "verify": verify,
                 "msps": self._msps_snapshot, "seen_txids": seen_txids,
@@ -947,17 +1036,7 @@ class TxValidator:
 
         t0 = time.perf_counter()
         from fabric_tpu.committer.sbe import SbeOverlay
-        # key-level endorsement is a CHANNEL CAPABILITY
-        # (common/capabilities/application.go KeyLevelEndorsement): on a
-        # channel whose config lacks it, validation parameters are inert
-        # and every key falls back to the namespace policy — peers that
-        # disagreed on this would produce divergent validity bitmaps.
-        use_sbe = self.sbe_lookup is not None
-        if use_sbe and self.bundle_source is not None:
-            from fabric_tpu.config import CAP_KEY_LEVEL_ENDORSEMENT
-            use_sbe = self.bundle_source.current().has_capability(
-                CAP_KEY_LEVEL_ENDORSEMENT)
-        overlay = SbeOverlay(self.sbe_lookup) if use_sbe else None
+        overlay = SbeOverlay(self.sbe_lookup) if state["use_sbe"] else None
         plugin = self._memoized_plugin({})
         for work in works:
             self._gate_tx(work, flags, verdict, overlay, plugin=plugin)

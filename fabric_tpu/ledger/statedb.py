@@ -59,6 +59,11 @@ _LEN = struct.Struct("<Q")
 SNAPSHOT_EVERY = 256  # batches between checkpoint compactions
 N_SHARDS = 8          # default key-hash stripe width
 
+# key-level endorsement keeps a key's validation parameter as an ordinary
+# versioned write to the companion namespace `<ns>#meta`
+# (committer/sbe.py); the store counts the live ones (`StateDB.meta_keys`)
+META_SUFFIX = "#meta"
+
 _FNV_OFFSET = 0xCBF29CE484222325
 _FNV_PRIME = 0x100000001B3
 _MASK64 = 0xFFFFFFFFFFFFFFFF
@@ -87,18 +92,35 @@ class UpdateBatch:
 
     `preshard` / `items_by_shard` cache the per-shard split so the
     parallel-commit scheduler and the device-validate rebuild can pay
-    the hash cost outside the store's apply lock."""
+    the hash cost outside the store's apply lock.
+
+    `touches_meta` notes whether any namespace staged so far is a
+    key-level-endorsement companion (`<ns>#meta`, committer/sbe.py): the
+    store recounts its validation parameters only for such a batch.  A
+    write pays one set look-up for it, the suffix test runs once a
+    namespace."""
 
     def __init__(self):
         self._updates: Dict[Tuple[str, str], Optional[VersionedValue]] = {}
         self._by_shard = None  # (n_shards, per-shard item lists)
+        self._namespaces: set = set()
+        self.touches_meta = False
+
+    def _note_namespace(self, ns: str) -> None:
+        self._namespaces.add(ns)
+        if ns.endswith(META_SUFFIX):
+            self.touches_meta = True
 
     def put(self, ns: str, key: str, value: bytes, version: Version) -> None:
+        if ns not in self._namespaces:
+            self._note_namespace(ns)
         self._updates[(ns, key)] = VersionedValue(value, version)
         self._by_shard = None
 
     def delete(self, ns: str, key: str, version: Version) -> None:
         # deletes still carry the deleting tx's version (stateleveldb tombstone)
+        if ns not in self._namespaces:
+            self._note_namespace(ns)
         self._updates[(ns, key)] = None
         self._by_shard = None
 
@@ -275,6 +297,9 @@ class StateDB:
         # are created at deploy; here create_index is called at
         # chaincode install, node/peer.py)
         self._index_fields: set = set()
+        # live keys in `<ns>#meta` namespaces: the channel's key-level
+        # validation parameters (`meta_keys`)
+        self._meta_keys = 0
         self._pool: Optional[ThreadPoolExecutor] = None
         self.last_recovery = {"source": "fresh", "wal_blocks": 0,
                               "savepoint": None}
@@ -489,6 +514,32 @@ class StateDB:
     def __len__(self):
         return sum(len(sh.data) for sh in self._shards)
 
+    def meta_keys(self) -> Tuple[Optional[int], int]:
+        """(savepoint, live keys in `<ns>#meta` namespaces): how many
+        key-level validation parameters the committed state holds, and
+        as of which block.  The validator's per-block rule asks this
+        (committer/txvalidator.py): no parameter in state, none in
+        flight, none in the block -> key-level endorsement cannot touch
+        the block.  Takes no lock, so a validator running ahead of a
+        commit never waits for it: `_apply_in_memory` moves the count
+        before the savepoint and this reads the savepoint first, so a
+        savepoint >= n comes with a count that holds block n's batch."""
+        savepoint = self._savepoint
+        return savepoint, self._meta_keys
+
+    def _scan_meta_keys(self) -> int:
+        return sum(1 for sh in self._shards for ns, _key in sh.data
+                   if ns.endswith(META_SUFFIX))
+
+    def _meta_delta(self, batch: UpdateBatch) -> int:
+        """What `batch` will add to the live `#meta` keys, read before
+        it is applied.  Only a batch that `touches_meta` gets here."""
+        delta = 0
+        for (ns, key), vv in batch.items():
+            if ns.endswith(META_SUFFIX):
+                delta += (vv is not None) - (self.get(ns, key) is not None)
+        return delta
+
     @property
     def _data(self) -> Dict[Tuple[str, str], VersionedValue]:
         """Merged read-only view of every shard (flat-store compat for
@@ -545,6 +596,7 @@ class StateDB:
     _HOST_CORES = os.cpu_count() or 1
 
     def _apply_in_memory(self, batch: UpdateBatch, block_num: int) -> None:
+        meta_delta = self._meta_delta(batch) if batch.touches_meta else 0
         per_shard = batch.items_by_shard(self.n_shards)
         busy = [i for i, items in enumerate(per_shard) if items]
         if (self._HOST_CORES > 1 and len(busy) > 1
@@ -558,6 +610,7 @@ class StateDB:
         else:
             for i in busy:
                 self._apply_shard(self._shards[i], per_shard[i])
+        self._meta_keys += meta_delta      # before the savepoint: meta_keys
         self._savepoint = block_num
 
     @classmethod
@@ -774,6 +827,7 @@ class StateDB:
             for sh in self._shards:
                 sh.sorted_keys = sorted(sh.data.keys())
             source = "legacy_snapshot"
+        self._meta_keys = self._scan_meta_keys()   # the WAL's batches add
         wal_blocks = 0
         if os.path.exists(self._wal_path()):
             with open(self._wal_path(), "rb") as f:

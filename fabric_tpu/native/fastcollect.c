@@ -14,8 +14,9 @@
  *            assemble(works, ...) -> per-tx gate plans + flat item table
  *            gate(plans, verdict, codes, ...) -> fold verdicts into flags
  *
- * collect() is the span-splicing walker shared by the legacy consumer
- * tail (txvalidator._collect_tx_fast, still used under SBE); digest/
+ * collect() is the span-splicing walker shared by the classic consumer
+ * tail (txvalidator._collect_tx_fast: the blocks a key-level validation
+ * parameter could touch); digest/
  * assemble/gate are the fully-native tail: txid dedup against a C-side
  * seen-set (plus the pipelined carry window and the ledger oracle),
  * creator/endorser memo SLOT assignment, flat dispatch-ordered
@@ -1101,7 +1102,9 @@ static PyObject *py_collect(PyObject *self, PyObject *args)
  *            txvalidator._gate_tx/_memoized_plugin, no per-tx Python.
  *
  * The Python tail (_collect_tx_fast/_gate_tx) stays as the line-for-line
- * mirror and the SBE path; both must produce bit-identical TxFlags
+ * mirror and the key-level-endorsement path (digest's n_meta is one of
+ * the three things the validator's per-block rule reads); both must
+ * produce bit-identical TxFlags
  * (state-fork invariant).  ValidationCode values are mirrored from
  * protocol/txflags.py below — guarded by the differential tests.
  */
@@ -1171,9 +1174,12 @@ static Py_ssize_t slot_of(PyObject *map, PyObject *list, PyObject *key)
  * Endorsements dedup by endorser bytes per action (policy.go:385-387,
  * first kept) BEFORE slot assignment — exactly the Python tail's
  * seen_idents order.  ns_names = sorted({cc_id} | write ns | meta base)
- * (the non-SBE namespace set; the deep path is only taken without SBE). */
+ * (the namespace set where no key has a validation parameter: the deep
+ * path is taken only for a block key-level endorsement cannot touch, and
+ * *n_meta, the "#meta" writes seen, is how the caller learns that this
+ * block is not one). */
 static PyObject *digest_actions(PyObject *acts, PyObject *emap,
-                                PyObject *endorsers)
+                                PyObject *endorsers, Py_ssize_t *n_meta)
 {
     Py_ssize_t na = PyList_GET_SIZE(acts);
     PyObject *out = PyList_New(na);
@@ -1188,6 +1194,7 @@ static PyObject *digest_actions(PyObject *acts, PyObject *emap,
         PyObject *meta = PyTuple_GET_ITEM(act, 4);
         PyObject *ns_set = NULL, *ns_names = NULL, *eseen = NULL,
                  *ends2 = NULL, *act2 = NULL;
+        *n_meta += PyList_GET_SIZE(meta);
         ns_set = PyDict_New();
         if (!ns_set)
             goto fail;
@@ -1264,13 +1271,17 @@ static PyObject *digest_actions(PyObject *acts, PyObject *emap,
 }
 
 /* digest(envs, channel_id, carry, oracle)
- *   -> (codes: bytearray, seen: {txid: tx_num}, works, creators, endorsers)
+ *   -> (codes: bytearray, seen: {txid: tx_num}, works, creators, endorsers,
+ *       n_meta)
  *
  * codes[i] is the FINAL ValidationCode for structurally-dead txs and
  * VC_NOT_VALIDATED (254) for live works.  works[j] =
  * (tx_num, txtype, creator_slot, payload, pdigest, signature, acts|None);
  * creators/endorsers are first-seen-ordered unique identity bytes whose
- * MSP resolution the Python caller performs once per slot.
+ * MSP resolution the Python caller performs once per slot.  n_meta counts
+ * the writes to "<ns>#meta" namespaces (validation parameters set or
+ * deleted) in the live works: 0 says no tx of this block can change a
+ * key's policy for a later one.
  *
  * Two envelope sources share one implementation: a Python sequence of
  * bytes objects (digest(), the classic entry), or a zero-copy span
@@ -1284,7 +1295,8 @@ static PyObject *digest_impl(PyObject *seq,
 {
     PyObject *carry = NULL, *codes = NULL, *seen = NULL,
              *works = NULL, *creators = NULL, *endorsers = NULL,
-             *cmap = NULL, *emap = NULL, *ret = NULL;
+             *cmap = NULL, *emap = NULL, *ret = NULL, *metao = NULL;
+    Py_ssize_t n_meta = 0;
     carry = PySequence_List(carry_in);
     if (!carry)
         goto done;
@@ -1383,7 +1395,7 @@ static PyObject *digest_impl(PyObject *seq,
             acts2 = Py_None;
             Py_INCREF(acts2);
         } else {
-            acts2 = digest_actions(acts_in, emap, endorsers);
+            acts2 = digest_actions(acts_in, emap, endorsers, &n_meta);
             if (!acts2) { Py_DECREF(rec); goto done; }
         }
         PyObject *work = PyTuple_New(7);
@@ -1411,9 +1423,12 @@ static PyObject *digest_impl(PyObject *seq,
         if (rc < 0)
             goto done;
     }
-    ret = PyTuple_New(5);
+    metao = PyLong_FromSsize_t(n_meta);
+    ret = metao ? PyTuple_New(6) : NULL;
     if (!ret)
         goto done;
+    PyTuple_SET_ITEM(ret, 5, metao);
+    metao = NULL;
     PyTuple_SET_ITEM(ret, 0, codes);
     PyTuple_SET_ITEM(ret, 1, seen);
     PyTuple_SET_ITEM(ret, 2, works);
@@ -1429,6 +1444,7 @@ done:
     Py_XDECREF(endorsers);
     Py_XDECREF(cmap);
     Py_XDECREF(emap);
+    Py_XDECREF(metao);
     return ret;
 }
 

@@ -411,16 +411,21 @@ class PeerChannel:
         provider_source = (bccsp_factory.provider_for_channel
                            if bccsp_factory.get_placement() is not None
                            else None)
-        # device_validate needs the deep C collect tail, which key-level
-        # endorsement (sbe_lookup) disables — enabling the fused path
-        # trades away per-key validation-parameter overrides on this
+        # key-level endorsement reads a key's validation parameter from
+        # the state, and the validator asks the same state before every
+        # block whether it holds any at all (`StateDB.meta_keys`): a
+        # block that no parameter can touch — none in state, none in
+        # flight, none in the block — is collected and gated on the deep
+        # C tail, every other on the classic one; nothing here chooses.
+        # The fused device path runs on the deep tail only, so enabling
+        # it trades away per-key validation-parameter overrides on this
         # peer (README "Device-resident validation")
         sbe = (None if device_validate is not None
                else statedb_lookup(self.ledger.statedb))
         self.validator = TxValidator(
             self.channel_id, None, ch_provider, self.policies,
             bundle_source=self.bundle_source,
-            sbe_lookup=sbe,
+            sbe_lookup=sbe, sbe_state=self.ledger.statedb.meta_keys,
             provider_source=provider_source,
             verify_cache=node.verify_cache,
             early_abort=early_abort,

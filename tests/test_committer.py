@@ -351,19 +351,22 @@ def test_fast_collect_late_error_parity_and_deep_nesting(world):
     assert fast[2] == int(ValidationCode.BAD_PAYLOAD)
 
 
-def test_deep_collect_three_way_differential_fuzz(world):
-    """State-fork invariant fuzz: the deep C tail (digest/assemble/gate),
-    the classic C-walker + Python-tail, and the pure-Python mirror must
-    produce bit-identical TxFlags and item counts over randomized
+def _fuzz_kit(world):
+    """(corpus, run, dup_raw) of the state-fork fuzz: randomized
     adversarial corpora — intra-block txid collisions, carry collisions
     across PIPELINED blocks, ledger-oracle duplicates, unknown-org
     creators, config txs, wrong-channel headers, and non-canonical
-    envelope bytes (truncations, junk, bitflips)."""
-    from fabric_tpu.committer import txvalidator as tv
+    envelope bytes (truncations, junk, bitflips) — and `run(mode, ...)`,
+    which validates two pipelined blocks of them with a validator built
+    for one of four modes: `deep` (no key-level lookup), `classic` (the
+    C walker + the Python tail), `python` (the pure-Python mirror),
+    `node` (built as node/peer.py builds it: the lookup and the state's
+    question, over an empty state)."""
+    from fabric_tpu.committer import sbe, txvalidator as tv
     if tv._fastcollect is None or not hasattr(tv._fastcollect, "digest"):
         pytest.skip("deep native tail unavailable")
-    import random
     from fabric_tpu.bccsp.factory import get_default
+    from fabric_tpu.ledger.statedb import StateDB
     from fabric_tpu.protocol.types import Block, BlockHeader, BlockMetadata
 
     org1, org2, _committer = world
@@ -427,8 +430,13 @@ def test_deep_collect_three_way_differential_fuzz(world):
             return getattr(self._mod, name)
 
     def run(mode, b1raws, b2raws, dup_raw):
+        wiring = {}
+        if mode == "node":
+            state = StateDB()
+            wiring = dict(sbe_lookup=sbe.statedb_lookup(state),
+                          sbe_state=state.meta_keys)
         v = TxValidator("ch", msps, provider, policies,
-                        ledger_has_txid=lambda t: t == led_txid)
+                        ledger_has_txid=lambda t: t == led_txid, **wiring)
         real = tv._fastcollect
         if mode == "python":
             v.force_python_collect = True
@@ -441,6 +449,8 @@ def test_deep_collect_three_way_differential_fuzz(world):
                        list(b2raws) + [dup_raw, led_raw], BlockMetadata())
             s1 = v.validate_begin(b1)
             s2 = v.validate_begin(b2)   # pipelined: b1 carry, not ledger
+            assert (bool(s1.get("deep")) == bool(s2.get("deep"))
+                    == (mode in ("deep", "node")))
             r1 = v.validate_finish(s1)
             r2 = v.validate_finish(s2)
             return (r1.flags.codes(), r2.flags.codes(),
@@ -448,13 +458,26 @@ def test_deep_collect_three_way_differential_fuzz(world):
         finally:
             tv._fastcollect = real
 
-    for seed in (11, 22, 33):
-        rng = random.Random(seed)
-        dup_raw = build.endorser_tx(
+    def dup_raw(seed):
+        return build.endorser_tx(
             "ch", "cc", "1.0", rw(writes=[KVWrite("dup", b"1")]),
             org1.new_identity("dupc"),
             [org1.new_identity("e1"), org2.new_identity("e2")],
             nonce=bytes([seed]) * 20).serialize()
+
+    return corpus, run, dup_raw
+
+
+def test_deep_collect_three_way_differential_fuzz(world):
+    """State-fork invariant fuzz: the deep C tail (digest/assemble/gate),
+    the classic C-walker + Python-tail, and the pure-Python mirror must
+    produce bit-identical TxFlags and item counts over `_fuzz_kit`'s
+    corpora."""
+    import random
+    corpus, run, dup_raw_of = _fuzz_kit(world)
+    for seed in (11, 22, 33):
+        rng = random.Random(seed)
+        dup_raw = dup_raw_of(seed)
         b1raws, b2raws = corpus(rng), corpus(rng)
         deep = run("deep", b1raws, b2raws, dup_raw)
         classic = run("classic", b1raws, b2raws, dup_raw)
@@ -466,6 +489,23 @@ def test_deep_collect_three_way_differential_fuzz(world):
         assert deep[1][len(b2raws)] == int(ValidationCode.DUPLICATE_TXID)
         assert deep[1][len(b2raws) + 1] == \
             int(ValidationCode.DUPLICATE_TXID)
+
+
+@pytest.mark.parametrize("seed", [11, 22, 33, 44])
+def test_node_wired_validator_takes_the_deep_tail_on_the_fuzz(world, seed):
+    """A validator built as node/peer.py builds it — the key-level lookup
+    and the state's count beside it — takes the deep tail on a state and
+    blocks that hold no validation parameter (`run` asserts which tail
+    each mode took), and its flags and item counts are the classic
+    tail's and the pure-Python mirror's."""
+    import random
+    corpus, run, dup_raw_of = _fuzz_kit(world)
+    rng = random.Random(seed)
+    dup_raw = dup_raw_of(seed)
+    b1raws, b2raws = corpus(rng), corpus(rng)
+    node = run("node", b1raws, b2raws, dup_raw)
+    assert node == run("classic", b1raws, b2raws, dup_raw)
+    assert node == run("python", b1raws, b2raws, dup_raw)
 
 
 def test_pipelined_inflight_duplicate_txid(world):

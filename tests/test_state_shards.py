@@ -465,3 +465,96 @@ def test_historydb_checkpoint_parallel_serial_bit_identity(tmp_path):
     re = HistoryDB(root=str(tmp_path / "par"), n_shards=8)
     assert re.last_recovery["source"] != "fresh"
     assert [m.txid for m in re.get_history("cc", "k00007")] == ["tx7"]
+
+
+# ---------------------------------------------------------------------------
+# the count of key-level validation parameters (`StateDB.meta_keys`)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n_shards,seed", [(1, 5), (4, 6), (7, 7)])
+def test_meta_keys_count_equals_a_scan(tmp_path, n_shards, seed):
+    """What the validator's per-block rule asks the state: after any
+    sequence of puts, deletes (of live and of absent keys), checkpoints
+    and recoveries — from the WAL, from a checkpoint, re-striped — the
+    count of live keys in `#meta` namespaces is a brute-force scan's, and
+    a batch notes a `#meta` namespace only when it stages one."""
+    rng = random.Random(seed)
+    root = str(tmp_path / "state")
+    namespaces = ("cc", "cc#meta", "bank#meta", "#meta", "meta", "cc#metal")
+
+    def scan(db):
+        return sum(1 for ns, _k in db._data if ns.endswith("#meta"))
+
+    def random_batch(block, choose_from):
+        batch = UpdateBatch()
+        for _ in range(rng.randrange(1, 12)):
+            ns, key = rng.choice(choose_from), f"k{rng.randrange(8)}"
+            if rng.random() < 0.45:
+                batch.delete(ns, key, Version(block, 0))
+            else:
+                batch.put(ns, key, b"v", Version(block, 0))
+        assert batch.touches_meta == any(
+            ns.endswith("#meta") for (ns, _k), _ in batch.items())
+        return batch
+
+    db = StateDB(root, snapshot_every=5, n_shards=n_shards)
+    seen_nonzero = False
+    for block in range(1, 21):
+        plain = rng.random() < 0.3
+        db.apply_updates(
+            random_batch(block, ("cc", "meta") if plain else namespaces),
+            block)
+        assert db.meta_keys() == (block, scan(db))
+        seen_nonzero |= scan(db) > 0
+    assert seen_nonzero
+    # every parameter deleted: the count is back at zero, and stays there
+    # through a recovery
+    wipe = UpdateBatch()
+    for ns, key in db._data:
+        if ns.endswith("#meta"):
+            wipe.delete(ns, key, Version(21, 0))
+    db.apply_updates(wipe, 21)
+    assert db.meta_keys() == (21, 0)
+    db = StateDB(root, snapshot_every=5, n_shards=n_shards)
+    assert db.meta_keys() == (21, 0)
+    for block in range(22, 60):
+        db.apply_updates(random_batch(block, namespaces), block)
+        if rng.random() < 0.2:
+            db.checkpoint()
+        if rng.random() < 0.3:
+            # WAL alone, checkpoint + WAL, or a re-striped checkpoint
+            restripe = rng.random() < 0.3
+            db = StateDB(root, snapshot_every=5,
+                         n_shards=(n_shards % 7) + 2 if restripe
+                         else n_shards)
+        assert db.meta_keys() == (block, scan(db))
+    assert scan(db) > 0
+
+
+def test_meta_keys_count_moves_before_the_savepoint():
+    """`meta_keys` takes no lock, so that a validator running ahead never
+    waits for a commit; it reads the savepoint, then the count.  That is
+    sound only while an apply moves the count BEFORE the savepoint: at
+    the moment a savepoint becomes visible, the count already holds that
+    block's batch.  Watched here at that very moment, a parameter added
+    every block."""
+    class Watched(StateDB):
+        at_savepoint = []
+
+        @property
+        def _savepoint(self):
+            return self.__dict__.get("savepoint_")
+
+        @_savepoint.setter
+        def _savepoint(self, block):
+            self.__dict__["savepoint_"] = block
+            self.at_savepoint.append((block, getattr(self, "_meta_keys", 0)))
+
+    db = Watched()
+    for block in range(1, 9):
+        batch = UpdateBatch()
+        batch.put("cc", f"k{block}", b"v", Version(block, 0))
+        batch.put("cc#meta", f"k{block}", b"POL", Version(block, 0))
+        db.apply_updates(batch, block)
+        assert db.meta_keys() == (block, block)
+    assert db.at_savepoint == [(None, 0)] + [(b, b) for b in range(1, 9)]

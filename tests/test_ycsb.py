@@ -604,7 +604,8 @@ def test_a_bump_run_exposes_what_the_parent_did_and_the_new_series(
 # twins PR 37 took out: `commit_phase_seconds` (the `ledger.*` spans and
 # `validator_stage_seconds{stage="commit"}` say the same) and
 # `validation_dispatch_seconds` (`validator_stage_seconds{stage=
-# "dispatch"}`)
+# "dispatch"}`), plus PR 38's `validator_tail_total` (which tail each
+# block took, in transactions)
 PARENT_FAMILIES = {
     "commit_graph_apply_batch_size",
     "committed_blocks_total", "committed_txs_total",
@@ -613,4 +614,5 @@ PARENT_FAMILIES = {
     "ledger_state_writes_total", "ledger_tx_total",
     "pipeline_collect_under_verify_frac", "state_checkpoint_height",
     "state_checkpoint_seconds", "state_checkpoint_total", "state_shard_keys",
-    "validation_duration_seconds", "validator_stage_seconds"}
+    "validation_duration_seconds", "validator_stage_seconds",
+    "validator_tail_total"}
