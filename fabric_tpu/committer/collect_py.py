@@ -55,7 +55,7 @@ def _ns_rwset(d, ns_writes: list, meta_writes: list) -> bool:
     # ">= 5" semantics: "#meta" itself is meta with base "" (sbe.py)
     is_meta = ns.endswith("#meta")
     base = ns[:-5] if is_meta else ns
-    keys = []
+    keys, deleted = [], []
     for w in writes:
         if not isinstance(w, dict):
             return False
@@ -76,8 +76,10 @@ def _ns_rwset(d, ns_writes: list, meta_writes: list) -> bool:
                  else (b"" if val is _MISSING else val)))
         else:
             keys.append(k)
+            if is_delete:
+                deleted.append(k)
     if not is_meta:
-        ns_writes.append((ns, tuple(keys)))
+        ns_writes.append((ns, tuple(keys), tuple(deleted)))
     return True
 
 

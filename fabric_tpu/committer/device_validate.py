@@ -268,7 +268,7 @@ class DeviceValidator:
         # the write lanes of final-valid txs, replayed as the serial
         # walk stages them: its UpdateBatch order, its history rows
         batch, history = prepared_from_lanes(
-            table, TxFlags.from_bytes(bytes(final)), num)
+            db, table, TxFlags.from_bytes(bytes(final)), num)
         # pre-split by state shard off the commit lock path; the
         # ledger's apply_updates consumes the cached split
         batch.preshard(getattr(self.statedb, "n_shards", 1))

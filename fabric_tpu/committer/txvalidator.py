@@ -109,6 +109,8 @@ class _TxWork:
     # SBE: base_ns -> written keys; and this tx's metadata updates
     written_keys: Dict[str, Tuple[str, ...]] = field(default_factory=dict)
     meta_writes: List[Tuple] = field(default_factory=list)
+    # (ns, key) of the written keys it deletes: their parameters go too
+    deletes: List[Tuple[str, str]] = field(default_factory=list)
 
 
 def _interval_union(ivals):
@@ -491,10 +493,12 @@ class TxValidator:
         policy_for = self.policies.policy_for
         for cc_id, endorsed, endorsements, ns_writes, meta in actions:
             namespaces = {cc_id}
-            for ns, keys in ns_writes:
+            for ns, keys, deleted in ns_writes:
                 namespaces.add(ns)
                 prev = work.written_keys.get(ns, ())
                 work.written_keys[ns] = prev + tuple(keys)
+                if deleted:
+                    work.deletes.extend((ns, k) for k in deleted)
             for base, k, v in meta:
                 namespaces.add(base)
                 work.meta_writes.append((base, k, v))
@@ -584,12 +588,14 @@ class TxValidator:
                         need_ns_policy = True
                         continue
                     if not plugin(kpol, valid_idents, evaluator):
+                        sbe_overlay.failures += 1
                         flags.set(work.tx_num,
                                   ValidationCode.ENDORSEMENT_POLICY_FAILURE)
                         return
                 for key in meta_keys:
-                    kpol = sbe_overlay.policy_for(ns, key) or pol
-                    if not plugin(kpol, valid_idents, evaluator):
+                    kpol = sbe_overlay.policy_for(ns, key)
+                    if not plugin(kpol or pol, valid_idents, evaluator):
+                        sbe_overlay.failures += kpol is not None
                         flags.set(work.tx_num,
                                   ValidationCode.ENDORSEMENT_POLICY_FAILURE)
                         return
@@ -597,10 +603,11 @@ class TxValidator:
                 flags.set(work.tx_num, ValidationCode.ENDORSEMENT_POLICY_FAILURE)
                 return
         flags.set(work.tx_num, ValidationCode.VALID)
-        if sbe_overlay is not None and work.meta_writes:
+        if sbe_overlay is not None and (work.meta_writes or work.deletes):
             # a VALID tx's metadata updates take effect for later txs in
-            # this block (the reference's intra-block dependency ordering)
-            sbe_overlay.apply_valid_tx(work.meta_writes)
+            # this block (the reference's intra-block dependency
+            # ordering), and a key it deletes loses its parameter
+            sbe_overlay.apply_valid_tx(work.meta_writes, work.deletes)
 
     # -- the block entry point (validator.go:181) ---------------------------
 
@@ -1040,10 +1047,46 @@ class TxValidator:
         plugin = self._memoized_plugin({})
         for work in works:
             self._gate_tx(work, flags, verdict, overlay, plugin=plugin)
-        return self._finished(state, flags, t0, dispatch_s, len(works))
+        return self._finished(state, flags, t0, dispatch_s, len(works),
+                              self._note_sbe(overlay))
+
+    def _note_sbe(self, overlay) -> dict:
+        """What key-level endorsement did in one block's gate, from the
+        overlay's plain ints: into the counters once a block, and back
+        as the `validator.gate` span's attributes."""
+        if overlay is None:
+            return {}
+        try:
+            from fabric_tpu.ops_plane import registry
+            ch = self.channel_id
+            keys = registry.counter(
+                "validator_sbe_keys_total",
+                "written keys and `#meta` keys the classic gate looked a "
+                "policy up for, by what answered: a committed validation "
+                "parameter, none (the namespace policy governs), or the "
+                "block's own updates")
+            keys.add(overlay.by_parameter, channel=ch, judged="parameter")
+            keys.add(overlay.by_namespace, channel=ch, judged="namespace")
+            keys.add(overlay.by_overlay, channel=ch, judged="overlay")
+            registry.counter(
+                "validator_sbe_failures_total",
+                "transactions a key's validation parameter failed"
+            ).add(overlay.failures, channel=ch)
+            registry.counter(
+                "validator_sbe_policies_total",
+                "distinct validation parameters decoded, a block"
+            ).add(overlay.policies, channel=ch)
+        except Exception:
+            pass
+        return {"sbe_keys": (overlay.by_parameter + overlay.by_namespace
+                             + overlay.by_overlay),
+                "sbe_overlay": overlay.by_overlay,
+                "sbe_failures": overlay.failures,
+                "sbe_policies": overlay.policies}
 
     def _finished(self, state: dict, flags: TxFlags, t0: float,
-                  dispatch_s: float, n_gated: int) -> ValidationResult:
+                  dispatch_s: float, n_gated: int,
+                  sbe_attrs: Optional[dict] = None) -> ValidationResult:
         """Close pass 2 for either tail: the gate's span, then the
         flags' way into the block's metadata (a BlockView decodes its
         metadata here), the stage metrics and the log line."""
@@ -1053,8 +1096,8 @@ class TxValidator:
         n_unique = len(state["items"])
         tracing.tracer.record_span(
             "validator.gate", t0, t0 + gate_s,
-            attributes={"block": int(block.header.number),
-                        "txs": n_gated})
+            attributes=dict(sbe_attrs or (), block=int(block.header.number),
+                            txs=n_gated))
         n_refs = state.get("n_refs")
         if n_refs is None:       # the classic tail counts its own here
             n_refs = sum(1 + sum(len(s) for _, _, s in w.namespaces)

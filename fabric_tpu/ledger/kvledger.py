@@ -442,6 +442,14 @@ class KVLedger:
 
         self._observe_apply(len(batch), len(history))
         self._count_block(flags, tally, history, mvcc_attrs["source"])
+        if batch.touches_meta:
+            # only such a batch moves the count: a channel without
+            # key-level endorsement never shows the series
+            from fabric_tpu.ops_plane import registry
+            registry.gauge(
+                "ledger_state_meta_keys", "key-level validation parameters "
+                "the state holds after the block's apply").set(
+                    self.statedb.meta_keys()[1], channel=self.channel_id)
         self.last_stats = stats
         logger.info(
             "[%s] committed block %d: %d/%d valid | validation=%.1fms "

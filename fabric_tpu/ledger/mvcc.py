@@ -12,7 +12,11 @@ rangequery_validator.go.  Semantics preserved exactly:
 - range queries are re-executed against committed-state-merged-with-batch
   and compared read-for-read; a mismatch (changed value version, added or
   removed key) is a PHANTOM_READ_CONFLICT;
-- a valid tx's writes join the batch at Version(block_num, tx_num).
+- a valid tx's writes join the batch at Version(block_num, tx_num);
+- a valid tx's delete of (ns, key) takes the key's validation parameter
+  (`<ns>#meta`, key) with it, at the same version and whatever the rw-set
+  said of `#meta` (committer/sbe.py): upstream keeps a key's metadata in
+  its versioned value, so there the parameter cannot outlive the key.
 
 The verify-then-gate restructure (SURVEY.md §7) does not touch this pass:
 it runs after the TPU verdict bitmap has been folded into the flags.
@@ -55,7 +59,7 @@ from fabric_tpu.protocol import (
 from fabric_tpu.protocol.txflags import TxFlags, ValidationCode
 from fabric_tpu.protocol.types import RangeQueryInfo, TX_ENDORSER
 
-from .statedb import StateDB, UpdateBatch
+from .statedb import META_SUFFIX, StateDB, UpdateBatch
 
 
 def _validate_read(db: StateDB, batch: UpdateBatch, ns: str,
@@ -144,14 +148,16 @@ def extract_rwset(env: Envelope) -> Optional[TxRwSet]:
 
 # -- the two sources ----------------------------------------------------------
 #
-# A source gives (records, committed).  `records` yields, for each tx whose
+# A source gives (records, committed, ident_of).  `records` yields, for each tx whose
 # flag is VALID and that carries a kv rw-set, in block order,
 #   (tx_num, txid, groups, writes)   or   (tx_num, None, None, None)
 # the second where the rw-set does not decode (BAD_RWSET).  `groups` is one
 # (reads, range_queries) pair a namespace, reads being (ident, version) with
 # version None | (block_num, tx_num); `writes` yields (ident, ns, key, value,
-# is_delete).  `ident` names a key within the block, and `committed(ident)`
-# is the version the state holds for it, None when absent.
+# is_delete).  `ident` names a key within the block, `committed(ident)`
+# is the version the state holds for it, None when absent, and
+# `ident_of(ns, key)` is the ident of a key the block may not name at all
+# (None then: nothing in the block can ask for it).
 
 
 def _version_pair(version: Optional[Version]):
@@ -184,7 +190,7 @@ def _envelope_source(db: StateDB, envelopes: List[Envelope], flags: TxFlags):
         vv = db.get(*ident)
         return None if vv is None else _version_pair(vv.version)
 
-    return records(), committed
+    return records(), committed, lambda ns, key: (ns, key)
 
 
 def committed_versions(db: StateDB, key_strs) -> list:
@@ -220,9 +226,23 @@ def _lane_records(table: "wire.LaneTable", flags: TxFlags):
         r0, w0 = r1, w1
 
 
+def _lane_idents(table: "wire.LaneTable"):
+    """ident_of for a lane table: the slot of (ns, key), None where the
+    block names no such key.  The map is built at the first call: only a
+    block that deletes a key pays for it."""
+    slots: dict = {}
+
+    def ident_of(ns: str, key: str):
+        if not slots:
+            slots.update((k, i) for i, k in enumerate(table.key_strs))
+        return slots.get((ns, key))
+    return ident_of
+
+
 def _lane_source(db: StateDB, table: "wire.LaneTable", flags: TxFlags):
     return (_lane_records(table, flags),
-            committed_versions(db, table.key_strs).__getitem__)
+            committed_versions(db, table.key_strs).__getitem__,
+            _lane_idents(table))
 
 
 def lane_source_of(block, flags: TxFlags):
@@ -244,32 +264,53 @@ def lane_source_of(block, flags: TxFlags):
     return table, None
 
 
-def _stage_writes(batch: UpdateBatch, history: list, staged: dict,
-                  block_num: int, tx_num: int, txid: str, writes) -> None:
-    """A valid tx's writes join the batch at Version(block_num, tx_num)."""
+def _stage_writes(db: StateDB, batch: UpdateBatch, history: list,
+                  staged: dict, ident_of, block_num: int, tx_num: int,
+                  txid: str, writes) -> None:
+    """A valid tx's writes join the batch at Version(block_num, tx_num),
+    and each key it deletes loses its validation parameter there too."""
     version = Version(block_num, tx_num)
     pair = (block_num, tx_num)
+    deleted = None
     for ident, ns, key, value, is_delete in writes:
         if is_delete:
             batch.delete(ns, key, version)
             staged[ident] = None
+            if not ns.endswith(META_SUFFIX):
+                deleted = deleted or []
+                deleted.append((ns, key))
         else:
             batch.put(ns, key, value, version)
             staged[ident] = pair
         history.append((tx_num, txid, ns, key, value, is_delete))
+    # after all of the tx's own writes: the delete wins over a parameter
+    # the same rw-set sets.  One look-up a delete; the history index keeps
+    # the writes the rw-sets hold, so the drop is no row of its own (a
+    # replay from stored flags, kvledger._apply_derived, reads the same)
+    for ns, key in deleted or ():
+        meta_ns = ns + META_SUFFIX
+        found, vv = batch.get(meta_ns, key)
+        if (vv if found else db.get(meta_ns, key)) is None:
+            continue                 # no parameter, or dropped already
+        batch.delete(meta_ns, key, version)
+        ident = ident_of(meta_ns, key)
+        if ident is not None:
+            staged[ident] = None
 
 
-def prepared_from_lanes(table: "wire.LaneTable", final: TxFlags,
-                        block_num: int):
+def prepared_from_lanes(db: StateDB, table: "wire.LaneTable",
+                        final: TxFlags, block_num: int):
     """(update_batch, history_writes) of a block whose FINAL flags are
     known (the fused device program's): the write lanes of its valid txs
     replayed in lane order — the put/delete sequence, and therefore the
-    UpdateBatch's order and the history rows, of the walk below."""
+    UpdateBatch's order and the history rows, of the walk below.  `db`
+    is asked for the parameters of the keys the block deletes."""
     batch, history = UpdateBatch(), []
+    ident_of = _lane_idents(table)
     for tx_num, txid, _groups, writes in _lane_records(table, final):
         if writes is not None:
-            _stage_writes(batch, history, {}, block_num, tx_num, txid,
-                          writes)
+            _stage_writes(db, batch, history, {}, ident_of, block_num,
+                          tx_num, txid, writes)
     return batch, history
 
 
@@ -288,9 +329,9 @@ def validate_and_prepare_batch(
     `tally`, when given, takes the reads validated and the conflicts.
     """
     if isinstance(source, wire.LaneTable):
-        records, committed = _lane_source(db, source, flags)
+        records, committed, ident_of = _lane_source(db, source, flags)
     else:
-        records, committed = _envelope_source(db, source, flags)
+        records, committed, ident_of = _envelope_source(db, source, flags)
     batch = UpdateBatch()
     # ident -> version of the writes that valid txs of this block staged
     # so far (None: a staged delete); what `batch` holds, by ident
@@ -327,8 +368,8 @@ def validate_and_prepare_batch(
             if not ok:
                 break
         if ok:
-            _stage_writes(batch, history, staged, block_num, tx_num, txid,
-                          writes)
+            _stage_writes(db, batch, history, staged, ident_of, block_num,
+                          tx_num, txid, writes)
     if tally is not None:
         tally.reads += reads
         tally.conflicts_block += against_block

@@ -41,7 +41,10 @@
  *                       (serde {action, proposal_hash} re-spliced from
  *                        the original encoding by span copy)
  *         endorsements: [(endorser, sig, sha256(endorsed||endorser)), ...]
- *         ns_writes:    [(namespace, (written keys...)), ...]  (non-meta)
+ *         ns_writes:    [(namespace, (written keys...), (deleted keys...)),
+ *                        ...]  (non-meta; the deleted are among the
+ *                        written: a key's validation parameter goes
+ *                        with the key, committer/sbe.py)
  *         meta_writes:  [(base_ns, key, value|None), ...]      ("#meta")
  *
  * SHA-256 uses the x86 SHA extensions when the CPU has them (this host
@@ -534,7 +537,7 @@ static int do_ns_rwset(cur_t *c, PyObject *ns_writes, PyObject *meta_writes)
     /* ">= 5": a namespace that IS exactly "#meta" is meta with base ""
      * (Python endswith + base_namespace slicing semantics, sbe.py) */
     int is_meta = ns_n >= 5 && memcmp(ns_p + ns_n - 5, "#meta", 5) == 0;
-    PyObject *ns_str = NULL, *keys_list = NULL;
+    PyObject *ns_str = NULL, *keys_list = NULL, *dels_list = NULL;
     if (is_meta)
         ns_str = PyUnicode_DecodeUTF8((const char *)ns_p, ns_n - 5, NULL);
     else {
@@ -590,29 +593,35 @@ static int do_ns_rwset(cur_t *c, PyObject *ns_writes, PyObject *meta_writes)
             Py_DECREF(tup);
         } else {
             rc = PyList_Append(keys_list, kstr);
+            if (rc == 0 && is_delete) {
+                if (!dels_list) dels_list = PyList_New(0);
+                rc = dels_list ? PyList_Append(dels_list, kstr) : -1;
+            }
             Py_DECREF(kstr);
         }
     }
     if (rc == 0 && !is_meta) {
         PyObject *keys_tup = PyList_AsTuple(keys_list);
-        if (!keys_tup)
+        /* no delete (nearly every rw-set): the shared empty tuple */
+        PyObject *dels_tup = dels_list ? PyList_AsTuple(dels_list)
+                                       : PyTuple_New(0);
+        PyObject *triple = (keys_tup && dels_tup) ? PyTuple_New(3) : NULL;
+        if (!triple) {
+            Py_XDECREF(keys_tup);
+            Py_XDECREF(dels_tup);
             rc = -1;
-        else {
-            PyObject *pair = PyTuple_New(2);
-            if (!pair) {
-                Py_DECREF(keys_tup);
-                rc = -1;
-            } else {
-                Py_INCREF(ns_str);
-                PyTuple_SET_ITEM(pair, 0, ns_str);
-                PyTuple_SET_ITEM(pair, 1, keys_tup);
-                rc = PyList_Append(ns_writes, pair);
-                Py_DECREF(pair);
-            }
+        } else {
+            Py_INCREF(ns_str);
+            PyTuple_SET_ITEM(triple, 0, ns_str);
+            PyTuple_SET_ITEM(triple, 1, keys_tup);
+            PyTuple_SET_ITEM(triple, 2, dels_tup);
+            rc = PyList_Append(ns_writes, triple);
+            Py_DECREF(triple);
         }
     }
     Py_DECREF(ns_str);
     Py_XDECREF(keys_list);
+    Py_XDECREF(dels_list);
     return rc;
 }
 

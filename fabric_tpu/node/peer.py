@@ -38,7 +38,7 @@ from fabric_tpu.chaincode import (
     LifecyclePolicyProvider,
     SimulationError,
 )
-from fabric_tpu.chaincode import kvstore, smallbank
+from fabric_tpu.chaincode import asset_sbe, kvstore, smallbank
 from fabric_tpu.chaincode.runtime import FuncContract
 from fabric_tpu.comm.rpc import RpcServer, connect
 from fabric_tpu.committer import Committer, TxValidator
@@ -117,7 +117,8 @@ def _asset_contract():
 
 DEV_CONTRACTS = {"asset_demo": _asset_contract,
                  "smallbank": smallbank.contract,
-                 "kvstore": kvstore.contract}
+                 "kvstore": kvstore.contract,
+                 "asset_sbe": asset_sbe.contract}
 
 
 class RemoteDeliver:
