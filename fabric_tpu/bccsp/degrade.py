@@ -38,7 +38,7 @@ import numpy as np
 from fabric_tpu.ops_plane import tracing
 from fabric_tpu.ops_plane.logging import jlog
 
-from .provider import Provider, VerifyItem
+from .provider import Provider, VerifyItem, as_list
 
 logger = logging.getLogger("fabric_tpu.bccsp.degrade")
 
@@ -149,17 +149,34 @@ class DegradingProvider(Provider):
         return 0
 
     def batch_verify_async(self, items: Sequence[VerifyItem]):
-        items = list(items)
+        return self._guarded(as_list(items), self.primary.batch_verify_async,
+                             self.sw.batch_verify_async)
+
+    def batch_verify_packed_async(self, batch):
+        """The packed verb behind the same breaker (owned here:
+        `__getattr__` would hand the table straight to the primary).
+        The software fallback builds the items from the table when, and
+        only when, it is the one that verifies."""
+        packed = getattr(self.primary, "batch_verify_packed_async", None)
+        if packed is None:      # a primary that knows items only
+            return self.batch_verify_async(list(batch))
+        return self._guarded(batch, packed,
+                             self.sw.batch_verify_packed_async)
+
+    def _guarded(self, items, primary, fallback):
+        """One verb — `primary(items)`, in its place `fallback(items)`
+        — behind the breaker; `items` is a list, or a signature table
+        for the packed verb."""
         if not self._use_primary():
-            return self.sw.batch_verify_async(items)
+            return fallback(items)
         fb0 = self._silent_fallbacks()
         try:
-            resolve = self.primary.batch_verify_async(items)
+            resolve = primary(items)
         except Exception as exc:
             self._on_failure("enqueue:" + type(exc).__name__)
             logger.warning("primary bccsp enqueue failed (%r); "
                            "falling back to %s", exc, self.sw.name)
-            return self.sw.batch_verify_async(items)
+            return fallback(items)
 
         def _resolve():
             try:
@@ -169,7 +186,7 @@ class DegradingProvider(Provider):
                 logger.warning("primary bccsp resolve failed (%r); "
                                "re-verifying %d items on %s",
                                exc, len(items), self.sw.name)
-                return self.sw.batch_verify(items)
+                return fallback(items)()
             if self._silent_fallbacks() > fb0:
                 # results are correct (primary already re-ran on its own
                 # sw path) but the device is sick: tell the breaker

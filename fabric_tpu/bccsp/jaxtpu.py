@@ -212,6 +212,7 @@ class _Pending(list):
         self.site = site
         self.t_mark = t_call
         self.span = None             # the batch's bccsp.batch_verify span
+        self.snap0 = None            # the stats at enqueue, span recording
 
 
 @dataclass(frozen=True)
@@ -649,7 +650,7 @@ class JaxTpuProvider(prov.Provider):
     ROWS_CHUNK = int(__import__("os").environ.get(
         "FABRIC_TPU_ROWS_CHUNK", "1024"))
 
-    def _verify_p256(self, items, idxs, pending):
+    def _verify_p256(self, items, idxs, pending, table=None):
         """Two-lane P-256 dispatch: signatures under device-resident (or
         residency-worthy) public keys take the row-grouped fixed-base
         comb kernel in ONE merged dispatch — the key-repetitive
@@ -667,46 +668,63 @@ class JaxTpuProvider(prov.Provider):
         array gathers): per-signature Python work was ~60% of the
         steady-state host time at 40k sigs/block.  The rec-based path
         below remains as the no-compiler fallback and differential
-        oracle."""
-        parse = _parse_der_sigs()
-        if parse is None:
-            return self._verify_p256_recs(items, idxs, pending)
-        n = len(idxs)
-        sigs = [None] * n
-        pays = [None] * n
-        key_ids = np.empty(n, np.int64)
-        pay_ok = np.empty(n, bool)
-        pk_map = {}
-        pks = []
-        for j, i in enumerate(idxs):
-            it = items[i]
-            sigs[j] = it.signature
-            p = it.payload
-            if len(p) == 32:
-                pays[j] = p
-                pay_ok[j] = True
-            else:
-                pays[j] = _ZERO32
-                pay_ok[j] = False
-            gid = pk_map.get(it.pubkey)
-            if gid is None:
-                gid = pk_map[it.pubkey] = len(pks)
-                pks.append(it.pubkey)
-            key_ids[j] = gid
-        ok, rs = parse(sigs)
+        oracle.
+
+        Two entries, one body.  From `items` at `idxs` the four fields
+        are taken apart here, an item at a time, and the signatures
+        parsed in one C call.  A signature `table` (the packed verb:
+        `native/fastcollect.c` SigTable) IS that loop's output — the
+        digests, r || s with its parse flag, the key ids into the
+        block's unique keys and the batch positions, written where the
+        block was walked — and enters below it; `items` and `idxs` are
+        then not looked at.  From `pk_ok` on nothing knows which."""
+        if table is not None:
+            n = table.n_rows
+            ok, rs, digests, pks = (table.ok, table.rs, table.digest,
+                                    table.keys)
+            sig_ok = np.frombuffer(ok, np.uint8).astype(bool)
+            key_ids = np.frombuffer(table.key, np.int32).astype(np.int64)
+            idxs_np = np.frombuffer(table.pos, np.int32).astype(np.int64)
+        else:
+            parse = _parse_der_sigs()
+            if parse is None:
+                return self._verify_p256_recs(items, idxs, pending)
+            n = len(idxs)
+            sigs = [None] * n
+            pays = [None] * n
+            key_ids = np.empty(n, np.int64)
+            pay_ok = np.empty(n, bool)
+            pk_map = {}
+            pks = []
+            for j, i in enumerate(idxs):
+                it = items[i]
+                sigs[j] = it.signature
+                p = it.payload
+                if len(p) == 32:
+                    pays[j] = p
+                    pay_ok[j] = True
+                else:
+                    pays[j] = _ZERO32
+                    pay_ok[j] = False
+                gid = pk_map.get(it.pubkey)
+                if gid is None:
+                    gid = pk_map[it.pubkey] = len(pks)
+                    pks.append(it.pubkey)
+                key_ids[j] = gid
+            ok, rs = parse(sigs)
+            sig_ok = np.frombuffer(ok, np.uint8).astype(bool) & pay_ok
+            digests = b"".join(pays)
+            idxs_np = np.asarray(idxs, np.int64)
         G = len(pks)
         pk_ok = np.empty(G, bool)
         for g, pk in enumerate(pks):
             pk_ok[g] = len(pk) == 65 and pk[0] == 0x04
-        valid = (np.frombuffer(ok, np.uint8).astype(bool)
-                 & pay_ok & pk_ok[key_ids])
+        valid = sig_ok & pk_ok[key_ids]
         self.stats["host_rejects"] += n - int(valid.sum())
         if not valid.any():
             return
         rsw = np.frombuffer(rs, ">u4").reshape(n, 16).astype(np.uint32)
-        ew = np.frombuffer(b"".join(pays), ">u4").reshape(n, 8).astype(
-            np.uint32)
-        idxs_np = np.asarray(idxs, np.int64)
+        ew = np.frombuffer(digests, ">u4").reshape(n, 8).astype(np.uint32)
         counts = np.bincount(key_ids[valid], minlength=G)
         slots = np.full(G, -1, np.int64)
         pinned = set()
@@ -1238,6 +1256,9 @@ class JaxTpuProvider(prov.Provider):
     # Ed25519 the P-256 program is the longer one.  Idemix last: its
     # host-side checks are the dearest of all.
     SCHEME_ORDER = (SCHEME_P256, SCHEME_ED25519, SCHEME_IDEMIX)
+    _VERBS = {SCHEME_P256: "_verify_p256",
+              SCHEME_ED25519: "_verify_ed25519",
+              SCHEME_IDEMIX: "_verify_idemix"}
 
     def batch_verify_async(self, items: Sequence[VerifyItem]):
         """Enqueue device verification and return resolve() -> bool[N].
@@ -1247,50 +1268,95 @@ class JaxTpuProvider(prov.Provider):
         results.  A device failure — at enqueue or at resolve — raises;
         with `degrade` it recomputes the whole batch on the sw provider
         instead (atomic: never a mix of device and sw verdicts)."""
+        items = prov.as_list(items)
+        pending = self._open_batch(len(items))
+        try:
+            by_scheme = {}
+            for i, it in enumerate(items):
+                by_scheme.setdefault(it.scheme, []).append(i)
+            for scheme in self.SCHEME_ORDER:
+                idxs = by_scheme.pop(scheme, None)
+                if idxs:
+                    getattr(self, self._VERBS[scheme])(items, idxs, pending)
+            for idxs in by_scheme.values():      # a scheme nobody verifies
+                self.stats["host_rejects"] += len(idxs)
+        except Exception as exc:
+            return self._enqueue_failed(exc, pending, items)
+        return self._resolver(pending, items)
+
+    def batch_verify_packed_async(self, batch):
+        """The same for a block's signature table (`Provider.
+        batch_verify_packed_async`): the rows enter the P-256 pack as
+        the arrays they are, first, as `SCHEME_ORDER` has it; the items
+        of any other shape in `batch.rest` go scheme by scheme like any
+        batch's, at the positions `batch.rest_pos` gives them.  One
+        `_Pending`, one span, one resolve.  The lanes' programs are
+        called at the depth `batch_verify_async` calls them (see
+        `_dispatched`), so the scheme loop is written out in both."""
+        pending = self._open_batch(len(batch))
+        try:
+            if batch.n_rows:
+                self._verify_p256(None, None, pending, batch)
+            at = np.frombuffer(batch.rest_pos, np.int32).tolist()
+            items = dict(zip(at, batch.rest))       # position -> item
+            by_scheme = {}
+            for i, it in items.items():
+                by_scheme.setdefault(it.scheme, []).append(i)
+            for scheme in self.SCHEME_ORDER:
+                idxs = by_scheme.pop(scheme, None)
+                if idxs:
+                    getattr(self, self._VERBS[scheme])(items, idxs, pending)
+            for idxs in by_scheme.values():      # a scheme nobody verifies
+                self.stats["host_rejects"] += len(idxs)
+        except Exception as exc:
+            return self._enqueue_failed(exc, pending, batch)
+        return self._resolver(pending, batch)
+
+    def _open_batch(self, n: int) -> _Pending:
+        """A batch of `n` items begins: its dispatch list, stamped with
+        who asked and when, and its span."""
         from fabric_tpu.ops_plane import tracing
         pending = _Pending(prov.current_site(), self._clock())
-        items = list(items)
-        verdicts = np.zeros(len(items), dtype=bool)
         # device-time bridge: one span per dispatched batch, started at
         # enqueue on the caller's trace and ended from whichever thread
         # resolves it, carrying batch size, block_until_ready wall time
         # and the cache-hit deltas from stats_snapshot()
         span = tracing.tracer.start_span(
             "bccsp.batch_verify", require_parent=True,
-            attributes={"provider": self.name, "batch_size": len(items)})
-        snap0 = self.stats_snapshot() if span.recording else None
+            attributes={"provider": self.name, "batch_size": n})
         pending.span = span
-        try:
-            by_scheme = {}
-            for i, it in enumerate(items):
-                by_scheme.setdefault(it.scheme, []).append(i)
-            verbs = {SCHEME_P256: self._verify_p256,
-                     SCHEME_ED25519: self._verify_ed25519,
-                     SCHEME_IDEMIX: self._verify_idemix}
-            for scheme in self.SCHEME_ORDER:
-                idxs = by_scheme.pop(scheme, None)
-                if idxs:
-                    verbs[scheme](items, idxs, pending)
-            for idxs in by_scheme.values():      # a scheme nobody verifies
-                self.stats["host_rejects"] += len(idxs)
-        except Exception as exc:
-            if not self.degrade:
+        pending.snap0 = self.stats_snapshot() if span.recording else None
+        return pending
+
+    def _enqueue_failed(self, exc, pending: _Pending, items):
+        """A lane's pack or call raised: raise DeviceError, or with
+        `degrade` verify the whole batch (`items`: a list or a signature
+        table) on the sw provider at resolve."""
+        span = pending.span
+        if not self.degrade:
+            span.end(status="ERROR")
+            raise prov.DeviceError(f"device dispatch failed: {exc!r}") \
+                from exc
+        logger.exception(
+            "TPU dispatch failed; falling back to sw provider")
+        self.stats["fallbacks"] += 1
+        span.set_attribute("fallback", "dispatch")
+
+        def resolve_fallback():
+            try:
+                return self.fallback.batch_verify(prov.as_list(items))
+            finally:
                 span.end(status="ERROR")
-                raise prov.DeviceError(f"device dispatch failed: {exc!r}") \
-                    from exc
-            logger.exception(
-                "TPU dispatch failed; falling back to sw provider")
-            self.stats["fallbacks"] += 1
-            span.set_attribute("fallback", "dispatch")
 
-            def resolve_fallback():
-                try:
-                    return self.fallback.batch_verify(items)
-                finally:
-                    span.end(status="ERROR")
+        return resolve_fallback
 
-            return resolve_fallback
-
+    def _resolver(self, pending: _Pending, items):
+        """resolve() for an enqueued batch: waits for each dispatch,
+        books it, and puts the verdicts at the items' positions."""
+        span = pending.span
+        snap0 = pending.snap0
+        n = len(items)
+        verdicts = np.zeros(n, dtype=bool)
         # in-flight device work between enqueue and resolve (decremented
         # once in resolve, success or fallback)
         try:
@@ -1326,7 +1392,7 @@ class JaxTpuProvider(prov.Provider):
                 span.set_attribute("fallback", "resolve")
                 span.end(status="ERROR")
                 self._drain_queue_depth(len(pending))
-                return self.fallback.batch_verify(items)
+                return self.fallback.batch_verify(prov.as_list(items))
             wall = time.perf_counter() - t0
             self._drain_queue_depth(len(pending))
             if span.recording:
@@ -1359,7 +1425,7 @@ class JaxTpuProvider(prov.Provider):
                     "batch_verify device resolve wait").observe(wall)
                 registry.counter(
                     "provider_device_sigs_total",
-                    "signatures resolved on device").add(len(items))
+                    "signatures resolved on device").add(n)
             except Exception:
                 pass
             return verdicts

@@ -83,6 +83,13 @@ def current_site() -> str:
     return getattr(_site, "name", "other")
 
 
+def as_list(items) -> list:
+    """The batch a verify verb keeps until its resolve: a list is taken
+    as it is — the caller built it for this call and leaves it alone —
+    anything else is copied into one."""
+    return items if isinstance(items, list) else list(items)
+
+
 class DeviceError(RuntimeError):
     """A device provider failed and was not asked to degrade: nothing
     verified the batch in its place.  The original error is the cause."""
@@ -122,7 +129,7 @@ class Provider:
         collection with device compute (SURVEY.md §7 hard-part #3).  The
         default is lazy-but-correct: work happens at resolve()."""
         from fabric_tpu.ops_plane import tracing
-        items = list(items)
+        items = as_list(items)
         span = tracing.tracer.start_span(
             "bccsp.batch_verify", require_parent=True,
             attributes={"provider": self.name, "batch_size": len(items)})
@@ -142,6 +149,20 @@ class Provider:
             return out
 
         return resolve
+
+    def batch_verify_packed_async(self, batch):
+        """`batch_verify_async` for a block's signature table
+        (`native/fastcollect.c` SigTable, what the validator's deep tail
+        collects): a sequence of VerifyItems in dispatch order whose
+        P-256 items exist only as rows of flat buffers — `digest`,
+        `rs`, `ok`, `key` into `keys`, `pos` — with the items of any
+        other shape in `rest` / `rest_pos`.  resolve() -> bool[len(batch)]
+        aligned with the positions.  A device provider packs from the
+        buffers; this default builds the items, once, and verifies them
+        as items.  A wrapper that forwards unknown attributes must own
+        this verb beside `batch_verify_async`, or a table would go round
+        what it wraps the items in."""
+        return self.batch_verify_async(list(batch))
 
     def hash(self, data: bytes, algo: str = HASH_SHA256) -> bytes:
         return hash_payload(data, algo)

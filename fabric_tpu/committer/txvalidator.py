@@ -650,7 +650,7 @@ class TxValidator:
 
     # -- the one verify step (both tails) -----------------------------------
 
-    def _dispatch(self, items: list) -> tuple:
+    def _dispatch(self, items) -> tuple:
         """Partition a block's unique items against the node's verdict
         cache and enqueue the misses on the device, ONE dispatch a
         block.  Never waits for the device; `_await` does.  MAC-verified
@@ -658,10 +658,27 @@ class TxValidator:
         MAC failure, stale epoch — is dispatched (the partition's home:
         verify_plane/cache.py).  A block of more than PROBE items is
         probed first, and where the probe finds the cache silent the
-        rest is dispatched unasked and nothing is stored."""
+        rest is dispatched unasked and nothing is stored.
+
+        `items` is the classic tail's list of VerifyItems or the deep
+        tail's signature table (fastcollect.c SigTable: a sequence of
+        the same items in which a P-256 item exists only as a row of
+        flat buffers until somebody asks for it).  A table goes to the
+        provider AS ARRAYS — `batch_verify_packed_async`, no VerifyItem
+        but the probe's — exactly where today's rule dispatches the
+        whole block unasked: more than PROBE unique items and a silent
+        probe (or no cache wired).  Where the probe is answered, the
+        block is small, or the provider lacks the verb, the items are
+        built and the block goes as items.  -> (partition, thread,
+        holder, (n_arrays, reason)): how many of the block's unique
+        items were handed over as arrays, and why the rest were not."""
         cache = self.verify_cache
         n = len(items)
-        if cache is None or not items:
+        table = None if isinstance(items, list) else items
+        reason = "classic_tail"
+        if table is not None and n <= PROBE:
+            items, table, reason = list(table), None, "small_block"
+        if cache is None or not n:
             part = all_miss(items)
         else:
             t0 = time.perf_counter()
@@ -673,12 +690,24 @@ class TxValidator:
             tracing.tracer.record_span(
                 "validator.cache_filter", t0, time.perf_counter(),
                 attributes={"items": n - part.n_bypassed})
-        if not part.misses:
-            return part, None, {}
-        # items are their OWN dedup keys (VerifyItem NamedTuple)
+        misses = part.misses
+        if table is not None and misses is not table:
+            table, reason = None, "cache_answered"
+        if not misses:
+            return part, None, {}, (0, reason)
+        provider = self._resolve_provider(len(misses))
+        n_arrays = 0
         with dispatch_site("validator"):
-            resolve = self._resolve_provider(
-                len(part.misses)).batch_verify_async(part.misses)
+            packed = _packed_verb(provider) if table is not None else None
+            if packed is not None:
+                # the rows as arrays; what is no row rides beside them
+                n_arrays, reason = table.n_rows, "scheme"
+                resolve = packed(table)
+            else:
+                if table is not None:
+                    misses, reason = list(table), "no_verb"
+                # items are their OWN dedup keys (VerifyItem NamedTuple)
+                resolve = provider.batch_verify_async(misses)
         # EAGER background resolution: a thread blocks on the results
         # the moment the dispatch is enqueued.  The provider's dispatch
         # account times the device by when a waiter that was ALREADY
@@ -698,7 +727,7 @@ class TxValidator:
 
         th = threading.Thread(target=run, daemon=True)
         th.start()
-        return part, th, holder
+        return part, th, holder, (n_arrays, reason)
 
     def _await(self, handle: tuple) -> np.ndarray:
         """Wait for `_dispatch`'s results, store them in the cache and
@@ -707,7 +736,7 @@ class TxValidator:
         inside the caller's dispatch-wait clock, under its own span so
         that the wait is told from it.  A bypassed block stores nothing:
         only the device's work is booked."""
-        part, th, holder = handle
+        part, th, holder, _handoff = handle
         if th is not None:
             th.join()
             if "err" in holder:
@@ -724,26 +753,44 @@ class TxValidator:
         self._note_coverage(part)
         return verdicts
 
-    def _collected(self, t0: float, num: int, n: int, part,
-                   tail: str, reason: str) -> float:
+    def _collected(self, t0: float, num: int, n: int, verify: tuple,
+                   tail: str, reason: str, **parts) -> float:
         """Close pass 1: the collect interval into the overlap window,
-        the block's transactions into `validator_tail_total` and the
-        `validator.collect` span; returns its seconds."""
+        the block's transactions into `validator_tail_total`, its unique
+        items into `validator_handoff_sigs_total` by the form the
+        provider got them in, and the `validator.collect` span (`parts`:
+        further attributes of it); returns its seconds."""
         collect_s = time.perf_counter() - t0
         self._econ.note_collect(t0, t0 + collect_s)
+        part = verify[0]
+        n_arrays, why_items = verify[3]
+        n_unique = part.n_hits + part.n_misses
         try:
             from fabric_tpu.ops_plane import registry
+            ch = self.channel_id
             registry.counter(
                 "validator_tail_total",
                 "transactions of the blocks validated, by the tail that "
                 "collected and gated them and why: deep (C, for a block "
                 "key-level endorsement cannot touch) or classic"
-            ).add(n, channel=self.channel_id, tail=tail, reason=reason)
+            ).add(n, channel=ch, tail=tail, reason=reason)
+            handoff = registry.counter(
+                "validator_handoff_sigs_total",
+                "unique verify items of the blocks validated, by the "
+                "form the provider was handed them in: arrays (rows of "
+                "the deep tail's signature table, no VerifyItem built) "
+                "or items, and why")
+            if n_arrays:
+                handoff.add(n_arrays, channel=ch, form="arrays",
+                            reason="bypassed")
+            if n_unique - n_arrays:
+                handoff.add(n_unique - n_arrays, channel=ch, form="items",
+                            reason=why_items)
         except Exception:
             pass
-        n_unique = part.n_hits + part.n_misses
         attrs = {"block": int(num), "txs": n, "unique_items": n_unique,
-                 "tail": tail}
+                 "tail": tail, "handoff_arrays": n_arrays,
+                 "handoff_items": n_unique - n_arrays, **parts}
         if tail == "classic":
             attrs["reason"] = reason
         if self.verify_cache is not None and n_unique:
@@ -866,7 +913,7 @@ class TxValidator:
         verify = self._dispatch(list(items))
         self._note_early_aborts(n_aborted)
         self._inflight_txids.append((num, seen_txids))
-        collect_s = self._collected(t0, num, n, verify[0], tail, reason)
+        collect_s = self._collected(t0, num, n, verify, tail, reason)
         return {"block": block, "flags": flags, "items": items,
                 "works": works, "verify": verify,
                 "msps": self._msps_snapshot, "seen_txids": seen_txids,
@@ -876,12 +923,13 @@ class TxValidator:
                     doomed, t0: float, use_sbe: bool) -> Optional[dict]:
         """Deep native pass 1: the C walker consumes its own tuples
         (fastcollect digest/assemble) — txid dedup, creator/endorser
-        memo slot assignment, and flat dispatch-ordered VerifyItem
-        interning all run without per-tx Python bytecode.  Python's
-        per-block work shrinks to resolving each UNIQUE identity once
-        and launching the block's async device dispatch (`_dispatch`).
-        Flag parity with the classic tail and the
-        pure-Python mirror is enforced differentially
+        memo slot assignment, and the block's unique signatures written
+        in dispatch order into one table of flat buffers all run
+        without per-tx Python bytecode, and without a Python object a
+        signature.  Python's per-block work shrinks to resolving each
+        UNIQUE identity once and launching the block's async device
+        dispatch (`_dispatch`).  Flag parity with the classic tail and
+        the pure-Python mirror is enforced differentially
         (tests/test_committer.py).  None, with nothing of the block
         kept, where the walk found a `#meta` write on a channel with
         key-level endorsement: that block is the classic tail's."""
@@ -901,6 +949,7 @@ class TxValidator:
             codes, seen_txids, works, creators, endorsers, n_meta = \
                 _fastcollect.digest(block.data, self.channel_id, carry,
                                     oracle)
+        t_walked = time.perf_counter()
         if n_meta and use_sbe:
             self._note_meta_block(num)
             return None
@@ -929,17 +978,26 @@ class TxValidator:
         c_ents = [self._resolve_creator(b) for b in creators]
         e_ents = [self._resolve_endorser(b) for b in endorsers]
 
-        index: Dict[VerifyItem, int] = {}   # item -> dispatch position
+        # the block's unique items in dispatch order: a P-256 item is a
+        # row of the table's flat buffers, not an object
         plans: list = []
         pol_cache: dict = {}
-        n_refs = _fastcollect.assemble(
-            works, c_ents, e_ents, endorsers, codes, index, plans,
+        t_assemble = time.perf_counter()
+        table, n_refs = _fastcollect.assemble(
+            works, c_ents, e_ents, endorsers, codes, plans,
             VerifyItem, SCHEME_P256, self.policies.policy_for, pol_cache)
-        verify = self._dispatch(list(index))
+        t_assembled = time.perf_counter()
+        verify = self._dispatch(table)
         self._inflight_txids.append((num, seen_txids))
-        collect_s = self._collected(t0, num, n, verify[0], "deep", "no_sbe")
+        # collect's parts, on the span: the C walk, assemble, and the
+        # hand-over (the probe + the provider's pack and enqueue)
+        collect_s = self._collected(
+            t0, num, n, verify, "deep", "no_sbe",
+            walk_ms=round((t_walked - t0) * 1e3, 3),
+            assemble_ms=round((t_assembled - t_assemble) * 1e3, 3),
+            handover_ms=round((time.perf_counter() - t_assembled) * 1e3, 3))
         return {"deep": True, "block": block, "codes": codes,
-                "plans": plans, "items": index, "verify": verify,
+                "plans": plans, "items": table, "verify": verify,
                 "msps": self._msps_snapshot, "seen_txids": seen_txids,
                 "collect_s": collect_s, "n_refs": n_refs}
 
@@ -1000,7 +1058,7 @@ class TxValidator:
         index = state["items"]
 
         t0 = time.perf_counter()
-        # positional over `index`, as gate and the fused path read it
+        # positional over the table, as gate and the fused path read it
         verdict = self._await(state["verify"]).view(np.uint8)
         dispatch_s = time.perf_counter() - t0
         tracing.tracer.record_span(
@@ -1127,6 +1185,17 @@ def _memo_ent(ident: Identity) -> tuple:
     if scheme in (SCHEME_P256, SCHEME_ED25519):
         return ident, ident._pub_wire, scheme
     return ident, None, None
+
+
+def _packed_verb(provider):
+    """The provider's `batch_verify_packed_async`, or None where it
+    knows items only: it has no such verb, or its item verb was replaced
+    on the instance (a fault injection — the benchmark's controls answer
+    yes from a `batch_verify_async` of their own), and the class's
+    packed verb would go round the replacement."""
+    if "batch_verify_async" in getattr(provider, "__dict__", ()):
+        return None
+    return getattr(provider, "batch_verify_packed_async", None)
 
 
 def _false_oracle(_txid: str) -> bool:

@@ -11,7 +11,10 @@
  *
  * Exported:  collect(envs: sequence[bytes], channel_id: str) -> list
  *            digest(envs, channel_id, carry, oracle) -> digested pass 1
- *            assemble(works, ...) -> per-tx gate plans + flat item table
+ *            assemble(works, ...) -> per-tx gate plans + the signature
+ *                                    table (SigTable: P-256 items as
+ *                                    flat buffers, no object an item)
+ *            pack_items(items, ...) -> the same table from VerifyItems
  *            gate(plans, verdict, codes, ...) -> fold verdicts into flags
  *
  * collect() is the span-splicing walker shared by the classic consumer
@@ -19,9 +22,10 @@
  * parameter could touch); digest/
  * assemble/gate are the fully-native tail: txid dedup against a C-side
  * seen-set (plus the pipelined carry window and the ledger oracle),
- * creator/endorser memo SLOT assignment, flat dispatch-ordered
- * VerifyItem interning, and a verdict-bitmap gate that never runs a
- * per-tx Python loop.  The no-compiler mirror for ALL of it is
+ * creator/endorser memo SLOT assignment, the block's unique signatures
+ * written in dispatch order into one table of flat buffers, and a
+ * verdict-bitmap gate that never runs a per-tx Python loop.  The
+ * no-compiler mirror for ALL of it is
  * committer/collect_py.py + the Python tail/gate in txvalidator.py —
  * the two paths must produce bit-identical TxFlags (state-fork
  * invariant, tested differentially in tests/test_committer.py).
@@ -54,6 +58,8 @@
 
 #define PY_SSIZE_T_CLEAN
 #include <Python.h>
+#include <structmember.h>
+#include <stddef.h>
 #include <stdint.h>
 #include <string.h>
 
@@ -1102,10 +1108,11 @@ static PyObject *py_collect(PyObject *self, PyObject *args)
  *            deserialize + chain validation) instead of running a ~10k
  *            iteration bytecode loop per block.
  * assemble() turns digested works + resolved identity slots into the
- *            flat dispatch-ordered VerifyItem table (interning with a
- *            cheap plain-tuple probe — tuples hash/compare equal to the
- *            VerifyItem NamedTuple, so only FIRST occurrences pay the
- *            namedtuple construction) and per-tx gate plans.
+ *            block's signature table (SigTable below: every P-256 item
+ *            a row of flat buffers — digest, r||s, key index, dispatch
+ *            position — deduplicated in C, no VerifyItem and no
+ *            container an item; items of another shape interned as
+ *            VerifyItems beside it) and per-tx gate plans.
  * gate()     folds the device verdict bitmap into final ValidationCodes
  *            with the same memoized policy-evaluation semantics as
  *            txvalidator._gate_tx/_memoized_plugin, no per-tx Python.
@@ -1511,90 +1518,419 @@ static PyObject *py_digest_spans(PyObject *self, PyObject *args)
     return ret;
 }
 
-/* VerifyItem interning.  index maps item -> dispatch position; for
- * an item that is its four plain fields (P-256 over the walker's
- * digest, Ed25519 over the message itself) we probe with a plain
- * 4-tuple FIRST (a tuple hashes and compares equal to the NamedTuple
- * with the same fields) so repeats — the overwhelmingly common case on
- * real blocks — never construct the NamedTuple at all, and nothing is
- * called in Python for one.  Stored keys must be real VerifyItems
- * because the dispatch path reads .scheme/.pubkey attributes off
- * them. */
-static Py_ssize_t intern_fields(PyObject *index, PyObject *cls,
-                                PyObject *scheme, PyObject *wire,
-                                PyObject *sig, PyObject *payload)
+/* The signature table: what the deep tail hands the provider.
+ *
+ * A block's unique verify items in dispatch order, WITHOUT a Python
+ * object an item.  Every item that is P-256's four plain fields (the
+ * scheme, a public key's wire bytes, a DER signature, the walker's
+ * 32-byte SHA-256) is one ROW of five flat buffers:
+ *
+ *   digest u8[n,32]   the digest the signature is over
+ *   rs     u8[n,64]   r32be || s32be, the DER parsed where it is read
+ *                     (der_sig64: parse_der_sigs' own rule); zeros and
+ *   ok     u8[n]      0 where it does not parse
+ *   key    i32[n]     index into `keys`, the block's unique public keys
+ *   pos    i32[n]     the item's dispatch position, ascending
+ *
+ * and an item of any other shape (Ed25519 over the message, idemix, a
+ * digest that is not 32 bytes) stays a VerifyItem in the short list
+ * `rest`, its position in `rest_pos` (i32, ascending).  Positions run
+ * over both, 0 .. len(table)-1, in the order the Python tail would
+ * intern the items: the verdicts come back aligned with them.
+ *
+ * Dedup is exact and lives here: rows in an open-addressed set keyed on
+ * the digest's first 8 bytes mixed with the signature's hash (the
+ * interpreter's keyed one, cached on the bytes object: a block of equal
+ * digests under crafted signatures cannot chain the probes), a full
+ * compare — key, digest, signature bytes — on a match; the rest in a
+ * dict, as the whole block was before.  The table IS a sequence of
+ * VerifyItems: len(), table[i] and iteration build the item at a
+ * position on demand (`cls(scheme, key, signature, digest)`), which is
+ * how the verdict cache's probe reads 256 of them and how a provider
+ * without the packed verb gets them all.  Built by assemble() and by
+ * pack_items(); never from Python. */
+
+typedef struct {
+    PyObject_HEAD
+    Py_ssize_t n_rows;          /* rows written */
+    Py_ssize_t cap;             /* rows the buffers hold until sealed */
+    Py_ssize_t n_pos;           /* positions given out: rows + rest */
+    PyObject *digest, *rs, *ok, *key, *pos;     /* bytes, see above */
+    PyObject *keys;             /* list of wire bytes */
+    PyObject *rest, *rest_pos;  /* list of items; bytes i32[len(rest)]:
+                                 * restmap's keys and values, when sealed */
+    PyObject *keymap;           /* wire -> key id; dropped when sealed */
+    PyObject *restmap;          /* item -> position; dropped when sealed */
+    PyObject **sig, **pay;      /* a row's signature and digest objects */
+    PyObject *cls, *scheme;     /* VerifyItem, SCHEME_P256 */
+    int32_t *set;               /* dedup set of rows; freed when sealed */
+    size_t mask;
+} SigTable;
+
+static PyTypeObject SigTableType;
+
+static int der_sig64(const uint8_t *p, Py_ssize_t sn, uint8_t out[64]);
+
+static void table_dealloc(SigTable *t)
+{
+    for (Py_ssize_t i = 0; t->sig && i < t->n_rows; i++) {
+        Py_DECREF(t->sig[i]);
+        Py_DECREF(t->pay[i]);
+    }
+    PyMem_Free(t->sig);
+    PyMem_Free(t->pay);
+    PyMem_Free(t->set);
+    Py_XDECREF(t->digest); Py_XDECREF(t->rs); Py_XDECREF(t->ok);
+    Py_XDECREF(t->key); Py_XDECREF(t->pos); Py_XDECREF(t->keys);
+    Py_XDECREF(t->rest); Py_XDECREF(t->rest_pos);
+    Py_XDECREF(t->keymap); Py_XDECREF(t->restmap);
+    Py_XDECREF(t->cls); Py_XDECREF(t->scheme);
+    Py_TYPE(t)->tp_free((PyObject *)t);
+}
+
+/* an empty table with room for `cap` rows */
+static SigTable *table_new(Py_ssize_t cap, PyObject *cls, PyObject *scheme)
+{
+    SigTable *t = PyObject_New(SigTable, &SigTableType);
+    if (!t)
+        return NULL;
+    memset((char *)t + sizeof(PyObject), 0,
+           sizeof(SigTable) - sizeof(PyObject));
+    Py_INCREF(cls);
+    t->cls = cls;
+    Py_INCREF(scheme);
+    t->scheme = scheme;
+    t->cap = cap;
+    size_t slots = 64;
+    while (slots < 2 * (size_t)cap)
+        slots <<= 1;
+    t->mask = slots - 1;
+    t->digest = PyBytes_FromStringAndSize(NULL, cap * 32);
+    t->rs = PyBytes_FromStringAndSize(NULL, cap * 64);
+    t->ok = PyBytes_FromStringAndSize(NULL, cap);
+    t->key = PyBytes_FromStringAndSize(NULL, cap * 4);
+    t->pos = PyBytes_FromStringAndSize(NULL, cap * 4);
+    t->keys = PyList_New(0);
+    t->keymap = PyDict_New();
+    t->restmap = PyDict_New();
+    t->sig = PyMem_Malloc((size_t)(cap ? cap : 1) * sizeof(PyObject *));
+    t->pay = PyMem_Malloc((size_t)(cap ? cap : 1) * sizeof(PyObject *));
+    t->set = PyMem_Malloc(slots * sizeof(int32_t));
+    if (!t->digest || !t->rs || !t->ok || !t->key || !t->pos || !t->keys
+        || !t->keymap || !t->restmap || !t->sig || !t->pay || !t->set) {
+        Py_DECREF(t);
+        PyErr_NoMemory();
+        return NULL;
+    }
+    memset(t->set, 0xff, slots * sizeof(int32_t));      /* -1: empty */
+    return t;
+}
+
+/* is (signature, payload) a row's: bytes over a 32-byte digest */
+static inline int row_shaped(PyObject *sig, PyObject *pay)
+{
+    return PyBytes_Check(sig) && PyBytes_Check(pay)
+        && PyBytes_GET_SIZE(pay) == 32;
+}
+
+/* The position of the row (key `kid`, `sig`, digest `pay`), written on
+ * first sight.  The caller has checked row_shaped() and that the table
+ * has room.  -1 with an exception set. */
+static Py_ssize_t table_add(SigTable *t, Py_ssize_t kid, PyObject *sig,
+                            PyObject *pay)
+{
+    const uint8_t *dg = (const uint8_t *)PyBytes_AS_STRING(pay);
+    const uint8_t *sp = (const uint8_t *)PyBytes_AS_STRING(sig);
+    Py_ssize_t sn = PyBytes_GET_SIZE(sig);
+    uint8_t *dgv = (uint8_t *)PyBytes_AS_STRING(t->digest);
+    int32_t *keyv = (int32_t *)PyBytes_AS_STRING(t->key);
+    int32_t *posv = (int32_t *)PyBytes_AS_STRING(t->pos);
+    Py_hash_t sh = PyObject_Hash(sig);
+    if (sh == -1 && PyErr_Occurred())
+        return -1;
+    uint64_t h;
+    memcpy(&h, dg, 8);
+    h ^= ((uint64_t)sh + (uint64_t)kid) * 0x9E3779B97F4A7C15ull;
+    h ^= h >> 32;
+    size_t i = (size_t)h & t->mask;
+    for (;; i = (i + 1) & t->mask) {
+        int32_t r = t->set[i];
+        if (r < 0)
+            break;
+        if (keyv[r] == (int32_t)kid && !memcmp(dgv + 32 * r, dg, 32)
+            && (t->sig[r] == sig
+                || (PyBytes_GET_SIZE(t->sig[r]) == sn
+                    && !memcmp(PyBytes_AS_STRING(t->sig[r]), sp,
+                               (size_t)sn))))
+            return posv[r];
+    }
+    Py_ssize_t r = t->n_rows;
+    if (r >= t->cap) {
+        PyErr_SetString(PyExc_SystemError, "signature table overrun");
+        return -1;
+    }
+    uint8_t *rsv = (uint8_t *)PyBytes_AS_STRING(t->rs) + 64 * r;
+    memcpy(dgv + 32 * r, dg, 32);
+    ((uint8_t *)PyBytes_AS_STRING(t->ok))[r] =
+        (uint8_t)der_sig64(sp, sn, rsv);
+    keyv[r] = (int32_t)kid;
+    posv[r] = (int32_t)t->n_pos;
+    Py_INCREF(sig);
+    t->sig[r] = sig;
+    Py_INCREF(pay);
+    t->pay[r] = pay;
+    t->set[i] = (int32_t)r;
+    t->n_rows = r + 1;
+    return t->n_pos++;
+}
+
+/* The position of an item that is no row, interned on first sight. */
+static Py_ssize_t table_add_item(SigTable *t, PyObject *item)
+{
+    PyObject *v = PyDict_GetItemWithError(t->restmap, item);
+    if (v)
+        return PyLong_AsSsize_t(v);
+    if (PyErr_Occurred())
+        return -1;
+    PyObject *iv = PyLong_FromSsize_t(t->n_pos);
+    int rc = iv ? PyDict_SetItem(t->restmap, item, iv) : -1;
+    Py_XDECREF(iv);
+    return rc < 0 ? -1 : t->n_pos++;
+}
+
+/* The same for an item that is four plain fields of another shape
+ * (Ed25519 over the message itself): probed with a plain 4-tuple FIRST
+ * (a tuple hashes and compares equal to the NamedTuple of the same
+ * fields), so a repeat constructs nothing and calls nothing in Python. */
+static Py_ssize_t table_add_fields(SigTable *t, PyObject *scheme,
+                                   PyObject *wire, PyObject *sig,
+                                   PyObject *payload)
 {
     PyObject *probe = PyTuple_Pack(4, scheme, wire, sig, payload);
     if (!probe)
         return -1;
-    PyObject *v = PyDict_GetItemWithError(index, probe);
+    PyObject *v = PyDict_GetItemWithError(t->restmap, probe);
     if (v) {
         Py_DECREF(probe);
         return PyLong_AsSsize_t(v);
     }
     if (PyErr_Occurred()) { Py_DECREF(probe); return -1; }
-    PyObject *item = PyObject_CallObject(cls, probe);
+    PyObject *item = PyObject_CallObject(t->cls, probe);
     Py_DECREF(probe);
     if (!item)
         return -1;
-    Py_ssize_t idx = PyDict_GET_SIZE(index);
-    PyObject *iv = PyLong_FromSsize_t(idx);
-    int rc = iv ? PyDict_SetItem(index, item, iv) : -1;
-    Py_XDECREF(iv);
+    Py_ssize_t idx = table_add_item(t, item);
     Py_DECREF(item);
-    return rc < 0 ? -1 : idx;
+    return idx;
 }
 
-/* an identity that shapes its own item (idemix): plain intern */
-static Py_ssize_t intern_item(PyObject *index, PyObject *item)
+/* Close a built table: the buffers cut to the rows written, the rest
+ * and its positions read off the dict that interned it (insertion
+ * order = ascending positions), what only the build needed let go. */
+static int table_seal(SigTable *t)
 {
-    PyObject *v = PyDict_GetItemWithError(index, item);
-    if (v)
-        return PyLong_AsSsize_t(v);
-    if (PyErr_Occurred())
+    Py_ssize_t n = t->n_rows, nr = PyDict_GET_SIZE(t->restmap);
+    t->rest = PyList_New(nr);
+    t->rest_pos = PyBytes_FromStringAndSize(NULL, nr * 4);
+    if (!t->rest || !t->rest_pos)
         return -1;
-    Py_ssize_t idx = PyDict_GET_SIZE(index);
-    PyObject *iv = PyLong_FromSsize_t(idx);
-    if (!iv)
+    int32_t *posv = (int32_t *)PyBytes_AS_STRING(t->rest_pos);
+    PyObject *item, *at;
+    for (Py_ssize_t i = 0, it = 0; PyDict_Next(t->restmap, &it, &item, &at);
+         i++) {
+        Py_INCREF(item);
+        PyList_SET_ITEM(t->rest, i, item);
+        posv[i] = (int32_t)PyLong_AsSsize_t(at);
+    }
+    if (n < t->cap
+        && (_PyBytes_Resize(&t->digest, n * 32) < 0
+            || _PyBytes_Resize(&t->rs, n * 64) < 0
+            || _PyBytes_Resize(&t->ok, n) < 0
+            || _PyBytes_Resize(&t->key, n * 4) < 0
+            || _PyBytes_Resize(&t->pos, n * 4) < 0))
         return -1;
-    int rc = PyDict_SetItem(index, item, iv);
-    Py_DECREF(iv);
-    return rc < 0 ? -1 : idx;
+    t->cap = n;
+    PyMem_Free(t->set);
+    t->set = NULL;
+    Py_CLEAR(t->keymap);
+    Py_CLEAR(t->restmap);
+    return 0;
 }
 
-/* assemble(works, c_ents, e_ents, endorsers, codes, index, plans,
- *          verify_item_cls, scheme_p256, policy_for, pol_cache) -> n_refs
- *
- * c_ents/e_ents: per-slot (identity, pub_wire|None, scheme|None) or
- * None for identities the MSP rejected.  With a pub_wire the item is
- * its four plain fields and is interned here: over the walker's
- * SHA-256 digest where the scheme is `scheme_p256`, over the message
- * itself otherwise (Ed25519 signs the message: payload for a creator,
- * endorsed || endorser for an endorsement).  Without one the identity
- * is asked (`verify_item`: idemix).  Appends to `plans`
- * (tx_num, creator_idx, [(policy, [(item_idx, identity)...])...]) and
- * interns items into `index` in EXACTLY the Python tail's order:
- * creator first, then each action's endorsements, then that action's
- * namespace policy lookups (a missing policy kills the tx but keeps
- * already-interned items — n_unique_items parity).  n_refs counts
- * 1 + sigset size per namespace entry over SURVIVING works only,
- * matching _finish_inner's accounting. */
-static PyObject *py_assemble(PyObject *self, PyObject *args)
+static Py_ssize_t table_len(PyObject *self)
 {
-    (void)self;
-    PyObject *works, *c_ents, *e_ents, *endorsers, *codes, *index,
-             *plans, *cls, *scheme, *policy_for, *pol_cache;
-    if (!PyArg_ParseTuple(args, "OOOOOOOOOOO", &works, &c_ents, &e_ents,
-                          &endorsers, &codes, &index, &plans, &cls,
-                          &scheme, &policy_for, &pol_cache))
-        return NULL;
-    if (!PyList_Check(works) || !PyList_Check(c_ents)
-        || !PyList_Check(e_ents) || !PyList_Check(endorsers)
-        || !PyByteArray_Check(codes) || !PyDict_Check(index)
-        || !PyList_Check(plans) || !PyDict_Check(pol_cache)) {
-        PyErr_SetString(PyExc_TypeError, "assemble(): bad argument types");
+    return ((SigTable *)self)->n_pos;
+}
+
+/* index of `want` in the ascending i32 vector, or -1 */
+static Py_ssize_t find_pos(const int32_t *v, Py_ssize_t n, Py_ssize_t want)
+{
+    Py_ssize_t lo = 0, hi = n;
+    while (lo < hi) {
+        Py_ssize_t mid = lo + (hi - lo) / 2;
+        if (v[mid] < want)
+            lo = mid + 1;
+        else
+            hi = mid;
+    }
+    return (lo < n && v[lo] == want) ? lo : -1;
+}
+
+/* table[i]: the VerifyItem at dispatch position i — a row's is built
+ * here, one of the rest is the one kept */
+static PyObject *table_item(PyObject *self, Py_ssize_t i)
+{
+    SigTable *t = (SigTable *)self;
+    if (i < 0 || i >= t->n_pos || !t->rest_pos) {
+        PyErr_SetString(PyExc_IndexError, "signature table position");
         return NULL;
     }
+    Py_ssize_t r = find_pos((const int32_t *)PyBytes_AS_STRING(t->pos),
+                            t->n_rows, i);
+    if (r >= 0) {
+        int32_t kid = ((const int32_t *)PyBytes_AS_STRING(t->key))[r];
+        return PyObject_CallFunctionObjArgs(
+            t->cls, t->scheme, PyList_GET_ITEM(t->keys, kid), t->sig[r],
+            t->pay[r], NULL);
+    }
+    r = find_pos((const int32_t *)PyBytes_AS_STRING(t->rest_pos),
+                 PyList_GET_SIZE(t->rest), i);
+    if (r < 0) {
+        PyErr_SetString(PyExc_SystemError, "signature table position lost");
+        return NULL;
+    }
+    PyObject *it = PyList_GET_ITEM(t->rest, r);
+    Py_INCREF(it);
+    return it;
+}
+
+static PySequenceMethods table_as_sequence = {
+    .sq_length = table_len,
+    .sq_item = table_item,
+};
+
+static PyMemberDef table_members[] = {
+    {"n_rows", T_PYSSIZET, offsetof(SigTable, n_rows), READONLY,
+     "rows: the items handed over as arrays"},
+    {"digest", T_OBJECT_EX, offsetof(SigTable, digest), READONLY,
+     "u8[n_rows,32]"},
+    {"rs", T_OBJECT_EX, offsetof(SigTable, rs), READONLY,
+     "u8[n_rows,64]: r32be || s32be"},
+    {"ok", T_OBJECT_EX, offsetof(SigTable, ok), READONLY,
+     "u8[n_rows]: the DER signature parsed"},
+    {"key", T_OBJECT_EX, offsetof(SigTable, key), READONLY,
+     "i32[n_rows]: index into keys"},
+    {"pos", T_OBJECT_EX, offsetof(SigTable, pos), READONLY,
+     "i32[n_rows]: dispatch positions, ascending"},
+    {"keys", T_OBJECT_EX, offsetof(SigTable, keys), READONLY,
+     "the rows' unique public keys, wire bytes"},
+    {"rest", T_OBJECT_EX, offsetof(SigTable, rest), READONLY,
+     "the items that are no row, VerifyItems"},
+    {"rest_pos", T_OBJECT_EX, offsetof(SigTable, rest_pos), READONLY,
+     "i32[len(rest)]: their dispatch positions, ascending"},
+    {NULL, 0, 0, 0, NULL}};
+
+static PyTypeObject SigTableType = {
+    PyVarObject_HEAD_INIT(NULL, 0)
+    .tp_name = "_fastcollect.SigTable",
+    .tp_basicsize = sizeof(SigTable),
+    .tp_dealloc = (destructor)table_dealloc,
+    .tp_as_sequence = &table_as_sequence,
+    .tp_flags = Py_TPFLAGS_DEFAULT | Py_TPFLAGS_DISALLOW_INSTANTIATION,
+    .tp_doc = "a block's unique verify items: P-256 rows as flat "
+              "buffers, the rest as VerifyItems; a sequence of "
+              "VerifyItems in dispatch order",
+    .tp_members = table_members,
+};
+
+/* pack_items(items, verify_item_cls, scheme_p256) -> SigTable
+ *
+ * The table of a sequence of VerifyItems, for a caller that has items
+ * and wants the packed verb (and for the provider's differential
+ * tests): an item that is a 4-tuple of `scheme_p256`, bytes, bytes and
+ * a 32-byte digest becomes a row, any other stays itself.  Equal items
+ * are one, at the first one's position. */
+static PyObject *py_pack_items(PyObject *self, PyObject *args)
+{
+    (void)self;
+    PyObject *items, *cls, *scheme;
+    if (!PyArg_ParseTuple(args, "OOO", &items, &cls, &scheme))
+        return NULL;
+    PyObject *seq = PySequence_Fast(items, "pack_items needs a sequence");
+    if (!seq)
+        return NULL;
+    Py_ssize_t n = PySequence_Fast_GET_SIZE(seq);
+    SigTable *t = table_new(n, cls, scheme);
+    int fail = t == NULL;
+    for (Py_ssize_t i = 0; !fail && i < n; i++) {
+        PyObject *it = PySequence_Fast_GET_ITEM(seq, i);
+        Py_ssize_t at;
+        int row = PyTuple_Check(it) && PyTuple_GET_SIZE(it) == 4
+            && PyBytes_Check(PyTuple_GET_ITEM(it, 1))
+            && row_shaped(PyTuple_GET_ITEM(it, 2), PyTuple_GET_ITEM(it, 3));
+        if (row)
+            row = PyObject_RichCompareBool(PyTuple_GET_ITEM(it, 0), scheme,
+                                           Py_EQ);
+        if (row < 0) {
+            at = -1;
+        } else if (row) {
+            at = slot_of(t->keymap, t->keys, PyTuple_GET_ITEM(it, 1));
+            if (at >= 0)
+                at = table_add(t, at, PyTuple_GET_ITEM(it, 2),
+                               PyTuple_GET_ITEM(it, 3));
+        } else {
+            at = table_add_item(t, it);
+        }
+        fail = at < 0;
+    }
+    Py_DECREF(seq);
+    if (!fail && table_seal(t) < 0)
+        fail = 1;
+    if (fail)
+        Py_CLEAR(t);
+    return (PyObject *)t;
+}
+
+#define KEY_UNSET (-1)      /* slot not looked at yet */
+#define KEY_NONE  (-2)      /* its items are no rows */
+#define KEY_ERR   (-3)
+
+/* Which of the table's keys a resolved identity's signatures go under:
+ * its id in t->keys where the identity's item is P-256's four plain
+ * fields, KEY_NONE where it keeps another shape.  Decided once a slot
+ * (`cache`), so an item costs an array read. */
+static int32_t slot_key(SigTable *t, int32_t *cache, Py_ssize_t slot,
+                        PyObject *ent)
+{
+    if (cache[slot] != KEY_UNSET)
+        return cache[slot];
+    PyObject *wire = PyTuple_GET_ITEM(ent, 1);
+    int32_t k = KEY_NONE;
+    if (PyBytes_Check(wire)) {
+        int eq = PyObject_RichCompareBool(PyTuple_GET_ITEM(ent, 2),
+                                          t->scheme, Py_EQ);
+        if (eq < 0)
+            return KEY_ERR;
+        if (eq) {
+            Py_ssize_t id = slot_of(t->keymap, t->keys, wire);
+            if (id < 0)
+                return KEY_ERR;
+            k = (int32_t)id;
+        }
+    }
+    cache[slot] = k;
+    return k;
+}
+
+/* assemble()'s walk over the works: fills `t` and `plans`, -> n_refs or
+ * -1 with an exception set */
+static Py_ssize_t assemble_works(SigTable *t, int32_t *ckey, int32_t *ekey,
+                                 PyObject *works, PyObject *c_ents,
+                                 PyObject *e_ents, PyObject *endorsers,
+                                 PyObject *codes, PyObject *plans,
+                                 PyObject *policy_for, PyObject *pol_cache)
+{
+    PyObject *scheme = t->scheme;
     uint8_t *cp = (uint8_t *)PyByteArray_AS_STRING(codes);
     Py_ssize_t ncodes = PyByteArray_GET_SIZE(codes);
     Py_ssize_t n_refs = 0;
@@ -1610,7 +1946,7 @@ static PyObject *py_assemble(PyObject *self, PyObject *args)
         if (tx < 0 || tx >= ncodes || cslot < 0
             || cslot >= PyList_GET_SIZE(c_ents)) {
             PyErr_SetString(PyExc_IndexError, "assemble(): slot range");
-            return NULL;
+            return -1;
         }
         PyObject *ent = PyList_GET_ITEM(c_ents, cslot);
         if (ent == Py_None) {         /* MSP rejected the creator */
@@ -1619,31 +1955,36 @@ static PyObject *py_assemble(PyObject *self, PyObject *args)
         }
         PyObject *creator = PyTuple_GET_ITEM(ent, 0);
         PyObject *wire = PyTuple_GET_ITEM(ent, 1);
+        PyObject *csig = PyTuple_GET_ITEM(work, 5);
         Py_ssize_t cidx;
-        if (wire != Py_None) {
+        int32_t kid = slot_key(t, ckey, cslot, ent);
+        if (kid == KEY_ERR)
+            return -1;
+        if (kid >= 0 && row_shaped(csig, PyTuple_GET_ITEM(work, 4))) {
+            cidx = table_add(t, kid, csig, PyTuple_GET_ITEM(work, 4));
+        } else if (wire != Py_None) {
             PyObject *cscheme = PyTuple_GET_ITEM(ent, 2);
             int digested = PyObject_RichCompareBool(cscheme, scheme, Py_EQ);
             if (digested < 0)
-                return NULL;
-            cidx = intern_fields(index, cls, cscheme, wire,
-                                 PyTuple_GET_ITEM(work, 5), /* signature */
-                                 PyTuple_GET_ITEM(work, digested
-                                                  ? 4       /* pdigest */
-                                                  : 3));    /* payload */
+                return -1;
+            cidx = table_add_fields(t, cscheme, wire, csig,
+                                    PyTuple_GET_ITEM(work, digested
+                                                     ? 4       /* pdigest */
+                                                     : 3));    /* payload */
         } else {
             PyObject *item = PyObject_CallMethodObjArgs(
-                creator, s_verify_item, PyTuple_GET_ITEM(work, 3),
-                PyTuple_GET_ITEM(work, 5), NULL);
+                creator, s_verify_item, PyTuple_GET_ITEM(work, 3), csig,
+                NULL);
             if (!item)
-                return NULL;
-            cidx = intern_item(index, item);
+                return -1;
+            cidx = table_add_item(t, item);
             Py_DECREF(item);
         }
         if (cidx < 0)
-            return NULL;
+            return -1;
         PyObject *entries = PyList_New(0);
         if (!entries)
-            return NULL;
+            return -1;
         int dead = 0;
         PyObject *acts = PyTuple_GET_ITEM(work, 6);
         if (txtype != 0 && acts != Py_None) {
@@ -1654,7 +1995,7 @@ static PyObject *py_assemble(PyObject *self, PyObject *args)
                 PyObject *ends2 = PyTuple_GET_ITEM(act, 2);
                 PyObject *ns_names = PyTuple_GET_ITEM(act, 3);
                 PyObject *sigset = PyList_New(0);
-                if (!sigset) { Py_DECREF(entries); return NULL; }
+                if (!sigset) { Py_DECREF(entries); return -1; }
                 for (Py_ssize_t e = 0; e < PyList_GET_SIZE(ends2); e++) {
                     PyObject *end3 = PyList_GET_ITEM(ends2, e);
                     Py_ssize_t slot =
@@ -1663,62 +2004,71 @@ static PyObject *py_assemble(PyObject *self, PyObject *args)
                         PyErr_SetString(PyExc_IndexError,
                                         "assemble(): endorser slot");
                         Py_DECREF(sigset); Py_DECREF(entries);
-                        return NULL;
+                        return -1;
                     }
                     PyObject *eent = PyList_GET_ITEM(e_ents, slot);
                     if (eent == Py_None)   /* undeserializable: skip */
                         continue;
                     PyObject *ident = PyTuple_GET_ITEM(eent, 0);
                     PyObject *ewire = PyTuple_GET_ITEM(eent, 1);
+                    PyObject *esig = PyTuple_GET_ITEM(end3, 1);
                     Py_ssize_t eidx;
+                    int32_t ekid = slot_key(t, ekey, slot, eent);
+                    if (ekid == KEY_ERR) {
+                        Py_DECREF(sigset); Py_DECREF(entries);
+                        return -1;
+                    }
                     PyObject *escheme =
                         ewire != Py_None ? PyTuple_GET_ITEM(eent, 2) : NULL;
-                    int digested = escheme
-                        ? PyObject_RichCompareBool(escheme, scheme, Py_EQ)
-                        : 0;
-                    if (digested < 0) {
-                        Py_DECREF(sigset); Py_DECREF(entries);
-                        return NULL;
+                    int digested = ekid >= 0;
+                    if (!digested && escheme) {
+                        digested = PyObject_RichCompareBool(escheme, scheme,
+                                                            Py_EQ);
+                        if (digested < 0) {
+                            Py_DECREF(sigset); Py_DECREF(entries);
+                            return -1;
+                        }
                     }
-                    if (digested) {
-                        eidx = intern_fields(index, cls, escheme, ewire,
-                                             PyTuple_GET_ITEM(end3, 1),
-                                             PyTuple_GET_ITEM(end3, 2));
+                    if (ekid >= 0
+                        && row_shaped(esig, PyTuple_GET_ITEM(end3, 2))) {
+                        eidx = table_add(t, ekid, esig,
+                                         PyTuple_GET_ITEM(end3, 2));
+                    } else if (digested) {
+                        eidx = table_add_fields(t, escheme, ewire, esig,
+                                                PyTuple_GET_ITEM(end3, 2));
                     } else {      /* over the message itself */
                         PyObject *msg = PySequence_Concat(
                             endorsed, PyList_GET_ITEM(endorsers, slot));
                         if (!msg) {
                             Py_DECREF(sigset); Py_DECREF(entries);
-                            return NULL;
+                            return -1;
                         }
                         if (escheme) {
-                            eidx = intern_fields(index, cls, escheme, ewire,
-                                                 PyTuple_GET_ITEM(end3, 1),
-                                                 msg);
+                            eidx = table_add_fields(t, escheme, ewire, esig,
+                                                    msg);
                             Py_DECREF(msg);
                         } else {
                             PyObject *item = PyObject_CallMethodObjArgs(
-                                ident, s_verify_item, msg,
-                                PyTuple_GET_ITEM(end3, 1), NULL);
+                                ident, s_verify_item, msg, esig, NULL);
                             Py_DECREF(msg);
                             if (!item) {
                                 Py_DECREF(sigset); Py_DECREF(entries);
-                                return NULL;
+                                return -1;
                             }
-                            eidx = intern_item(index, item);
+                            eidx = table_add_item(t, item);
                             Py_DECREF(item);
                         }
                     }
                     if (eidx < 0) {
                         Py_DECREF(sigset); Py_DECREF(entries);
-                        return NULL;
+                        return -1;
                     }
                     PyObject *eio = PyLong_FromSsize_t(eidx);
                     PyObject *pair = eio ? PyTuple_New(2) : NULL;
                     if (!pair) {
                         Py_XDECREF(eio);
                         Py_DECREF(sigset); Py_DECREF(entries);
-                        return NULL;
+                        return -1;
                     }
                     PyTuple_SET_ITEM(pair, 0, eio);
                     Py_INCREF(ident);
@@ -1727,7 +2077,7 @@ static PyObject *py_assemble(PyObject *self, PyObject *args)
                     Py_DECREF(pair);
                     if (rc < 0) {
                         Py_DECREF(sigset); Py_DECREF(entries);
-                        return NULL;
+                        return -1;
                     }
                 }
                 for (Py_ssize_t s = 0; s < PyList_GET_SIZE(ns_names);
@@ -1738,7 +2088,7 @@ static PyObject *py_assemble(PyObject *self, PyObject *args)
                     if (!pol) {
                         if (PyErr_Occurred()) {
                             Py_DECREF(sigset); Py_DECREF(entries);
-                            return NULL;
+                            return -1;
                         }
                         pol = PyObject_CallFunctionObjArgs(policy_for,
                                                            ns, NULL);
@@ -1746,7 +2096,7 @@ static PyObject *py_assemble(PyObject *self, PyObject *args)
                                                    pol) < 0) {
                             Py_XDECREF(pol);
                             Py_DECREF(sigset); Py_DECREF(entries);
-                            return NULL;
+                            return -1;
                         }
                         Py_DECREF(pol);   /* pol_cache holds it */
                     }
@@ -1758,7 +2108,7 @@ static PyObject *py_assemble(PyObject *self, PyObject *args)
                     PyObject *entry = PyTuple_New(2);
                     if (!entry) {
                         Py_DECREF(sigset); Py_DECREF(entries);
-                        return NULL;
+                        return -1;
                     }
                     Py_INCREF(pol);
                     PyTuple_SET_ITEM(entry, 0, pol);
@@ -1768,7 +2118,7 @@ static PyObject *py_assemble(PyObject *self, PyObject *args)
                     Py_DECREF(entry);
                     if (rc < 0) {
                         Py_DECREF(sigset); Py_DECREF(entries);
-                        return NULL;
+                        return -1;
                     }
                 }
                 Py_DECREF(sigset);
@@ -1786,7 +2136,7 @@ static PyObject *py_assemble(PyObject *self, PyObject *args)
         PyObject *cio = PyLong_FromSsize_t(cidx);
         if (!plan || !cio) {
             Py_XDECREF(plan); Py_XDECREF(cio); Py_DECREF(entries);
-            return NULL;
+            return -1;
         }
         Py_INCREF(PyTuple_GET_ITEM(work, 0));
         PyTuple_SET_ITEM(plan, 0, PyTuple_GET_ITEM(work, 0));
@@ -1795,9 +2145,83 @@ static PyObject *py_assemble(PyObject *self, PyObject *args)
         int rc = PyList_Append(plans, plan);
         Py_DECREF(plan);
         if (rc < 0)
-            return NULL;
+            return -1;
     }
-    return PyLong_FromSsize_t(n_refs);
+    return n_refs;
+}
+
+/* assemble(works, c_ents, e_ents, endorsers, codes, plans,
+ *          verify_item_cls, scheme_p256, policy_for, pol_cache)
+ *   -> (table: SigTable, n_refs)
+ *
+ * c_ents/e_ents: per-slot (identity, pub_wire|None, scheme|None) or
+ * None for identities the MSP rejected.  With a pub_wire the item is
+ * its four plain fields: under `scheme_p256` it is over the walker's
+ * SHA-256 digest and becomes a ROW of the table — no VerifyItem, no
+ * container, nothing called in Python; under another scheme it is over
+ * the message itself (Ed25519 signs the message: payload for a
+ * creator, endorsed || endorser for an endorsement) and is interned in
+ * the table's short list of VerifyItems.  Without one the identity is
+ * asked (`verify_item`: idemix) and its item goes to that list too.
+ * Appends to `plans`
+ * (tx_num, creator_idx, [(policy, [(item_idx, identity)...])...]),
+ * the indices being dispatch positions over rows and list together, in
+ * EXACTLY the Python tail's order:
+ * creator first, then each action's endorsements, then that action's
+ * namespace policy lookups (a missing policy kills the tx but keeps
+ * already-interned items — n_unique_items parity).  n_refs counts
+ * 1 + sigset size per namespace entry over SURVIVING works only,
+ * matching _finish_inner's accounting. */
+static PyObject *py_assemble(PyObject *self, PyObject *args)
+{
+    (void)self;
+    PyObject *works, *c_ents, *e_ents, *endorsers, *codes, *plans, *cls,
+             *scheme, *policy_for, *pol_cache;
+    if (!PyArg_ParseTuple(args, "OOOOOOOOOO", &works, &c_ents, &e_ents,
+                          &endorsers, &codes, &plans, &cls, &scheme,
+                          &policy_for, &pol_cache))
+        return NULL;
+    if (!PyList_Check(works) || !PyList_Check(c_ents)
+        || !PyList_Check(e_ents) || !PyList_Check(endorsers)
+        || !PyByteArray_Check(codes) || !PyList_Check(plans)
+        || !PyDict_Check(pol_cache)) {
+        PyErr_SetString(PyExc_TypeError, "assemble(): bad argument types");
+        return NULL;
+    }
+    /* room for every signature the works carry: one a creator, one an
+     * endorsement kept by digest's per-action dedup */
+    Py_ssize_t cap = 0;
+    for (Py_ssize_t w = 0; w < PyList_GET_SIZE(works); w++) {
+        PyObject *work = PyList_GET_ITEM(works, w);
+        if (!PyTuple_Check(work) || PyTuple_GET_SIZE(work) != 7) {
+            PyErr_SetString(PyExc_TypeError, "assemble(): bad work");
+            return NULL;
+        }
+        PyObject *acts = PyTuple_GET_ITEM(work, 6);
+        cap += 1;
+        for (Py_ssize_t a = 0;
+             acts != Py_None && a < PyList_GET_SIZE(acts); a++)
+            cap += PyList_GET_SIZE(
+                PyTuple_GET_ITEM(PyList_GET_ITEM(acts, a), 2));
+    }
+    Py_ssize_t nc = PyList_GET_SIZE(c_ents), ne = PyList_GET_SIZE(e_ents);
+    SigTable *t = table_new(cap, cls, scheme);
+    int32_t *slots = PyMem_Malloc((size_t)(nc + ne + 1) * sizeof(int32_t));
+    PyObject *ret = NULL;
+    if (t && slots) {
+        for (Py_ssize_t i = 0; i < nc + ne; i++)
+            slots[i] = KEY_UNSET;
+        Py_ssize_t n_refs = assemble_works(
+            t, slots, slots + nc, works, c_ents, e_ents, endorsers, codes,
+            plans, policy_for, pol_cache);
+        if (n_refs >= 0 && table_seal(t) == 0)
+            ret = Py_BuildValue("(On)", (PyObject *)t, n_refs);
+    } else if (t) {
+        PyErr_NoMemory();
+    }
+    PyMem_Free(slots);
+    Py_XDECREF(t);
+    return ret;
 }
 
 /* gate(plans, verdict: buffer[u8], codes, plugin, evaluator, eval_cache)
@@ -1961,6 +2385,24 @@ static int der_int32(const uint8_t **pp, const uint8_t *end, uint8_t out[32])
     return 0;
 }
 
+/* one signature: strict-DER SEQUENCE of two such INTEGERs and nothing
+ * else -> 1 with r32be || s32be in out, 0 with out zeroed */
+static int der_sig64(const uint8_t *p, Py_ssize_t sn, uint8_t out[64])
+{
+    const uint8_t *end = p + sn;
+    /* SEQUENCE header, short-form length covering the whole rest */
+    if (sn >= 8 && p[0] == 0x30 && p[1] < 0x80
+        && (Py_ssize_t)p[1] == sn - 2) {
+        p += 2;
+        if (der_int32(&p, end, out) == 0
+            && der_int32(&p, end, out + 32) == 0
+            && p == end)                 /* no trailing bytes */
+            return 1;
+    }
+    memset(out, 0, 64);
+    return 0;
+}
+
 static PyObject *py_parse_der_sigs(PyObject *self, PyObject *args)
 {
     (void)self;
@@ -1979,27 +2421,17 @@ static PyObject *py_parse_der_sigs(PyObject *self, PyObject *args)
     }
     uint8_t *ok = (uint8_t *)PyBytes_AS_STRING(ok_b);
     uint8_t *rs = (uint8_t *)PyBytes_AS_STRING(rs_b);
-    memset(rs, 0, (size_t)n * 64);
     for (Py_ssize_t i = 0; i < n; i++) {
         PyObject *sig = PySequence_Fast_GET_ITEM(seq, i);
         char *cp;
         Py_ssize_t sn;
-        ok[i] = 0;
         if (PyBytes_AsStringAndSize(sig, &cp, &sn) < 0) {
             PyErr_Clear();               /* non-bytes: host reject */
+            memset(rs + i * 64, 0, 64);
+            ok[i] = 0;
             continue;
         }
-        const uint8_t *p = (const uint8_t *)cp;
-        const uint8_t *end = p + sn;
-        /* SEQUENCE header, short-form length covering the whole rest */
-        if (sn < 8 || p[0] != 0x30 || p[1] >= 0x80
-            || (Py_ssize_t)p[1] != sn - 2)
-            continue;
-        p += 2;
-        if (der_int32(&p, end, rs + i * 64) < 0) continue;
-        if (der_int32(&p, end, rs + i * 64 + 32) < 0) continue;
-        if (p != end) continue;          /* trailing bytes */
-        ok[i] = 1;
+        ok[i] = (uint8_t)der_sig64((const uint8_t *)cp, sn, rs + i * 64);
     }
     Py_DECREF(seq);
     PyObject *out = Py_BuildValue("(NN)", ok_b, rs_b);
@@ -2029,8 +2461,11 @@ static PyMethodDef methods[] = {
      "digest_spans(base, spans, channel_id, carry, oracle) -> "
      "digest() over zero-copy (u64 off, u64 len) spans into base"},
     {"assemble", py_assemble, METH_VARARGS,
-     "assemble(works, c_ents, e_ents, endorsers, codes, index, plans, "
-     "verify_item_cls, scheme_p256, policy_for, pol_cache) -> n_refs"},
+     "assemble(works, c_ents, e_ents, endorsers, codes, plans, "
+     "verify_item_cls, scheme_p256, policy_for, pol_cache) -> "
+     "(SigTable, n_refs)"},
+    {"pack_items", py_pack_items, METH_VARARGS,
+     "pack_items(items, verify_item_cls, scheme_p256) -> SigTable"},
     {"gate", py_gate, METH_VARARGS,
      "gate(plans, verdict, codes, plugin, evaluator, eval_cache)"},
     {"parse_der_sigs", py_parse_der_sigs, METH_VARARGS,
@@ -2051,7 +2486,17 @@ PyMODINIT_FUNC PyInit__fastcollect(void)
         sha256_block = sha256_block_shani;
 #endif
     s_verify_item = PyUnicode_InternFromString("verify_item");
-    if (!s_verify_item)
+    if (!s_verify_item || PyType_Ready(&SigTableType) < 0)
         return NULL;
-    return PyModule_Create(&moddef);
+    PyObject *m = PyModule_Create(&moddef);
+    if (m) {
+        Py_INCREF(&SigTableType);
+        if (PyModule_AddObject(m, "SigTable",
+                               (PyObject *)&SigTableType) < 0) {
+            Py_DECREF(&SigTableType);
+            Py_DECREF(m);
+            return NULL;
+        }
+    }
+    return m;
 }

@@ -486,5 +486,11 @@ class CachingProvider:
         return lambda: part.settle(resolve(), site=self._site,
                                    scope=self._scope)
 
+    def batch_verify_packed_async(self, batch):
+        """A signature table is asked of the cache item by item like any
+        other batch (owned here: `__getattr__` would hand it to the
+        inner provider unasked)."""
+        return self.batch_verify_async(batch)
+
     def __getattr__(self, name):
         return getattr(self._inner, name)

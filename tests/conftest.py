@@ -67,3 +67,22 @@ def counting():
         def __getattr__(self, name):
             return getattr(self.inner, name)
     return Counting
+
+
+@pytest.fixture
+def handed_over():
+    """handed_over() -> `validator_handoff_sigs_total` on channel `ch` by
+    (form, reason); handed_over(before) -> what moved since `before`."""
+    from fabric_tpu.ops_plane import registry
+    pairs = [("arrays", "bypassed")] + [("items", r) for r in (
+        "small_block", "cache_answered", "scheme", "classic_tail",
+        "no_verb")]
+
+    def read(before=None):
+        c = registry.counter("validator_handoff_sigs_total")
+        now = {p: int(c.value(channel="ch", form=p[0], reason=p[1]))
+               for p in pairs}
+        if before is None:
+            return now
+        return {p: n - before[p] for p, n in now.items() if n != before[p]}
+    return read

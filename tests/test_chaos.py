@@ -309,6 +309,59 @@ def test_degrading_provider_identical_flags_and_recovery():
     assert "bccsp_breaker_transitions_total" in text
 
 
+def test_packed_verb_default_and_behind_the_breaker():
+    """A signature table through the providers that do not pack from it:
+    the software provider's default builds the items and answers with
+    the item verb's verdicts; the degrading provider owns the verb — a
+    sick primary's packed failures count against the same breaker, the
+    fallback answers from the table, and while the breaker is open the
+    primary is not touched."""
+    from fabric_tpu.bccsp import SCHEME_P256, VerifyItem
+    from fabric_tpu.bccsp.degrade import DegradingProvider
+    from fabric_tpu.bccsp.sw import SoftwareProvider
+    from fabric_tpu.native import load
+
+    fc = load("_fastcollect")
+    if fc is None:
+        pytest.skip("no native extension")
+    sw = SoftwareProvider()
+    items = _mixed_items(sw)
+    k = sw.key_gen("ed25519")
+    items.append(VerifyItem("ed25519", k.public_bytes(), sw.sign(k, b"m"),
+                            b"m"))
+    table = fc.pack_items(items + items[:2], VerifyItem, SCHEME_P256)
+    assert list(table) == items and table.n_rows == len(items) - 1
+    expected = sw.batch_verify(items)
+    assert not expected.all() and expected.any()
+    assert np.array_equal(sw.batch_verify_packed_async(table)(), expected)
+
+    class SickPacked(_SickPrimary):
+        packed_calls = 0
+
+        def batch_verify_packed_async(self, batch):
+            self.packed_calls += 1
+            return self.batch_verify_async(batch)
+
+    primary = SickPacked(fail_batches=2, inner=SoftwareProvider())
+    deg = DegradingProvider(primary, sw, failure_threshold=2,
+                            cooldown_base_s=30.0, cooldown_max_s=60.0)
+    for i in range(2):
+        got = deg.batch_verify_packed_async(table)()
+        assert np.array_equal(got, expected), f"batch {i} diverged"
+    assert primary.packed_calls == 2 and primary.remaining == 0
+    assert deg.degraded is True             # both failures were counted
+    got = deg.batch_verify_packed_async(table)()
+    assert np.array_equal(got, expected)
+    assert primary.packed_calls == 2        # open: the primary is left alone
+
+    # a primary that knows items only is handed the items, guarded alike
+    plain = _SickPrimary(fail_batches=1, inner=SoftwareProvider())
+    deg = DegradingProvider(plain, sw, failure_threshold=1,
+                            cooldown_base_s=30.0, cooldown_max_s=60.0)
+    assert np.array_equal(deg.batch_verify_packed_async(table)(), expected)
+    assert plain.remaining == 0 and deg.degraded is True
+
+
 # ---------------------------------------------------------------------------
 # unit: committer idempotent replay
 # ---------------------------------------------------------------------------

@@ -351,7 +351,14 @@ def test_fast_collect_late_error_parity_and_deep_nesting(world):
     assert fast[2] == int(ValidationCode.BAD_PAYLOAD)
 
 
-def _fuzz_kit(world):
+# transactions a fuzzed block: 330-390 unique verify items (`run`
+# asserts it), more than the validator's PROBE, so that the deep tail
+# hands its signature table over as arrays — the path the fuzz must
+# compare
+FUZZ_TXS = 400
+
+
+def _fuzz_kit(world, handed_over):
     """(corpus, run, dup_raw) of the state-fork fuzz: randomized
     adversarial corpora — intra-block txid collisions, carry collisions
     across PIPELINED blocks, ledger-oracle duplicates, unknown-org
@@ -386,7 +393,7 @@ def _fuzz_kit(world):
         [org1.new_identity("e1"), org2.new_identity("e2")],
         nonce=led_nonce).serialize()
 
-    def corpus(rng, n=30):
+    def corpus(rng, n=FUZZ_TXS):
         raws = []
         for _ in range(n):
             kind = rng.randrange(10)
@@ -447,12 +454,22 @@ def _fuzz_kit(world):
                        list(b1raws) + [dup_raw], BlockMetadata())
             b2 = Block(BlockHeader(6, b"p", b"d"),
                        list(b2raws) + [dup_raw, led_raw], BlockMetadata())
+            before = handed_over()
             s1 = v.validate_begin(b1)
             s2 = v.validate_begin(b2)   # pipelined: b1 carry, not ledger
             assert (bool(s1.get("deep")) == bool(s2.get("deep"))
                     == (mode in ("deep", "node")))
             r1 = v.validate_finish(s1)
             r2 = v.validate_finish(s2)
+            # per block arrays + items = its unique items; a deep block
+            # above PROBE goes as arrays, every other as items
+            moved = handed_over(before)
+            n_unique = r1.n_unique_items + r2.n_unique_items
+            assert sum(moved.values()) == n_unique
+            assert min(r1.n_unique_items, r2.n_unique_items) > tv.PROBE
+            assert moved == ({("arrays", "bypassed"): n_unique}
+                             if mode in ("deep", "node") else
+                             {("items", "classic_tail"): n_unique})
             return (r1.flags.codes(), r2.flags.codes(),
                     r1.n_unique_items, r2.n_unique_items)
         finally:
@@ -468,13 +485,13 @@ def _fuzz_kit(world):
     return corpus, run, dup_raw
 
 
-def test_deep_collect_three_way_differential_fuzz(world):
+def test_deep_collect_three_way_differential_fuzz(world, handed_over):
     """State-fork invariant fuzz: the deep C tail (digest/assemble/gate),
     the classic C-walker + Python-tail, and the pure-Python mirror must
     produce bit-identical TxFlags and item counts over `_fuzz_kit`'s
     corpora."""
     import random
-    corpus, run, dup_raw_of = _fuzz_kit(world)
+    corpus, run, dup_raw_of = _fuzz_kit(world, handed_over)
     for seed in (11, 22, 33):
         rng = random.Random(seed)
         dup_raw = dup_raw_of(seed)
@@ -492,14 +509,15 @@ def test_deep_collect_three_way_differential_fuzz(world):
 
 
 @pytest.mark.parametrize("seed", [11, 22, 33, 44])
-def test_node_wired_validator_takes_the_deep_tail_on_the_fuzz(world, seed):
+def test_node_wired_validator_takes_the_deep_tail_on_the_fuzz(world, seed,
+                                                              handed_over):
     """A validator built as node/peer.py builds it — the key-level lookup
     and the state's count beside it — takes the deep tail on a state and
     blocks that hold no validation parameter (`run` asserts which tail
     each mode took), and its flags and item counts are the classic
     tail's and the pure-Python mirror's."""
     import random
-    corpus, run, dup_raw_of = _fuzz_kit(world)
+    corpus, run, dup_raw_of = _fuzz_kit(world, handed_over)
     rng = random.Random(seed)
     dup_raw = dup_raw_of(seed)
     b1raws, b2raws = corpus(rng), corpus(rng)

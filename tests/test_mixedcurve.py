@@ -144,18 +144,18 @@ def flip(sig: bytes) -> bytes:
     return sig[:-1] + bytes([sig[-1] ^ 0x01])
 
 
-def mixed_block(network):
-    """12 transactions under AND(Org1, Org2, Org3), creators of all three
-    orgs in turn (so of both curves); one endorsement broken on each
-    curve, one creator signature broken on each curve.  -> (block,
+def mixed_block(network, n_txs=12):
+    """`n_txs` transactions under AND(Org1, Org2, Org3), creators of all
+    three orgs in turn (so of both curves); one endorsement broken on
+    each curve, one creator signature broken on each curve.  -> (block,
     expected codes)"""
     peers = network["peers"]
     creators = [network["clients"][org][i] for i in range(2) for org in ORGS]
     envs, want = [], []
-    for t in range(12):
+    for t in range(n_txs):
         creator = creators[t % len(creators)]
         rwset = TxRwSet((NsRwSet("cc", writes=(
-            KVWrite(f"k{t}", b"v" * (1 + 97 * t)),)),))
+            KVWrite(f"k{t}", b"v" * (1 + 97 * (t % 12))),)),))
         env = build.endorser_tx("ch", "cc", "1.0", rwset, creator, peers)
         code = VALID
         if t in (3, 4):                 # Org2's (P-256), Org3's (Ed25519)
@@ -210,6 +210,66 @@ def test_a_mixed_block_gets_the_same_flags_on_every_tail(network):
     assert all(type(it) is VerifyItem and len(it.pubkey) == 32
                and len(it.payload) > 64 for it in by_scheme[SCHEME_ED25519])
     assert all(len(it.payload) == 32 for it in by_scheme[SCHEME_P256])
+
+
+def test_a_big_mixed_block_is_a_table_and_a_short_list(network,
+                                                       device_provider,
+                                                       handed_over):
+    """Above the validator's PROBE the deep tail hands over P-256 as the
+    table's rows and Ed25519 as the VerifyItems beside it: two thirds
+    and one third of the block's unique items, every one at the position
+    the Python tail gives it, every verdict at its position — the
+    tampered Org3 endorsement's and the broken Ed25519 creator's among
+    them — from the software provider (which builds the items) and from
+    the device provider (which packs the rows as they are)."""
+    from fabric_tpu.committer.txvalidator import PROBE
+    n_txs = 72
+    block, want = mixed_block(network, n_txs)
+    sw = SoftwareProvider()
+    classic = validator_for(network, sw, python_tail=True)
+    state = classic.validate_begin(block)
+    order = list(state["items"])                 # the Python tail's
+    assert classic.validate_finish(state).flags.codes() == want
+    assert len(order) == 4 * n_txs > PROBE
+
+    seen = []
+
+    class Spy(SoftwareProvider):
+        def batch_verify_packed_async(self, batch):
+            seen.append(batch)
+            return super().batch_verify_packed_async(batch)
+
+    before = handed_over()
+    v = validator_for(network, Spy())
+    state = v.validate_begin(block)
+    table = state["items"]
+    assert v.validate_finish(state).flags.codes() == want
+    assert seen == [table] and list(table) == order
+    assert (table.n_rows, len(table.rest)) == (192, 96)
+    assert handed_over(before) == {
+        ("arrays", "bypassed"): 192, ("items", "scheme"): 96}
+    pos = np.frombuffer(table.pos, np.int32).tolist()
+    rest_pos = np.frombuffer(table.rest_pos, np.int32).tolist()
+    assert sorted(pos + rest_pos) == list(range(4 * n_txs))
+    assert all(order[p].scheme == SCHEME_P256 for p in pos)
+    assert [order[p] for p in rest_pos] == table.rest
+    assert all(type(it) is VerifyItem and it.scheme == SCHEME_ED25519
+               for it in table.rest)
+    assert table.digest == b"".join(order[p].payload for p in pos)
+    assert len(table.keys) == 6 and all(len(k) == 65 for k in table.keys)
+
+    truth = sw.batch_verify(order)
+    assert truth.tolist().count(False) == 4
+    broken = [order[i].scheme for i in np.nonzero(~truth)[0]]
+    assert sorted(broken) == sorted([SCHEME_P256, SCHEME_ED25519] * 2)
+
+    tpu = device_provider
+    before = dict(tpu.stats)
+    assert tpu.batch_verify_packed_async(table)().tolist() == truth.tolist()
+    assert tpu.stats["dispatches"] - before["dispatches"] == 2
+    assert tpu.stats["device_sigs"] - before["device_sigs"] == 4 * n_txs
+    assert tpu.stats["fast_key_sigs"] - before["fast_key_sigs"] == 4 * n_txs
+    assert tpu.stats["fallbacks"] == 0 and tpu.stats["host_rejects"] == 0
 
 
 @pytest.fixture(scope="module")

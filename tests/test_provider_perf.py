@@ -209,6 +209,60 @@ def test_rows_chunk_splits_large_grids(keypool):
     assert (np.asarray(out) == np.asarray(sw)).all()
 
 
+def test_packed_verb_is_the_item_verb_verdict_for_verdict(keypool):
+    """`batch_verify_packed_async` over a signature table == the item
+    verb over the table's items: the verdicts and every stat (`rows`
+    for three keys above `fast_key_threshold`, the ladder lane for two
+    below, a `rows_chunk` that splits the grid), over a batch that holds
+    one of each thing the host must refuse or the device must — and an
+    exact duplicate, which the table holds once."""
+    from fabric_tpu.native import load
+    fc = load("_fastcollect")
+    if fc is None:
+        pytest.skip("no native extension")
+    good = _sigs(keypool[:3], 6) + _sigs(keypool[3:5], 2, seed=8)
+    it = good[0]
+    r, s_ = decode_dss_signature(it.signature)
+    odd = [
+        it._replace(signature=it.signature[:-1]),               # cut DER
+        it._replace(signature=it.signature + b"\x00"),          # trailing
+        it._replace(signature=encode_dss_signature(1 << 256, s_)),  # r
+        it._replace(signature=b"\x30\x06\x02\x01\x01\x02\x01\x00"),   # s = 0
+        it._replace(signature=encode_dss_signature(r, p256.N - s_)),  # high S
+        it._replace(pubkey=it.pubkey[1:]),                      # 64-byte key
+        it._replace(payload=it.payload[:31]),                   # 31-byte digest
+        it._replace(payload=hashlib.sha256(b"other").digest()),  # unsound
+        VerifyItem(*good[1]),                                   # duplicate
+    ]
+    batch = good + odd
+    random.Random(3).shuffle(batch)
+    table = fc.pack_items(batch, VerifyItem, SCHEME_P256)
+    items = list(table)
+    assert items == list(dict.fromkeys(batch)) and len(items) == len(batch) - 1
+    assert table.n_rows == len(items) - 1 and len(table.rest) == 1   # 31 bytes
+
+    stats = ("host_rejects", "dispatches", "device_sigs", "fast_key_sigs",
+             "h2d_bytes", "fallbacks")
+
+    def run(verb, arg):
+        prov = JaxTpuProvider(fast_row_c=4, rows_chunk=2,
+                              fast_key_threshold=4)
+        out = getattr(prov, verb)(arg)()
+        return np.asarray(out).tolist(), {k: prov.stats[k] for k in stats}
+
+    packed, packed_stats = run("batch_verify_packed_async", table)
+    by_item, item_stats = run("batch_verify_async", items)
+    assert packed == by_item
+    assert packed_stats == item_stats
+    # cut, trailing, r, s = 0, the short key, the short digest
+    assert item_stats["host_rejects"] == 6
+    assert item_stats["fast_key_sigs"] == 18 + 2         # high S, unsound
+    assert item_stats["device_sigs"] == 18 + 4 + 2
+    assert item_stats["dispatches"] >= 3 + 1             # the grid is split
+    want = JaxTpuProvider().fallback.batch_verify(items)
+    assert packed == np.asarray(want).tolist() and sum(packed) == 22
+
+
 class _SlowAsyncProvider:
     """Fake device with an injected verify latency.  batch_verify_async
     enqueues instantly and returns a resolve() that blocks until the

@@ -104,6 +104,11 @@ class CountingProvider:
         resolve = self.inner.batch_verify_async(items)
         return resolve
 
+    def batch_verify_packed_async(self, batch):
+        # a wrapper that forwards what it does not know owns the packed
+        # verb, or a signature table would go round its count
+        return self.batch_verify_async(batch)
+
     def __getattr__(self, name):
         return getattr(self.inner, name)
 
@@ -783,6 +788,184 @@ def test_probe_leaves_a_block_of_probe_items_alone(orgs, sw_provider, tail,
         stored = [a["items"] for name, a in spans
                   if name == "validator.cache_store"]
         assert stored == ([] if bypassed else [PROBE])
+
+
+# -- the deep tail's hand-over: arrays where the block is dispatched unasked ----
+
+def test_an_answered_probe_hands_the_block_over_as_items(orgs, sw_provider,
+                                                         monkeypatch,
+                                                         handed_over):
+    """A deep-tail block above PROBE whose probe the cache answers — one
+    probed item known, then the whole block on its replay — builds its
+    items and goes the items' way: flags the cache-less validator's, the
+    cache fed, `form="items", reason="cache_answered"` moved by every
+    unique item of the block and `arrays` by none."""
+    org1, org2 = orgs
+    msps = _msps(org1, org2)
+    envs = _sized_envs(org1, org2, 300)
+    envs[5] = _broken(envs[5])
+    before = handed_over()
+    order, off = _validate("validator_deep", msps, sw_provider, None, envs)
+    n = len(order)
+    assert handed_over(before) == {("arrays", "bypassed"): n}   # no cache
+    truth = [bool(v) for v in sw_provider.batch_verify(order)]
+
+    cache = VerdictCache(capacity=4096)
+    at = probe_positions(n)[17]
+    cache.put(order[at], truth[at], scope="ch")
+    inner = CountingProvider(sw_provider)
+    spans = _record_spans(monkeypatch)
+    for replay, dispatched in enumerate(([it for i, it in enumerate(order)
+                                          if i != at], None)):
+        before = handed_over()
+        got, on = _validate("validator_deep", msps, inner, cache, envs)
+        assert on == off and got == order
+        assert handed_over(before) == {("items", "cache_answered"): n}
+        if dispatched is not None:
+            assert inner.batches == [dispatched]         # as items, once
+            assert all(type(it) is type(order[0]) for it in inner.batches[0])
+        else:
+            assert len(inner.batches) == 1               # all answered
+    collects = [a for name, a in spans if name == "validator.collect"]
+    assert [(c["handoff_arrays"], c["handoff_items"]) for c in collects] == [
+        (0, n), (0, n)]
+    assert len(cache) == n
+
+
+def test_a_small_deep_block_and_a_classic_block_go_as_items(orgs,
+                                                            sw_provider,
+                                                            handed_over):
+    org1, org2 = orgs
+    msps = _msps(org1, org2)
+    envs = _sized_envs(org1, org2, PROBE)
+    before = handed_over()
+    _validate("validator_deep", msps, sw_provider, VerdictCache(), envs)
+    assert handed_over(before) == {("items", "small_block"): PROBE}
+    before = handed_over()
+    _validate("validator_classic", msps, sw_provider, None,
+              _sized_envs(org1, org2, 300))
+    assert handed_over(before) == {("items", "classic_tail"): 300}
+
+
+def test_a_provider_without_the_packed_verb_gets_items(orgs, sw_provider,
+                                                       handed_over):
+    """`no_verb`: a provider that knows items only — it has no packed
+    verb, or its item verb was replaced on the instance, as the
+    benchmark's yes-verifier control does — is handed the items, through
+    the verb it has."""
+    org1, org2 = orgs
+    msps = _msps(org1, org2)
+    envs = _sized_envs(org1, org2, 300)
+    envs[9] = _broken(envs[9])
+    order, off = _validate("validator_deep", msps, sw_provider, None, envs)
+
+    class ItemsOnly:
+        name = "items-only"
+
+        def __init__(self):
+            self.batches = []
+
+        def batch_verify_async(self, items):
+            self.batches.append(items)
+            return sw_provider.batch_verify_async(items)
+
+    bare = ItemsOnly()
+    before = handed_over()
+    got, on = _validate("validator_deep", msps, bare, None, envs)
+    assert on == off and bare.batches == [order]
+    assert handed_over(before) == {("items", "no_verb"): 300}
+
+    from fabric_tpu.bccsp.sw import SoftwareProvider
+    yes = SoftwareProvider()
+    real = yes.batch_verify_async
+    yes.batch_verify_async = lambda items: (
+        lambda resolve=real(items): np.ones_like(resolve()))
+    before = handed_over()
+    _, flags = _validate("validator_deep", msps, yes, None, envs)
+    assert flags.count(int(ValidationCode.VALID)) == len(envs)   # all "sound"
+    assert handed_over(before) == {("items", "no_verb"): 300}
+
+
+def test_a_bypassed_deep_block_builds_no_item_a_signature(orgs, sw_provider,
+                                                          monkeypatch,
+                                                          handed_over):
+    """The mechanical guard.  On a silent cache the deep tail of a block
+    far above PROBE constructs the probe's PROBE VerifyItems and no
+    other — `VerifyItem.__new__` is counted — and, as `assemble` then
+    calls nothing in Python per signature, the cyclic collector (which
+    CPython runs only between bytecodes) begins no generation-1 or -2
+    pass inside it.  The parent's `assemble` constructed an item a
+    signature, and each construction let the collector in while the
+    whole block's containers were young."""
+    import gc
+    from fabric_tpu.committer import txvalidator as tv
+    org1, org2 = orgs
+    msps = _msps(org1, org2)
+    creator = org1.new_identity("client")
+    endorsers = [org1.new_identity("e1"), org2.new_identity("e2")]
+    n_txs = 1500
+    envs = [make_tx(org1, org2, creator=creator, endorsers=endorsers,
+                    rwset=rw(writes=[KVWrite(f"k{i}", b"v")]))
+            for i in range(n_txs)]
+    block = make_block(envs)
+
+    made = []
+
+    class Counted(tv.VerifyItem):
+        __slots__ = ()
+
+        def __new__(cls, *fields):
+            made.append(1)
+            return super().__new__(cls, *fields)
+
+    inside, began = [], []
+
+    class Watched:
+        def __getattr__(self, name):
+            fn = getattr(real, name)
+            if name != "assemble":
+                return fn
+
+            def assemble(*args):
+                inside.append(1)
+                try:
+                    return fn(*args)
+                finally:
+                    inside.pop()
+            return assemble
+
+    def on_gc(phase, info):
+        if phase == "start" and inside:
+            began.append(info["generation"])
+
+    class TakesArrays:
+        """A provider that reads the table's buffers and builds nothing
+        (the device provider's part; its verdicts are not the point)."""
+        name = "takes-arrays"
+
+        def batch_verify_packed_async(self, batch):
+            assert len(batch.digest) == 32 * batch.n_rows == 32 * len(batch)
+            return lambda: np.ones(len(batch), dtype=bool)
+
+    real = tv._fastcollect
+    monkeypatch.setattr(tv, "VerifyItem", Counted)
+    monkeypatch.setattr(tv, "_fastcollect", Watched())
+    v = TxValidator("ch", msps, TakesArrays(), _policies(),
+                    verify_cache=VerdictCache(capacity=4096))
+    before = handed_over()
+    gc.collect()
+    gc.freeze()                  # as utils/heap.block_boundary leaves it
+    gc.callbacks.append(on_gc)
+    try:
+        state = v.validate_begin(block)
+    finally:
+        gc.callbacks.remove(on_gc)
+        gc.unfreeze()
+    assert state.get("deep") and len(state["items"]) == 3 * n_txs
+    assert len(made) == PROBE                 # the probe's, and no other
+    assert not [g for g in began if g >= 1], began
+    assert handed_over(before) == {("arrays", "bypassed"): 3 * n_txs}
+    assert v.validate_finish(state).flags.valid_count() == n_txs
 
 
 # -- differential fuzz: cache-on == cache-off --------------------------------
