@@ -393,27 +393,77 @@ class TxValidator:
         from fabric_tpu.msp import deserialize_from_msps
         return deserialize_from_msps(self.msps, ident_bytes)
 
-    def _resolve_creator(self, ident_bytes: bytes):
+    def _resolve_creator(self, ident_bytes: bytes, acct: "_Identities"):
         """Creator memo value (`_memo_ent`), or None for identities
         the MSP rejects (deserialize + chain-validate —
         the (0, creator) memo of the Python tail, resolved once per
-        unique creator on the deep path)."""
+        unique creator on the deep path).  Booked in the block's
+        identity account: one more creator seen first, the seconds, and
+        why the MSP refused it where it did."""
+        t0 = time.perf_counter()
         creator = self._deserialize(ident_bytes)
-        if creator is not None and not _msp_validates(self.msps, creator):
-            creator = None
-        return None if creator is None else _memo_ent(creator)
+        reason = ("undecodable" if creator is None
+                  else _msp_rejects(self.msps, creator))
+        acct.first += 1
+        if reason is not None:
+            acct.rejected[reason] = acct.rejected.get(reason, 0) + 1
+        ent = None if reason is not None else _memo_ent(creator)
+        acct.add(t0)
+        return ent
 
-    def _resolve_endorser(self, ident_bytes: bytes):
+    def _resolve_endorser(self, ident_bytes: bytes, acct: "_Identities"):
         """Endorser memo value — deserialize only, NO chain validation
         (the (1, endorser) memo: an unrecognized endorser merely weakens
         the policy, policy.go:390-393)."""
+        t0 = time.perf_counter()
         ident = self._deserialize(ident_bytes)
+        acct.endorsers += 1
+        acct.add(t0)
         return None if ident is None else _memo_ent(ident)
+
+    def _note_identities(self, num: int, acct: "_Identities") -> None:
+        """One block's identity resolution, from the account's plain
+        numbers: the creators into `validator_creators_total` by whether
+        the block's memo knew them, the refused ones into
+        `validator_creator_rejected_total` by the MSP's reason, and the
+        span `validator.identities` — on the deep tail the stretch
+        between the C walk and `assemble`; on the classic tail, where a
+        resolution happens at the first transaction that needs it, from
+        the first one's start for the sum of them all."""
+        try:
+            from fabric_tpu.ops_plane import registry
+            ch = self.channel_id
+            seen = registry.counter(
+                "validator_creators_total",
+                "transactions whose creator the validator resolved, by "
+                "whether the block's memo knew the identity: first "
+                "(deserialised and chain-validated through the MSP) or "
+                "again (answered from the block's memo)")
+            seen.add(acct.first, channel=ch, seen="first")
+            seen.add(acct.again, channel=ch, seen="again")
+            if acct.rejected:
+                refused = registry.counter(
+                    "validator_creator_rejected_total",
+                    "unique creators of a block the MSP refused, by why: "
+                    "undecodable (or of no known MSP), revoked, "
+                    "untrusted, expired")
+                for reason, n in acct.rejected.items():
+                    refused.add(n, channel=ch, reason=reason)
+        except Exception:
+            pass
+        if acct.start is not None:
+            tracing.tracer.record_span(
+                "validator.identities", acct.start,
+                acct.start + acct.seconds,
+                attributes={"block": int(num),
+                            "unique_creators": acct.first,
+                            "unique_endorsers": acct.endorsers,
+                            "rejected": sum(acct.rejected.values())})
 
     def _collect_tx_fast(self, tx_num: int, rec, flags: TxFlags,
                          seen_txids: Dict[str, int],
                          items: Dict[VerifyItem, None],
-                         memo: dict, n_txs: int = 1,
+                         memo: dict, acct: "_Identities", n_txs: int = 1,
                          has_txid=None, doomed=None) -> Optional[_TxWork]:
         """Pass-1 tail for one tx whose structural walk ran in either
         front walker — C (native/fastcollect.c) or the Python mirror
@@ -470,7 +520,9 @@ class TxValidator:
         ckey = (0, creator_bytes)
         ent = memo.get(ckey, memo)
         if ent is memo:
-            ent = memo[ckey] = self._resolve_creator(creator_bytes)
+            ent = memo[ckey] = self._resolve_creator(creator_bytes, acct)
+        else:
+            acct.again += 1
         if ent is None:
             flags.set(tx_num, ValidationCode.BAD_CREATOR_SIGNATURE)
             return None
@@ -511,7 +563,8 @@ class TxValidator:
                 ekey = (1, endorser)
                 ent = memo.get(ekey, memo)
                 if ent is memo:
-                    ent = memo[ekey] = self._resolve_endorser(endorser)
+                    ent = memo[ekey] = self._resolve_endorser(endorser,
+                                                              acct)
                 if ent is None:
                     continue
                 ident, e_wire, scheme = ent
@@ -896,10 +949,11 @@ class TxValidator:
             lambda t: any(t in s for s in carry)
             or self.ledger_has_txid(t)))
         memo: dict = {}
+        acct = _Identities()
         n_aborted = 0
         for tx_num, rec in enumerate(recs):
             work = self._collect_tx_fast(tx_num, rec, flags, seen_txids,
-                                         items, memo, n_txs=n,
+                                         items, memo, acct, n_txs=n,
                                          has_txid=has_txid, doomed=doomed)
             if work is None and doomed is not None and tx_num in doomed \
                     and flags.flag(tx_num) in (
@@ -910,6 +964,7 @@ class TxValidator:
                 works.append(work)
         if use_sbe and any(w.meta_writes for w in works):
             self._note_meta_block(num)
+        self._note_identities(num, acct)
         verify = self._dispatch(list(items))
         self._note_early_aborts(n_aborted)
         self._inflight_txids.append((num, seen_txids))
@@ -975,8 +1030,11 @@ class TxValidator:
             self._note_early_aborts(n_aborted)
         # one MSP resolution per unique identity (the whole-block analogue
         # of the classic tail's (0,creator)/(1,endorser) memo dicts)
-        c_ents = [self._resolve_creator(b) for b in creators]
-        e_ents = [self._resolve_endorser(b) for b in endorsers]
+        acct = _Identities()
+        c_ents = [self._resolve_creator(b, acct) for b in creators]
+        e_ents = [self._resolve_endorser(b, acct) for b in endorsers]
+        acct.again = len(works) - len(creators)
+        self._note_identities(num, acct)
 
         # the block's unique items in dispatch order: a P-256 item is a
         # row of the table's flat buffers, not an object
@@ -994,6 +1052,7 @@ class TxValidator:
         collect_s = self._collected(
             t0, num, n, verify, "deep", "no_sbe",
             walk_ms=round((t_walked - t0) * 1e3, 3),
+            identities_ms=round(acct.seconds * 1e3, 3),
             assemble_ms=round((t_assembled - t_assemble) * 1e3, 3),
             handover_ms=round((time.perf_counter() - t_assembled) * 1e3, 3))
         return {"deep": True, "block": block, "codes": codes,
@@ -1203,11 +1262,34 @@ def _false_oracle(_txid: str) -> bool:
     return False
 
 
-def _msp_validates(msps: Dict[str, object], ident: Identity) -> bool:
+def _msp_rejects(msps: Dict[str, object], ident: Identity) -> Optional[str]:
+    """None where the identity's MSP validates its chain, else why not
+    (`MSPValidationError.reason`)."""
     msp = msps.get(ident.mspid)
     if msp is None:
-        return False
+        return "undecodable"
     try:
-        return msp.is_valid(ident)
-    except Exception:
-        return False
+        msp.validate(ident)
+    except Exception as exc:
+        return getattr(exc, "reason", "untrusted")
+    return None
+
+
+class _Identities:
+    """One block's account of identity resolution (plain numbers, read
+    once a block by `_note_identities`)."""
+    __slots__ = ("start", "seconds", "first", "again", "endorsers",
+                 "rejected")
+
+    def __init__(self):
+        self.start = None        # the first resolution's start
+        self.seconds = 0.0       # the resolutions' sum
+        self.first = 0           # creators the block's memo did not know
+        self.again = 0           # transactions whose creator it knew
+        self.endorsers = 0       # unique endorsers resolved
+        self.rejected = {}       # reason -> unique creators refused
+
+    def add(self, t0: float) -> None:
+        if self.start is None:
+            self.start = t0
+        self.seconds += time.perf_counter() - t0

@@ -68,6 +68,20 @@ class CA:
                        encipher_only=False, decipher_only=False), critical=True))
         self.cert = builder.sign(signing_key, _sign_alg(signing_key))
 
+    @classmethod
+    def load(cls, cert_pem: bytes, key_pem: bytes) -> "CA":
+        """An issuing CA from its certificate and key (a pool's worker
+        that issues a share of an org's enrolments)."""
+        ca = cls.__new__(cls)
+        ca.cert = x509.load_pem_x509_certificate(cert_pem)
+        ca._key = serialization.load_pem_private_key(key_pem, password=None)
+        ca.name = ca.cert.subject.rfc4514_string()
+        ca.scheme = (SCHEME_P256
+                     if isinstance(ca._key, ec.EllipticCurvePrivateKey)
+                     else SCHEME_ED25519)
+        ca.parent = None
+        return ca
+
     def cert_pem(self) -> bytes:
         return self.cert.public_bytes(serialization.Encoding.PEM)
 

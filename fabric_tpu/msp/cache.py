@@ -2,11 +2,19 @@
 
 Parity: /root/reference/msp/cache/cache.go (caches DeserializeIdentity,
 Validate and SatisfiesPrincipal with LRU size 100, sitting in front of the
-per-tx hot path so repeated cert-chain checks are deduped)."""
+per-tx hot path so repeated cert-chain checks are deduped).
+
+Every look-up is booked in `msp_cache_total{msp, op, result}` (always
+on: one counter add a call): `op` is the cache asked — `deserialize`,
+`validate`, `principal` — and `result` whether it answered (`hit`) or
+the wrapped MSP had to (`miss`).  A channel with more live identities
+than CACHE_SIZE reads as misses here."""
 
 from __future__ import annotations
 
 from collections import OrderedDict
+
+from fabric_tpu.ops_plane.metrics import registry
 
 from .identity import Identity
 from .msp import MSP, MSPValidationError, Principal
@@ -41,16 +49,23 @@ class CachedMSP:
         self._deser = _LRU(size)
         self._valid = _LRU(size)
         self._princ = _LRU(size)
-        self.stats = {"hits": 0, "misses": 0}
+        self._lookups = registry.counter(
+            "msp_cache_total",
+            "look-ups of an MSP's LRU caches, by the cache asked "
+            "(deserialize, validate, principal) and whether it answered "
+            "(hit) or the MSP did (miss)")
+
+    def _note(self, op: str, hit: bool) -> None:
+        self._lookups.add(1, msp=self.mspid, op=op,
+                          result="hit" if hit else "miss")
 
     def deserialize_identity(self, data: bytes) -> Identity:
         hit, v = self._deser.get(data)
+        self._note("deserialize", hit)
         if hit:
-            self.stats["hits"] += 1
             if isinstance(v, Exception):
                 raise v
             return v
-        self.stats["misses"] += 1
         try:
             ident = self.inner.deserialize_identity(data)
         except Exception as e:
@@ -62,12 +77,11 @@ class CachedMSP:
     def validate(self, ident: Identity) -> None:
         key = ident
         hit, err = self._valid.get(key)
+        self._note("validate", hit)
         if hit:
-            self.stats["hits"] += 1
             if err is not None:
                 raise err
             return
-        self.stats["misses"] += 1
         try:
             self.inner.validate(ident)
         except MSPValidationError as e:
@@ -85,10 +99,9 @@ class CachedMSP:
     def satisfies_principal(self, ident: Identity, p: Principal) -> bool:
         key = (ident, p)
         hit, v = self._princ.get(key)
+        self._note("principal", hit)
         if hit:
-            self.stats["hits"] += 1
             return v
-        self.stats["misses"] += 1
         v = self.inner.satisfies_principal(ident, p)
         self._princ.put(key, v)
         return v

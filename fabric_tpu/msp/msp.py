@@ -10,17 +10,24 @@ Chain validation is host-side X.509 (OpenSSL via `cryptography`); the
 signatures *inside* certificates are CA signatures checked once per
 identity and cached (see cache.py), so they are off the per-block hot
 path — exactly like the reference, where msp/cache sits in front of the
-per-tx flow (SURVEY.md §2 msp/cache row).
+per-tx flow (SURVEY.md §2 msp/cache row).  A channel with more live
+identities than the cache holds pays a chain validation an identity a
+block: `msp_validate_seconds{msp, result}` times every one (always on,
+one observation a call), `result` being `ok` or why the chain failed —
+`revoked`, `untrusted` (no trusted issuer, an issuer that is no CA),
+`expired` (a certificate outside its validity period).
 """
 
 from __future__ import annotations
 
 import datetime
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
 
 from fabric_tpu.crypto import x509
 from fabric_tpu.crypto import NameOID
+from fabric_tpu.ops_plane.metrics import registry
 
 from .identity import Identity
 
@@ -60,7 +67,16 @@ class MSPConfig:
 
 
 class MSPValidationError(Exception):
-    pass
+    """`reason`: how a chain failed, as `msp_validate_seconds` labels
+    it (`revoked` | `untrusted` | `expired`)."""
+
+    def __init__(self, message: str, reason: str = "untrusted"):
+        super().__init__(message)
+        self.reason = reason
+
+
+_VALIDATE_BUCKETS = (0.00005, 0.0001, 0.0002, 0.0005, 0.001, 0.005, 0.025,
+                     float("inf"))
 
 
 class MSP:
@@ -85,6 +101,11 @@ class MSP:
             crl = x509.load_pem_x509_crl(crl_pem)
             for rev in crl:
                 self._revoked.add((crl.issuer.public_bytes(), rev.serial_number))
+        self._validations = registry.histogram(
+            "msp_validate_seconds",
+            "one certificate-chain validation (chain building with each "
+            "link's CA signature, validity periods, CRLs), by its result",
+            buckets=_VALIDATE_BUCKETS)
 
     # -- deserialization ---------------------------------------------------
 
@@ -101,12 +122,29 @@ class MSP:
                  at_time: Optional[datetime.datetime] = None) -> None:
         """Raises MSPValidationError unless the identity chains to our roots,
         is within its validity period, and is not revoked."""
+        t0 = time.perf_counter()
+        result = "ok"
+        try:
+            self._check_chain(ident, at_time)
+        except MSPValidationError as e:
+            result = e.reason
+            raise
+        except Exception:
+            result = "untrusted"     # a certificate the checks choke on
+            raise
+        finally:
+            self._validations.observe(time.perf_counter() - t0,
+                                      msp=self.mspid, result=result)
+
+    def _check_chain(self, ident: Identity,
+                     at_time: Optional[datetime.datetime]) -> None:
         now = at_time or datetime.datetime.now(datetime.timezone.utc)
         chain = self._build_chain(ident.cert)
         for depth, cert in enumerate(chain):
             if not (cert.not_valid_before_utc <= now <= cert.not_valid_after_utc):
                 raise MSPValidationError(
-                    f"cert at depth {depth} outside validity period")
+                    f"cert at depth {depth} outside validity period",
+                    reason="expired")
             if depth > 0:
                 # issuers must be CAs
                 try:
@@ -120,7 +158,8 @@ class MSP:
                         f"issuer at depth {depth} lacks BasicConstraints")
             issuer_sub = cert.issuer.public_bytes()
             if (issuer_sub, cert.serial_number) in self._revoked:
-                raise MSPValidationError(f"cert at depth {depth} is revoked")
+                raise MSPValidationError(f"cert at depth {depth} is revoked",
+                                         reason="revoked")
 
     def is_valid(self, ident: Identity) -> bool:
         try:

@@ -120,6 +120,93 @@ def provision_orderers(base_dir: str, n: int, channel_id: str = "ch",
     return paths
 
 
+# -- the roll: enrolment at the scale of an application's users ---------------
+
+ROLL_CHUNK = 4096    # members issued in one task; a roll larger than one
+                     # chunk is issued by a pool of processes
+
+
+def issue_roll_chunk(ca_cert_pem: bytes, ca_key_pem: bytes, mspid: str,
+                     scheme, first: int, count: int) -> Tuple[list, list]:
+    """Members `first` .. `first + count` of one org's roll, enrolled
+    under the org's CA (common name `user<j>@<org>`, as one
+    `registerAndEnrollUser` a user would leave): (certificates, keys),
+    PEM strings.  A task of `enrol_roll`'s pool, so it takes the CA as
+    PEM and builds the issuer again."""
+    from fabric_tpu.msp.ca import CA
+    issuer = CA.load(ca_cert_pem, ca_key_pem)
+    certs, keys = [], []
+    for j in range(first, first + count):
+        cert, key = issuer.issue(f"user{j}@{mspid}", scheme=scheme)
+        certs.append(_cert_pem(cert).decode())
+        keys.append(_key_pem(key).decode())
+    return certs, keys
+
+
+def roll_member(index: int, n_orgs: int) -> Tuple[int, int]:
+    """(org position, number within the org's roll) of roll member
+    `index`: org by org in turn, as the pooled clients are dealt."""
+    return index % n_orgs, index // n_orgs
+
+
+def enrol_roll(base_dir: str, orgs: dict, org_schemes: dict, size: int,
+               revoked) -> Tuple[dict, dict]:
+    """Enrol `size` clients over `orgs` ({name: DevOrg}, in order), org
+    by org in turn, and revoke the members `revoked` names (roll
+    indices): ({org: path of its roll}, {org: its CRL as PEM, signed by
+    its CA — none for an org with nobody revoked}).  One roll per org:
+    `roll_<org>.json` = {"mspid", "cert_pem": [...], "key_pem": [...],
+    "revoked": [numbers within the org]} — a certificate and a key a
+    member, not a client config each."""
+    from fabric_tpu.crypto import x509
+    names = list(orgs)
+    gone = {name: [] for name in names}      # before anything is issued
+    for index in sorted(set(revoked)):
+        if not 0 <= index < size:
+            raise ValueError(f"revoked member {index} is not on a roll "
+                             f"of {size}")
+        k, j = roll_member(index, len(names))
+        gone[names[k]].append(j)
+    per_org = [len(range(k, size, len(names))) for k in range(len(names))]
+    tasks = [(name, first, min(ROLL_CHUNK, n - first))
+             for name, n in zip(names, per_org)
+             for first in range(0, n, ROLL_CHUNK)]
+
+    def args_of(name, first, count):
+        ca = orgs[name].issuer
+        return (ca.cert_pem(), _key_pem(ca._key), name,
+                org_schemes.get(name), first, count)
+
+    if size > ROLL_CHUNK:
+        # spawned workers import the caller's `__main__`: a script that
+        # enrols a roll this large needs its `if __name__ == "__main__"`
+        import multiprocessing
+        from concurrent.futures import ProcessPoolExecutor
+        with ProcessPoolExecutor(
+                max_workers=min(len(tasks), os.cpu_count() or 1, 8),
+                mp_context=multiprocessing.get_context("spawn")) as pool:
+            chunks = list(pool.map(issue_roll_chunk,
+                                   *zip(*(args_of(*t) for t in tasks))))
+    else:
+        chunks = [issue_roll_chunk(*args_of(*t)) for t in tasks]
+    certs = {name: [] for name in names}
+    keys = {name: [] for name in names}
+    for (name, _first, _count), (c, k) in zip(tasks, chunks):
+        certs[name].extend(c)
+        keys[name].extend(k)
+    paths, crls = {}, {}
+    for name in names:
+        paths[name] = os.path.join(base_dir, f"roll_{name}.json")
+        with open(paths[name], "w") as f:
+            json.dump({"mspid": name, "cert_pem": certs[name],
+                       "key_pem": keys[name], "revoked": gone[name]}, f)
+        if gone[name]:
+            crls[name] = orgs[name].issuer.crl(
+                [x509.load_pem_x509_certificate(certs[name][j].encode())
+                 for j in gone[name]])
+    return paths, crls
+
+
 def free_ports(n: int) -> List[int]:
     """n ports the OS just handed out (bound momentarily, released)."""
     import socket
@@ -143,7 +230,9 @@ def provision_network(base_dir: str, n_orderers: int = 3,
                       batch: BatchConfig = None,
                       spare_orderers: int = 0,
                       clients_per_org: int = 1,
-                      org_schemes: dict = None) -> dict:
+                      org_schemes: dict = None,
+                      roll_size: int = 0,
+                      roll_revoked=()) -> dict:
     """Full dev network: orderer cluster + peer-org peers on one channel.
 
     The nwo-style harness (reference: integration/nwo/network.go:173) —
@@ -169,6 +258,16 @@ def provision_network(base_dir: str, n_orderers: int = 3,
     the state of a Fabric v3 channel (capability V3_0) whose orgs move
     to Ed25519 one at a time.  An org not named signs P-256, as does
     every org CA and admin: the channel's MSPs accept both either way.
+
+    `roll_size`: besides the pooled clients, enrol that many clients
+    over the peer orgs, org by org in turn — the account holders of an
+    application whose users are Fabric identities (one enrolment
+    certificate each from the org's CA).  They are written as one roll
+    per org ("rolls": {org: path}, `enrol_roll`), not as a client
+    config each.  `roll_revoked`: the roll members (indices) whose
+    certificates are revoked: each org's CRL, signed by its CA, is in
+    that org's MSP in the genesis channel config, as `fabric-ca-client
+    revoke` + `gencrl` + a config update would leave it.
     """
     from fabric_tpu.orderer.cluster import cert_fingerprint
 
@@ -186,12 +285,17 @@ def provision_network(base_dir: str, n_orderers: int = 3,
     peer_ports = ports[n_orderers:n_orderers + n_peers]
     spare_ports = ports[n_orderers + n_peers:]
 
+    # the roll first: its CRLs are part of the channel config that every
+    # node's file carries
+    rolls, crls = ({}, {}) if not roll_size else enrol_roll(
+        base_dir, p_orgs, org_schemes, int(roll_size), roll_revoked)
     org_cfgs = []
     for name, org in all_orgs.items():
         mc = org.msp_config()
         org_cfgs.append(OrgConfig(mspid=name,
                                   root_certs=tuple(mc.root_certs_pem),
-                                  admins=tuple(mc.admin_certs_pem)))
+                                  admins=tuple(mc.admin_certs_pem),
+                                  crls=(crls[name],) if name in crls else ()))
     # consenter identities first: the channel config itself carries the
     # rich consenter entries (raft id -> addr + mspid + cert fingerprint)
     creds = [ord_org.issuer.issue(f"orderer{i + 1}@OrdererOrg")
@@ -384,4 +488,4 @@ def provision_network(base_dir: str, n_orderers: int = 3,
     return {"orderers": orderer_paths, "peers": peer_paths,
             "spare_orderers": spare_paths,
             "clients": clients, "clients_ed25519": clients_ed25519,
-            "client_pool": client_pool, "admins": admins}
+            "client_pool": client_pool, "admins": admins, "rolls": rolls}

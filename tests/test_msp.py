@@ -88,6 +88,14 @@ def test_ed25519_org():
 
 
 def test_cached_msp(org):
+    from fabric_tpu.ops_plane import registry
+    lookups = registry.counter("msp_cache_total")
+
+    def asked(result):
+        return sum(lookups.value(msp="Org1MSP", op=op, result=result)
+                   for op in ("deserialize", "validate", "principal"))
+
+    hits0, misses0 = asked("hit"), asked("miss")
     cmsp = CachedMSP(org.msp())
     user = org.new_identity("gina")
     data = user.serialize()
@@ -95,8 +103,8 @@ def test_cached_msp(org):
         ident = cmsp.deserialize_identity(data)
         cmsp.validate(ident)
         assert cmsp.satisfies_principal(ident, Principal.member("Org1MSP"))
-    assert cmsp.stats["hits"] >= 12
-    assert cmsp.stats["misses"] == 3
+    assert asked("hit") - hits0 == 12
+    assert asked("miss") - misses0 == 3
 
 
 def test_msp_manager(org):

@@ -606,7 +606,9 @@ def test_a_bump_run_exposes_what_the_parent_did_and_the_new_series(
 # `validation_dispatch_seconds` (`validator_stage_seconds{stage=
 # "dispatch"}`), plus PR 38's `validator_tail_total` (which tail each
 # block took, in transactions) and PR 40's `validator_handoff_sigs_total`
-# (the form the provider got each block's unique items in)
+# (the form the provider got each block's unique items in) and PR 41's
+# `validator_creators_total` (a block's creators by whether its memo knew
+# them)
 PARENT_FAMILIES = {
     "commit_graph_apply_batch_size",
     "committed_blocks_total", "committed_txs_total",
@@ -616,4 +618,5 @@ PARENT_FAMILIES = {
     "pipeline_collect_under_verify_frac", "state_checkpoint_height",
     "state_checkpoint_seconds", "state_checkpoint_total", "state_shard_keys",
     "validation_duration_seconds", "validator_stage_seconds",
-    "validator_handoff_sigs_total", "validator_tail_total"}
+    "validator_creators_total", "validator_handoff_sigs_total",
+    "validator_tail_total"}
