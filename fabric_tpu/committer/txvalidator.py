@@ -406,10 +406,123 @@ class TxValidator:
                   else _msp_rejects(self.msps, creator))
         acct.first += 1
         if reason is not None:
-            acct.rejected[reason] = acct.rejected.get(reason, 0) + 1
+            acct.reject(reason)
         ent = None if reason is not None else _memo_ent(creator)
         acct.add(t0)
         return ent
+
+    def _resolve_creators(self, creators: list, acct: "_Identities"):
+        """The deep tail's `_resolve_creator` for a block's unique
+        creators at once: each deserialised, then one `validate_many` an
+        MSP (an MSP without that verb, or whose `validate` was replaced
+        on the instance, is asked one identity at a time) -> (c_ents,
+        deferred).
+
+        Where a block brings a CA enough unseen creators for the
+        provider's rows lane (`CachedMSP.validate_many`: the count is
+        the provider's own `fast_key_threshold`, read off the block's
+        cache misses; a provider without one has no such lane and
+        nothing is deferred), the CA's signatures over their
+        certificates are not checked here: they go to the provider as
+        ONE `batch_verify_async` of their own, enqueued now, so that
+        the device works under `assemble` and the hand-over.  Not rows
+        of the block's table: three CA keys' six rows more would lift a
+        500-tx block's twelve past `rows@16` into `rows@64`, four times
+        the grid for 12% more signatures and a program nobody warmed; a
+        second `rows@16` costs ~10 ms of an idle chip and compiles
+        nothing.  Not through the verdict cache either: the MSP's LRU
+        *is* the identity cache.  A deferred creator gets its memo
+        entry as a sound one does; `_settle_creators` takes the refused
+        ones out before the gate.  `deferred`: None, or what that needs."""
+        t0 = time.perf_counter()
+        msps = self.msps
+        ents: list = [None] * len(creators)
+        by_msp: dict = {}          # mspid -> (creator slots, identities)
+        for slot, raw in enumerate(creators):
+            ident = self._deserialize(raw)
+            if ident is None:
+                acct.reject("undecodable")
+                continue
+            ents[slot] = _memo_ent(ident)
+            slots, idents = by_msp.setdefault(ident.mspid, ([], []))
+            slots.append(slot)
+            idents.append(ident)
+        t_parsed = time.perf_counter()
+        provider = self.provider
+        min_batch = getattr(provider, "fast_key_threshold", None)
+        chains, items = [], []
+        for mspid, (slots, idents) in by_msp.items():
+            msp = msps[mspid]
+            many = _many_verb(msp)
+            if many is None:
+                errors = [_msp_error(msp, ident) for ident in idents]
+                pending = None
+            else:
+                errors, pending = many(idents, min_batch)
+            for slot, err in zip(slots, errors):
+                if err is not None:
+                    ents[slot] = None
+                    acct.reject(getattr(err, "reason", "untrusted"))
+            if pending is not None:
+                chains.append((pending, slots))
+                items += pending.items
+        acct.first += len(creators)
+        deferred = None
+        if items:
+            t_checked = time.perf_counter()
+            with dispatch_site("validator"):
+                resolve = provider.batch_verify_async(items)
+            deferred = {"chains": chains,
+                        "wait": _resolve_eagerly(resolve, self._econ),
+                        "parent": tracing.tracer.current_context()}
+            # the span's parts: certificates parsed, chains' host checks
+            # and items, the pack and enqueue of the items
+            acct.attrs = {
+                "deferred": len(items),
+                "parse_ms": round((t_parsed - t0) * 1e3, 3),
+                "chains_ms": round((t_checked - t_parsed) * 1e3, 3),
+                "enqueue_ms": round(
+                    (time.perf_counter() - t_checked) * 1e3, 3)}
+        acct.add(t0)
+        return ents, deferred
+
+    def _settle_creators(self, state: dict) -> dict:
+        """The verdicts of a block's deferred certificate signatures,
+        before the gate: every creator's into its MSP's cache and
+        account, and every transaction of a creator whose certificate
+        the CA did not sign stamped BAD_CREATOR_SIGNATURE with its plan
+        dropped — the code `assemble` gives a creator the MSP refused
+        on the host, so the flags are the host branch's bit for bit.
+        Then the block's identity account is booked, which waited for
+        these.  -> the wait for the verdicts and the settling, in ms,
+        for the caller's span."""
+        t0 = time.perf_counter()
+        deferred = state["deferred"]
+        thread, holder = deferred["wait"]
+        thread.join()
+        if "err" in holder:
+            raise holder["err"]
+        t_ready = time.perf_counter()
+        verdicts = holder["out"]
+        acct = state["identities"]
+        refused = set()
+        lo = 0
+        for pending, slots in deferred["chains"]:
+            hi = lo + len(pending.items)
+            for k, err in pending.settle(verdicts[lo:hi]):
+                refused.add(slots[k])
+                acct.reject(err.reason)
+            lo = hi
+        if refused:
+            codes = state["codes"]
+            bad = {w[0] for w in state["works"] if w[2] in refused}
+            for tx in bad:
+                codes[tx] = int(ValidationCode.BAD_CREATOR_SIGNATURE)
+            state["plans"] = [p for p in state["plans"] if p[0] not in bad]
+        self._note_identities(int(state["block"].header.number), acct,
+                              parent=deferred["parent"])
+        return {"certs_wait_ms": round((t_ready - t0) * 1e3, 3),
+                "settle_ms": round((time.perf_counter() - t_ready) * 1e3, 3)}
 
     def _resolve_endorser(self, ident_bytes: bytes, acct: "_Identities"):
         """Endorser memo value — deserialize only, NO chain validation
@@ -421,7 +534,8 @@ class TxValidator:
         acct.add(t0)
         return None if ident is None else _memo_ent(ident)
 
-    def _note_identities(self, num: int, acct: "_Identities") -> None:
+    def _note_identities(self, num: int, acct: "_Identities",
+                         parent=None) -> None:
         """One block's identity resolution, from the account's plain
         numbers: the creators into `validator_creators_total` by whether
         the block's memo knew them, the refused ones into
@@ -429,7 +543,11 @@ class TxValidator:
         span `validator.identities` — on the deep tail the stretch
         between the C walk and `assemble`; on the classic tail, where a
         resolution happens at the first transaction that needs it, from
-        the first one's start for the sum of them all."""
+        the first one's start for the sum of them all.  A block that
+        deferred certificate signatures is booked when their verdicts
+        are in (`_settle_creators`, under the `parent` context collect
+        ran in) and says how many in `deferred`, with the span's parts
+        beside it (`parse_ms`, `chains_ms`, `enqueue_ms`)."""
         try:
             from fabric_tpu.ops_plane import registry
             ch = self.channel_id
@@ -452,13 +570,13 @@ class TxValidator:
         except Exception:
             pass
         if acct.start is not None:
+            attrs = {"block": int(num), "unique_creators": acct.first,
+                     "unique_endorsers": acct.endorsers,
+                     "rejected": sum(acct.rejected.values()),
+                     **acct.attrs}
             tracing.tracer.record_span(
                 "validator.identities", acct.start,
-                acct.start + acct.seconds,
-                attributes={"block": int(num),
-                            "unique_creators": acct.first,
-                            "unique_endorsers": acct.endorsers,
-                            "rejected": sum(acct.rejected.values())})
+                acct.start + acct.seconds, attributes=attrs, parent=parent)
 
     def _collect_tx_fast(self, tx_num: int, rec, flags: TxFlags,
                          seen_txids: Dict[str, int],
@@ -761,25 +879,7 @@ class TxValidator:
                     misses, reason = list(table), "no_verb"
                 # items are their OWN dedup keys (VerifyItem NamedTuple)
                 resolve = provider.batch_verify_async(misses)
-        # EAGER background resolution: a thread blocks on the results
-        # the moment the dispatch is enqueued.  The provider's dispatch
-        # account times the device by when a waiter that was ALREADY
-        # blocked saw the output (`t_ready`, bccsp/dispatch_account.py),
-        # and a driver that begins block N+1 before finishing block N
-        # keeps this fetch ahead of the later dispatch.
-        holder: dict = {}
-        t_disp = time.perf_counter()
-        econ = self._econ
-
-        def run():
-            try:
-                holder["out"] = resolve()
-                econ.note_verify(t_disp, time.perf_counter())
-            except BaseException as exc:   # re-raised in _await
-                holder["err"] = exc
-
-        th = threading.Thread(target=run, daemon=True)
-        th.start()
+        th, holder = _resolve_eagerly(resolve, self._econ)
         return part, th, holder, (n_arrays, reason)
 
     def _await(self, handle: tuple) -> np.ndarray:
@@ -1031,10 +1131,11 @@ class TxValidator:
         # one MSP resolution per unique identity (the whole-block analogue
         # of the classic tail's (0,creator)/(1,endorser) memo dicts)
         acct = _Identities()
-        c_ents = [self._resolve_creator(b, acct) for b in creators]
+        c_ents, deferred = self._resolve_creators(creators, acct)
         e_ents = [self._resolve_endorser(b, acct) for b in endorsers]
         acct.again = len(works) - len(creators)
-        self._note_identities(num, acct)
+        if deferred is None:
+            self._note_identities(num, acct)
 
         # the block's unique items in dispatch order: a P-256 item is a
         # row of the table's flat buffers, not an object
@@ -1055,10 +1156,13 @@ class TxValidator:
             identities_ms=round(acct.seconds * 1e3, 3),
             assemble_ms=round((t_assembled - t_assemble) * 1e3, 3),
             handover_ms=round((time.perf_counter() - t_assembled) * 1e3, 3))
-        return {"deep": True, "block": block, "codes": codes,
-                "plans": plans, "items": table, "verify": verify,
-                "msps": self._msps_snapshot, "seen_txids": seen_txids,
-                "collect_s": collect_s, "n_refs": n_refs}
+        state = {"deep": True, "block": block, "codes": codes,
+                 "plans": plans, "items": table, "verify": verify,
+                 "msps": self._msps_snapshot, "seen_txids": seen_txids,
+                 "collect_s": collect_s, "n_refs": n_refs}
+        if deferred is not None:
+            state.update(deferred=deferred, identities=acct, works=works)
+        return state
 
     # per-block stage SLIs + live overlap gauge (the SLO plane's inputs;
     # the "commit" stage lands next door in committer._observe_metrics)
@@ -1117,13 +1221,17 @@ class TxValidator:
         index = state["items"]
 
         t0 = time.perf_counter()
+        # enqueued first, so ready first: settled on the host while the
+        # device runs the block's own programs
+        settled = (self._settle_creators(state) if "deferred" in state
+                   else {})
         # positional over the table, as gate and the fused path read it
         verdict = self._await(state["verify"]).view(np.uint8)
         dispatch_s = time.perf_counter() - t0
         tracing.tracer.record_span(
             "validator.dispatch_wait", t0, t0 + dispatch_s,
             attributes={"block": int(block.header.number),
-                        "unique_items": len(index)})
+                        "unique_items": len(index), **settled})
 
         t0 = time.perf_counter()
         flags = None
@@ -1257,9 +1365,53 @@ def _packed_verb(provider):
     return getattr(provider, "batch_verify_packed_async", None)
 
 
+def _many_verb(msp):
+    """The MSP's `validate_many`, or None where it validates one
+    identity at a time: it has no such verb (an idemix MSP), or its
+    `validate` was replaced on the instance and the class's verb would
+    go round the replacement."""
+    if "validate" in getattr(msp, "__dict__", ()):
+        return None
+    return getattr(msp, "validate_many", None)
+
+
+def _resolve_eagerly(resolve, econ: _PipelineEconomics) -> tuple:
+    """EAGER background resolution: a thread blocks on the results the
+    moment a dispatch is enqueued -> (thread, holder), the results in
+    holder["out"] or what `resolve` raised in holder["err"].  The
+    provider's dispatch account times the device by when a waiter that
+    was ALREADY blocked saw the output (`t_ready`,
+    bccsp/dispatch_account.py), and a driver that begins block N+1
+    before finishing block N keeps this fetch ahead of the later
+    dispatch."""
+    holder: dict = {}
+    t_disp = time.perf_counter()
+
+    def run():
+        try:
+            holder["out"] = resolve()
+            econ.note_verify(t_disp, time.perf_counter())
+        except BaseException as exc:   # re-raised by whoever joins
+            holder["err"] = exc
+
+    th = threading.Thread(target=run, daemon=True)
+    th.start()
+    return th, holder
+
+
 def _false_oracle(_txid: str) -> bool:
     """Default ledger-txid oracle for an unwired validator."""
     return False
+
+
+def _msp_error(msp, ident: Identity) -> Optional[Exception]:
+    """None where the MSP validates the identity's chain, else what it
+    raised."""
+    try:
+        msp.validate(ident)
+    except Exception as exc:
+        return exc
+    return None
 
 
 def _msp_rejects(msps: Dict[str, object], ident: Identity) -> Optional[str]:
@@ -1268,18 +1420,15 @@ def _msp_rejects(msps: Dict[str, object], ident: Identity) -> Optional[str]:
     msp = msps.get(ident.mspid)
     if msp is None:
         return "undecodable"
-    try:
-        msp.validate(ident)
-    except Exception as exc:
-        return getattr(exc, "reason", "untrusted")
-    return None
+    err = _msp_error(msp, ident)
+    return None if err is None else getattr(err, "reason", "untrusted")
 
 
 class _Identities:
     """One block's account of identity resolution (plain numbers, read
     once a block by `_note_identities`)."""
     __slots__ = ("start", "seconds", "first", "again", "endorsers",
-                 "rejected")
+                 "rejected", "attrs")
 
     def __init__(self):
         self.start = None        # the first resolution's start
@@ -1288,6 +1437,12 @@ class _Identities:
         self.again = 0           # transactions whose creator it knew
         self.endorsers = 0       # unique endorsers resolved
         self.rejected = {}       # reason -> unique creators refused
+        self.attrs = {}          # further attributes of the span, where a
+        #                          provider got creators' CA signatures:
+        #                          how many, and the stretch's parts
+
+    def reject(self, reason: str) -> None:
+        self.rejected[reason] = self.rejected.get(reason, 0) + 1
 
     def add(self, t0: float) -> None:
         if self.start is None:

@@ -11,6 +11,7 @@ collection instead of verifying immediately.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import cached_property
 from typing import Optional
 
 from fabric_tpu.crypto import x509
@@ -84,6 +85,12 @@ class Identity:
     @property
     def subject(self) -> str:
         return self.cert.subject.rfc4514_string()
+
+    @cached_property
+    def issuer_der(self) -> bytes:
+        """The certificate's issuer name as DER, what an MSP looks its
+        CAs and its CRLs up by: encoded once an identity."""
+        return self.cert.issuer.public_bytes()
 
     def expires_at(self):
         return self.cert.not_valid_after_utc

@@ -10,18 +10,18 @@ from collections import OrderedDict
 
 import pytest
 
-from fabric_tpu.bccsp import SCHEME_P256
+from fabric_tpu.bccsp import SCHEME_ED25519, SCHEME_P256
 from fabric_tpu.bccsp.factory import init_factories, FactoryOpts
-from fabric_tpu.bccsp.sw import SigningKey
+from fabric_tpu.bccsp.sw import P256_HALF_N, SigningKey, SoftwareProvider
 from fabric_tpu.committer import PolicyRegistry, TxValidator
 from fabric_tpu.committer import txvalidator as tv
 from fabric_tpu.config import Bundle, ChannelConfig
-from fabric_tpu.crypto import ec, hashes, x509
+from fabric_tpu.crypto import decode_dss_signature, ec, hashes, x509
 from fabric_tpu.msp import CachedMSP, Principal
-from fabric_tpu.msp.ca import DevOrg
+from fabric_tpu.msp.ca import CA, DevOrg
 from fabric_tpu.msp.cache import CACHE_SIZE
 from fabric_tpu.msp.identity import SigningIdentity
-from fabric_tpu.msp.msp import MSPValidationError
+from fabric_tpu.msp.msp import MSP, MSPConfig, MSPValidationError
 from fabric_tpu.node import provision
 from fabric_tpu.node.orderer import load_signing_identity
 from fabric_tpu.ops_plane import registry, tracing
@@ -39,10 +39,10 @@ def sw_provider():
     return init_factories(FactoryOpts(default="SW"))
 
 
-def forge(victim: SigningIdentity) -> SigningIdentity:
+def forge(victim: SigningIdentity, serial=None) -> SigningIdentity:
     """An identity under `victim`'s subject and its CA's issuer name,
     with a key of the forger's own, signed by a key that is not the
-    CA's."""
+    CA's (under `serial`, where the forger copies one)."""
     rogue = ec.generate_private_key(ec.SECP256R1())
     key = ec.generate_private_key(ec.SECP256R1())
     now = datetime.datetime.now(datetime.timezone.utc)
@@ -50,7 +50,7 @@ def forge(victim: SigningIdentity) -> SigningIdentity:
             .subject_name(victim.cert.subject)
             .issuer_name(victim.cert.issuer)
             .public_key(key.public_key())
-            .serial_number(x509.random_serial_number())
+            .serial_number(serial or x509.random_serial_number())
             .not_valid_before(now - datetime.timedelta(minutes=5))
             .not_valid_after(now + datetime.timedelta(days=30))
             .add_extension(x509.BasicConstraints(ca=False, path_length=None),
@@ -294,9 +294,10 @@ def unseen_block(sw_provider):
     return raws, [int(c) for c in want], msps, policies
 
 
-def tamper(env, creator):
+def tamper(env, creator, which=1):
     """The envelope signed again over a transaction whose second
-    endorsement has one signature byte flipped."""
+    endorsement (or the one at `which`) has one signature byte
+    flipped."""
     from fabric_tpu.protocol import Transaction, TransactionAction
     from fabric_tpu.protocol.types import TX_ENDORSER
     from fabric_tpu.utils import serde
@@ -304,9 +305,9 @@ def tamper(env, creator):
     tx = Transaction.from_dict(payload["data"])
     ta = tx.actions[0]
     ends = list(ta.endorsements)
-    ends[1] = Endorsement(ends[1].endorser,
-                          ends[1].signature[:-1]
-                          + bytes([ends[1].signature[-1] ^ 1]))
+    ends[which] = Endorsement(ends[which].endorser,
+                              ends[which].signature[:-1]
+                              + bytes([ends[which].signature[-1] ^ 1]))
     ta = TransactionAction(ta.proposal_hash, ta.action, tuple(ends))
     header = payload["header"]
     return build.signed_envelope(
@@ -397,3 +398,362 @@ def test_creators_a_block_repeats_are_counted_again(sw_provider):
              for k in ("first", "again")}
     assert (after["first"] - before["first"],
             after["again"] - before["again"]) == (2, 8)
+
+
+# -- a CA's signature on the device: the deep tail's deferred links ---------------
+
+class RowsSW(SoftwareProvider):
+    """The software provider with what the deep tail reads off a device
+    provider: the count at which a key earns the rows lane.  It keeps
+    every batch it was handed."""
+    fast_key_threshold = 64
+
+    def __init__(self):
+        super().__init__()
+        self.batches = []
+
+    def batch_verify_async(self, items):
+        self.batches.append(list(items))
+        return super().batch_verify_async(items)
+
+
+PER_CA = 200
+BIG = {"revoked": (3, 17, 40, 41, 398), "expired": (8, 9),
+       "forged": (5, 30, 391), "tampered": (19, 29, 41, 300)}
+
+
+def high_s(ident) -> bool:
+    return decode_dss_signature(ident.cert.signature)[1] > P256_HALF_N
+
+
+@pytest.fixture(scope="module")
+def big_block(sw_provider):
+    """(raws, expected codes, fresh MSPs, policies, creators): 400
+    transactions, a creator each, 200 under each of two CAs; five
+    creators revoked by their org's CRL, two past their validity period,
+    three forged, four endorsements tampered (one on a revoked
+    creator's transaction), and about half the certificates signed with
+    s > n/2, as OpenSSL leaves them."""
+    orgs = [DevOrg("Org1"), DevOrg("Org2")]
+    past = (datetime.datetime.now(datetime.timezone.utc)
+            - datetime.timedelta(minutes=1))
+    creators = [orgs[i % 2].new_identity(
+        f"user{i}", not_after=past if i in BIG["expired"] else None)
+        for i in range(2 * PER_CA)]
+    crls = [org.issuer.crl([creators[i].cert for i in BIG["revoked"]
+                            if i % 2 == k]) for k, org in enumerate(orgs)]
+
+    def msps():
+        return {org.mspid: CachedMSP(org.msp(crls_pem=[crl]))
+                for org, crl in zip(orgs, crls)}
+
+    # one endorsement a transaction: on the CPU backend a program takes
+    # seconds, and 400 signatures fit the smallest rows grid
+    policies = PolicyRegistry()
+    policies.set_policy("cc", parse_policy("OR('Org1.member')"))
+    endorsers = [orgs[0].new_identity("e1")]
+    refused = BIG["revoked"] + BIG["expired"] + BIG["forged"]
+    raws, want = [], []
+    for i, creator in enumerate(creators):
+        if i in BIG["forged"]:
+            creator = forge(creator)
+        rwset = TxRwSet((NsRwSet("cc", writes=(KVWrite(f"k{i}", b"v"),)),))
+        env = build.endorser_tx("big", "cc", "1.0", rwset, creator, endorsers)
+        if i in BIG["tampered"]:
+            env = tamper(env, creator, which=0)
+        raws.append(env.serialize())
+        want.append(int(ValidationCode.BAD_CREATOR_SIGNATURE if i in refused
+                        else ValidationCode.ENDORSEMENT_POLICY_FAILURE
+                        if i in BIG["tampered"] else ValidationCode.VALID))
+    assert 100 < sum(high_s(c) for c in creators) < 300
+    return raws, want, msps, policies, creators
+
+
+def run_tail(tail, provider, big_block, monkeypatch):
+    """-> (codes, creators refused by reason, leaf links by where,
+    chains validated, CA signatures the identities span says were sent)."""
+    raws, _want, msps, policies, _creators = big_block
+    v = TxValidator("big", msps(), provider, policies)
+    if tail == "python":
+        v.force_python_collect = True
+    elif tail == "classic":
+        monkeypatch.setattr(tv, "_fastcollect", _NoDigest(tv._fastcollect))
+    reasons = ("revoked", "untrusted", "expired", "undecodable")
+    refused0 = {r: counted("validator_creator_rejected_total",
+                           channel="big", reason=r) for r in reasons}
+    links0 = {w: counted("msp_chain_signatures_total", where=w)
+              for w in ("device", "host")}
+    seen0 = counted("msp_validate_seconds")
+    spans = {}
+    monkeypatch.setattr(
+        tracing.tracer, "record_span",
+        lambda name, t0, t1, attributes=None, parent=None:
+        spans.__setitem__(name, attributes))
+    state = v.validate_begin(
+        Block(BlockHeader(7, b"p", b"d"), list(raws), BlockMetadata()))
+    assert bool(state.get("deep")) == tail.startswith("deep")
+    codes = [int(c) for c in v.validate_finish(state).flags.codes()]
+    monkeypatch.undo()
+    sent = spans["validator.identities"].get("deferred", 0)
+    assert (sent > 0) == (tail in ("deep-device", "deep-rows"))
+    assert ("settle_ms" in spans["validator.dispatch_wait"]) == (sent > 0)
+    assert spans["validator.identities"]["rejected"] == sum(
+        len(BIG[k]) for k in ("revoked", "expired", "forged"))
+    refused = {r: counted("validator_creator_rejected_total", channel="big",
+                          reason=r) - refused0[r] for r in reasons}
+    links = {w: counted("msp_chain_signatures_total", where=w) - links0[w]
+             for w in links0}
+    return (codes, refused, links, counted("msp_validate_seconds") - seen0,
+            sent)
+
+
+@pytest.mark.parametrize("tail", ["deep-device", "deep-rows", "deep",
+                                  "classic", "python"])
+def test_a_block_of_unseen_creators_reads_the_same_on_every_tail(
+        big_block, sw_provider, monkeypatch, tail):
+    """Flag for flag and refusal for refusal: the deep tail with the
+    device provider (CPU backend) and with `RowsSW`, both deferring the
+    CA's signatures; the deep tail on the software provider, the
+    classic and the Python tail, every chain on the host."""
+    if tv._fastcollect is None and tail != "python":
+        pytest.skip("native fastcollect unavailable")
+    want = big_block[1]
+    if tail == "deep-device":
+        from fabric_tpu.bccsp.jaxtpu import JaxTpuProvider
+        provider = JaxTpuProvider()
+    else:
+        provider = RowsSW() if tail == "deep-rows" else sw_provider
+    codes, refused, links, observed, sent = run_tail(
+        tail, provider, big_block, monkeypatch)
+    assert codes == want
+    assert refused == {"revoked": len(BIG["revoked"]),
+                       "untrusted": len(BIG["forged"]),
+                       "expired": len(BIG["expired"]), "undecodable": 0}
+    # one observation an identity on both branches: 400 creators and
+    # the endorser the policy's principal validates
+    assert observed == 2 * PER_CA + 1
+    checked_here = len(BIG["revoked"]) + len(BIG["expired"])
+    if tail in ("deep-device", "deep-rows"):
+        # the host checks decided first and sent nothing for those
+        assert links == {"device": 2 * PER_CA - checked_here, "host": 1}
+        assert sent == 2 * PER_CA - checked_here
+    else:
+        assert links == {"device": 0, "host": 2 * PER_CA + 1}
+    if tail == "deep-device":
+        # two programs of the block's own and one for the certificates
+        assert provider.stats["dispatches"] == 3
+        assert provider.stats["fallbacks"] == 0
+    if tail == "deep-rows":
+        certs, = [b for b in provider.batches
+                  if len(b) == 2 * PER_CA - checked_here]
+        assert all(decode_dss_signature(it.signature)[1] <= P256_HALF_N
+                   for it in certs)
+        assert len({it.pubkey for it in certs}) == 2
+
+
+def hot_block(endorser, creators, channel="hot"):
+    raws = [build.endorser_tx(
+        channel, "cc", "1.0",
+        TxRwSet((NsRwSet("cc", writes=(KVWrite(f"k{i}", b"v"),)),)),
+        c, [endorser]).serialize() for i, c in enumerate(creators)]
+    return Block(BlockHeader(1, b"p", b"d"), raws, BlockMetadata())
+
+
+def test_settlement_fills_the_cache_as_validate_does(sw_provider):
+    """150 unseen creators of one CA, one forged, through the deferred
+    branch: the validation cache holds what 150 `validate` calls in the
+    block's order leave — the last 100, the forged one a cached
+    `untrusted` — and the same creators in the next block are hits that
+    send nothing."""
+    if tv._fastcollect is None:
+        pytest.skip("native fastcollect unavailable")
+    org = DevOrg("Org1")
+    creators = [org.new_identity(f"u{i}") for i in range(150)]
+    creators[120] = forge(creators[120])
+    policies = PolicyRegistry()
+    policies.set_policy("cc", parse_policy("OR('Org1.member')"))
+    cmsp, twin = CachedMSP(org.msp()), CachedMSP(org.msp())
+    provider = RowsSW()
+    endorser = org.new_identity("e")
+    v = TxValidator("hot", {"Org1": cmsp}, provider, policies)
+    res = v.validate(hot_block(endorser, creators))
+    assert res.flags.valid_count() == 149
+    assert [len(b) for b in provider.batches] == [150, 300]
+    for c in creators:
+        try:
+            twin.validate(twin.deserialize_identity(c.serialize()))
+        except MSPValidationError:
+            pass
+
+    def held(m):
+        return [(k.cert.serial_number, getattr(e, "reason", e))
+                for k, e in m._valid._d.items()]
+
+    assert held(cmsp) == held(twin) and len(held(cmsp)) == CACHE_SIZE
+    assert held(cmsp)[0][0] == creators[50].cert.serial_number
+    assert (creators[120].cert.serial_number, "untrusted") in held(cmsp)
+    # the next block: the cached hundred again
+    hits0 = counted("msp_cache_total", msp="Org1", op="validate",
+                    result="hit")
+    seen0 = counted("msp_validate_seconds", msp="Org1")
+    del provider.batches[:]
+    res = v.validate(hot_block(endorser, creators[50:]))
+    assert res.flags.valid_count() == 99
+    assert counted("msp_cache_total", msp="Org1", op="validate",
+                   result="hit") - hits0 == 100
+    assert counted("msp_validate_seconds", msp="Org1") == seen0
+    # one dispatch, the block's own: 99 sound creators x 2 signatures
+    assert [len(b) for b in provider.batches] == [198]
+    assert held(cmsp) == held(twin)
+
+
+def test_a_refused_identity_keeps_nothing_of_its_block_alive(sw_provider):
+    """An error `validate_many` stores carries no traceback: that would
+    hold the call's frame, and through it every identity of the block,
+    in a cycle only a whole-heap pass frees — and a committing peer
+    freezes what a block leaves (`utils/heap.py`)."""
+    import gc
+    import weakref
+    org = DevOrg("Org1")
+    members = [org.new_identity(f"u{i}") for i in range(2 * CACHE_SIZE + 20)]
+    half = len(members) // 2
+    crl = org.issuer.crl([members[7].cert, members[half + 7].cert])
+    cmsp = CachedMSP(org.msp(crls_pem=[crl]))
+
+    def block(some):
+        idents = [cmsp.inner.deserialize_identity(m.serialize())
+                  for m in some]
+        errors, deferred = cmsp.validate_many(idents, 64)
+        assert [e.reason for e in errors if e is not None] == ["revoked"]
+        assert all(e.__traceback__ is None for e in errors if e is not None)
+        assert deferred.settle([True] * len(deferred.items)) == []
+        return weakref.ref(idents[0])
+
+    gc.collect()
+    gc.disable()
+    try:
+        first = block(members[:half])
+        block(members[half:])       # evicts the first block's entries
+        assert first() is None
+    finally:
+        gc.enable()
+
+
+def two_keyed(name: str):
+    """An org whose CA rolled its key over: two trusted roots of one
+    subject -> (MSP config, the newer CA)."""
+    old, new = CA(name), CA(name)
+    assert old.cert.subject == new.cert.subject
+    return MSPConfig(mspid=name, root_certs_pem=[old.cert_pem(),
+                                                 new.cert_pem()]), new
+
+
+def sha384_identity(org: DevOrg, name: str) -> SigningIdentity:
+    key = ec.generate_private_key(ec.SECP256R1())
+    now = datetime.datetime.now(datetime.timezone.utc)
+    cert = (x509.CertificateBuilder()
+            .subject_name(x509.Name([x509.NameAttribute(
+                x509.oid.NameOID.COMMON_NAME, name)]))
+            .issuer_name(org.issuer.cert.subject)
+            .public_key(key.public_key())
+            .serial_number(x509.random_serial_number())
+            .not_valid_before(now - datetime.timedelta(minutes=5))
+            .not_valid_after(now + datetime.timedelta(days=30))
+            .sign(org.issuer._key, hashes.SHA384()))
+    return SigningIdentity(org.mspid, cert, SigningKey(SCHEME_P256, key))
+
+
+@pytest.mark.parametrize("case", ["few", "two_issuers", "ed25519_ca",
+                                  "sha384_link", "blind"])
+def test_links_that_stay_on_the_host(sw_provider, case):
+    """Under the threshold, two trusted candidates of the issuer's name,
+    a link that is not P-256 over SHA-256, an MSP whose `validate` was
+    replaced on the instance: `validate_many` defers nothing, answers
+    what `validate` answers and books the links under `host`."""
+    n = 70
+    if case == "two_issuers":
+        config, ca = two_keyed("Rolled")
+        msp = MSP(config)
+        idents = [SigningIdentity("Rolled", *_issue(ca, f"u{i}"))
+                  for i in range(n)]
+    elif case == "ed25519_ca":
+        org = DevOrg("EdCa", scheme=SCHEME_ED25519)
+        msp, idents = org.msp(), [org.new_identity(f"u{i}") for i in range(n)]
+    else:
+        org = DevOrg("Plain" + case)
+        msp = org.msp()
+        if case == "sha384_link":
+            idents = [sha384_identity(org, f"u{i}") for i in range(n)]
+        else:
+            idents = [org.new_identity(f"u{i}")
+                      for i in range(10 if case == "few" else n)]
+    if case == "blind":
+        msp.validate = lambda ident, at_time=None: None
+        idents[3] = forge(idents[3])
+    cmsp = CachedMSP(msp)
+    host0 = counted("msp_chain_signatures_total", msp=msp.mspid, where="host")
+    errors, deferred = cmsp.validate_many(idents, 64)
+    assert deferred is None and errors == [None] * len(idents)
+    assert counted("msp_chain_signatures_total", msp=msp.mspid,
+                   where="device") == 0
+    assert counted("msp_chain_signatures_total", msp=msp.mspid,
+                   where="host") - host0 == (0 if case == "blind"
+                                             else len(idents))
+    assert all(cmsp.is_valid(i) for i in idents)
+
+
+def _issue(ca: CA, name: str):
+    cert, key = ca.issue(name)
+    return cert, SigningKey(SCHEME_P256, key)
+
+
+def test_a_lite_certificate_stays_on_the_host():
+    """`crypto/lite_x509` certificates state no signature algorithm and
+    give no to-be-signed bytes: never eligible."""
+    import types
+    from fabric_tpu.crypto import lite_ec, lite_hashes, lite_x509
+    from fabric_tpu.msp import msp as mspmod
+    key = lite_ec.generate_private_key(lite_ec.SECP256R1())
+    org = DevOrg("LiteHost")
+    now = datetime.datetime.now(datetime.timezone.utc)
+    name = lite_x509.Name([lite_x509.NameAttribute(
+        lite_x509.NameOID.COMMON_NAME, "lite")])
+    cert = (lite_x509.CertificateBuilder().subject_name(name)
+            .issuer_name(name).public_key(key.public_key())
+            .serial_number(7).not_valid_before(now)
+            .not_valid_after(now + datetime.timedelta(days=1))
+            .sign(key, lite_hashes.SHA256()))
+    assert mspmod._link_algorithm(cert) is None
+    sound = org.new_identity("sound")
+    msp = org.msp()
+    assert msp.deferrable_under(sound) is not None
+    lite = types.SimpleNamespace(cert=cert, issuer_der=sound.issuer_der)
+    assert msp.deferrable_under(lite) is None
+
+
+def test_the_host_decides_first_on_the_deferred_branch(sw_provider):
+    """The one order that differs, pinned: `validate` checks the CA's
+    signature before the CRL, so a certificate both forged and listed by
+    serial is `untrusted` there; `validate_deferred` runs the host
+    checks first, refuses it as `revoked` and hands nothing back.  The
+    flag is BAD_CREATOR_SIGNATURE either way."""
+    org = DevOrg("Org1")
+    victim = org.new_identity("victim")
+    msp = org.msp(crls_pem=[org.issuer.crl([victim.cert])])
+    both = forge(victim, serial=victim.cert.serial_number)
+    with pytest.raises(MSPValidationError) as on_host:
+        msp.validate(both)
+    assert on_host.value.reason == "untrusted"
+    with pytest.raises(MSPValidationError) as deferred:
+        msp.validate_deferred(both)
+    assert deferred.value.reason == "revoked"
+    # a forged one alone comes back as a link only a provider refuses
+    forged = forge(victim)
+    link = msp.validate_deferred(forged)
+    assert not sw_provider.verify(link.item)
+    err, = msp.settle_many([forged], [link], [False])
+    assert err.reason == "untrusted"
+    sound = org.new_identity("sound")
+    link = msp.validate_deferred(sound)
+    assert sw_provider.verify(link.item)
+    assert msp.settle_many([sound], [link], [True]) == [None]

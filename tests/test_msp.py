@@ -6,9 +6,14 @@ import pytest
 
 from fabric_tpu.bccsp import SCHEME_P256, SCHEME_ED25519
 from fabric_tpu.bccsp.factory import init_factories, FactoryOpts
+from fabric_tpu.bccsp.sw import SoftwareProvider
+from fabric_tpu.crypto import ec, hashes, serialization, x509
 from fabric_tpu.msp import MSP, MSPManager, Principal, CachedMSP
+from fabric_tpu.msp import msp as mspmod
+from fabric_tpu.msp.identity import Identity
 from fabric_tpu.msp.msp import MSPValidationError
 from fabric_tpu.msp.ca import DevOrg
+from test_enrolment import forge
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -115,3 +120,133 @@ def test_msp_manager(org):
     assert ident.mspid == "Org1MSP"
     with pytest.raises(MSPValidationError):
         mgr.get_msp("NopeMSP")
+
+
+# -- the split: host checks + the leaf link's signature ------------------------------
+
+
+# what `MSP.validate` said of each before chain validation was split in
+# two (read on the parent commit), and what it and the deferred entry
+# say now: (validate, validate_deferred), "link" where the deferred
+# entry hands the signature back
+CHAINS = {
+    "sound": ("ok", "link"),
+    "under_intermediate": ("ok", "link"),
+    "revoked": ("revoked", "revoked"),
+    "expired": ("expired", "expired"),
+    "forged": ("untrusted", "link"),
+    "forged_and_listed": ("untrusted", "revoked"),
+    "forged_and_expired": ("untrusted", "expired"),
+    "foreign_org": ("untrusted", "untrusted"),
+    "issued_by_a_member": ("untrusted", "untrusted"),
+    "ed25519_org": ("ok", "ok"),
+    "the_root_itself": ("ok", "ok"),
+    "revoked_intermediate": ("revoked", "revoked"),
+}
+
+
+def _issued(org, name, algorithm=None, issuer_key=None, issuer_name=None,
+            not_before=None, not_after=None, public_key=None):
+    """A certificate under `org`'s CA's name made by hand: signed by
+    `issuer_key` (the CA's own unless given) with `algorithm`."""
+    now = datetime.datetime.now(datetime.timezone.utc)
+    return (x509.CertificateBuilder()
+            .subject_name(x509.Name([x509.NameAttribute(
+                x509.oid.NameOID.COMMON_NAME, name)]))
+            .issuer_name(issuer_name or org.issuer.cert.subject)
+            .public_key(public_key
+                        or ec.generate_private_key(ec.SECP256R1()).public_key())
+            .serial_number(x509.random_serial_number())
+            .not_valid_before(not_before or now - datetime.timedelta(minutes=5))
+            .not_valid_after(not_after or now + datetime.timedelta(days=1))
+            .sign(issuer_key or org.issuer._key, algorithm or hashes.SHA256()))
+
+
+def _chain_case(case):
+    """-> (msp, identity) of one row of CHAINS."""
+    past = (datetime.datetime.now(datetime.timezone.utc)
+            - datetime.timedelta(minutes=1))
+    if case == "ed25519_org":
+        org = DevOrg("EdOrg2", scheme=SCHEME_ED25519)
+        return org.msp(), org.new_identity("frank")
+    org = DevOrg("SplitMSP", with_intermediate=(
+        case in ("under_intermediate", "revoked_intermediate")))
+    user = org.new_identity("user")
+    if case in ("sound", "under_intermediate"):
+        return org.msp(), user
+    if case == "revoked":
+        return org.msp(crls_pem=[org.issuer.crl([user.cert])]), user
+    if case == "revoked_intermediate":
+        return org.msp(crls_pem=[org.root.crl([org.intermediate.cert])]), user
+    if case == "expired":
+        return org.msp(), org.new_identity("late", not_after=past)
+    if case == "forged":
+        return org.msp(), forge(user)
+    if case == "forged_and_listed":
+        return (org.msp(crls_pem=[org.issuer.crl([user.cert])]),
+                forge(user, serial=user.cert.serial_number))
+    if case == "forged_and_expired":
+        rogue = ec.generate_private_key(ec.SECP256R1())
+        return org.msp(), Identity("SplitMSP", _issued(
+            org, "late", issuer_key=rogue, not_after=past,
+            not_before=past - datetime.timedelta(minutes=5)))
+    if case == "foreign_org":
+        return org.msp(), DevOrg("EvilMSP").new_identity("mallory")
+    if case == "issued_by_a_member":
+        # a member's certificate (no CA) listed as an intermediate, and
+        # an identity it signed
+        config = org.msp_config()
+        config.intermediate_certs_pem.append(
+            user.cert.public_bytes(serialization.Encoding.PEM))
+        return MSP(config), Identity("SplitMSP", _issued(
+            org, "child", issuer_key=user._key.key,
+            issuer_name=user.cert.subject))
+    if case == "the_root_itself":
+        return org.msp(), Identity("SplitMSP", org.root.cert)
+    raise AssertionError(case)
+
+
+@pytest.mark.parametrize("case", sorted(CHAINS))
+def test_validate_says_what_it_said_before_the_split(case):
+    msp, ident = _chain_case(case)
+    want, want_deferred = CHAINS[case]
+    for _ in range(2):                      # and says it again
+        if want == "ok":
+            msp.validate(ident)
+        else:
+            with pytest.raises(MSPValidationError) as refused:
+                msp.validate(ident)
+            assert refused.value.reason == want
+    # the same two parts, the signature handed back where it is eligible
+    if want_deferred in ("ok", "link"):
+        link = msp.validate_deferred(ident)
+        assert (link is not None) == (want_deferred == "link")
+        if link is not None:
+            ok = SoftwareProvider().verify(link.item)
+            assert ok == (want == "ok")
+            err, = msp.settle_many([ident], [link], [ok])
+            assert (err.reason if err else "ok") == want
+    else:
+        with pytest.raises(MSPValidationError) as refused:
+            msp.validate_deferred(ident)
+        assert refused.value.reason == want_deferred
+
+
+def test_an_unsupported_signature_algorithm_is_refused_by_name():
+    """A link signed with an algorithm no MSP takes is `untrusted`
+    because the host part says so, reading the field the eligibility
+    test reads — not because the library choked somewhere."""
+    org = DevOrg("AlgMSP")
+    msp = org.msp()
+    odd = Identity("AlgMSP", _issued(org, "odd", hashes.SHA224()))
+    assert mspmod._link_algorithm(odd.cert) not in mspmod.LINK_ALGORITHMS
+    assert msp.deferrable_under(odd) is None
+    for entry in (msp.validate, msp.validate_deferred):
+        with pytest.raises(MSPValidationError, match="signed with") as refused:
+            entry(odd)
+        assert refused.value.reason == "untrusted"
+    # SHA-384 under the same key is a link the host checks, not the device
+    fine = Identity("AlgMSP", _issued(org, "fine", hashes.SHA384()))
+    assert msp.deferrable_under(fine) is None
+    msp.validate(fine)
+    assert msp.validate_deferred(fine) is None

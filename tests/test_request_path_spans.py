@@ -189,6 +189,10 @@ def test_profile_route_reply_puts_the_trace_beside_the_programs_record(
     register_routes(ops, enabled=True)
     ops.start()
     stop = threading.Event()
+    # ending a trace takes seconds on a loaded host, and the worker
+    # roots ~100 traces a second meanwhile: keep the window's
+    held = tracer_on.recorder.max_traces
+    tracer_on.recorder.max_traces = 16384
 
     def work():
         while not stop.is_set():
@@ -206,6 +210,7 @@ def test_profile_route_reply_puts_the_trace_beside_the_programs_record(
         stop.set()
         worker.join()
         ops.stop()
+        tracer_on.recorder.max_traces = held
     assert body["python_tracer"] is False        # off unless asked
     assert body["mark"] == "profile.mark"
     assert body["start_perf"] <= body["mark_perf"] <= body["end_perf"]
