@@ -122,13 +122,13 @@ def probe_accelerator() -> dict:
 
 
 def build_native() -> None:
-    """All three extensions from the committed .c sources.  A copied
+    """All four extensions from the committed .c sources.  A copied
     tree's .so says nothing by its mtime, so stale ones go first."""
     from fabric_tpu import native
     ndir = os.path.dirname(native.__file__)
     for so in glob.glob(os.path.join(ndir, "*.so")):
         os.remove(so)
-    for name in ("_ftlv", "_fastcollect", "_fastparse"):
+    for name in ("_ftlv", "_fastcollect", "_fastparse", "_fastmvcc"):
         check(native.load(name) is not None, f"native extension {name} "
               "built from source and loaded")
 

@@ -298,15 +298,17 @@ class KVLedger:
                                           source, flags, tally)
 
     def _count_block(self, flags: TxFlags, tally: Optional[MvccTally],
-                     history: list, source: Optional[str] = None) -> None:
+                     history: list, mvcc_attrs: Optional[dict] = None
+                     ) -> None:
         """One committed block into the always-on counters: its
         transactions by final code, the writes of its valid txs
         (`history`: how many, and their key + value bytes) and, where
         the serial walk validated it (`tally`), the reads it checked,
-        the conflicts it found and which `source` supplied its rw-sets.
+        the conflicts it found, which source supplied its rw-sets and
+        which form the walk took (`mvcc_attrs`, the `ledger.mvcc` span's).
         The default-off commit paths do not walk read by read: their
         blocks move no `path="serial"` series, so those never read as
-        "no conflicts", and no source."""
+        "no conflicts", and no source and no walk."""
         from fabric_tpu.ops_plane import registry
         ch = self.channel_id
         txs = registry.counter(
@@ -327,7 +329,13 @@ class KVLedger:
             "ledger_commit_source_total", "transactions of the blocks the "
             "serial MVCC walk validated, by what supplied their rw-sets: "
             "the block's lane table, or its envelopes decoded again").add(
-                len(flags), channel=ch, source=source)
+                len(flags), channel=ch, source=mvcc_attrs["source"])
+        registry.counter(
+            "ledger_mvcc_walk_total", "transactions of those blocks, by the "
+            "form the walk took: one pass over the lane table's arrays, or "
+            "one Python iteration a transaction, and why").add(
+                len(flags), channel=ch, walk=mvcc_attrs["walk"],
+                reason=mvcc_attrs.get("reason", "none"))
         registry.counter(
             "ledger_mvcc_reads_total", "reads validated, by the commit "
             "path that counts them (the serial MVCC walk)").add(
@@ -412,6 +420,12 @@ class KVLedger:
                 mvcc_attrs = {"source": "envelopes", "reason": reason}
             batch, history = self._validate_and_prepare(
                 block.header.number, source, flags, tally)
+            if tally is not None:
+                # the form the walk took; "python" comes with why: the
+                # source's reason above, or the walk's own (mvcc.walk_of)
+                mvcc_attrs["walk"] = tally.walk
+                if tally.reason is not None:
+                    mvcc_attrs["reason"] = tally.reason
         # split the batch by shard before the apply takes shard locks
         # (the parallel-commit / device-validate planes do the same)
         batch.preshard(getattr(self.statedb, "n_shards", 1))
@@ -441,7 +455,7 @@ class KVLedger:
             stats.phase("ledger.history_commit", "history_commit_s", t0)
 
         self._observe_apply(len(batch), len(history))
-        self._count_block(flags, tally, history, mvcc_attrs["source"])
+        self._count_block(flags, tally, history, mvcc_attrs)
         if batch.touches_meta:
             # only such a batch moves the count: a channel without
             # key-level endorsement never shows the series

@@ -39,6 +39,16 @@ is native, no two keys of the block collide in the hash, the table's tx
 count is the flags', and no tx whose flag is still VALID has status
 RANGE or UNKNOWN (range replay stays host work over decoded reads);
 otherwise the whole block goes through the envelope source.
+
+One walk, two forms.  Over a lane table the walk is one pass over the
+table's arrays (`_array_walk`, native/fastmvcc.c): a slot's shard hashed
+once, over the key's bytes in the block; the committed versions fetched
+a shard at a time into three arrays; the decision in C, in lane order;
+the batch and the history rows built from the surviving write lanes.
+The per-transaction Python walk below stays as the envelope source's
+walk, as the walk of a process whose extension did not build (`walk_of`
+says which, and why) and as the oracle the array pass is held to
+(tests/test_commit_lanes.py: three ways on the same bytes).
 """
 
 from __future__ import annotations
@@ -59,7 +69,8 @@ from fabric_tpu.protocol import (
 from fabric_tpu.protocol.txflags import TxFlags, ValidationCode
 from fabric_tpu.protocol.types import RangeQueryInfo, TX_ENDORSER
 
-from .statedb import META_SUFFIX, StateDB, UpdateBatch
+from .statedb import (META_SUFFIX, StateDB, UpdateBatch, VersionedValue,
+                      _fastmvcc, shard_of)
 
 
 def _validate_read(db: StateDB, batch: UpdateBatch, ns: str,
@@ -80,11 +91,15 @@ def _validate_read(db: StateDB, batch: UpdateBatch, ns: str,
 
 class MvccTally:
     """What one block's serial walk did, in plain ints: the ledger adds
-    them to its counters once per block."""
-    __slots__ = ("reads", "conflicts_block", "conflicts_state")
+    them to its counters once per block.  `walk` is the form the walk
+    took — "arrays" | "python" — and `reason` why not arrays (`walk_of`;
+    None where the source was the envelopes: the source's reason says)."""
+    __slots__ = ("reads", "conflicts_block", "conflicts_state", "walk",
+                 "reason")
 
     def __init__(self):
         self.reads = self.conflicts_block = self.conflicts_state = 0
+        self.walk, self.reason = "python", None
 
 
 def _merged_range(db: StateDB, batch: UpdateBatch, ns: str,
@@ -264,6 +279,128 @@ def lane_source_of(block, flags: TxFlags):
     return table, None
 
 
+def walk_of():
+    """The second rule, read off what this process built: (the form the
+    walk over a lane table takes, why not arrays).  The array pass
+    (`_array_walk`) gives every block the Python walk's answers — the
+    parameter a delete takes along included, folded into its decision —
+    so the one reason left is "no_native": `native/fastmvcc.c` did not
+    build.  FABRIC_TPU_NO_NATIVE=1 never gets here: without the native
+    extractor there is no lane table (`lane_source_of`: "no_native")."""
+    if _fastmvcc is None:
+        return "python", "no_native"
+    return "arrays", None
+
+
+_WALK_CODES = (int(ValidationCode.VALID),
+               int(ValidationCode.MVCC_READ_CONFLICT),
+               int(ValidationCode.BAD_RWSET))
+
+
+def _parameter_idents(db: StateDB, table: "wire.LaneTable", n_shards: int):
+    """What the array walk needs to drop a deleted key's validation
+    parameter as `_stage_writes` does: (companion, keys, shards), or None
+    where no delete of the block can meet one — the state holds no
+    parameter and the block names no `#meta` namespace.  `companion` has,
+    for each write lane that deletes (ns, key), the ident of (`ns#meta`,
+    key): its slot where the block names it; else, where the state holds
+    it, one past the slots (`keys`, `shards`: those idents' keys and
+    their shards); else -1: neither named nor held, so nothing in this
+    block can give it a parameter."""
+    key_strs = table.key_strs
+    if not db.meta_keys()[1] and not any(
+            ns.endswith(META_SUFFIX) for ns, _key in key_strs):
+        return None
+    wr = table.writes
+    slot_of = {k: i for i, k in enumerate(key_strs)}
+    companion = np.full(len(wr), -1, dtype=np.int64)
+    past: dict = {}
+    keys, shards = [], []
+    rows = np.flatnonzero(wr[:, 2])
+    for row, slot in zip(rows.tolist(), wr[rows, 1].tolist()):
+        ns, key = key_strs[slot]
+        if ns.endswith(META_SUFFIX):
+            continue
+        meta = (ns + META_SUFFIX, key)
+        ident = slot_of.get(meta)
+        if ident is None:
+            ident = past.get(meta)
+        if ident is None and db.get(*meta) is not None:
+            ident = past[meta] = len(key_strs) + len(keys)
+            keys.append(meta)
+            shards.append(shard_of(*meta, n_shards))
+        if ident is not None:
+            companion[row] = ident
+    return companion, keys, np.asarray(shards, dtype=np.int32)
+
+
+def _array_walk(db: StateDB, block_num: int, table: "wire.LaneTable",
+                flags: TxFlags, tally: Optional[MvccTally]):
+    """`validate_and_prepare_batch` over a lane table as passes over its
+    arrays (native/fastmvcc.c): each slot hashed to its shard once, over
+    the key's bytes in the block; the committed versions fetched a shard
+    at a time into three arrays; the read-by-read decision, and which
+    write lanes survive it, in C, in lane order; the batch and the
+    history rows built from the surviving rows in one loop, the batch's
+    per-shard split filled from the shard each slot already carries."""
+    n_shards = db.n_shards
+    key_strs, wr = table.key_strs, table.writes
+    shards = np.frombuffer(
+        _fastmvcc.slot_shards(table.base, table.keys, n_shards),
+        dtype=np.int32)
+    has, blk, txn = db.versions_of(key_strs, shards)
+    companion = None
+    if wr[:, 2].any():
+        found = _parameter_idents(db, table, n_shards)
+        if found is not None:
+            # idents past the slots: parameters the state holds (at a
+            # version no lane can ask for) and the block does not name
+            companion, past_keys, past_shards = found
+            key_strs = key_strs + past_keys
+            has = np.pad(has, (0, len(past_keys)), constant_values=1)
+            blk, txn = (np.pad(a, (0, len(past_keys))) for a in (blk, txn))
+            shards = np.concatenate((shards, past_shards))
+    codes = bytearray(flags.to_bytes())
+    reads, against_block, against_state, staged = _fastmvcc.walk(
+        table.tx, table.reads, wr, codes, has, blk, txn, block_num,
+        companion, _WALK_CODES)
+    flags.load(codes)
+    if tally is not None:
+        tally.reads += reads
+        tally.conflicts_block += against_block
+        tally.conflicts_state += against_state
+    staged = np.frombuffer(staged, dtype=np.int64).reshape(-1, 2)
+    rows = wr[staged[:, 0]]
+    base, txids = table.base, table.txids
+    updates: dict = {}
+    history: List[Tuple[int, str, str, str, bytes, bool]] = []
+    at = -1
+    for (tx_num, slot, is_delete, off, n), drop in zip(
+            rows.tolist(), staged[:, 1].tolist()):
+        if drop >= 0:                 # the parameter goes with its key:
+            updates[key_strs[drop]] = None      # no history row of its own
+            continue
+        if tx_num != at:
+            at, txid = tx_num, txids[tx_num]
+            version = Version(block_num, tx_num)
+        k = key_strs[slot]
+        value = bytes(base[off:off + n])
+        updates[k] = None if is_delete else VersionedValue(value, version)
+        history.append((tx_num, txid, k[0], k[1], value, bool(is_delete)))
+    by_shard = None
+    if n_shards > 1:
+        # the dict keeps a key's first position: idents in that order
+        idents = np.where(staged[:, 1] >= 0, staged[:, 1], rows[:, 1])
+        if len(updates) != len(idents):
+            _, first = np.unique(idents, return_index=True)
+            idents = idents[np.sort(first)]
+        lists: List[list] = [[] for _ in range(n_shards)]
+        for item, shard in zip(updates.items(), shards[idents].tolist()):
+            lists[shard].append(item)
+        by_shard = (n_shards, lists)
+    return UpdateBatch.from_staged(updates, by_shard), history
+
+
 def _stage_writes(db: StateDB, batch: UpdateBatch, history: list,
                   staged: dict, ident_of, block_num: int, tx_num: int,
                   txid: str, writes) -> None:
@@ -329,6 +466,11 @@ def validate_and_prepare_batch(
     `tally`, when given, takes the reads validated and the conflicts.
     """
     if isinstance(source, wire.LaneTable):
+        walk, reason = walk_of()
+        if tally is not None:
+            tally.walk, tally.reason = walk, reason
+        if walk == "arrays":
+            return _array_walk(db, block_num, source, flags, tally)
         records, committed, ident_of = _lane_source(db, source, flags)
     else:
         records, committed, ident_of = _envelope_source(db, source, flags)

@@ -50,10 +50,18 @@ from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Dict, Iterator, List, Optional, Tuple
 
+import numpy as np
+
 from fabric_tpu.ledger import checkpoint as ckpt
 from fabric_tpu.ledger.fsync import flush_and_sync
 from fabric_tpu.protocol import Version
 from fabric_tpu.utils import serde
+
+try:
+    from fabric_tpu import native as _native_pkg
+    _fastmvcc = _native_pkg.load("_fastmvcc")
+except Exception:  # pragma: no cover - broken toolchain
+    _fastmvcc = None
 
 _LEN = struct.Struct("<Q")
 SNAPSHOT_EVERY = 256  # batches between checkpoint compactions
@@ -69,7 +77,7 @@ _FNV_PRIME = 0x100000001B3
 _MASK64 = 0xFFFFFFFFFFFFFFFF
 
 
-def shard_of(ns: str, key: str, n_shards: int) -> int:
+def _shard_of_py(ns: str, key: str, n_shards: int) -> int:
     """Deterministic shard for a (namespace, key): FNV-1a 64 over the
     NUL-joined pair.  Stable across processes/restarts — checkpoints,
     prepared batches, and snapshot transfers all agree on placement."""
@@ -79,6 +87,11 @@ def shard_of(ns: str, key: str, n_shards: int) -> int:
     for b in (ns + "\x00" + key).encode("utf-8"):
         h = ((h ^ b) * _FNV_PRIME) & _MASK64
     return h % n_shards
+
+
+# the same function of the same bytes, in C where the extension built
+# (native/fastmvcc.c; tests/test_fastmvcc.py holds it to the mirror)
+shard_of = _shard_of_py if _fastmvcc is None else _fastmvcc.shard_of
 
 
 @dataclass(frozen=True)
@@ -105,6 +118,20 @@ class UpdateBatch:
         self._by_shard = None  # (n_shards, per-shard item lists)
         self._namespaces: set = set()
         self.touches_meta = False
+
+    @classmethod
+    def from_staged(cls, updates: dict, by_shard=None) -> "UpdateBatch":
+        """The batch a put/delete sequence staged elsewhere, in bulk,
+        would have built (mvcc's array walk): `updates` as that sequence
+        leaves the dict, and `by_shard` the split, (n_shards, per-shard
+        item lists), where the stager carried each key's shard —
+        `preshard` then hashes nothing."""
+        batch = cls()
+        batch._updates = updates
+        batch._by_shard = by_shard
+        for ns in {k[0] for k in updates}:
+            batch._note_namespace(ns)
+        return batch
 
     def _note_namespace(self, ns: str) -> None:
         self._namespaces.add(ns)
@@ -313,6 +340,23 @@ class StateDB:
         sh = self._shards[shard_of(ns, key, self.n_shards)]
         with sh.lock:
             return sh.data.get((ns, key))
+
+    def versions_of(self, key_strs: list, shards):
+        """The committed version of every key of `key_strs`, in bulk:
+        (has uint8, block_num int64, tx_num int64) arrays, `has` 0 where
+        the state holds no such key.  `shards` is each key's shard, int32
+        (`_fastmvcc.slot_shards`): keys are hashed by whoever has their
+        bytes, once, and each shard's lock is taken once.  Native only."""
+        n = len(key_strs)
+        has = np.zeros(n, dtype=np.uint8)
+        blk = np.zeros(n, dtype=np.int64)
+        txn = np.zeros(n, dtype=np.int64)
+        for i in np.unique(shards).tolist():
+            sh = self._shards[i]
+            with sh.lock:
+                _fastmvcc.fetch_versions(sh.data, key_strs, shards, i,
+                                         has, blk, txn)
+        return has, blk, txn
 
     def get_version(self, ns: str, key: str) -> Optional[Version]:
         vv = self.get(ns, key)

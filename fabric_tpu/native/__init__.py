@@ -10,6 +10,8 @@ Current extensions:
   _ftlv        — the canonical serde codec (fabric_tpu/utils/serde.py)
   _fastcollect — txvalidator pass-1 block walker + SHA-256 (SHA-NI)
   _fastparse   — zero-copy wire ingest: block/envelope span parser
+  _fastmvcc    — the commit's MVCC walk over a lane table's arrays, and
+                 the state store's key hash (ledger/mvcc.py, statedb.py)
 """
 
 from __future__ import annotations

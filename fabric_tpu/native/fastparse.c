@@ -1211,6 +1211,111 @@ error:
 }
 
 /* ------------------------------------------------------------------ */
+/* the arena's strings, for the host                                   */
+
+/* arena_keys(base, keys) -> [(ns, key)] of the arena's keys section
+ * (n_keys x 5 cells [hash, ns_off, ns_len, key_off, key_len]), decoded
+ * once a slot: what wire.LaneTable.key_strs gives.  A namespace equal to
+ * the slot before's is the same str object (a block names a handful). */
+static PyObject *py_arena_keys(PyObject *self, PyObject *args)
+{
+    (void)self;
+    Py_buffer in, kb;
+    if (!PyArg_ParseTuple(args, "y*y*", &in, &kb))
+        return NULL;
+    PyObject *out = NULL, *prev_ns = NULL;
+    if (kb.len % 40) {
+        PyErr_SetString(PyExc_ValueError, "arena_keys: keys is n x 5 cells");
+        goto done;
+    }
+    const char *base = in.buf;
+    uint64_t blen = (uint64_t)in.len, p_off = 0, p_len = 0;
+    Py_ssize_t n = kb.len / 40;
+    out = PyList_New(n);
+    if (!out)
+        goto done;
+    for (Py_ssize_t i = 0; i < n; i++) {
+        uint64_t c[5];
+        memcpy(c, (const uint8_t *)kb.buf + 40 * i, 40);
+        if (c[1] > blen || c[2] > blen - c[1]
+                || c[3] > blen || c[4] > blen - c[3]) {
+            PyErr_SetString(PyExc_ValueError,
+                            "arena_keys: key span outside base");
+            Py_CLEAR(out);
+            goto done;
+        }
+        if (!prev_ns || c[2] != p_len
+                || memcmp(base + c[1], base + p_off, (size_t)p_len)) {
+            Py_XDECREF(prev_ns);
+            prev_ns = PyUnicode_DecodeUTF8(base + c[1], (Py_ssize_t)c[2],
+                                           "strict");
+            p_off = c[1];
+            p_len = c[2];
+        }
+        PyObject *key = prev_ns ? PyUnicode_DecodeUTF8(
+            base + c[3], (Py_ssize_t)c[4], "strict") : NULL;
+        PyObject *pair = key ? PyTuple_Pack(2, prev_ns, key) : NULL;
+        Py_XDECREF(key);
+        if (!pair) {
+            Py_CLEAR(out);
+            goto done;
+        }
+        PyList_SET_ITEM(out, i, pair);
+    }
+done:
+    Py_XDECREF(prev_ns);
+    PyBuffer_Release(&in);
+    PyBuffer_Release(&kb);
+    return out;
+}
+
+/* arena_txids(base, tx) -> [txid | None] of the arena's tx section
+ * (n_tx x 3 cells [status, txid_off, txid_len]): None where the status
+ * is not OK.  What wire.LaneTable.txids gives. */
+static PyObject *py_arena_txids(PyObject *self, PyObject *args)
+{
+    (void)self;
+    Py_buffer in, tb;
+    if (!PyArg_ParseTuple(args, "y*y*", &in, &tb))
+        return NULL;
+    PyObject *out = NULL;
+    if (tb.len % 24) {
+        PyErr_SetString(PyExc_ValueError, "arena_txids: tx is n x 3 cells");
+        goto done;
+    }
+    uint64_t blen = (uint64_t)in.len;
+    Py_ssize_t n = tb.len / 24;
+    out = PyList_New(n);
+    if (!out)
+        goto done;
+    for (Py_ssize_t i = 0; i < n; i++) {
+        uint64_t c[3];
+        memcpy(c, (const uint8_t *)tb.buf + 24 * i, 24);
+        PyObject *txid;
+        if (c[0] != LN_OK) {
+            txid = Py_None;
+            Py_INCREF(txid);
+        } else if (c[1] > blen || c[2] > blen - c[1]) {
+            PyErr_SetString(PyExc_ValueError,
+                            "arena_txids: txid span outside base");
+            txid = NULL;
+        } else {
+            txid = PyUnicode_DecodeUTF8((const char *)in.buf + c[1],
+                                        (Py_ssize_t)c[2], "strict");
+        }
+        if (!txid) {
+            Py_CLEAR(out);
+            goto done;
+        }
+        PyList_SET_ITEM(out, i, txid);
+    }
+done:
+    PyBuffer_Release(&in);
+    PyBuffer_Release(&tb);
+    return out;
+}
+
+/* ------------------------------------------------------------------ */
 /* stats                                                               */
 
 static PyObject *py_stats(PyObject *self, PyObject *noarg)
@@ -1247,6 +1352,10 @@ static PyMethodDef methods[] = {
     {"rwset_lanes", py_rwset_lanes, METH_VARARGS,
      "rwset_lanes(base, spans) -> (flags, n_tx, n_keys, n_reads, "
      "n_writes, arena) | None"},
+    {"arena_keys", py_arena_keys, METH_VARARGS,
+     "arena_keys(base, keys) -> [(ns, key)] of the arena's keys section"},
+    {"arena_txids", py_arena_txids, METH_VARARGS,
+     "arena_txids(base, tx) -> [txid | None] of the arena's tx section"},
     {"stats", py_stats, METH_NOARGS,
      "stats() -> arena-pool, accept/reject and rw-lane counters"},
     {NULL, NULL, 0, NULL},

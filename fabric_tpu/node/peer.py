@@ -1488,7 +1488,8 @@ class PeerNode:
             "backend": getattr(prov, "backend", prov.name),
             "degraded": bool(getattr(prov, "degraded", False)),
             "native": {n: f"fabric_tpu.native.{n}" in sys.modules
-                       for n in ("_ftlv", "_fastcollect", "_fastparse")},
+                       for n in ("_ftlv", "_fastcollect", "_fastparse",
+                                 "_fastmvcc")},
             "stats": None, "device": None}
         if snap is not None:
             from fabric_tpu.bccsp.jaxtpu import device_report

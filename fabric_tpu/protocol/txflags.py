@@ -61,6 +61,12 @@ class TxFlags:
     def set(self, i: int, code: ValidationCode) -> None:
         self._codes[i] = int(code)
 
+    def load(self, data: bytes) -> None:
+        """Every code at once, in place: `to_bytes` back in."""
+        if len(data) != len(self._codes):
+            raise ValueError("load: one code a transaction")
+        self._codes = list(data)
+
     def flag(self, i: int) -> ValidationCode:
         return ValidationCode(self._codes[i])
 

@@ -283,13 +283,15 @@ class LaneTable:
     commit path reads instead of decoding envelopes again.  Rows keep
     the arena's order (lanes ascend by tx); every offset indexes `base`.
 
-      status  [n_tx]  LANE_* of each tx
+      tx      (n_tx, 3) int64      [status, txid_off, txid_len]
+      status  [n_tx]  LANE_* of each tx (tx's first column)
       reads   (n_reads, 5) int64   [tx, slot, has_version, block, txnum]
       writes  (n_writes, 5) int64  [tx, slot, is_delete, value_off, len]
+      keys    (n_keys, 5) int64    [hash, ns_off, ns_len, key_off, key_len]
     """
 
-    __slots__ = ("base", "n_tx", "status", "reads", "writes", "_tx",
-                 "_keys", "_txids", "_key_strs")
+    __slots__ = ("base", "n_tx", "tx", "status", "reads", "writes",
+                 "keys", "_txids", "_key_strs")
 
     def __init__(self, base: _Raw, lanes: tuple):
         _flags, n_tx, n_keys, n_reads, n_writes, arena = lanes
@@ -298,13 +300,13 @@ class LaneTable:
         o = 3 * n_tx
         self.base = base
         self.n_tx = n_tx
-        self._tx = cells[:o].reshape(n_tx, 3)
-        self.status = self._tx[:, 0]
+        self.tx = cells[:o].reshape(n_tx, 3)
+        self.status = self.tx[:, 0]
         self.reads = cells[o:o + 5 * n_reads].reshape(n_reads, 5)
         o += 5 * n_reads
         self.writes = cells[o:o + 5 * n_writes].reshape(n_writes, 5)
         o += 5 * n_writes
-        self._keys = cells[o:o + 5 * n_keys].reshape(n_keys, 5)
+        self.keys = cells[o:o + 5 * n_keys].reshape(n_keys, 5)
         self._txids: Optional[List[Optional[str]]] = None
         self._key_strs: Optional[List[Tuple[str, str]]] = None
 
@@ -315,9 +317,12 @@ class LaneTable:
         is what Envelope.header().channel_header.txid gives."""
         if self._txids is None:
             base = self.base
-            self._txids = [
-                str(base[off:off + n], "utf-8") if st == LANE_OK else None
-                for st, off, n in self._tx.tolist()]
+            if _fastparse is not None:
+                self._txids = _fastparse.arena_txids(base, self.tx)
+            else:
+                self._txids = [
+                    str(base[off:off + n], "utf-8") if st == LANE_OK
+                    else None for st, off, n in self.tx.tolist()]
         return self._txids
 
     @property
@@ -325,17 +330,20 @@ class LaneTable:
         """(namespace, key) of each interned slot, decoded once."""
         if self._key_strs is None:
             base = self.base
-            self._key_strs = [
-                (str(base[no:no + nn], "utf-8"),
-                 str(base[ko:ko + kn], "utf-8"))
-                for _h, no, nn, ko, kn in self._keys.tolist()]
+            if _fastparse is not None:
+                self._key_strs = _fastparse.arena_keys(base, self.keys)
+            else:
+                self._key_strs = [
+                    (str(base[no:no + nn], "utf-8"),
+                     str(base[ko:ko + kn], "utf-8"))
+                    for _h, no, nn, ko, kn in self.keys.tolist()]
         return self._key_strs
 
     def txs_writing_under(self, marker: str) -> List[int]:
         """The OK txs, ascending, with a write whose namespace holds
         `marker`."""
         slot_has = np.fromiter((marker in ns for ns, _key in self.key_strs),
-                               dtype=bool, count=len(self._keys))
+                               dtype=bool, count=len(self.keys))
         if not slot_has.any():
             return []
         w = self.writes

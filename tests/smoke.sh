@@ -77,10 +77,12 @@ python tests/smoke_soak.py
 echo "== incident capture drill (SLO burn -> verified 3-node flight-recorder bundle) =="
 python tests/smoke_incident.py
 
-echo "== ASan/UBSan fuzz corpus vs the native wire parser =="
+echo "== ASan/UBSan fuzz corpus vs the native wire parser and the MVCC pass =="
 # Build _fastparse with the sanitizers and drive the full adversarial
 # corpus (tests/test_fastparse.py --asan-corpus) through it: any heap
 # overflow / UB in the span parser aborts here instead of shipping.
+# _fastmvcc the same way (tests/test_fastmvcc.py --asan-corpus: the key
+# hash, the walk over random and malformed lanes, the version fetch).
 # Skipped gracefully when the toolchain lacks the sanitizer runtimes.
 san_tmp=$(mktemp -d)
 trap 'rm -rf "$san_tmp"' EXIT
@@ -96,6 +98,14 @@ if echo 'int main(void){return 0;}' > "$san_tmp/probe.c" \
     ASAN_OPTIONS=detect_leaks=0 \
     PYTHONPATH="$san_tmp:$PYTHONPATH" \
         python tests/test_fastparse.py --asan-corpus
+    "${CC:-cc}" -fsanitize=address,undefined -fno-sanitize-recover=all \
+        -O1 -g -shared -fPIC -Wall -Wextra -Werror \
+        -I"$(python -c 'import sysconfig;print(sysconfig.get_path("include"))')" \
+        fabric_tpu/native/fastmvcc.c -o "$san_tmp/_fastmvcc.so"
+    LD_PRELOAD="$("${CC:-cc}" -print-file-name=libasan.so)" \
+    ASAN_OPTIONS=detect_leaks=0 \
+    PYTHONPATH="$san_tmp:$PYTHONPATH" \
+        python tests/test_fastmvcc.py --asan-corpus
 else
     echo "skip: sanitizer toolchain unavailable"
 fi

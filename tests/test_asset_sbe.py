@@ -413,13 +413,24 @@ def txs_of(reg_model, calls, start=0):
             for n, (fn, args, client, orgs) in enumerate(calls)]
 
 
-SOURCES = ["lanes", "envelopes"]
+SOURCES = ["lanes", "lanes-python", "envelopes"]
+
+
+@pytest.fixture(autouse=True)
+def the_walk_its_source_names(request, monkeypatch):
+    """"lanes-python": the lane table walked one Python iteration a
+    transaction, through the rule's seam (`mvcc.walk_of`: what a
+    `native/fastmvcc.c` that did not build leaves) — "lanes" is walked as
+    arrays."""
+    params = getattr(getattr(request.node, "callspec", None), "params", {})
+    if params.get("source") == "lanes-python":
+        monkeypatch.setattr(mvcc, "_fastmvcc", None)
 
 
 def block_for(source: str, raw: bytes):
     """The same bytes as the deliver loop hands them (a BlockView: the
     lane source) or decoded whole (the envelope source)."""
-    return wire.parse_block(raw) if source == "lanes" \
+    return wire.parse_block(raw) if source.startswith("lanes") \
         else Block.deserialize(raw)
 
 
@@ -438,8 +449,10 @@ def run_blocks(world, provider, source, reg_model, stream):
         committer.store_block(block_for(source, raw))
         got = stored_flags(committer.ledger, number)
         assert got == want, number
-        assert committer.ledger.last_stats.span_attrs[
-            "ledger.mvcc"]["source"] == source
+        span = committer.ledger.last_stats.span_attrs["ledger.mvcc"]
+        assert (span["source"], span["walk"]) == {
+            "lanes": ("lanes", "arrays"), "lanes-python": ("lanes", "python"),
+            "envelopes": ("envelopes", "python")}[source]
         out.append(got)
     return committer, out
 
@@ -595,12 +608,18 @@ def test_prepared_from_lanes_drops_the_parameter_as_the_walk_does(
     for source in SOURCES:
         block = block_for(source, raw)
         flags = TxFlags.from_bytes(gate)
-        supplier = (mvcc.lane_source_of(block, flags)[0] if source == "lanes"
+        supplier = (mvcc.lane_source_of(block, flags)[0]
+                    if source.startswith("lanes")
                     else _safe_envelopes(block))
-        batch, history = mvcc.validate_and_prepare_batch(db, 1, supplier,
-                                                         flags)
+        with pytest.MonkeyPatch.context() as seam:
+            if source == "lanes-python":
+                seam.setattr(mvcc, "_fastmvcc", None)
+            tally = mvcc.MvccTally()
+            batch, history = mvcc.validate_and_prepare_batch(
+                db, 1, supplier, flags, tally)
+        assert tally.walk == ("arrays" if source == "lanes" else "python")
         batches[source] = (list(flags.to_bytes()), _batch(batch), history)
-    assert batches["lanes"] == batches["envelopes"]
+    assert batches["lanes"] == batches["envelopes"] == batches["lanes-python"]
     final, staged, history = batches["lanes"]
     assert final == [V, V, MVCC, V]
     assert staged[(META, "a")] is None and staged[(META, "c")] is None

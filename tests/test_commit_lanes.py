@@ -6,6 +6,8 @@ rows, commit hash and MvccTally equal — the rule that picks one
 that take their txids from the same table: the block store's index, the
 commit notifier and the private-data coordinator.
 """
+import collections
+import contextlib
 import os
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -20,6 +22,7 @@ from fabric_tpu.ledger import KVLedger, LedgerConfig
 from fabric_tpu.ledger import mvcc
 from fabric_tpu.ledger.blkstorage import BlockStore
 from fabric_tpu.ledger.kvledger import _safe_envelopes
+from fabric_tpu.ledger.statedb import UpdateBatch
 from fabric_tpu.msp.ca import DevOrg
 from fabric_tpu.ops_plane import registry, tracing
 from fabric_tpu.privdata import coordinator as coordinator_mod
@@ -111,55 +114,94 @@ def mvcc_span(ledger):
     return ledger.last_stats.span_attrs["ledger.mvcc"]
 
 
-def through_both_sources(stream):
-    """Feed `stream` — [(envelopes, gate codes | None)] — to two ledgers:
-    one gets BlockViews (the lane source), one plain Blocks (the envelope
-    source).  Before each commit the two sources walk the same bytes over
-    the same state and every output is compared; after it, the ledgers.
-    -> (final codes per block, tally per block)."""
-    by_lanes, by_envs = KVLedger("ch", LedgerConfig()), KVLedger(
-        "ch", LedgerConfig())
+@contextlib.contextmanager
+def the_python_walk():
+    """The rule's seam (`mvcc.walk_of`): what a `native/fastmvcc.c` that
+    did not build leaves behind.  A lane table is then walked one Python
+    iteration a transaction, as before PR 43."""
+    was, mvcc._fastmvcc = mvcc._fastmvcc, None
+    try:
+        yield
+    finally:
+        mvcc._fastmvcc = was
+
+
+WALKS = {"arrays": {"source": "lanes", "walk": "arrays"},
+         "python": {"source": "lanes", "walk": "python",
+                    "reason": "no_native"},
+         "envelopes": {"source": "envelopes", "walk": "python",
+                       "reason": "no_view"}}
+
+
+def walked(db, number, source, gate, python=False):
+    """One walk of `source` over `db`: everything it gives back."""
+    flags, tally = TxFlags.from_bytes(gate), mvcc.MvccTally()
+    with (the_python_walk() if python else contextlib.nullcontext()):
+        batch, history = mvcc.validate_and_prepare_batch(
+            db, number, source, flags, tally)
+    split = batch.items_by_shard(db.n_shards)       # warm, or hashed now
+    fresh = UpdateBatch()
+    fresh._updates = dict(batch.items())
+    assert split == fresh.items_by_shard(db.n_shards)
+    return {"flags": flags.to_bytes(), "batch": list(batch.items()),
+            "history": history, "tally": tally_of(tally),
+            "walk": (tally.walk, tally.reason),
+            "touches_meta": batch.touches_meta,
+            "namespaces": batch._namespaces}
+
+
+def through_three_walks(stream, config=LedgerConfig):
+    """Feed `stream` — [(envelopes, gate codes | None)] — to three
+    ledgers: two get BlockViews (the lane source: one walked as arrays,
+    one by the Python walk, forced through the rule's seam), one plain
+    Blocks (the envelope source).  Before each commit the three walk the
+    same bytes over the same state and every output is compared; after
+    it, the ledgers.  -> (final codes per block, tally per block)."""
+    ledgers = {name: KVLedger("ch", config()) for name in WALKS}
+    db = ledgers["arrays"].statedb
     prev, codes, tallies = GENESIS, [], []
     for number, (envelopes, gate) in enumerate(stream):
         raw, nxt = raw_block(number, prev, envelopes)
         gate = bytes(gate if gate is not None else [V] * len(envelopes))
         view = view_of(raw, gate)
-        f_l, f_e = TxFlags.from_bytes(gate), TxFlags.from_bytes(gate)
-        table, reason = mvcc.lane_source_of(view, f_l)
+        table, reason = mvcc.lane_source_of(view, TxFlags.from_bytes(gate))
         assert reason is None and isinstance(table, wire.LaneTable)
-        t_l, t_e = mvcc.MvccTally(), mvcc.MvccTally()
-        b_l, h_l = mvcc.validate_and_prepare_batch(
-            by_lanes.statedb, number, table, f_l, t_l)
-        b_e, h_e = mvcc.validate_and_prepare_batch(
-            by_lanes.statedb, number, _safe_envelopes(plain_of(raw)), f_e,
-            t_e)
-        assert f_l.to_bytes() == f_e.to_bytes()
-        assert list(b_l.items()) == list(b_e.items())      # in order
-        assert h_l == h_e
-        assert tally_of(t_l) == tally_of(t_e)
-        assert t_l.conflicts_block + t_l.conflicts_state == sum(
-            a != b and b == MVCC for a, b in zip(gate, f_l.to_bytes()))
+        got = {"arrays": walked(db, number, table, gate),
+               "python": walked(db, number, table, gate, python=True),
+               "envelopes": walked(db, number,
+                                   _safe_envelopes(plain_of(raw)), gate)}
+        assert [got[w].pop("walk") for w in WALKS] == [
+            ("arrays", None), ("python", "no_native"), ("python", None)]
+        assert got["arrays"] == got["envelopes"]     # batch: in order
+        assert got["python"] == got["envelopes"]
+        final, tally = got["arrays"]["flags"], got["arrays"]["tally"]
+        assert tally[1] + tally[2] == sum(
+            a != b and b == MVCC for a, b in zip(gate, final))
 
-        by_lanes.commit(view)
-        assert mvcc_span(by_lanes) == {"source": "lanes"}
+        ledgers["arrays"].commit(view)
         # no envelope list was built, unless the block store's index
         # had a tx to read for which the table does not speak
         assert (view._data is None) == all(
             st == wire.LANE_OK for st in table.status.tolist())
-        by_envs.commit(plain_of(raw, gate))
-        assert mvcc_span(by_envs) == {"source": "envelopes",
-                                      "reason": "no_view"}
-        assert (bytes(view.metadata.items[META_TXFLAGS])
-                == f_l.to_bytes())
-        assert by_lanes.commit_hash == by_envs.commit_hash
-        codes.append(list(f_l.to_bytes()))
-        tallies.append(tally_of(t_l))
+        with the_python_walk():
+            ledgers["python"].commit(view_of(raw, gate))
+        ledgers["envelopes"].commit(plain_of(raw, gate))
+        for walk, attrs in WALKS.items():
+            assert mvcc_span(ledgers[walk]) == attrs
+            assert ledgers[walk].commit_hash == ledgers["arrays"].commit_hash
+        assert bytes(view.metadata.items[META_TXFLAGS]) == final
+        codes.append(list(final))
+        tallies.append(tally)
         prev = nxt
-    assert state_of(by_lanes) == state_of(by_envs)
-    assert history_of(by_lanes) == history_of(by_envs)
-    for number in range(len(stream)):
-        assert (by_lanes.blockstore.get_by_number(number).serialize()
-                == by_envs.blockstore.get_by_number(number).serialize())
+    for ledger in ledgers.values():
+        assert state_of(ledger) == state_of(ledgers["envelopes"])
+        assert history_of(ledger) == history_of(ledgers["envelopes"])
+        assert (ledger.statedb.status()["shard_keys"]
+                == ledgers["envelopes"].statedb.status()["shard_keys"])
+        for number in range(len(stream)):
+            assert (ledger.blockstore.get_by_number(number).serialize()
+                    == ledgers["envelopes"].blockstore.get_by_number(
+                        number).serialize())
     return codes, tallies
 
 
@@ -255,43 +297,216 @@ def case_smallbank_chains(ids):
     return stream, want, None
 
 
+def case_thrice_written(ids):
+    """A key written by three valid txs of a block (blind writes), other
+    keys between them: the batch keeps its first position and its last
+    value, the history all three."""
+    b1 = [tx(ids, rw(writes=[KVWrite("k03", b"one")])),
+          tx(ids, rw(writes=[KVWrite("new", b"n"), KVWrite("k03", b"two")])),
+          tx(ids, rw(writes=[KVWrite("k01", b"m")])),
+          tx(ids, rw(writes=[KVWrite("k03", b"three"),
+                             KVWrite("k03", b"four")]))]
+    stream = [(seed(ids), None), (b1, None)]
+    return stream, [[V] * 8, [V] * 4], [(0, 0, 0), (0, 0, 0)]
+
+
+def case_nil_after_a_staged_delete(ids):
+    """nil = nil: a read without a version holds against a delete staged
+    earlier in the block, and against a key the state never held; once
+    the key is written again the same read fails against the block."""
+    b1 = [tx(ids, rw(writes=[KVWrite("k03", b"", True)])),
+          tx(ids, rw(reads=[KVRead("k03", None)],
+                     writes=[KVWrite("k03", b"new")])),
+          tx(ids, rw(reads=[KVRead("never", None)])),
+          tx(ids, rw(reads=[KVRead("k03", None)])),
+          tx(ids, rw(reads=[KVRead("k03", Version(1, 1))],   # the staged put
+                     writes=[KVWrite("k03", b"", True),
+                             KVWrite("gone", b"", True)])),
+          tx(ids, rw(reads=[KVRead("gone", None), KVRead("k03", None)]))]
+    stream = [(seed(ids), None), (b1, None)]
+    return (stream, [[V] * 8, [V, V, V, MVCC, V, V]],
+            [(0, 0, 0), (6, 1, 0)])
+
+
+def case_bad_between_valid(ids):
+    """A gate-valid tx whose rw-set does not decode, between two that
+    do: BAD_RWSET, and the walk goes on with the lanes after it."""
+    creator, _ = ids
+    junk = build.signed_envelope(TX_ENDORSER, "ch", {"not": "a tx"}, creator)
+    b1 = [tx(ids, rw(reads=[KVRead("k00", Version(0, 0))],
+                     writes=[KVWrite("k00", b"a")])),
+          junk,
+          tx(ids, rw(reads=[KVRead("k00", Version(0, 0))],
+                     writes=[KVWrite("k01", b"b")])),
+          tx(ids, rw(reads=[KVRead("k01", Version(0, 1))],
+                     writes=[KVWrite("k01", b"c")]))]
+    stream = [(seed(ids), None), (b1, None)]
+    return (stream, [[V] * 8, [V, BADRW, MVCC, V]],
+            [(0, 0, 0), (3, 1, 0)])
+
+
+def case_valid_txs_write_nothing(ids):
+    """The only valid txs of the block read and write nothing; the one
+    that writes failed the gate.  An empty batch, no history row."""
+    b1 = [tx(ids, rw(reads=[KVRead("k00", Version(0, 0))])),
+          tx(ids, rw(writes=[KVWrite("k00", b"never")])),
+          tx(ids, rw(reads=[KVRead("k01", Version(0, 1)),
+                            KVRead("k02", Version(0, 2))])),
+          tx(ids, rw())]
+    stream = [(seed(ids), None), (b1, [V, POLICY, V, V])]
+    return stream, [[V] * 8, [V, POLICY, V, V]], [(0, 0, 0), (3, 0, 0)]
+
+
+def case_one_tx(ids):
+    b1 = [tx(ids, rw(reads=[KVRead("k05", Version(0, 5))],
+                     writes=[KVWrite("k05", b"5")]))]
+    b2 = [tx(ids, rw(reads=[KVRead("k05", Version(0, 5))],
+                     writes=[KVWrite("k05", b"6")]))]
+    stream = [(seed(ids), None), (b1, None), (b2, None)]
+    return (stream, [[V] * 8, [V], [MVCC]],
+            [(0, 0, 0), (1, 0, 0), (1, 0, 1)])
+
+
+def case_parameters_dropped(ids):
+    """Key-level validation parameters (`cc#meta`) and the deletes that
+    take them along: one held in state and not named by the block, one
+    the block names, one set and deleted by the same tx, one dropped
+    twice in a block, and a read of a dropped parameter after it."""
+    def meta(writes=(), reads=()):
+        return NsRwSet("cc#meta", reads=tuple(reads), writes=tuple(writes))
+
+    b1 = [tx(ids, TxRwSet((meta([KVWrite(f"k{i:02d}", b"p")
+                                 for i in range(5)]),)))]
+    b2 = [
+        tx(ids, rw(writes=[KVWrite("k00", b"", True)])),     # held, unnamed
+        tx(ids, TxRwSet((NsRwSet("cc", writes=(KVWrite("k01", b"", True),)),
+                         meta([KVWrite("k01", b"again")])))),  # set + delete
+        tx(ids, TxRwSet((meta(reads=[KVRead("k02", Version(1, 0))]),))),
+        tx(ids, rw(writes=[KVWrite("k02", b"", True)])),     # named by a read
+        tx(ids, TxRwSet((meta(reads=[KVRead("k02", Version(1, 0))]),))),
+        tx(ids, TxRwSet((meta(reads=[KVRead("k02", None)]),))),
+        tx(ids, rw(writes=[KVWrite("k00", b"back")])),
+        tx(ids, rw(writes=[KVWrite("k00", b"", True)])),     # dropped already
+        tx(ids, TxRwSet((meta([KVWrite("k03", b"", True)]),))),  # by hand
+        tx(ids, rw(writes=[KVWrite("k03", b"", True),
+                           KVWrite("k07", b"", True)])),     # k07: none held
+        tx(ids, rw(reads=[KVRead("k04", Version(0, 3))],     # loses MVCC:
+                   writes=[KVWrite("k04", b"", True)])),     # k04's stays
+    ]
+    b3 = [tx(ids, TxRwSet((meta(reads=[KVRead("k04", Version(1, 0)),
+                                       KVRead("k00", None),
+                                       KVRead("k01", None)]),)))]
+    stream = [(seed(ids), None), (b1, None), (b2, None), (b3, None)]
+    want = [[V] * 8, [V], [V, V, V, V, MVCC, V, V, V, V, V, MVCC], [V]]
+    return stream, want, [(0, 0, 0), (0, 0, 0), (4, 1, 1), (3, 0, 0)]
+
+
 @pytest.mark.parametrize("case", [
     case_bump_repeats, case_deletes, case_absent_keys,
-    case_garbage_bad_and_config, case_smallbank_chains],
+    case_garbage_bad_and_config, case_smallbank_chains,
+    case_thrice_written, case_nil_after_a_staged_delete,
+    case_bad_between_valid, case_valid_txs_write_nothing, case_one_tx,
+    case_parameters_dropped],
     ids=lambda c: c.__name__[5:])
-def test_the_lane_source_gives_the_envelope_sources_answers(ids, case):
+def test_the_three_walks_give_the_same_answers(ids, case):
     stream, want_codes, want_tallies = case(ids)
-    codes, tallies = through_both_sources(stream)
+    codes, tallies = through_three_walks(stream)
     assert codes == want_codes
     if want_tallies is not None:
         assert tallies == want_tallies
 
 
-def test_two_ledgers_one_stream_end_at_the_same_hash_and_state(ids):
-    """The durable pair: ledgers on disk, one fed views and one plain
-    blocks, reopened, agree with each other and with themselves."""
-    stream, want, _ = case_bump_repeats(ids)
-    import tempfile
-    with tempfile.TemporaryDirectory() as tmp:
-        roots = [os.path.join(tmp, "lanes"), os.path.join(tmp, "envs")]
-        ledgers = [KVLedger("ch", LedgerConfig(root=r)) for r in roots]
-        prev = GENESIS
-        for number, (envelopes, _gate) in enumerate(stream):
-            raw, prev = raw_block(number, prev, envelopes)
-            gate = [V] * len(envelopes)
-            ledgers[0].commit(view_of(raw, gate))
-            ledgers[1].commit(plain_of(raw, gate))
-        assert mvcc_span(ledgers[0])["source"] == "lanes"
-        assert mvcc_span(ledgers[1])["source"] == "envelopes"
-        reopened = [KVLedger("ch", LedgerConfig(root=r)) for r in roots]
-        for a, b in ((ledgers[0], ledgers[1]), (reopened[0], reopened[1]),
-                     (ledgers[0], reopened[0])):
+def test_the_batch_keeps_a_keys_first_position_and_last_value(ids):
+    stream, _, _ = case_thrice_written(ids)
+    ledger = KVLedger("ch", LedgerConfig())
+    raw0, prev = raw_block(0, GENESIS, stream[0][0])
+    ledger.commit(view_of(raw0, [V] * 8))
+    raw1, _ = raw_block(1, prev, stream[1][0])
+    view = view_of(raw1, [V] * 4)
+    table, _ = mvcc.lane_source_of(view, TxFlags.from_bytes(bytes(4)))
+    got = walked(ledger.statedb, 1, table, bytes(4))
+    assert got["walk"] == ("arrays", None)
+    assert [(k, vv.value, vv.version.tx_num) for k, vv in got["batch"]] == [
+        (("cc", "k03"), b"four", 3), (("cc", "new"), b"n", 1),
+        (("cc", "k01"), b"m", 2)]
+    assert [(t, key, value) for t, _, _, key, value, _ in got["history"]] == [
+        (0, "k03", b"one"), (1, "new", b"n"), (1, "k03", b"two"),
+        (2, "k01", b"m"), (3, "k03", b"three"), (3, "k03", b"four")]
+
+
+def test_two_hundred_smallbank_blocks_end_alike_on_all_three(ids):
+    """A seeded random stream: 5 opening blocks and 195 of the mix at
+    s = 1.0 over 60 accounts, one envelope in 9 tampered."""
+    creator, endorsers = ids
+    plan = model.plan_chain(2**31 + 43, 60, 195, 12, 6, 9)
+    assert len(plan) == 200
+    stream, want = [], []
+    for block in plan:
+        raw, _ = model.build_block(block, GENESIS, "ch", "smallbank",
+                                   endorsers, [creator] * 6)
+        stream.append(([Envelope.deserialize(b)
+                        for b in Block.deserialize(raw).data],
+                       [V if c == MVCC else c for c in block["codes"]]))
+        want.append(list(block["codes"]))
+    codes, tallies = through_three_walks(stream)
+    assert codes == want
+    # every tx of a block is simulated on the state before the block:
+    # what fails, fails against the block
+    assert sum(t[1] for t in tallies) == sum(
+        c == MVCC for block in codes for c in block) > 300
+
+
+@pytest.mark.parametrize("shards", [1, 13])
+def test_the_three_walks_agree_at_other_stripe_widths(ids, shards):
+    """One shard (no split at all) and a width that is no power of two:
+    the array pass's warm split is the hashed one (`walked` compares)."""
+    for case in (case_bump_repeats, case_parameters_dropped):
+        stream, want_codes, _ = case(ids)
+        codes, _ = through_three_walks(
+            stream, lambda: LedgerConfig(state_shards=shards))
+        assert codes == want_codes
+
+
+@pytest.mark.parametrize("case", [case_bump_repeats,
+                                  case_parameters_dropped],
+                         ids=lambda c: c.__name__[5:])
+def test_three_ledgers_one_stream_end_at_the_same_bytes(ids, tmp_path, case):
+    """The durable three: ledgers on disk, fed views walked as arrays,
+    views walked in Python and plain blocks, write the same state and
+    history WALs byte for byte and, reopened, agree with each other and
+    with themselves."""
+    stream, want, _ = case(ids)
+    roots = {walk: str(tmp_path / walk) for walk in WALKS}
+    ledgers = {walk: KVLedger("ch", LedgerConfig(root=roots[walk]))
+               for walk in WALKS}
+    prev = GENESIS
+    for number, (envelopes, _gate) in enumerate(stream):
+        raw, prev = raw_block(number, prev, envelopes)
+        gate = [V] * len(envelopes)
+        ledgers["arrays"].commit(view_of(raw, gate))
+        with the_python_walk():
+            ledgers["python"].commit(view_of(raw, gate))
+        ledgers["envelopes"].commit(plain_of(raw, gate))
+    assert {w: mvcc_span(ledgers[w]) for w in WALKS} == WALKS
+    for db, wal in (("statedb", "state.wal"), ("historydb", "history.wal")):
+        written = {}
+        for w in WALKS:
+            with open(os.path.join(getattr(ledgers[w], db).root, wal),
+                      "rb") as f:
+                written[w] = f.read()
+        assert written["arrays"] == written["python"] == written["envelopes"]
+        assert len(written["arrays"]) > 100
+    reopened = {w: KVLedger("ch", LedgerConfig(root=roots[w])) for w in WALKS}
+    for w in WALKS:
+        for a, b in ((ledgers[w], ledgers["envelopes"]),
+                     (reopened[w], reopened["envelopes"]),
+                     (ledgers[w], reopened[w])):
             assert a.commit_hash == b.commit_hash
             assert state_of(a) == state_of(b)
             assert history_of(a) == history_of(b)
-        assert [list(reopened[0].blockstore.get_by_number(n)
-                     .metadata.items[META_TXFLAGS])
-                for n in range(3)] == want
+    assert [list(reopened["arrays"].blockstore.get_by_number(n)
+                 .metadata.items[META_TXFLAGS])
+            for n in range(len(stream))] == want
 
 
 # -- the rule and its demotions -----------------------------------------------
@@ -366,7 +581,8 @@ def test_a_demoted_block_commits_through_the_envelope_source(
     raw, _ = raw_block(1, prev, envelopes)
     gate = gate or [V] * len(envelopes)
     ledger.commit(parse(raw, gate))
-    assert mvcc_span(ledger) == {"source": "envelopes", "reason": reason}
+    assert mvcc_span(ledger) == {"source": "envelopes", "reason": reason,
+                                 "walk": "python"}
     oracle.commit(plain_of(raw, gate))
     assert ledger.commit_hash == oracle.commit_hash
     assert state_of(ledger) == state_of(oracle)
@@ -375,6 +591,46 @@ def test_a_demoted_block_commits_through_the_envelope_source(
     # the oracle shares the channel's series: two blocks, both by envelopes
     assert after["lanes"] == before["lanes"]
     assert after["envelopes"] - before["envelopes"] == 2 * len(gate)
+    # ... and both by the Python walk, each for its source's reason
+    want = collections.Counter({("arrays", "none"): 8,
+                                ("python", "no_view"): 8 + len(gate)})
+    want[("python", reason)] += len(gate)
+    assert walk_counts(channel, reason, "no_view") == want
+
+
+@pytest.mark.parametrize("reason", ["no_native"])
+def test_a_lane_table_the_array_pass_cannot_take_is_walked_in_python(
+        ids, reason):
+    """One case a reason of `mvcc.walk_of`.  It has one: the pass folds
+    the parameter a delete takes along into its decision, so a block that
+    deletes on a channel that holds parameters is walked as arrays too
+    (`case_parameters_dropped`), and what is left is a process whose
+    `native/fastmvcc.c` did not build."""
+    channel = "ch-walk-" + reason
+    ledger, oracle = (KVLedger(channel, LedgerConfig()) for _ in range(2))
+    stream, want, _ = case_parameters_dropped(ids)
+    prev = GENESIS
+    for number, (envelopes, _gate) in enumerate(stream):
+        raw, prev = raw_block(number, prev, envelopes)
+        gate = [V] * len(envelopes)
+        before = walk_counts(channel, reason)
+        oracle.commit(view_of(raw, gate))
+        assert mvcc_span(oracle) == WALKS["arrays"]
+        assert mvcc.walk_of() == ("arrays", None)
+        with the_python_walk():
+            assert mvcc.walk_of() == ("python", reason)
+            view = view_of(raw, gate)
+            ledger.commit(view)
+        assert mvcc_span(ledger) == {"source": "lanes", "walk": "python",
+                                     "reason": reason}
+        after = walk_counts(channel, reason)
+        assert {k: after[k] - before[k] for k in after} == {
+            ("arrays", "none"): len(gate), ("python", reason): len(gate)}
+        assert ledger.commit_hash == oracle.commit_hash
+        assert list(view.metadata.items[META_TXFLAGS]) == want[number]
+    assert state_of(ledger) == state_of(oracle)
+    assert history_of(ledger) == history_of(oracle)
+    assert ledger.statedb.meta_keys() == oracle.statedb.meta_keys() == (3, 1)
 
 
 def test_a_gate_invalid_range_or_unknown_tx_does_not_demote(ids):
@@ -384,7 +640,7 @@ def test_a_gate_invalid_range_or_unknown_tx_does_not_demote(ids):
                  without_nonce(ids),
                  tx(ids, rw(reads=[KVRead("k00", Version(0, 0))],
                             writes=[KVWrite("k00", b"y")]))]
-    codes, tallies = through_both_sources(
+    codes, tallies = through_three_walks(
         [(seed(ids), None), (envelopes, [POLICY, POLICY, V])])
     assert codes[1] == [POLICY, POLICY, V]
     assert tallies[1] == (1, 0, 0)
@@ -422,8 +678,68 @@ def test_the_mvcc_span_carries_its_source(ids):
                     got[s["attributes"]["source"]] = s["attributes"]
     finally:
         t.enabled = was
-    assert got["lanes"] == {"source": "lanes"}
-    assert got["envelopes"] == {"source": "envelopes", "reason": "no_view"}
+    assert got["lanes"] == WALKS["arrays"]
+    assert got["envelopes"] == WALKS["envelopes"]
+
+
+def walk_counts(channel, *reasons):
+    c = registry.counter("ledger_mvcc_walk_total")
+    return {(w, r): c.value(channel=channel, walk=w, reason=r)
+            for w, r in [("arrays", "none")]
+            + [("python", r) for r in dict.fromkeys(reasons)]}
+
+
+SERIAL_SERIES = [("ledger_commit_source_total", {"source": "lanes"}),
+                 ("ledger_commit_source_total", {"source": "envelopes"}),
+                 ("ledger_mvcc_reads_total", {"path": "serial"}),
+                 ("ledger_mvcc_conflicts_total",
+                  {"path": "serial", "against": "block"}),
+                 ("ledger_mvcc_conflicts_total",
+                  {"path": "serial", "against": "state"}),
+                 ("ledger_state_writes_total", {}),
+                 ("ledger_state_write_bytes_total", {})]
+
+
+def serial_counts(channel):
+    return [registry.counter(name).value(channel=channel, **labels)
+            for name, labels in SERIAL_SERIES]
+
+
+def test_the_walk_counter_adds_up_and_moves_no_other_series(ids):
+    """Per block, arrays + python = the block's tx count; and what the
+    serial walk counts — its source, the reads it validated, the
+    conflicts it found, the writes it staged — reads the same under the
+    array pass as under the Python walk on the same stream."""
+    channels = {"arrays": "ch-series-arrays", "python": "ch-series-python"}
+    ledgers = {w: KVLedger(ch, LedgerConfig()) for w, ch in channels.items()}
+    streams = [case(ids)[0] for case in (
+        case_smallbank_chains, case_absent_keys, case_parameters_dropped)]
+    moved_reads = 0
+    for stream in streams:
+        prev = GENESIS
+        for walk, ledger in ledgers.items():     # a fresh chain a stream
+            ledgers[walk] = KVLedger(channels[walk], LedgerConfig())
+        for number, (envelopes, gate) in enumerate(stream):
+            raw, prev = raw_block(number, prev, envelopes)
+            gate = gate if gate is not None else [V] * len(envelopes)
+            moved = {}
+            for walk, channel in channels.items():
+                before = (walk_counts(channel, "no_native"),
+                          serial_counts(channel))
+                with (the_python_walk() if walk == "python"
+                      else contextlib.nullcontext()):
+                    ledgers[walk].commit(view_of(raw, gate))
+                after = (walk_counts(channel, "no_native"),
+                         serial_counts(channel))
+                walks = {k: after[0][k] - before[0][k] for k in after[0]}
+                assert sum(walks.values()) == len(envelopes)
+                assert walks[("arrays", "none")] == (
+                    len(envelopes) if walk == "arrays" else 0)
+                moved[walk] = [a - b for a, b in zip(after[1], before[1])]
+            assert moved["arrays"] == moved["python"]
+            assert moved["arrays"][:2] == [len(envelopes), 0]
+            moved_reads += moved["arrays"][2]
+    assert moved_reads > 100
 
 
 def source_counts(channel):

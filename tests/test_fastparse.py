@@ -468,6 +468,49 @@ def test_rwset_lanes_native_matches_mirror():
     assert n_accept > 10 and n_collide > 0  # corpus exercised both paths
 
 
+def _arena_strings(mod, base, lanes):
+    """(key_strs, txids) of an extracted arena through `mod`'s decoders."""
+    _f, n_tx, n_keys, n_reads, n_writes, arena = lanes
+    cells = memoryview(arena).cast("B")
+    keys_at = 8 * (3 * n_tx + 5 * (n_reads + n_writes))
+    return (mod.arena_keys(base, cells[keys_at:keys_at + 40 * n_keys]),
+            mod.arena_txids(base, cells[:24 * n_tx]))
+
+
+def test_arena_strings_native_match_the_python_decode(monkeypatch):
+    """`LaneTable.key_strs` / `.txids` through `arena_keys` / `arena_txids`
+    against the comprehension they replace, over the lane corpus; a
+    namespace that repeats is one str object."""
+    n_tables = n_keys = 0
+    for seed in (11, 22):
+        for base, spans in lane_fuzz_corpus(seed):
+            lanes = wire._fastparse.rwset_lanes(base, spans)
+            if lanes is None or lanes[0]:
+                continue
+            native = wire.LaneTable(base, lanes)
+            with monkeypatch.context() as m:
+                m.setattr(wire, "_fastparse", None)
+                mirror = wire.LaneTable(base, lanes)
+                want = (mirror.key_strs, mirror.txids)
+            assert (native.key_strs, native.txids) == want
+            assert _arena_strings(wire._fastparse, base, lanes) == want
+            for (a, _), (b, _) in zip(native.key_strs, native.key_strs[1:]):
+                assert (a is b) == (a == b)
+            n_tables += 1
+            n_keys += len(want[0])
+    assert n_tables > 10 and n_keys > 50
+    with pytest.raises(ValueError):
+        wire._fastparse.arena_keys(b"abc", struct.pack("=5Q", 0, 0, 2, 2, 2))
+    with pytest.raises(ValueError):
+        wire._fastparse.arena_txids(b"abc", struct.pack("=3Q", 0, 2, 2))
+    with pytest.raises(ValueError):
+        wire._fastparse.arena_keys(b"abc", b"\x00" * 39)
+    with pytest.raises(UnicodeDecodeError):
+        wire._fastparse.arena_keys(b"\xff\xfe", struct.pack("=5Q", 0, 0, 1, 1, 1))
+    assert wire._fastparse.arena_txids(
+        b"abc", struct.pack("=6Q", 1, 9, 9, 0, 1, 2)) == [None, "bc"]
+
+
 # -- ASan/UBSan smoke driver (tests/smoke.sh) --------------------------------
 
 def run_sanitizer_corpus(mod, seeds=(11, 22, 33)):
@@ -495,6 +538,7 @@ def run_sanitizer_corpus(mod, seeds=(11, 22, 33)):
             if lanes is not None:
                 if lanes[5] is not None:
                     memoryview(lanes[5])[:]
+                    _arena_strings(mod, base, lanes)
                 n_lane += 1
     return n_blk, n_env, n_lane
 

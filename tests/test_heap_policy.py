@@ -110,8 +110,19 @@ def ballast(n: int) -> list:
 
 
 def room() -> int:
-    """Blocks the heap may still grow by before the rule thaws."""
+    """Blocks the heap may still grow by before the rule thaws, read
+    after a collection: garbage on its way out is no part of the heap."""
+    gc.collect()
     return 2 * heap._blocks_at_thaw - sys.getallocatedblocks()
+
+
+def slack() -> int:
+    """What a commit may give back or take between the rule's reading of
+    the heap and the test's, as a share of the heap the worker has: the
+    block's own temporaries, and whatever an earlier test file of this
+    worker left filling up — a flight recorder at capacity drops a whole
+    trace for each one a block adds.  A twentieth of the bar."""
+    return heap._blocks_at_thaw // 20
 
 
 def thaws() -> int:
@@ -128,7 +139,8 @@ def test_freezes_at_each_boundary_and_thaws_by_doubling(
     commit(committer, blocks[0])
     assert thaws() == 1                  # the first boundary thaws
     at_thaw = heap._blocks_at_thaw       # the heap's size the rule doubles
-    assert 0 < at_thaw <= sys.getallocatedblocks() + 1000
+    assert at_thaw > 0
+    assert abs(at_thaw - sys.getallocatedblocks()) < slack()
     assert frozen(committer.ledger)
 
     counts = [gc.get_freeze_count()]
@@ -147,11 +159,11 @@ def test_freezes_at_each_boundary_and_thaws_by_doubling(
     assert thaws() == 1 and heap._blocks_at_thaw == at_thaw
 
     # past it: one whole-heap pass, and the bar moves to the new size
-    keep.append(ballast(room()))
+    keep.append(ballast(room() + slack()))
     commit(committer, blocks[4])
     assert thaws() == 2
     # (the pass took what cyclic garbage the blocks had left frozen)
-    assert heap._blocks_at_thaw > 2 * at_thaw - 1000
+    assert heap._blocks_at_thaw > 2 * at_thaw - at_thaw // 20
     commit(committer, blocks[5])
     assert thaws() == 2
 

@@ -608,12 +608,14 @@ def test_a_bump_run_exposes_what_the_parent_did_and_the_new_series(
 # block took, in transactions) and PR 40's `validator_handoff_sigs_total`
 # (the form the provider got each block's unique items in) and PR 41's
 # `validator_creators_total` (a block's creators by whether its memo knew
-# them)
+# them) and PR 43's `ledger_mvcc_walk_total` (the form the serial MVCC
+# walk took, in transactions)
 PARENT_FAMILIES = {
     "commit_graph_apply_batch_size",
     "committed_blocks_total", "committed_txs_total",
     "ledger_commit_source_total", "ledger_height",
     "ledger_mvcc_conflicts_total", "ledger_mvcc_reads_total",
+    "ledger_mvcc_walk_total",
     "ledger_state_writes_total", "ledger_tx_total",
     "pipeline_collect_under_verify_frac", "state_checkpoint_height",
     "state_checkpoint_seconds", "state_checkpoint_total", "state_shard_keys",
