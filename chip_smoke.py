@@ -698,10 +698,6 @@ def run() -> dict:
         check(all(st2["native"].values()),
               f"three native extensions loaded in the device peer "
               f"({st2['native']})")
-        state = http_json("GET", nw.ops[dev_org] + "/state")
-        demoted = state["channels"][CHANNEL].get(
-            "device_validate", {}).get("demotions", {}).get("error", 0)
-        check(demoted == 0, 'demotions with reason="error" == 0')
         for org in PEER_ORGS[1:]:
             check(nw.provider_status(org)["device"] is None,
                   f"{org}'s peer (SW) reports no device")

@@ -1,5 +1,4 @@
 from .txvalidator import TxValidator, PolicyRegistry, ValidationResult
-from .committer import Committer, PipelinedCommitter
+from .committer import Committer
 
-__all__ = ["TxValidator", "PolicyRegistry", "ValidationResult", "Committer",
-           "PipelinedCommitter"]
+__all__ = ["TxValidator", "PolicyRegistry", "ValidationResult", "Committer"]

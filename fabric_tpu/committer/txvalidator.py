@@ -231,8 +231,7 @@ class TxValidator:
                  ledger_has_txid=None, bundle_source=None,
                  sbe_lookup=None, sbe_state=None,
                  validation_plugin: str = "DefaultValidation",
-                 provider_source=None, verify_cache=None,
-                 early_abort=None, device_validate=None):
+                 provider_source=None, verify_cache=None):
         self.channel_id = channel_id
         self._static_msps = msps
         self._provider = provider
@@ -282,21 +281,6 @@ class TxValidator:
         # block are also pruned: a replay of the same or an earlier
         # block (catch-up, crash recovery) is not a duplicate of itself.
         self._inflight_txids: List[Tuple[int, Dict[str, int]]] = []
-        # parallel-commit early abort (parallel_commit.EarlyAbortAnalyzer
-        # or None): txs provably doomed to MVCC_READ_CONFLICT by a
-        # preceding same-block write are flagged during pass 1 and their
-        # VerifyItems never reach the device — don't burn verify slots
-        # on txs that lose MVCC anyway
-        self.early_abort = early_abort
-        # fused device validation (device_validate.DeviceValidator or
-        # None): on the deep tail the gate fold AND MVCC run as one
-        # device dispatch; the prepared UpdateBatch is stashed for the
-        # ledger.  A demoted block (hash collision, range query, ...)
-        # silently falls back to the host gate below — correctness
-        # never depends on the device path.  It runs only on blocks that
-        # take the deep tail; node/peer.py builds its validator with
-        # sbe_lookup=None, so there every block does.
-        self.device_validate = device_validate
         # live pipeline-economics window (overlap gauge for the SLO plane)
         self._econ = _PipelineEconomics()
 
@@ -341,53 +325,6 @@ class TxValidator:
         return PolicyEvaluator(self.msps, self.provider)
 
     # -- pass 1: structural + collect ---------------------------------------
-
-    def _doomed_txs(self, block: Block) -> Optional[dict]:
-        """tx_num -> MVCC_READ_CONFLICT from the early-abort analyzer,
-        or None when unwired / guard-failed / analyzer error.  Never
-        lets an analysis failure take the block down — early abort is a
-        pure optimization; the MVCC pass remains authoritative."""
-        if self.early_abort is None:
-            return None
-        # fetch the pending-window overlay ONCE here so dooming and the
-        # mid-window accounting below judge the same frozen snapshot (a
-        # pipelined driver validates block N+1 while N's apply is still
-        # in flight; the analyzer needs the overlay to keep dooming
-        # across the savepoint gap — see earlyabort.py guard notes)
-        overlay = None
-        src = getattr(self.early_abort, "overlay_source", None)
-        if src is not None:
-            try:
-                overlay = src()
-            except Exception:
-                overlay = None
-        if overlay is not None and not overlay.empty:
-            try:
-                from fabric_tpu.ops_plane import registry
-                registry.counter(
-                    "validator_midwindow_blocks_total",
-                    "blocks validated while commit-window predecessors "
-                    "were still in flight").add(1, channel=self.channel_id)
-            except Exception:
-                pass
-        try:
-            doomed = self.early_abort.doomed(block, overlay=overlay)
-        except Exception:
-            logger.exception("early-abort analysis failed; skipping")
-            return None
-        return doomed or None
-
-    def _note_early_aborts(self, n: int) -> None:
-        if not n:
-            return
-        try:
-            from fabric_tpu.ops_plane import registry
-            registry.counter(
-                "commit_graph_early_aborts_total",
-                "txs flagged MVCC_READ_CONFLICT before device dispatch"
-            ).add(n, channel=self.channel_id)
-        except Exception:
-            pass
 
     def _deserialize(self, ident_bytes: bytes) -> Optional[Identity]:
         from fabric_tpu.msp import deserialize_from_msps
@@ -582,7 +519,7 @@ class TxValidator:
                          seen_txids: Dict[str, int],
                          items: Dict[VerifyItem, None],
                          memo: dict, acct: "_Identities", n_txs: int = 1,
-                         has_txid=None, doomed=None) -> Optional[_TxWork]:
+                         has_txid=None) -> Optional[_TxWork]:
         """Pass-1 tail for one tx whose structural walk ran in either
         front walker — C (native/fastcollect.c) or the Python mirror
         (committer/collect_py.py).  One consumer tail for both walkers
@@ -621,15 +558,6 @@ class TxValidator:
 
         if txtype == 0 and n_txs != 1:
             flags.set(tx_num, ValidationCode.INVALID_CONFIG_TRANSACTION)
-            return None
-
-        # early abort: a tx the analyzer proved cannot win MVCC is
-        # flagged NOW, after txid registration (later duplicates of its
-        # txid must still read DUPLICATE_TXID) and before any identity
-        # resolution or VerifyItem interning — its signatures never
-        # reach the device
-        if doomed is not None and tx_num in doomed:
-            flags.set(tx_num, doomed[tx_num])
             return None
 
         # creator identity: deserialize + chain-validate, memoized per
@@ -1020,13 +948,11 @@ class TxValidator:
             and not self.ledger_has_txid(next(iter(m)))]
         carry = [m for _, m in self._inflight_txids]
 
-        doomed = self._doomed_txs(block)
-
         t0 = time.perf_counter()
         use_sbe = self._sbe_enabled()
         tail, reason = self._tail_of(use_sbe)
         if tail == "deep":
-            state = self._begin_deep(block, num, carry, doomed, t0, use_sbe)
+            state = self._begin_deep(block, num, carry, t0, use_sbe)
             if state is not None:
                 return state
             # the block itself sets or deletes a parameter, and an
@@ -1050,23 +976,16 @@ class TxValidator:
             or self.ledger_has_txid(t)))
         memo: dict = {}
         acct = _Identities()
-        n_aborted = 0
         for tx_num, rec in enumerate(recs):
             work = self._collect_tx_fast(tx_num, rec, flags, seen_txids,
                                          items, memo, acct, n_txs=n,
-                                         has_txid=has_txid, doomed=doomed)
-            if work is None and doomed is not None and tx_num in doomed \
-                    and flags.flag(tx_num) in (
-                        ValidationCode.MVCC_READ_CONFLICT,
-                        ValidationCode.PHANTOM_READ_CONFLICT):
-                n_aborted += 1
+                                         has_txid=has_txid)
             if work is not None:
                 works.append(work)
         if use_sbe and any(w.meta_writes for w in works):
             self._note_meta_block(num)
         self._note_identities(num, acct)
         verify = self._dispatch(list(items))
-        self._note_early_aborts(n_aborted)
         self._inflight_txids.append((num, seen_txids))
         collect_s = self._collected(t0, num, n, verify, tail, reason)
         return {"block": block, "flags": flags, "items": items,
@@ -1075,7 +994,7 @@ class TxValidator:
                 "collect_s": collect_s, "use_sbe": use_sbe}
 
     def _begin_deep(self, block: Block, num: int, carry: list,
-                    doomed, t0: float, use_sbe: bool) -> Optional[dict]:
+                    t0: float, use_sbe: bool) -> Optional[dict]:
         """Deep native pass 1: the C walker consumes its own tuples
         (fastcollect digest/assemble) — txid dedup, creator/endorser
         memo slot assignment, and the block's unique signatures written
@@ -1108,26 +1027,6 @@ class TxValidator:
         if n_meta and use_sbe:
             self._note_meta_block(num)
             return None
-        if doomed:
-            # early abort on the deep path: DROP the work tuple (assemble
-            # interns every work's items regardless of its code, and gate
-            # overwrites the code of any planned tx — filtering is the
-            # only insertion point that keeps the tx off the device AND
-            # out of the gate) and stamp the code.  Only txs still clean
-            # after the structural walk are doomed; a structural code
-            # (dup txid etc.) wins, matching the classic tail's ordering.
-            not_validated = int(ValidationCode.NOT_VALIDATED)
-            n_aborted = 0
-            kept = []
-            for w in works:
-                tx = w[0]
-                if tx in doomed and codes[tx] == not_validated:
-                    codes[tx] = int(doomed[tx])
-                    n_aborted += 1
-                else:
-                    kept.append(w)
-            works = kept
-            self._note_early_aborts(n_aborted)
         # one MSP resolution per unique identity (the whole-block analogue
         # of the classic tail's (0,creator)/(1,endorser) memo dicts)
         acct = _Identities()
@@ -1225,7 +1124,7 @@ class TxValidator:
         # device runs the block's own programs
         settled = (self._settle_creators(state) if "deferred" in state
                    else {})
-        # positional over the table, as gate and the fused path read it
+        # positional over the table, as gate reads it
         verdict = self._await(state["verify"]).view(np.uint8)
         dispatch_s = time.perf_counter() - t0
         tracing.tracer.record_span(
@@ -1234,17 +1133,9 @@ class TxValidator:
                         "unique_items": len(index), **settled})
 
         t0 = time.perf_counter()
-        flags = None
-        if self.device_validate is not None:
-            # fused device path: gate fold + MVCC in one dispatch; the
-            # prepared batch is stashed for the ledger.  None = demoted
-            # (collision / range / ...) — fall through to the host gate.
-            flags = self.device_validate.run(
-                state, verdict, self.validation_plugin, self.evaluator)
-        if flags is None:
-            _fastcollect.gate(state["plans"], verdict, codes,
-                              self.validation_plugin, self.evaluator, {})
-            flags = TxFlags.from_bytes(bytes(codes))
+        _fastcollect.gate(state["plans"], verdict, codes,
+                          self.validation_plugin, self.evaluator, {})
+        flags = TxFlags.from_bytes(bytes(codes))
         return self._finished(state, flags, t0, dispatch_s,
                               len(state["plans"]))
 

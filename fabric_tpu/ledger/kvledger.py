@@ -19,7 +19,6 @@ import hashlib
 import logging
 import os
 import shutil
-import threading
 import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
@@ -81,36 +80,6 @@ class LedgerConfig:
     # independently locked + independently flushable shards; 1 = the
     # flat store (differential oracle)
     state_shards: int = 8
-    # parallel MVCC commit plane (committer/parallel_commit/): wavefront
-    # scheduler replaces the serial validate_and_prepare_batch walk —
-    # bit-identical output, enforced differentially.  Must be configured
-    # uniformly across the peers of a channel only as an operational
-    # convention (the OUTPUT is identical; only timing differs).
-    parallel_commit: bool = False
-    commit_workers: int = 4             # static cap on the worker pool
-    # adaptive sizing: the pool tracks the rolling max conflict-graph
-    # wave width, clamped to commit_workers (scheduler.target_workers)
-    commit_adaptive: bool = True
-    # serial fallback: run the oracle walk directly (and count it) when
-    # the wave machinery cannot pay off — 1-core host, or the adaptive
-    # pool would provision a single worker anyway.  Differential tests
-    # that must exercise the wave path set this False.
-    commit_serial_fallback: bool = True
-    # cross-block wavefront pipelining (committer/parallel_commit
-    # CommitWindow): W > 0 enables the commit_begin/commit_finish entry
-    # points with at most W blocks admitted-but-unretired.  The serial
-    # commit() stays available (and is the differential oracle) but
-    # refuses to run while window blocks are in flight.  Output is
-    # bit-identical to serial commits of the same stream; only timing
-    # differs.  0 = disabled.
-    commit_window: int = 0
-    # fused device validation (committer/device_validate.py): commit()
-    # consumes the validator's prepared UpdateBatch via the registered
-    # prepared-source instead of re-running host MVCC — the flags in
-    # block metadata and the statedb savepoint must still match what
-    # the device validated against, else host MVCC runs (always safe).
-    # Default OFF until parity is proven per deployment.
-    device_validate: bool = False
 
 
 @dataclass
@@ -160,30 +129,6 @@ class KVLedger:
         # set by _recover: how much work reopening this ledger cost
         self.last_recovery: Dict[str, int] = {
             "replayed_blocks": 0, "start": 0, "height": 0}
-        # DeviceValidator.take_prepared when device_validate is wired:
-        # (number, flags_bytes, savepoint) -> (final_flags, batch,
-        # history) | None
-        self._prepared_source = None
-        self._commit_scheduler = None
-        if self.config.parallel_commit:
-            # function-level import: ledger <- committer.parallel_commit
-            # <- ledger.mvcc would otherwise cycle at module load
-            from fabric_tpu.committer.parallel_commit import (
-                ParallelCommitScheduler)
-            self._commit_scheduler = ParallelCommitScheduler(
-                max_workers=self.config.commit_workers,
-                channel_id=channel_id,
-                adaptive=self.config.commit_adaptive,
-                serial_fallback=self.config.commit_serial_fallback)
-        self._commit_window = None
-        if self.config.commit_window > 0:
-            from fabric_tpu.committer.parallel_commit import CommitWindow
-            self._commit_window = CommitWindow(
-                channel_id=channel_id,
-                max_window=self.config.commit_window)
-        # serializes commit_finish calls (one finishing thread is the
-        # intended shape; the lock makes a second one safe, not fast)
-        self._finish_lock = threading.Lock()
         self._recover()
 
     # -- recovery (recovery.go) --------------------------------------------
@@ -259,56 +204,20 @@ class KVLedger:
         if state_has_it:
             history = _history_writes_from_flags(envelopes, flags)
         else:
-            batch, history = self._validate_and_prepare(
-                num, envelopes, flags)
+            batch, history = validate_and_prepare_batch(
+                self.statedb, num, envelopes, flags)
             self.statedb.apply_updates(batch, num)
         if self.historydb is not None:
             self.historydb.commit(num, history)  # savepoint-guarded, idempotent
 
-    def set_prepared_source(self, fn) -> None:
-        """Register the device validator's prepared-batch source
-        (DeviceValidator.take_prepared).  None unregisters."""
-        self._prepared_source = fn
-
-    def _take_prepared(self, block: Block):
-        """(final_flags_bytes, batch, history) from the device
-        validator's stash, or None when absent/stale (host MVCC runs)."""
-        if self._prepared_source is None or not self.config.device_validate:
-            return None
-        try:
-            return self._prepared_source(
-                block.header.number,
-                block.metadata.items[META_TXFLAGS],
-                self.statedb.savepoint)
-        except Exception:
-            logger.exception("prepared-batch source failed; "
-                             "falling back to host MVCC")
-            return None
-
-    def _validate_and_prepare(self, num: int, source, flags: TxFlags,
-                              tally: Optional[MvccTally] = None):
-        """MVCC pass: the wavefront scheduler when parallel_commit is
-        on (over envelopes), the serial oracle otherwise (over either
-        of its sources) — identical output either way.  Only the oracle
-        fills `tally`."""
-        if self._commit_scheduler is not None:
-            return self._commit_scheduler.validate_and_prepare_batch(
-                self.statedb, num, source, flags)
-        return validate_and_prepare_batch(self.statedb, num,
-                                          source, flags, tally)
-
-    def _count_block(self, flags: TxFlags, tally: Optional[MvccTally],
-                     history: list, mvcc_attrs: Optional[dict] = None
-                     ) -> None:
+    def _count_block(self, flags: TxFlags, tally: MvccTally,
+                     history: list, mvcc_attrs: dict) -> None:
         """One committed block into the always-on counters: its
         transactions by final code, the writes of its valid txs
-        (`history`: how many, and their key + value bytes) and, where
-        the serial walk validated it (`tally`), the reads it checked,
-        the conflicts it found, which source supplied its rw-sets and
-        which form the walk took (`mvcc_attrs`, the `ledger.mvcc` span's).
-        The default-off commit paths do not walk read by read: their
-        blocks move no `path="serial"` series, so those never read as
-        "no conflicts", and no source and no walk."""
+        (`history`: how many, and their key + value bytes), the reads
+        the walk checked and the conflicts it found (`tally`), which
+        source supplied its rw-sets and which form the walk took
+        (`mvcc_attrs`, the `ledger.mvcc` span's)."""
         from fabric_tpu.ops_plane import registry
         ch = self.channel_id
         txs = registry.counter(
@@ -323,8 +232,6 @@ class KVLedger:
             "ledger_state_write_bytes_total", "key + value bytes of those "
             "writes").add(sum(len(w[3].encode()) + len(w[4])
                               for w in history), channel=ch)
-        if tally is None:
-            return
         registry.counter(
             "ledger_commit_source_total", "transactions of the blocks the "
             "serial MVCC walk validated, by what supplied their rw-sets: "
@@ -373,10 +280,6 @@ class KVLedger:
         if self.paused:
             raise RuntimeError(
                 f"channel {self.channel_id!r} is paused (resume() first)")
-        if self._commit_window is not None and self._commit_window.depth():
-            raise RuntimeError(
-                "serial commit while the pipelined window has blocks in "
-                "flight (commit_finish them or abort_window() first)")
         if META_TXFLAGS not in block.metadata.items:
             raise ValueError("block metadata missing txflags "
                              "(txvalidator must run first)")
@@ -396,38 +299,24 @@ class KVLedger:
                             total_txs=n_txs(block))
 
         t0 = time.perf_counter()
-        tally = None                 # only the serial walk has one
-        prepared = self._take_prepared(block)
-        if prepared is not None:
-            # fused device validation already ran MVCC in the
-            # validator's single dispatch: consume the prepared batch —
-            # no envelope materialization, no host MVCC walk
-            final_bytes, batch, history = prepared
-            flags = TxFlags.from_bytes(final_bytes)
-            mvcc_attrs = {"source": "prepared"}
+        flags = TxFlags.from_bytes(block.metadata.items[META_TXFLAGS])
+        tally = MvccTally()
+        # the walk reads the block's lane table where the block allows
+        # it, else its envelopes, decoded again
+        source, reason = lane_source_of(block, flags)
+        if source is not None:
+            mvcc_attrs = {"source": "lanes"}
         else:
-            flags = TxFlags.from_bytes(block.metadata.items[META_TXFLAGS])
-            # the serial walk reads the block's lane table where the
-            # block allows it, else its envelopes, decoded again
-            source, reason = None, "scheduler"
-            if self._commit_scheduler is None:
-                tally = MvccTally()
-                source, reason = lane_source_of(block, flags)
-            if source is not None:
-                mvcc_attrs = {"source": "lanes"}
-            else:
-                source = _safe_envelopes(block)
-                mvcc_attrs = {"source": "envelopes", "reason": reason}
-            batch, history = self._validate_and_prepare(
-                block.header.number, source, flags, tally)
-            if tally is not None:
-                # the form the walk took; "python" comes with why: the
-                # source's reason above, or the walk's own (mvcc.walk_of)
-                mvcc_attrs["walk"] = tally.walk
-                if tally.reason is not None:
-                    mvcc_attrs["reason"] = tally.reason
+            source = _safe_envelopes(block)
+            mvcc_attrs = {"source": "envelopes", "reason": reason}
+        batch, history = validate_and_prepare_batch(
+            self.statedb, block.header.number, source, flags, tally)
+        # the form the walk took; "python" comes with why: the source's
+        # reason above, or the walk's own (mvcc.walk_of)
+        mvcc_attrs["walk"] = tally.walk
+        if tally.reason is not None:
+            mvcc_attrs["reason"] = tally.reason
         # split the batch by shard before the apply takes shard locks
-        # (the parallel-commit / device-validate planes do the same)
         batch.preshard(getattr(self.statedb, "n_shards", 1))
         stats.phase("ledger.mvcc", "state_validation_s", t0, mvcc_attrs)
         stats.valid_txs = flags.valid_count()
@@ -474,128 +363,6 @@ class KVLedger:
             stats.history_commit_s * 1e3)
         return stats
 
-    # -- pipelined commit (the cross-block wavefront window) ----------------
-
-    def pending_overlay(self):
-        """Frozen write-set snapshot of the window's in-flight blocks
-        (PendingOverlay; empty when the window is idle, None when the
-        pipelined window is disabled).  The early-abort analyzer's
-        overlay_source, and the dooming bound for admit-time waves."""
-        if self._commit_window is None:
-            return None
-        return self._commit_window.pending_overlay()
-
-    def commit_begin(self, block: Block):
-        """Admit `block` to the pipelined commit window and validate its
-        EARLY waves — the txs whose footprints provably avoid every
-        in-flight predecessor's pending write set — typically while the
-        predecessor's apply is still running on the finishing thread.
-        Returns the window ticket for commit_finish.  Single admitting
-        thread; blocks must arrive in chain order."""
-        if self._commit_window is None:
-            raise RuntimeError(
-                "pipelined commit disabled (LedgerConfig.commit_window)")
-        if self.paused:
-            raise RuntimeError(
-                f"channel {self.channel_id!r} is paused (resume() first)")
-        if META_TXFLAGS not in block.metadata.items:
-            raise ValueError("block metadata missing txflags "
-                             "(txvalidator must run first)")
-        from fabric_tpu.protocol import block_header_hash
-        tail = self._commit_window.tail()
-        if tail is not None:
-            expected_num = tail.num + 1
-            expected_prev = tail.header_hash
-        else:
-            # window empty: no concurrent finish can be in flight, so
-            # the chain tip is stable here
-            info = self.blockstore.chain_info()
-            expected_num = info.height
-            expected_prev = (info.current_hash if info.height
-                             else b"\x00" * 32)
-        if block.header.number != expected_num:
-            raise ValueError(
-                f"out-of-order commit_begin: got block "
-                f"{block.header.number}, expected {expected_num}")
-        if block.header.previous_hash != expected_prev:
-            raise ValueError(
-                f"block {block.header.number} previous_hash mismatch")
-        flags = TxFlags.from_bytes(block.metadata.items[META_TXFLAGS])
-        envelopes = _safe_envelopes(block)
-        entry = self._commit_window.admit(
-            self.statedb, block.header.number,
-            block_header_hash(block.header), envelopes, flags)
-        # the block rides the ticket un-mutated: metadata (final flags,
-        # commit hash) is only stamped at finish, so an aborted window
-        # leaves it pristine for the exactly-once replay
-        return (entry, block)
-
-    def commit_finish(self, ticket) -> CommitStats:
-        """Promote the ticket's deferred waves, then retire it: rebuild
-        the final batch in strict tx order, chain the commit hash, store
-        the block, and apply state + history.  Strictly in admit order
-        (head of window only) — that ordering is what keeps the windowed
-        stream bit-identical to serial commits."""
-        entry, block = ticket
-        with self._finish_lock:
-            t0 = time.perf_counter()
-            batch, history = self._commit_window.finish(
-                self.statedb, entry)
-            batch.preshard(getattr(self.statedb, "n_shards", 1))
-            flags = entry.flags
-            stats = CommitStats(block_num=entry.num,
-                                total_txs=len(block.data))
-            # the early waves ran at admit time, on the admitting
-            # thread: their seconds count, the span is this thread's
-            stats.state_validation_s = entry.validate_s
-            stats.phase("ledger.mvcc", "state_validation_s", t0)
-            stats.valid_txs = flags.valid_count()
-            block.metadata.items[META_TXFLAGS] = flags.to_bytes()
-            self._commit_hash = hashlib.sha256(
-                self._commit_hash + block.header.data_hash
-                + flags.to_bytes()).digest()
-            block.metadata.items[META_COMMIT_HASH] = self._commit_hash
-
-            # the retirement tail is the window's overlap counterpart:
-            # admits of successor blocks time their validation against
-            # this span
-            self._commit_window.apply_started()
-            try:
-                t1 = time.perf_counter()
-                self.blockstore.add_block(block)
-                stats.phase("ledger.block_commit", "block_commit_s", t1)
-
-                t1 = time.perf_counter()
-                self.statedb.apply_updates(batch, entry.num)
-                stats.phase("ledger.state_commit", "state_commit_s", t1)
-
-                if self.historydb is not None:
-                    t1 = time.perf_counter()
-                    self.historydb.commit(entry.num, history)
-                    stats.phase("ledger.history_commit",
-                                "history_commit_s", t1)
-            finally:
-                self._commit_window.apply_ended()
-            self._commit_window.retire(entry)
-
-            self._observe_apply(len(batch), len(history))
-            self._count_block(flags, None, history)
-            self.last_stats = stats
-            logger.info(
-                "[%s] committed block %d (windowed, %d early / %d "
-                "deferred): %d/%d valid",
-                self.channel_id, stats.block_num, entry.early_n,
-                entry.deferred_n, stats.valid_txs, stats.total_txs)
-            return stats
-
-    def abort_window(self) -> int:
-        """Drop every admitted-but-unfinished window block (pipeline
-        teardown or error recovery).  None of them reached the block
-        store, so they replay later exactly once; returns the count."""
-        if self._commit_window is None:
-            return 0
-        return self._commit_window.reset()
-
     # -- queries ------------------------------------------------------------
 
     @property
@@ -630,11 +397,6 @@ class KVLedger:
         }
         if self.historydb is not None:
             out["history"] = self.historydb.status()
-        if self._commit_scheduler is not None:
-            out["commit_serial_fallbacks"] = (
-                self._commit_scheduler.serial_fallbacks)
-        if self._commit_window is not None:
-            out["commit_window"] = self._commit_window.stats()
         return out
 
     def snapshot_export(self):

@@ -60,8 +60,6 @@ import numpy as np
 from fabric_tpu.protocol import wire
 from fabric_tpu.protocol import (
     Envelope,
-    KVRead,
-    NsRwSet,
     Transaction,
     TxRwSet,
     Version,
@@ -71,22 +69,6 @@ from fabric_tpu.protocol.types import RangeQueryInfo, TX_ENDORSER
 
 from .statedb import (META_SUFFIX, StateDB, UpdateBatch, VersionedValue,
                       _fastmvcc, shard_of)
-
-
-def _validate_read(db: StateDB, batch: UpdateBatch, ns: str,
-                   read: KVRead) -> bool:
-    """validateKVRead (validator.go:175): version equality, nil-safe
-    (the wavefront scheduler's per-read check; the serial walk below
-    compares the same way over its sources' records)."""
-    found, vv = batch.get(ns, read.key)
-    if not found:
-        vv = db.get(ns, read.key)
-    committed = None if vv is None else vv.version  # None: absent or deleted
-    if committed is None and read.version is None:
-        return True
-    return not (committed is None or read.version is None
-                or committed.block_num != read.version.block_num
-                or committed.tx_num != read.version.tx_num)
 
 
 class MvccTally:
@@ -153,12 +135,6 @@ def parse_endorser_tx(env: Envelope) -> Optional[Tuple[str, TxRwSet]]:
     if not tx.actions:
         return None
     return ch["txid"], tx.actions[0].action.rwset
-
-
-def extract_rwset(env: Envelope) -> Optional[TxRwSet]:
-    """Compatibility wrapper over parse_endorser_tx."""
-    parsed = parse_endorser_tx(env)
-    return None if parsed is None else parsed[1]
 
 
 # -- the two sources ----------------------------------------------------------
@@ -433,22 +409,6 @@ def _stage_writes(db: StateDB, batch: UpdateBatch, history: list,
         ident = ident_of(meta_ns, key)
         if ident is not None:
             staged[ident] = None
-
-
-def prepared_from_lanes(db: StateDB, table: "wire.LaneTable",
-                        final: TxFlags, block_num: int):
-    """(update_batch, history_writes) of a block whose FINAL flags are
-    known (the fused device program's): the write lanes of its valid txs
-    replayed in lane order — the put/delete sequence, and therefore the
-    UpdateBatch's order and the history rows, of the walk below.  `db`
-    is asked for the parameters of the keys the block deletes."""
-    batch, history = UpdateBatch(), []
-    ident_of = _lane_idents(table)
-    for tx_num, txid, _groups, writes in _lane_records(table, final):
-        if writes is not None:
-            _stage_writes(db, batch, history, {}, ident_of, block_num,
-                          tx_num, txid, writes)
-    return batch, history
 
 
 def validate_and_prepare_batch(

@@ -7,10 +7,9 @@ update batches applied atomically with a savepoint, ordered range scans.
 
 Layout: keys stripe across ``n_shards`` independently-locked shards by a
 deterministic hash of (namespace, key) — `shard_of`.  Batched applies
-land shard-parallel (the parallel-commit and device-validate planes
-pre-split their prepared batches with `UpdateBatch.preshard`, so the
-split cost is off the commit lock path), while point reads take only the
-owning shard's lock.
+land shard-parallel (the commit pre-splits its batch with
+`UpdateBatch.preshard`, so the split cost is off the commit lock path),
+while point reads take only the owning shard's lock.
 
 Durability model: ONE append-only WAL of update batches (a single fsync
 per block keeps the savepoint atomic ACROSS shards — per-shard WALs
@@ -104,8 +103,7 @@ class UpdateBatch:
     """statedb.UpdateBatch: puts/deletes staged by MVCC validation.
 
     `preshard` / `items_by_shard` cache the per-shard split so the
-    parallel-commit scheduler and the device-validate rebuild can pay
-    the hash cost outside the store's apply lock.
+    commit pays the hash cost outside the store's apply lock.
 
     `touches_meta` notes whether any namespace staged so far is a
     key-level-endorsement companion (`<ns>#meta`, committer/sbe.py): the

@@ -617,14 +617,16 @@ reject:
 }
 
 /* ------------------------------------------------------------------ */
-/* rwset_lanes — device-resident validation lane extractor.
+/* rwset_lanes — the rw-set lane extractor.
  *
  * rwset_lanes(base_buf, spans_buf) walks every envelope span of a
  * block (spans_buf = n × (u64 off, u64 len) pairs, the same layout
  * parse_block emits) and classifies each tx against the EXACT
  * semantics of ledger/mvcc.parse_endorser_tx + protocol/types
- * from_dict laxity, emitting fixed-width uint64 lanes for the fused
- * XLA gate+MVCC program (committer/device_validate.py):
+ * from_dict laxity, emitting fixed-width uint64 lanes for the lane
+ * table's readers (protocol/wire.py LaneTable: the ledger's MVCC walk,
+ * the block store's txid index, the commit notifier, the private-data
+ * coordinator):
  *
  *   status 0 OK       strict endorser tx; lanes emitted
  *   status 1 SKIP     parse_endorser_tx provably returns None

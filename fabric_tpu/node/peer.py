@@ -319,20 +319,6 @@ class PeerChannel:
         self.bundle_source = BundleSource(Bundle(channel_cfg),
                                           config_height=config_height)
         self.msps = self.bundle_source.current().msps
-        # parallel MVCC commit plane (committer/parallel_commit).  The
-        # early_abort sub-knob defaults to the plane's enabled state;
-        # NOTE it must be uniform across a channel's peers — a doomed
-        # tx's flag byte is MVCC_READ_CONFLICT even where the skipped
-        # signature gate would have said otherwise, and flags feed the
-        # commit hash (see parallel_commit/earlyabort.py).
-        pc_cfg = dict(node.cfg.get("parallel_commit", {}))
-        # fused device validation (committer/device_validate.py): gate
-        # fold + MVCC as one XLA dispatch per block, prepared batch
-        # consumed by the ledger.  Same uniformity note as early_abort
-        # (demotions fall back bit-identically, so only timing differs,
-        # but keep it uniform as an operational convention).
-        dv_cfg = dict(node.cfg.get("device_validate", {}))
-        dv_on = bool(dv_cfg.get("enabled", False))
         # sharded state plane knobs: `state: {shards, checkpoint_every}`
         st_cfg = dict(node.cfg.get("state", {}))
         ledger_root = f"{ch_dir}/ledger"
@@ -348,35 +334,7 @@ class PeerChannel:
             LedgerConfig(root=ledger_root,
                          state_shards=int(st_cfg.get("shards", 8)),
                          snapshot_every=int(
-                             st_cfg.get("checkpoint_every", 256)),
-                         parallel_commit=bool(pc_cfg.get("enabled", False)),
-                         commit_workers=int(pc_cfg.get("max_workers", 4)),
-                         commit_adaptive=bool(pc_cfg.get("adaptive", True)),
-                         commit_serial_fallback=bool(
-                             pc_cfg.get("serial_fallback", True)),
-                         # cross-block wavefront window (README
-                         # "Cross-block wavefront"): W > 0 enables the
-                         # pipelined commit_begin/commit_finish entry
-                         # points used by PipelinedCommitter drivers
-                         commit_window=int(pc_cfg.get("window", 0)),
-                         device_validate=dv_on))
-        early_abort = None
-        if pc_cfg.get("early_abort", pc_cfg.get("enabled", False)):
-            from fabric_tpu.committer.parallel_commit import (
-                EarlyAbortAnalyzer,
-            )
-            # overlay_source keeps dooming sound while the pipelined
-            # window holds uncommitted predecessors (savepoint lag)
-            early_abort = EarlyAbortAnalyzer(
-                self.ledger.statedb, self.channel_id,
-                overlay_source=self.ledger.pending_overlay)
-        device_validate = None
-        if dv_on:
-            from fabric_tpu.committer.device_validate import DeviceValidator
-            device_validate = DeviceValidator(
-                self.ledger.statedb, self.channel_id,
-                window=int(dv_cfg.get("window", 4096)))
-            self.ledger.set_prepared_source(device_validate.take_prepared)
+                             st_cfg.get("checkpoint_every", 256))))
 
         cfg = node.cfg
         self.policies = LifecyclePolicyProvider(self.ledger.statedb)
@@ -418,19 +376,13 @@ class PeerChannel:
         # block that no parameter can touch — none in state, none in
         # flight, none in the block — is collected and gated on the deep
         # C tail, every other on the classic one; nothing here chooses.
-        # The fused device path runs on the deep tail only, so enabling
-        # it trades away per-key validation-parameter overrides on this
-        # peer (README "Device-resident validation")
-        sbe = (None if device_validate is not None
-               else statedb_lookup(self.ledger.statedb))
         self.validator = TxValidator(
             self.channel_id, None, ch_provider, self.policies,
             bundle_source=self.bundle_source,
-            sbe_lookup=sbe, sbe_state=self.ledger.statedb.meta_keys,
+            sbe_lookup=statedb_lookup(self.ledger.statedb),
+            sbe_state=self.ledger.statedb.meta_keys,
             provider_source=provider_source,
-            verify_cache=node.verify_cache,
-            early_abort=early_abort,
-            device_validate=device_validate)
+            verify_cache=node.verify_cache)
         self.committer = Committer(self.ledger, self.validator,
                                    bundle_source=self.bundle_source,
                                    provider=ch_provider,
@@ -796,6 +748,15 @@ class PeerNode:
     def __init__(self, cfg: dict, data_dir: str):
         import os
 
+        # PR 44 removed both mechanisms.  A peer that had one on must be
+        # reconfigured, not started without it unasked: `early_abort`
+        # stamped flags, and flags feed the channel's commit hash
+        for section in ("parallel_commit", "device_validate"):
+            if section in cfg:
+                raise ValueError(
+                    f"peer config has a {section!r} section: the mechanism "
+                    f"was removed in PR 44 and the key is no longer read; "
+                    f"delete it (README, \"The commit path\")")
         self.cfg = cfg
         self.data_dir = data_dir
         self.channel_id = cfg.get("channel_id", "ch")
@@ -1455,24 +1416,8 @@ class PeerNode:
             str(body["file"]), int(body["offset"]))
 
     def _state_route(self, path, body):
-        demotions = registry.counter(
-            "validator_device_demotions_total",
-            "device-validation demotions to the host path, by reason")
-        out = {}
-        for cid, ch in sorted(self.channels.items()):
-            st = ch.ledger.state_status()
-            by_reason = demotions.breakdown("reason", channel=cid)
-            if by_reason:
-                # policy_width called out: it is the k<=8 truth-table
-                # cap's real-world demotion rate (README "Device-
-                # resident validation")
-                st["device_validate"] = {
-                    "demotions": {r: int(n)
-                                  for r, n in sorted(by_reason.items())},
-                    "policy_width_demotions": int(
-                        by_reason.get("policy_width", 0)),
-                }
-            out[cid] = st
+        out = {cid: ch.ledger.state_status()
+               for cid, ch in sorted(self.channels.items())}
         return 200, {"channels": out, "provider": self._provider_status()}
 
     def _provider_status(self) -> dict:

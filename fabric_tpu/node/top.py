@@ -134,20 +134,6 @@ def collect_node(addr: str, timeout: float = 2.0) -> dict:
     ov = [v for _, v in
           metrics.get("pipeline_collect_under_verify_frac", ())]
     row["overlap"] = (sum(ov) / len(ov)) if ov else None
-    # fused device validation: demotions to the host path by reason,
-    # policy_width (the k<=8 truth-table cap) called out; the per-
-    # channel split lives on GET /state
-    dem = metrics.get("validator_device_demotions_total", ()) or ()
-    if dem:
-        by_reason: Dict[str, float] = {}
-        for labels, v in dem:
-            r = labels.get("reason", "?")
-            by_reason[r] = by_reason.get(r, 0.0) + v
-        row["devval_demotions"] = by_reason
-        row["devval_policy_width"] = by_reason.get("policy_width", 0.0)
-    else:
-        row["devval_demotions"] = None
-        row["devval_policy_width"] = None
     row["queue_depth"] = _sum(metrics.get("provider_dispatch_queue_depth"))
     row["breakers_open"] = _sum(metrics.get("gateway_orderer_breaker_open"))
     row["faults_fired"] = _sum(metrics.get("fault_injected_total"))
@@ -288,10 +274,10 @@ def _fmt_devices(devs) -> str:
 
 
 _COLS = ("NODE", "HT", "TX/S", "COLLECT", "DISP", "GATE", "COMMIT",
-         "OCC", "DEV", "DEVVAL", "OVLP", "VCACHE", "SPEC", "STATE",
+         "OCC", "DEV", "OVLP", "VCACHE", "SPEC", "STATE",
          "RES", "QD", "BRKR", "SHED", "FAULTS", "BYZ", "LIFE", "INC",
          "SLO", "HEALTH")
-_WIDTHS = (21, 6, 8, 9, 9, 9, 9, 5, 10, 9, 5, 6, 5, 11, 9, 4, 5, 9, 7,
+_WIDTHS = (21, 6, 8, 9, 9, 9, 9, 5, 10, 5, 6, 5, 11, 9, 4, 5, 9, 7,
            12, 8, 10, 12, 8)
 
 # gateway_admission_state gauge value -> short cell tag
@@ -373,21 +359,6 @@ def _fmt_state(row: dict) -> str:
     return f"{n}sh/{k}" + ("" if ck is None else f"@{ck:.0f}")
 
 
-def _fmt_devval(row: dict) -> str:
-    """`<demotions>[pw:N]`: fused-device-validation demotions to the
-    host path, with the policy_width share (blocks demoted by the k<=8
-    truth-table cap — the cap's real-world demotion rate) called out;
-    `-` until the plane demotes (or on nodes running host MVCC only)."""
-    dem = row.get("devval_demotions")
-    if dem is None:
-        return "-"
-    cell = f"{sum(dem.values()):.0f}"
-    pw = dem.get("policy_width", 0.0)
-    if pw:
-        cell += f"[pw:{pw:.0f}]"
-    return cell
-
-
 def _fmt_res(row: dict) -> str:
     """`<RSS MB>M/<fd count>`: the resource collector's footprint cell;
     `-` on nodes that run with `resources` disabled."""
@@ -436,7 +407,7 @@ _SORT_KEYS = {
     "rate": "rate", "occupancy": "occupancy", "dev": "devices",
     "vcache": "vcache", "spec": "spec", "shed": "shed_total",
     "state": "state_keys", "byz": "byz_quarantines", "res": "rss",
-    "life": "lifecycle", "devval": "devval_policy_width",
+    "life": "lifecycle",
     "inc": "inc_count",
 }
 
@@ -495,7 +466,6 @@ def render(rows: List[dict], spark_name: Optional[str] = None) -> str:
             _fmt_pair(r.get("collect")), _fmt_pair(r.get("dispatch")),
             _fmt_pair(r.get("gate")), _fmt_pair(r.get("commit")),
             _fmt_pct(r.get("occupancy")), _fmt_devices(r.get("devices")),
-            _fmt_devval(r),
             _fmt_pct(r.get("overlap")),
             _fmt_pct(r.get("vcache")), _fmt_pct(r.get("spec")),
             _fmt_state(r), _fmt_res(r),

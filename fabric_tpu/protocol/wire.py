@@ -111,11 +111,9 @@ class BlockView:
         (flags, n_tx, n_keys, n_reads, n_writes, arena) — see
         rwset_lanes() below.  Zero-copy like data_spans: no per-tx
         Python objects are built.  Extracted at the first access and
-        kept: the fused device program (committer/device_validate.py)
-        reads the tuple, and everything after the validator reads it as
-        a LaneTable through lane_table() — the ledger's MVCC walk, the
-        block store's txid index, the commit notifier and the
-        private-data coordinator."""
+        kept: its four readers take it as a LaneTable through
+        lane_table() — the ledger's MVCC walk, the block store's txid
+        index, the commit notifier and the private-data coordinator."""
         if self._lanes is None:
             self._lanes = (rwset_lanes(self.raw, self._spans),)
         return self._lanes[0]
@@ -245,19 +243,20 @@ def parse_block_py(raw: _Raw):
 
 
 # ---------------------------------------------------------------------------
-# rw-set validation lanes (device-resident block validation)
+# rw-set validation lanes
 #
 # rwset_lanes(base, spans) classifies every envelope span against the
 # exact semantics of ledger/mvcc.parse_endorser_tx and emits fixed-width
-# uint64 lane tables for the fused XLA gate+MVCC program
-# (committer/device_validate.py).  Statuses:
+# uint64 lane tables for the lane table's four readers (LaneTable below:
+# the ledger's MVCC walk, the block store's txid index, the commit
+# notifier, the private-data coordinator).  Statuses:
 #
 #   0 OK       strict endorser tx, lanes emitted
 #   1 SKIP     parse_endorser_tx provably returns None
 #   2 BAD      parse_endorser_tx provably raises (oracle stamps
 #              BAD_RWSET on a gate-valid tx)
 #   3 RANGE    endorser tx with a non-empty range_queries list
-#   4 UNKNOWN  host outcome deterministic but device-inexpressible
+#   4 UNKNOWN  host outcome deterministic but no lane can say it
 #
 # Result tuple (flags, n_tx, n_keys, n_reads, n_writes, arena):
 #   flags  0 ok | 1 key-hash collision (arena is None; caller demotes)
@@ -409,8 +408,9 @@ def envelope_summary_py(raw: _Raw) -> Optional[Tuple[str, str, str]]:
 # Line-for-line mirror of the C lane extractor (native/fastparse.c
 # py_rwset_lanes and its walk_* helpers).  Every status decision and
 # every emitted cell must match the native output byte-for-byte
-# (tests/test_device_validate.py drives them differentially); it is
-# also the no-compiler fallback wired through rwset_lanes() above.
+# (tests/test_fastparse.py and tests/test_commit_lanes.py drive them
+# differentially); it is also the no-compiler fallback wired through
+# rwset_lanes() above.
 
 _M64 = (1 << 64) - 1
 

@@ -47,17 +47,8 @@ python tests/smoke_window.py
 echo "== sharded mesh window probe (8 virtual devices, divergence gate) =="
 python tests/smoke_mesh.py
 
-echo "== parallel commit probe (wavefront vs serial oracle, two-stack gate) =="
-python tests/smoke_parallel_commit.py
-
-echo "== cross-block wavefront probe (windowed pipeline vs serial, overlap gate) =="
-python tests/smoke_wavefront.py
-
 echo "== overload probe (open-loop 2x saturation, admission shed + recovery) =="
 python tests/smoke_overload.py
-
-echo "== device validation probe (fused gate+MVCC vs host oracle, two-stack gate) =="
-python tests/smoke_device_validate.py
 
 echo "== snapshot rejoin drill (wiped peer, faulted transfer, tail-bounded) =="
 python tests/smoke_snapshot.py

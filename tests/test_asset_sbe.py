@@ -584,12 +584,10 @@ def test_the_commit_decides_not_the_rwset(world, sw_provider, source, says):
     assert tails()[("deep", "no_sbe")] - before[("deep", "no_sbe")] == 1
 
 
-def test_prepared_from_lanes_drops_the_parameter_as_the_walk_does(
-        world, sw_provider):
-    """The third supplier of a commit's batch — the fused device path's
-    replay of the write lanes under final flags — against the serial
-    walk over both sources, on a block that deletes a key with a
-    parameter, one without, and a key whose delete loses MVCC."""
+def test_the_three_walks_drop_the_parameter_alike(world, sw_provider):
+    """The contract's own deletes through the walk's three forms, on a
+    block that deletes a key with a parameter, one without, and a key
+    whose delete loses MVCC."""
     reg_model = model.Registry(ORGS)
     committer, _ = run_blocks(world, sw_provider, "lanes", reg_model, [
         [CREATE_A, ("CreateAsset", ["b", "1", "client@Org2"], 1, ORGS),
@@ -625,12 +623,6 @@ def test_prepared_from_lanes_drops_the_parameter_as_the_walk_does(
     assert staged[(META, "a")] is None and staged[(META, "c")] is None
     assert (META, "b") not in staged        # its delete lost MVCC
     assert all(ns == CC for _, _, ns, _, _, _ in history)
-    table = mvcc.lane_source_of(wire.parse_block(raw),
-                                TxFlags.from_bytes(bytes(final)))[0]
-    batch, rows = mvcc.prepared_from_lanes(db, table,
-                                           TxFlags.from_bytes(bytes(final)),
-                                           1)
-    assert (_batch(batch), rows) == (staged, history)
 
 
 def _batch(batch) -> dict:

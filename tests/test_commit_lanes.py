@@ -4,11 +4,16 @@ decoded again, on the same bytes — flags, UpdateBatch order, history
 rows, commit hash and MvccTally equal — the rule that picks one
 (`mvcc.lane_source_of`) and each of its demotions, and the three readers
 that take their txids from the same table: the block store's index, the
-commit notifier and the private-data coordinator.
+commit notifier and the private-data coordinator.  The adversarial
+corpora and the seeded generators that PR 8, 11 and 17 wrote against the
+wave scheduler, the commit window and the fused device validator (gone
+in PR 44) are inputs here: every one of them through the walk's three
+forms.
 """
 import collections
 import contextlib
 import os
+import random
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
@@ -35,6 +40,8 @@ from fabric_tpu.utils import serde
 
 V = int(ValidationCode.VALID)
 MVCC = int(ValidationCode.MVCC_READ_CONFLICT)
+PHANTOM = int(ValidationCode.PHANTOM_READ_CONFLICT)
+BADSIG = int(ValidationCode.BAD_CREATOR_SIGNATURE)
 POLICY = int(ValidationCode.ENDORSEMENT_POLICY_FAILURE)
 BADRW = int(ValidationCode.BAD_RWSET)
 GENESIS = b"\x00" * 32
@@ -133,6 +140,11 @@ WALKS = {"arrays": {"source": "lanes", "walk": "arrays"},
                        "reason": "no_view"}}
 
 
+def walking_as(walk):
+    """The seam closed for the form "python", left alone for the others."""
+    return the_python_walk() if walk == "python" else contextlib.nullcontext()
+
+
 def walked(db, number, source, gate, python=False):
     """One walk of `source` over `db`: everything it gives back."""
     flags, tally = TxFlags.from_bytes(gate), mvcc.MvccTally()
@@ -150,28 +162,53 @@ def walked(db, number, source, gate, python=False):
             "namespaces": batch._namespaces}
 
 
+def spans_of(reason=None):
+    """What `ledger.mvcc` says on each of the three ledgers for a block
+    the lane source takes, or one it refuses for `reason`: then the two
+    fed views decode the envelopes again, as the one fed plain blocks
+    always does."""
+    if reason is None:
+        return WALKS
+    refused = {"source": "envelopes", "walk": "python", "reason": reason}
+    return {"arrays": refused, "python": refused,
+            "envelopes": WALKS["envelopes"]}
+
+
 def through_three_walks(stream, config=LedgerConfig):
-    """Feed `stream` — [(envelopes, gate codes | None)] — to three
-    ledgers: two get BlockViews (the lane source: one walked as arrays,
-    one by the Python walk, forced through the rule's seam), one plain
-    Blocks (the envelope source).  Before each commit the three walk the
-    same bytes over the same state and every output is compared; after
-    it, the ledgers.  -> (final codes per block, tally per block)."""
+    """Feed `stream` — [(envelopes, gate codes | None[, reason])] — to
+    three ledgers: two get BlockViews (the lane source: one walked as
+    arrays, one by the Python walk, forced through the rule's seam), one
+    plain Blocks (the envelope source).  Before each commit the three
+    walk the same bytes over the same state and every output is
+    compared; after it, the ledgers.  A block that comes with a `reason`
+    is one `lane_source_of` must refuse for it (a still-VALID range
+    query: "range"): there the views' envelopes are walked against the
+    plain block's, and the span says why.
+    -> (final codes per block, tally per block)."""
     ledgers = {name: KVLedger("ch", config()) for name in WALKS}
     db = ledgers["arrays"].statedb
     prev, codes, tallies = GENESIS, [], []
-    for number, (envelopes, gate) in enumerate(stream):
+    for number, (envelopes, gate, *reason) in enumerate(stream):
+        reason = reason[0] if reason else None
         raw, nxt = raw_block(number, prev, envelopes)
         gate = bytes(gate if gate is not None else [V] * len(envelopes))
         view = view_of(raw, gate)
-        table, reason = mvcc.lane_source_of(view, TxFlags.from_bytes(gate))
-        assert reason is None and isinstance(table, wire.LaneTable)
-        got = {"arrays": walked(db, number, table, gate),
-               "python": walked(db, number, table, gate, python=True),
-               "envelopes": walked(db, number,
+        table, why = mvcc.lane_source_of(view, TxFlags.from_bytes(gate))
+        assert why == reason and (table is None) == (reason is not None)
+        got = {"envelopes": walked(db, number,
                                    _safe_envelopes(plain_of(raw)), gate)}
-        assert [got[w].pop("walk") for w in WALKS] == [
-            ("arrays", None), ("python", "no_native"), ("python", None)]
+        if table is None:
+            got["arrays"] = walked(db, number, _safe_envelopes(view), gate)
+            got["python"] = walked(db, number,
+                                   _safe_envelopes(view_of(raw)), gate,
+                                   python=True)
+            forms = [("python", None)] * 3
+        else:
+            got["arrays"] = walked(db, number, table, gate)
+            got["python"] = walked(db, number, table, gate, python=True)
+            forms = [("arrays", None), ("python", "no_native"),
+                     ("python", None)]
+        assert [got[w].pop("walk") for w in WALKS] == forms
         assert got["arrays"] == got["envelopes"]     # batch: in order
         assert got["python"] == got["envelopes"]
         final, tally = got["arrays"]["flags"], got["arrays"]["tally"]
@@ -181,12 +218,13 @@ def through_three_walks(stream, config=LedgerConfig):
         ledgers["arrays"].commit(view)
         # no envelope list was built, unless the block store's index
         # had a tx to read for which the table does not speak
-        assert (view._data is None) == all(
-            st == wire.LANE_OK for st in table.status.tolist())
+        if table is not None:
+            assert (view._data is None) == all(
+                st == wire.LANE_OK for st in table.status.tolist())
         with the_python_walk():
             ledgers["python"].commit(view_of(raw, gate))
         ledgers["envelopes"].commit(plain_of(raw, gate))
-        for walk, attrs in WALKS.items():
+        for walk, attrs in spans_of(reason).items():
             assert mvcc_span(ledgers[walk]) == attrs
             assert ledgers[walk].commit_hash == ledgers["arrays"].commit_hash
         assert bytes(view.metadata.items[META_TXFLAGS]) == final
@@ -223,7 +261,8 @@ def case_bump_repeats(ids):
 
 def case_deletes(ids):
     """A delete in one block and stale / absent reads after it; a delete
-    inside a block and reads of that key after it in the same block."""
+    inside a block and reads of that key after it in the same block, the
+    last one at the version the block itself put it back at."""
     b1 = [tx(ids, rw(writes=[KVWrite("k01", b"", True)]))]
     b2 = [
         tx(ids, rw(reads=[KVRead("k01", Version(0, 1))])),     # stale
@@ -235,10 +274,11 @@ def case_deletes(ids):
         tx(ids, rw(reads=[KVRead("k02", None)],                # absent now
                    writes=[KVWrite("k02", b"again")])),
         tx(ids, rw(reads=[KVRead("k02", None)])),              # written: block
+        tx(ids, rw(reads=[KVRead("k02", Version(2, 4))])),     # the re-put
     ]
     stream = [(seed(ids), None), (b1, None), (b2, None)]
-    want = [[V] * 8, [V], [MVCC, V, V, MVCC, V, MVCC]]
-    return stream, want, [(0, 0, 0), (0, 0, 0), (6, 2, 1)]
+    want = [[V] * 8, [V], [MVCC, V, V, MVCC, V, MVCC, V]]
+    return stream, want, [(0, 0, 0), (0, 0, 0), (7, 2, 1)]
 
 
 def case_absent_keys(ids):
@@ -262,19 +302,21 @@ def case_absent_keys(ids):
 
 def case_garbage_bad_and_config(ids):
     """A gate-invalid tx whose rw-set is garbage stays as the gate left
-    it; the same bytes gate-valid are BAD_RWSET; a config tx among
-    endorser txs is skipped; an endorser tx without actions too."""
+    it; the same bytes gate-valid are BAD_RWSET, and so is an envelope
+    whose payload is no payload at all; a config tx among endorser txs
+    is skipped; an endorser tx without actions too."""
     creator, _ = ids
     junk = build.signed_envelope(TX_ENDORSER, "ch", {"not": "a tx"}, creator)
+    bomb = Envelope(b"\xde\xad\xbe\xef", b"")
     config = build.signed_envelope(TX_CONFIG, "ch", {"config": 1}, creator)
     empty = build.signed_envelope(TX_ENDORSER, "ch", {"actions": []},
                                   creator)
     good = [tx(ids, rw(reads=[KVRead("k07", Version(0, 7))],
                        writes=[KVWrite("k07", b"g")])),
             tx(ids, rw(reads=[KVRead("k07", Version(0, 7))]))]
-    b1 = [junk, good[0], junk, config, empty, good[1]]
-    stream = [(seed(ids), None), (b1, [POLICY, V, V, V, V, V])]
-    want = [[V] * 8, [POLICY, V, BADRW, V, V, MVCC]]
+    b1 = [junk, good[0], junk, config, empty, good[1], bomb]
+    stream = [(seed(ids), None), (b1, [POLICY, V, V, V, V, V, V])]
+    want = [[V] * 8, [POLICY, V, BADRW, V, V, MVCC, BADRW]]
     return stream, want, [(0, 0, 0), (2, 1, 0)]
 
 
@@ -401,12 +443,146 @@ def case_parameters_dropped(ids):
     return stream, want, [(0, 0, 0), (0, 0, 0), (4, 1, 1), (3, 0, 0)]
 
 
+def case_chain_reads_the_blocks_own_puts(ids):
+    """A write-write chain on one key whose later links read the
+    versions the block itself staged: the winner's put holds, a loser's
+    would-be version never existed."""
+    b1 = [tx(ids, rw(reads=[KVRead("k00", Version(0, 0))],
+                     writes=[KVWrite("k00", b"a")])),
+          tx(ids, rw(reads=[KVRead("k00", Version(0, 0))],    # tx0 won
+                     writes=[KVWrite("k00", b"b")])),
+          tx(ids, rw(reads=[KVRead("k00", Version(1, 0))],    # tx0's put
+                     writes=[KVWrite("k00", b"c")])),
+          tx(ids, rw(reads=[KVRead("k00", Version(1, 2))])),  # tx2's put
+          tx(ids, rw(reads=[KVRead("k00", Version(1, 1))]))]  # tx1 lost
+    stream = [(seed(ids), None), (b1, None)]
+    return stream, [[V] * 8, [V, MVCC, V, V, MVCC]], [(0, 0, 0), (5, 2, 0)]
+
+
+def held(i, block=0):
+    return KVRead(f"k{i:02d}", Version(block, i))
+
+
+def case_range_phantoms(ids):
+    """Phantoms made and unmade by the block's own writes, under both
+    `itr_exhausted`: the block is the envelope source's ("range"), and
+    the next one, which reads what the phantoms' writes would have left,
+    is the lane source's again."""
+    full = RangeQueryInfo("k05", "k08", True, (held(5), held(6), held(7)))
+    b1 = [tx(ids, rw(ranges=[full], writes=[KVWrite("z0", b"1")])),
+          tx(ids, rw(writes=[KVWrite("k06", b"new")])),       # inside
+          tx(ids, rw(ranges=[full], writes=[KVWrite("z1", b"1")])),
+          tx(ids, rw(writes=[KVWrite("k09", b"x")])),         # outside
+          tx(ids, rw(ranges=[RangeQueryInfo("k10", "k12", True,
+                                            (held(10), held(11)))],
+                     writes=[KVWrite("z2", b"1")])),
+          tx(ids, rw(ranges=[RangeQueryInfo("k05", "k08", False,
+                                            (held(5), held(6)))])),
+          tx(ids, rw(writes=[KVWrite("k05", b"", True)])),    # the start key
+          tx(ids, rw(ranges=[RangeQueryInfo("k10", "k12", False,
+                                            (held(10),))],
+                     writes=[KVWrite("z3", b"1")]))]          # a prefix: ok
+    b2 = [tx(ids, rw(reads=[KVRead("z1", None),               # never landed
+                            KVRead("z0", Version(1, 0)),
+                            KVRead("k05", None)]))]
+    stream = [(seed(ids, 13), None), (b1, None, "range"), (b2, None)]
+    want = [[V] * 13, [V, V, PHANTOM, V, V, PHANTOM, V, V], [V]]
+    return stream, want, [(0, 0, 0), (0, 0, 0), (3, 0, 0)]
+
+
+def case_all_conflict_then_none(ids):
+    """Every tx of a block reads a version nobody wrote: an empty batch,
+    no history row.  Then every tx of a block holds, on keys of its own."""
+    b1 = [tx(ids, rw(reads=[KVRead(f"k{i:02d}", Version(9, 9))],
+                     writes=[KVWrite(f"k{i:02d}", b"x")])) for i in range(8)]
+    b2 = [tx(ids, rw(reads=[held(i)], writes=[KVWrite(f"n{i}", b"y")]))
+          for i in range(8)]
+    stream = [(seed(ids), None), (b1, None), (b2, None)]
+    return (stream, [[V] * 8, [MVCC] * 8, [V] * 8],
+            [(0, 0, 0), (8, 0, 8), (8, 0, 0)])
+
+
+def case_gate_losers_write_nothing(ids):
+    """Txs the signature gate or the policy failed, with rw-sets that
+    would have won MVCC: their writes never land, so the tx after them
+    conflicts with the winner only, and a read of what the loser wrote
+    still holds."""
+    b1 = [tx(ids, rw(reads=[held(0)], writes=[KVWrite("k00", b"a")])),
+          tx(ids, rw(reads=[held(0)], writes=[KVWrite("k00", b"b")])),
+          tx(ids, rw(writes=[KVWrite("k01", b"c")])),
+          tx(ids, rw(reads=[held(0)], writes=[KVWrite("k00", b"d")])),
+          tx(ids, rw(reads=[held(1)]))]
+    stream = [(seed(ids), None), (b1, [V, POLICY, BADSIG, V, V])]
+    return (stream, [[V] * 8, [V, POLICY, BADSIG, MVCC, V]],
+            [(0, 0, 0), (3, 1, 0)])
+
+
+def case_adjacent_block_chains(ids):
+    """Chains across adjacent blocks: a block reads and rewrites what the
+    one before it wrote (write-read), overwrites it blind (write-write),
+    and the block after reads both the stale and the fresh version
+    (read-write), the fresh one once more after a tx of its own block
+    took it."""
+    b1 = [tx(ids, rw(writes=[KVWrite(f"k{i:02d}", b"v1")])) for i in range(4)]
+    b2 = [tx(ids, rw(reads=[held(0, 1)], writes=[KVWrite("k00", b"w1")])),
+          tx(ids, rw(writes=[KVWrite("k01", b"blind")])),
+          tx(ids, rw(writes=[KVWrite("z0", b"z")]))]
+    b3 = [tx(ids, rw(reads=[held(0, 1)],                      # stale
+                     writes=[KVWrite("lost0", b"never")])),
+          tx(ids, rw(reads=[KVRead("k00", Version(2, 0))],
+                     writes=[KVWrite("k00", b"w2")])),        # fresh
+          tx(ids, rw(reads=[KVRead("k00", Version(2, 0))])),  # taken: block
+          tx(ids, rw(reads=[held(1, 1)],                      # overwritten
+                     writes=[KVWrite("lost3", b"never")]))]
+    stream = [(seed(ids), None), (b1, None), (b2, None), (b3, None)]
+    want = [[V] * 8, [V] * 4, [V] * 3, [MVCC, V, MVCC, MVCC]]
+    return stream, want, [(0, 0, 0), (0, 0, 0), (1, 0, 0), (4, 1, 2)]
+
+
+def case_cross_block_range_phantom(ids):
+    """A key written into an interval by one block is a phantom to the
+    next block's scan of it, and part of the result for the scan after."""
+    b1 = [tx(ids, rw(writes=[KVWrite("k025", b"phantom")]))]
+    b2 = [tx(ids, rw(ranges=[RangeQueryInfo("k02", "k05", True,
+                                            (held(2), held(3), held(4)))],
+                     writes=[KVWrite("z1", b"s")])),
+          tx(ids, rw(writes=[KVWrite("z2", b"i")]))]
+    b3 = [tx(ids, rw(ranges=[RangeQueryInfo(
+              "k02", "k05", True,
+              (held(2), KVRead("k025", Version(1, 0)), held(3), held(4)))],
+              writes=[KVWrite("z3", b"s")])),
+          tx(ids, rw(reads=[KVRead("z1", None)]))]            # never landed
+    stream = [(seed(ids), None), (b1, None), (b2, None, "range"),
+              (b3, None, "range")]
+    want = [[V] * 8, [V], [PHANTOM, V], [V, V]]
+    return stream, want, [(0, 0, 0)] * 3 + [(1, 0, 0)]
+
+
+def case_doomed_then_rewritten(ids):
+    """A tx that loses MVCC leaves none of its writes: the next block
+    reads its key as absent, and the version it would have had as
+    stale; the key the winner rewrote is read at the winner's."""
+    b1 = [tx(ids, rw(reads=[KVRead("k00", Version(9, 9))],
+                     writes=[KVWrite("k50", b"never")])),
+          tx(ids, rw(reads=[held(1)], writes=[KVWrite("k01", b"won")]))]
+    b2 = [tx(ids, rw(reads=[KVRead("k50", None)],
+                     writes=[KVWrite("z3", b"ok")])),
+          tx(ids, rw(reads=[KVRead("k01", Version(1, 1))])),
+          tx(ids, rw(reads=[KVRead("k50", Version(1, 0))]))]
+    stream = [(seed(ids), None), (b1, None), (b2, None)]
+    return (stream, [[V] * 8, [MVCC, V], [V, V, MVCC]],
+            [(0, 0, 0), (2, 0, 1), (3, 0, 1)])
+
+
 @pytest.mark.parametrize("case", [
     case_bump_repeats, case_deletes, case_absent_keys,
     case_garbage_bad_and_config, case_smallbank_chains,
     case_thrice_written, case_nil_after_a_staged_delete,
     case_bad_between_valid, case_valid_txs_write_nothing, case_one_tx,
-    case_parameters_dropped],
+    case_parameters_dropped, case_chain_reads_the_blocks_own_puts,
+    case_range_phantoms, case_all_conflict_then_none,
+    case_gate_losers_write_nothing, case_adjacent_block_chains,
+    case_cross_block_range_phantom, case_doomed_then_rewritten],
     ids=lambda c: c.__name__[5:])
 def test_the_three_walks_give_the_same_answers(ids, case):
     stream, want_codes, want_tallies = case(ids)
@@ -414,6 +590,157 @@ def test_the_three_walks_give_the_same_answers(ids, case):
     assert codes == want_codes
     if want_tallies is not None:
         assert tallies == want_tallies
+
+
+# -- the seeded generators ----------------------------------------------------
+#
+# Written against the wave scheduler (PR 8), the commit window (PR 11) and
+# the fused device validator (PR 17); generator and seeds as they were,
+# one case a seed, through the walk's three forms.
+
+
+def fuzz_stream(ids, opening, blocks, gate_ranges):
+    """`blocks` — the rw-sets of each — after the `opening` blocks, as a
+    stream for `through_three_walks`.  A block in which a range query is
+    still VALID is the envelope source's ("range"); with `gate_ranges`
+    every tx that carries one failed its policy, and the same reads,
+    writes and deletes are the lane source's."""
+    stream = [([tx(ids, r) for r in rwsets], None) for rwsets in opening]
+    for rwsets in blocks:
+        ranged = [any(n.range_queries for n in r.ns_rwsets) for r in rwsets]
+        gate = [POLICY if gate_ranges and r else V for r in ranged]
+        reason = "range" if any(ranged) and not gate_ranges else None
+        stream.append(([tx(ids, r) for r in rwsets], gate, reason))
+    return stream
+
+
+def through_three_walks_with_and_without_ranges(ids, opening, blocks):
+    ranged = any(n.range_queries for rwsets in blocks for r in rwsets
+                 for n in r.ns_rwsets)
+    for gate_ranges in (False, True) if ranged else (False,):
+        through_three_walks(fuzz_stream(ids, opening, blocks, gate_ranges))
+
+
+def twenty_keys_at_block_one():
+    """Blocks 0 and 1: k00..k19 = b"v<i>" at Version(1, i)."""
+    return [[rw(writes=[KVWrite("opened", b"")])],
+            [rw(writes=[KVWrite(f"k{i:02d}", b"v%d" % i)])
+             for i in range(20)]]
+
+
+def random_rwsets(rng, keys, n_txs, version_of, range_stop):
+    """One block of the PR 8 / PR 11 generator: stale, fresh and nil
+    reads, puts, deletes, and a range query in three txs of ten."""
+    rwsets = []
+    for _t in range(n_txs):
+        reads, writes, ranges = [], [], []
+        for _ in range(rng.randrange(0, 3)):
+            k = rng.choice(keys)
+            reads.append(KVRead(k, rng.choice(
+                [version_of(int(k[1:])), Version(7, 7), None])))
+        for _ in range(rng.randrange(0, 3)):
+            k = rng.choice(keys)
+            if rng.random() < 0.25:
+                writes.append(KVWrite(k, b"", True))
+            else:
+                writes.append(KVWrite(k, rng.randbytes(4)))
+        if rng.random() < 0.3:
+            lo, hi = sorted(rng.sample(range(12), 2))
+            recs = tuple(KVRead(f"k{i:02d}", version_of(i))
+                         for i in range(lo, min(hi, range_stop)))
+            ranges.append(RangeQueryInfo(f"k{lo:02d}", f"k{hi:02d}",
+                                         rng.random() < 0.5, recs))
+        rwsets.append(rw(reads=reads, writes=writes, ranges=ranges))
+    return rwsets
+
+
+@pytest.mark.parametrize("rng_seed", range(25))
+def test_a_seeded_random_block_through_the_three_walks(ids, rng_seed):
+    """`test_differential_fuzz_random_blocks` of PR 8: one block of 1-9
+    txs over twelve of twenty committed keys."""
+    rng = random.Random(rng_seed)
+    keys = [f"k{i:02d}" for i in range(12)]
+    block = random_rwsets(rng, keys, rng.randrange(1, 10),
+                          lambda i: Version(1, i), 12)
+    through_three_walks_with_and_without_ranges(
+        ids, twenty_keys_at_block_one(), [block])
+
+
+@pytest.mark.parametrize("rng_seed", range(1000, 1025))
+def test_a_seeded_random_stream_through_the_three_walks(ids, rng_seed):
+    """`test_window_differential_fuzz_25_seeds` of PR 11: eight of twelve
+    keys opened in block 0, then 2-4 blocks of 1-5 txs, each over the
+    state the ones before it left."""
+    rng = random.Random(rng_seed)
+    keys = [f"k{i:02d}" for i in range(12)]
+    opening = [[rw(writes=[KVWrite(k, b"s%d" % i)])
+                for i, k in enumerate(keys[:8])]]
+    blocks = [random_rwsets(rng, keys, rng.randrange(1, 6),
+                            lambda i: Version(0, i), 8)
+              for _b in range(rng.randrange(2, 5))]
+    through_three_walks_with_and_without_ranges(ids, opening, blocks)
+
+
+@pytest.mark.parametrize("rng_seed", range(7000, 7010))
+def test_a_seeded_stream_of_blind_rewrites_keeps_its_batch_order(ids,
+                                                                 rng_seed):
+    """`test_window_level_batch_insertion_order_fuzz` of PR 11: three
+    blocks of 1-4 txs, up to two writes a tx on ten keys that repeat, so
+    a key's first position and last value are what the batch must keep
+    (`walked` compares the batches item by item, in order)."""
+    rng = random.Random(rng_seed)
+    keys = [f"k{i:02d}" for i in range(10)]
+    blocks = []
+    for _b in range(3):
+        rwsets = []
+        for _t in range(rng.randrange(1, 5)):
+            reads = [KVRead(rng.choice(keys),
+                            rng.choice([Version(1, 3), None]))
+                     for _ in range(rng.randrange(0, 2))]
+            writes = [KVWrite(rng.choice(keys), rng.randbytes(3))
+                      for _ in range(rng.randrange(0, 3))]
+            rwsets.append(rw(reads=reads, writes=writes))
+        blocks.append(rwsets)
+    through_three_walks(
+        fuzz_stream(ids, twenty_keys_at_block_one(), blocks, False))
+
+
+@pytest.mark.parametrize("rng_seed", [0xFAB11])
+def test_seeded_blocks_of_eight_through_the_three_walks(ids, rng_seed):
+    """`test_seeded_random_blocks` of PR 17: 3 blocks x 8 txs, up to
+    three reads (the version block 0 left, a random one, none) and two
+    writes or deletes a tx over eight keys.  The reads stay those of
+    block 0 as the state drifts under them."""
+    rng = random.Random(rng_seed)
+    keys = [f"k{i:02d}" for i in range(8)]
+    committed = {k: Version(0, i) for i, k in enumerate(keys)}
+    blocks = []
+    for blk in (1, 2, 3):
+        rwsets = []
+        for _tx in range(8):
+            reads, writes = [], []
+            for k in rng.sample(keys, rng.randint(0, 3)):
+                choice = rng.random()
+                if choice < 0.5:
+                    ver = committed.get(k)
+                elif choice < 0.75:
+                    ver = Version(rng.randint(0, 3), rng.randint(0, 7))
+                else:
+                    ver = None
+                reads.append(KVRead(k, ver))
+            for k in rng.sample(keys, rng.randint(0, 2)):
+                if rng.random() < 0.25:
+                    writes.append(KVWrite(k, b"", True))
+                else:
+                    writes.append(KVWrite(k, bytes([blk, rng.randint(0, 9)])))
+            rwsets.append(rw(reads=reads, writes=writes))
+        blocks.append(rwsets)
+    opening = [[rw(writes=[KVWrite(k, b"v0")]) for k in keys]]
+    codes, tallies = through_three_walks(
+        fuzz_stream(ids, opening, blocks, False))
+    flat = [c for block in codes[1:] for c in block]
+    assert flat.count(MVCC) > 5 and flat.count(V) > 5
+    assert sum(t[1] for t in tallies) > 0 < sum(t[2] for t in tallies)
 
 
 def test_the_batch_keeps_a_keys_first_position_and_last_value(ids):
@@ -467,9 +794,11 @@ def test_the_three_walks_agree_at_other_stripe_widths(ids, shards):
         assert codes == want_codes
 
 
-@pytest.mark.parametrize("case", [case_bump_repeats,
-                                  case_parameters_dropped],
-                         ids=lambda c: c.__name__[5:])
+@pytest.mark.parametrize("case", [
+    case_bump_repeats, case_parameters_dropped, case_range_phantoms,
+    case_gate_losers_write_nothing, case_adjacent_block_chains,
+    case_cross_block_range_phantom, case_doomed_then_rewritten],
+    ids=lambda c: c.__name__[5:])
 def test_three_ledgers_one_stream_end_at_the_same_bytes(ids, tmp_path, case):
     """The durable three: ledgers on disk, fed views walked as arrays,
     views walked in Python and plain blocks, write the same state and
@@ -480,14 +809,14 @@ def test_three_ledgers_one_stream_end_at_the_same_bytes(ids, tmp_path, case):
     ledgers = {walk: KVLedger("ch", LedgerConfig(root=roots[walk]))
                for walk in WALKS}
     prev = GENESIS
-    for number, (envelopes, _gate) in enumerate(stream):
+    for number, (envelopes, gate, *reason) in enumerate(stream):
         raw, prev = raw_block(number, prev, envelopes)
-        gate = [V] * len(envelopes)
+        gate = gate or [V] * len(envelopes)
         ledgers["arrays"].commit(view_of(raw, gate))
         with the_python_walk():
             ledgers["python"].commit(view_of(raw, gate))
         ledgers["envelopes"].commit(plain_of(raw, gate))
-    assert {w: mvcc_span(ledgers[w]) for w in WALKS} == WALKS
+        assert {w: mvcc_span(ledgers[w]) for w in WALKS} == spans_of(*reason)
     for db, wal in (("statedb", "state.wal"), ("historydb", "history.wal")):
         written = {}
         for w in WALKS:
@@ -507,6 +836,56 @@ def test_three_ledgers_one_stream_end_at_the_same_bytes(ids, tmp_path, case):
     assert [list(reopened["arrays"].blockstore.get_by_number(n)
                  .metadata.items[META_TXFLAGS])
             for n in range(len(stream))] == want
+
+
+@pytest.mark.parametrize("torn", [1, 2, 3])
+def test_a_crash_between_state_and_history_replays_the_block_once(
+        ids, tmp_path, torn):
+    """The ledger fed views, killed after block `torn`'s state commit
+    and before its history commit, over the adjacent-block chains: the
+    reopened ledger replays that block's history from the flags the
+    array walk stored — the rows of its VALID txs, none of the ones that
+    lost MVCC, none twice — and the chain goes on over it."""
+    stream, want, _ = case_adjacent_block_chains(ids)
+    root = str(tmp_path / "torn")
+    ledger, whole = KVLedger("ch", LedgerConfig(root=root)), KVLedger("ch")
+    raws, prev = [], GENESIS
+    for number, (envelopes, _gate) in enumerate(stream):
+        raw, prev = raw_block(number, prev, envelopes)
+        raws.append(raw)
+
+    def feed(ledger, numbers):
+        for number in numbers:
+            ledger.commit(view_of(raws[number], [V] * len(stream[number][0])))
+            assert mvcc_span(ledger) == WALKS["arrays"]
+
+    feed(whole, range(len(raws)))
+    feed(ledger, range(torn))
+
+    def die(number, rows):
+        raise RuntimeError("kill -9 (injected before the history commit)")
+
+    ledger.historydb.commit = die
+    with pytest.raises(RuntimeError, match="before the history commit"):
+        feed(ledger, [torn])
+    assert ledger.statedb.savepoint == torn
+    assert ledger.historydb.savepoint == torn - 1
+
+    reopened = KVLedger("ch", LedgerConfig(root=root))
+    assert reopened.last_recovery == {"replayed_blocks": 1, "start": torn,
+                                      "height": torn + 1}
+    assert reopened.historydb.savepoint == torn
+    feed(reopened, range(torn + 1, len(raws)))
+    assert reopened.commit_hash == whole.commit_hash
+    assert state_of(reopened) == state_of(whole)
+    assert history_of(reopened) == history_of(whole)
+    assert not {("cc", "lost0"), ("cc", "lost3")} & set(history_of(reopened))
+    assert [list(reopened.blockstore.get_by_number(n)
+                 .metadata.items[META_TXFLAGS])
+            for n in range(len(raws))] == want
+    # and a second reopening finds nothing left to replay
+    assert KVLedger("ch", LedgerConfig(root=root)).last_recovery[
+        "replayed_blocks"] == 0
 
 
 # -- the rule and its demotions -----------------------------------------------

@@ -444,8 +444,8 @@ def lane_fuzz_corpus(seed, org1=None, org2=None, envs=None):
 
 def test_rwset_lanes_native_matches_mirror():
     """Full-tuple bit identity: accept/reject/collision decision, lane
-    counts, and every arena byte (the device validator consumes these
-    lanes verbatim — tests/test_device_validate.py gates end-to-end)."""
+    counts, and every arena byte (the commit path's walk consumes these
+    lanes verbatim — tests/test_commit_lanes.py gates end-to-end)."""
     org1, org2 = _org_world()
     envs = _lane_envs(org1, org2)
     n_accept = n_collide = 0

@@ -73,3 +73,22 @@ def test_peer_listens_on_env_overridden_port(tmp_path, monkeypatch):
             conn.close()
     finally:
         peer.stop()
+
+
+@pytest.mark.parametrize("section,body", [
+    ("parallel_commit", {"enabled": True, "early_abort": True}),
+    ("device_validate", {"enabled": False})])
+def test_a_peer_refuses_to_start_with_a_removed_commit_section(
+        tmp_path, section, body):
+    """PR 44 took the wave scheduler, the commit window, early abort and
+    the fused device validator out.  A config that still carries their
+    section stops the peer before it touches anything, whatever the
+    section says, and the error names the key."""
+    from fabric_tpu.node.peer import main
+
+    p = tmp_path / "peer.json"
+    p.write_text(json.dumps({"data_dir": str(tmp_path / "data"),
+                             "mspid": "Org1MSP", section: body}))
+    with pytest.raises(ValueError, match=f"'{section}'.*removed in PR 44"):
+        main([str(p)])
+    assert not (tmp_path / "data").exists()

@@ -1,8 +1,8 @@
 """SmallBank, the second contract, against its plain model
 (`fabric_tpu/testing/smallbank_model.py`): what the endorser's simulate
 records, a seeded chain of hot-account blocks through the committer
-under both providers and through the three default-off commit paths, a
-short mix through gateway → orderers → peers, and the ledger's counters.
+under both providers and through the walk's three forms, a short mix
+through gateway → orderers → peers, and the ledger's counters.
 """
 
 import json
@@ -15,18 +15,18 @@ import pytest
 from fabric_tpu.bccsp.factory import FactoryOpts, init_factories
 from fabric_tpu.chaincode import (ChaincodeDefinition, ChaincodeRegistry,
                                   smallbank)
-from fabric_tpu.committer import (Committer, PipelinedCommitter,
-                                  PolicyRegistry, TxValidator)
+from fabric_tpu.committer import Committer, PolicyRegistry, TxValidator
 from fabric_tpu.endorser import Endorser, signed_proposal
 from fabric_tpu.ledger import KVLedger, LedgerConfig
 from fabric_tpu.msp import CachedMSP
 from fabric_tpu.msp.ca import DevOrg
 from fabric_tpu.ops_plane import registry
 from fabric_tpu.policy import parse_policy
-from fabric_tpu.protocol import wire
+from fabric_tpu.protocol import Block, wire
 from fabric_tpu.protocol.types import META_TXFLAGS, ChaincodeAction
 from fabric_tpu.testing import smallbank_model as model
 from fabric_tpu.utils import serde
+from test_commit_lanes import WALKS, walking_as
 
 CC = "smallbank"
 ACCOUNTS = 40
@@ -56,27 +56,12 @@ class World:
             raws.append(raw)
         return raws
 
-    def committer(self, provider, device_validate=False, early_abort=False,
-                  **ledger_cfg):
+    def committer(self, provider):
         policies = PolicyRegistry()
         policies.set_policy(CC, parse_policy(
             "AND('Org1.member', 'Org2.member')"))
-        ledger = KVLedger("ch", LedgerConfig(device_validate=device_validate,
-                                             **ledger_cfg))
-        dv = None
-        if device_validate:
-            from fabric_tpu.committer.device_validate import DeviceValidator
-            dv = DeviceValidator(ledger.statedb, "ch")
-            ledger.set_prepared_source(dv.take_prepared)
-        ea = None
-        if early_abort:
-            from fabric_tpu.committer.parallel_commit import (
-                EarlyAbortAnalyzer)
-            ea = EarlyAbortAnalyzer(ledger.statedb, "ch",
-                                    overlay_source=ledger.pending_overlay)
-        return Committer(ledger, TxValidator(
-            "ch", self.msps, provider, policies, device_validate=dv,
-            early_abort=ea))
+        return Committer(KVLedger("ch", LedgerConfig()), TxValidator(
+            "ch", self.msps, provider, policies))
 
 
 @pytest.fixture(scope="module")
@@ -319,61 +304,37 @@ def test_chain_through_replay_equals_the_model(provisioned, tmp_path, bccsp):
         seen)
 
 
-@pytest.mark.parametrize("path", ["parallel_commit", "early_abort",
-                                  "commit_window", "device_validate"])
-def test_default_off_commit_paths_give_the_oracles_answers(
-        world, chain, sw_provider, path):
-    """The evidence ROADMAP D2 waits for: conflict chains over hot keys
-    through the wave scheduler (serial fallback off; alone and with the
-    early-abort analyzer before the signature gate), the cross-block
-    window and the fused device validation — the serial oracle's flags,
-    state and commit hash, which are the model's."""
+@pytest.mark.parametrize("form", list(WALKS))
+def test_chain_through_the_serial_walk_equals_the_model(world, chain,
+                                                        sw_provider, form):
+    """Conflict chains over hot keys — most of a block aborting, a tx
+    whose first writer was itself invalid going through — by each form
+    of the walk: the lane table as arrays, the lane table in Python, the
+    envelopes decoded again.  The same flags, balances and commit hash,
+    which are the model's; and the reads and conflicts counted alike."""
     plan, raws = chain
-    oracle = world.committer(sw_provider)
+    span = WALKS[form]
+    source, walk, reason = (span["source"], span["walk"],
+                            span.get("reason", "none"))
+    committer = world.committer(sw_provider)
+    walked = registry.counter("ledger_mvcc_walk_total")
+    conflicts = registry.counter("ledger_mvcc_conflicts_total")
+    before = (walked.value(channel="ch", walk=walk, reason=reason),
+              conflicts.value(channel="ch", path="serial", against="block"))
     for raw in raws:
-        oracle.store_block(wire.parse_block(raw))
-    if path in ("parallel_commit", "early_abort"):
-        other = world.committer(sw_provider, parallel_commit=True,
-                                commit_serial_fallback=False,
-                                early_abort=path == "early_abort")
-    elif path == "commit_window":
-        other = world.committer(sw_provider, commit_window=4)
-    else:
-        other = world.committer(sw_provider, device_validate=True)
-    fused = registry.counter("validator_device_blocks_total")
-    fused_before = fused.total()
-    walked = registry.counter("ledger_mvcc_reads_total")
-    counted = registry.counter("ledger_tx_total")
-    walked_before, counted_before = walked.total(), counted.total()
-    if path == "commit_window":
-        pipe = PipelinedCommitter(other)
-        try:
-            for fut in [pipe.submit(wire.parse_block(raw)) for raw in raws]:
-                fut.result(timeout=120)
-        finally:
-            pipe.close()
-    else:
-        for raw in raws:
-            other.store_block(wire.parse_block(raw))
-    # the path under test did the work, not a fallback to the oracle
-    if path in ("parallel_commit", "early_abort"):
-        scheduler = other.ledger._commit_scheduler
-        assert scheduler.last_waves > 1 and scheduler.last_edges > 0
-    elif path == "commit_window":
-        stats = other.ledger._commit_window.stats()
-        assert stats["admitted"] == stats["retired"] == len(plan)
-        assert stats["early_txs"] + stats["deferred_txs"] > 0
-    else:
-        assert fused.total() - fused_before == len(plan)
-    # ... and says so: its blocks are counted, the serial walk's reads
-    # are not
-    assert (counted.total() - counted_before
-            == sum(len(b["codes"]) for b in plan))
-    assert walked.total() == walked_before
-    flags = [stored_flags(other.ledger, b["number"]) for b in plan]
-    assert flags == [stored_flags(oracle.ledger, b["number"]) for b in plan]
-    assert other.ledger.commit_hash == oracle.ledger.commit_hash
-    assert_equals_model(plan, flags, balances_of(other.ledger.get_state))
+        with walking_as(form):
+            committer.store_block(wire.parse_block(raw) if source == "lanes"
+                                  else Block.deserialize(raw))
+        assert committer.ledger.last_stats.span_attrs["ledger.mvcc"] == span
+    codes = [c for b in plan for c in b["codes"]]
+    assert (walked.value(channel="ch", walk=walk, reason=reason) - before[0]
+            == len(codes))
+    # every tx is simulated on the state before its block: what fails,
+    # fails against the block
+    assert (conflicts.value(channel="ch", path="serial", against="block")
+            - before[1] == codes.count(model.MVCC_CONFLICT) > 200)
+    flags = [stored_flags(committer.ledger, b["number"]) for b in plan]
+    assert_equals_model(plan, flags, balances_of(committer.ledger.get_state))
 
 
 # -- the counters ---------------------------------------------------------------

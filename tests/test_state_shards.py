@@ -229,8 +229,7 @@ def test_ledger_commit_chain_identical_across_shard_widths(tmp_path, org):
     ledgers = {}
     for n in SHARD_COUNTS:
         cfg = LedgerConfig(root=str(tmp_path / f"n{n}"), snapshot_every=3,
-                           state_shards=n,
-                           parallel_commit=(n == 4))  # mix the commit planes
+                           state_shards=n)
         ledgers[n] = KVLedger("ch", cfg)
         _commit_all(ledgers[n], env_blocks)
     ref = ledgers[1]

@@ -2,9 +2,9 @@
 (`fabric_tpu/testing/ycsb_model.py`): the model's key naming and zipfian
 against fixed vectors, what the endorser's simulate records, the
 program's `BlockCutter` under Fabric's default `BatchSize`, seeded chains
-of load + update blocks through the committer — from the lane table and
-from the envelopes, and through the three default-off commit paths —
-and the ledger's byte, fsync and checkpoint counters.
+of load + update blocks through the committer — the walk's three forms:
+the lane table as arrays, the lane table in Python, the envelopes — and
+the ledger's byte, fsync and checkpoint counters.
 """
 
 import importlib.util
@@ -16,8 +16,7 @@ import pytest
 from fabric_tpu.bccsp.factory import FactoryOpts, init_factories
 from fabric_tpu.chaincode import (ChaincodeDefinition, ChaincodeRegistry,
                                   kvstore)
-from fabric_tpu.committer import (Committer, PipelinedCommitter,
-                                  PolicyRegistry, TxValidator)
+from fabric_tpu.committer import Committer, PolicyRegistry, TxValidator
 from fabric_tpu.config import BatchConfig
 from fabric_tpu.endorser import Endorser, signed_proposal
 from fabric_tpu.ledger import KVLedger, LedgerConfig, mvcc
@@ -32,6 +31,7 @@ from fabric_tpu.protocol import (Block, Envelope, KVRead, KVWrite, NsRwSet,
 from fabric_tpu.protocol.types import META_TXFLAGS, ChaincodeAction
 from fabric_tpu.testing import ycsb_model as model
 from fabric_tpu.utils import serde
+from test_commit_lanes import WALKS, walking_as
 
 CC = "kvstore"
 POLICY = "AND('Org1.member', 'Org2.member', 'Org3.member')"
@@ -59,26 +59,11 @@ class World:
         self.creators = [self.orgs[i % 3].new_identity(f"client{i}")
                          for i in range(CREATORS)]
 
-    def committer(self, provider, device_validate=False, early_abort=False,
-                  **ledger_cfg):
+    def committer(self, provider):
         policies = PolicyRegistry()
         policies.set_policy(CC, parse_policy(POLICY))
-        ledger = KVLedger("ch", LedgerConfig(device_validate=device_validate,
-                                             **ledger_cfg))
-        dv = None
-        if device_validate:
-            from fabric_tpu.committer.device_validate import DeviceValidator
-            dv = DeviceValidator(ledger.statedb, "ch")
-            ledger.set_prepared_source(dv.take_prepared)
-        ea = None
-        if early_abort:
-            from fabric_tpu.committer.parallel_commit import (
-                EarlyAbortAnalyzer)
-            ea = EarlyAbortAnalyzer(ledger.statedb, "ch",
-                                    overlay_source=ledger.pending_overlay)
-        return Committer(ledger, TxValidator(
-            "ch", self.msps, provider, policies, device_validate=dv,
-            early_abort=ea))
+        return Committer(KVLedger("ch", LedgerConfig()), TxValidator(
+            "ch", self.msps, provider, policies))
 
 
 @pytest.fixture(scope="module")
@@ -344,33 +329,41 @@ def test_what_the_model_rejects_the_contract_rejects(endorsing, world, fn,
 
 # -- the seeded chain through the committer -----------------------------------
 
-@pytest.mark.parametrize("source", ["lanes", "envelopes"])
+@pytest.mark.parametrize("form", list(WALKS))
 def test_chain_through_the_serial_walk_equals_the_model(world, chain,
-                                                        sw_provider, source):
+                                                        sw_provider, form):
     """A block of blind writes takes the lane source where it arrives as
-    a `BlockView`, and the envelope source as a plain `Block`: the same
-    flags, records and commit hash, which are the model's."""
+    a `BlockView` — walked as arrays, or in Python where
+    `native/fastmvcc.c` did not build — and the envelope source as a
+    plain `Block`: the same flags, records and commit hash, which are
+    the model's."""
     _txs, blocks = chain
+    span = WALKS[form]
+    source, walk, reason = (span["source"], span["walk"],
+                            span.get("reason", "none"))
     committer = world.committer(sw_provider)
     other = world.committer(sw_provider)
     moved = registry.counter("ledger_commit_source_total")
-    before = moved.value(channel="ch", source=source)
+    walked = registry.counter("ledger_mvcc_walk_total")
+    before = (moved.value(channel="ch", source=source),
+              walked.value(channel="ch", walk=walk, reason=reason))
     for b in blocks:
         parsed = (wire.parse_block(b["raw"]) if source == "lanes"
                   else Block.deserialize(b["raw"]))
         if source == "lanes":
             gate = TxFlags.from_bytes(bytes(len(b["txs"])))
-            table, reason = mvcc.lane_source_of(
+            table, why = mvcc.lane_source_of(
                 wire.parse_block(b["raw"]), gate)
-            assert reason is None and isinstance(table, wire.LaneTable)
-        committer.store_block(parsed)
-        assert (committer.ledger.last_stats.span_attrs["ledger.mvcc"]
-                ["source"] == source)
+            assert why is None and isinstance(table, wire.LaneTable)
+        with walking_as(form):
+            committer.store_block(parsed)
+        assert committer.ledger.last_stats.span_attrs["ledger.mvcc"] == span
         other.store_block(Block.deserialize(b["raw"])
                           if source == "lanes"
                           else wire.parse_block(b["raw"]))
-    assert (moved.value(channel="ch", source=source) - before
-            == RECORDS + UPDATES)
+    assert (moved.value(channel="ch", source=source) - before[0]
+            == walked.value(channel="ch", walk=walk, reason=reason)
+            - before[1] == RECORDS + UPDATES)
     flags = [stored_flags(committer.ledger, b["number"]) for b in blocks]
     assert_equals_model(blocks, flags, records_of(committer.ledger.get_state))
     assert committer.ledger.commit_hash == other.ledger.commit_hash
@@ -384,50 +377,6 @@ def test_chain_through_the_serial_walk_equals_the_model(world, chain,
     got = [(m.block_num, m.tx_num)
            for m in committer.ledger.get_history(CC, hot)]
     assert sorted(got) == want and len(want) > 5
-
-
-@pytest.mark.parametrize("path", ["parallel_commit", "early_abort",
-                                  "commit_window", "device_validate"])
-def test_default_off_commit_paths_give_the_oracles_answers(
-        world, chain, sw_provider, path):
-    """Blind writes with repeats on hot keys through the wave scheduler
-    (alone and with the early-abort analyzer), the cross-block window and
-    the fused device validation: the serial oracle's flags, records and
-    commit hash, which are the model's; the byte counter moves alike."""
-    _txs, blocks = chain
-    raws = [b["raw"] for b in blocks]
-    oracle = world.committer(sw_provider)
-    wrote = registry.counter("ledger_state_write_bytes_total")
-    b0 = wrote.value(channel="ch")
-    for raw in raws:
-        oracle.store_block(wire.parse_block(raw))
-    oracle_bytes = wrote.value(channel="ch") - b0
-    if path in ("parallel_commit", "early_abort"):
-        other = world.committer(sw_provider, parallel_commit=True,
-                                commit_serial_fallback=False,
-                                early_abort=path == "early_abort")
-    elif path == "commit_window":
-        other = world.committer(sw_provider, commit_window=4)
-    else:
-        other = world.committer(sw_provider, device_validate=True)
-    walked = registry.counter("ledger_commit_source_total")
-    walked_before, b1 = walked.total(), wrote.value(channel="ch")
-    if path == "commit_window":
-        pipe = PipelinedCommitter(other)
-        try:
-            for fut in [pipe.submit(wire.parse_block(raw)) for raw in raws]:
-                fut.result(timeout=120)
-        finally:
-            pipe.close()
-    else:
-        for raw in raws:
-            other.store_block(wire.parse_block(raw))
-    assert walked.total() == walked_before      # not the serial walk
-    assert wrote.value(channel="ch") - b1 == oracle_bytes > 0
-    flags = [stored_flags(other.ledger, b["number"]) for b in blocks]
-    assert flags == [stored_flags(oracle.ledger, b["number"]) for b in blocks]
-    assert other.ledger.commit_hash == oracle.ledger.commit_hash
-    assert_equals_model(blocks, flags, records_of(other.ledger.get_state))
 
 
 def test_a_read_of_an_updated_record_conflicts(world, chain, sw_provider):
