@@ -26,6 +26,37 @@ class SimulationError(Exception):
     pass
 
 
+# -- composite keys (shim CreateCompositeKey / SplitCompositeKey) -------------
+# "\x00" + object type + "\x00" + attribute + "\x00" ...: the namespace
+# byte U+0000 keeps composite keys apart from simple keys (a scan of the
+# simple keys starts at "\x01"), and U+10FFFF, the largest code point,
+# closes a partial-key range, so neither may appear inside a part.
+
+COMPOSITE_NS = "\x00"
+MAX_CODE_POINT = "\U0010ffff"
+SIMPLE_KEY_START = "\x01"
+
+
+def create_composite_key(object_type: str, attributes=()) -> str:
+    if not object_type:
+        raise SimulationError("composite key: empty object type")
+    parts = (object_type, *attributes)
+    for part in parts:
+        if COMPOSITE_NS in part or MAX_CODE_POINT in part:
+            raise SimulationError(
+                f"composite key: U+0000 or U+10FFFF in {part!r}")
+    return COMPOSITE_NS + "".join(part + COMPOSITE_NS for part in parts)
+
+
+def split_composite_key(key: str) -> Tuple[str, List[str]]:
+    """-> (object type, [attributes]) of a key `create_composite_key`
+    made."""
+    if len(key) < 3 or key[0] != COMPOSITE_NS or key[-1] != COMPOSITE_NS:
+        raise SimulationError(f"not a composite key: {key!r}")
+    object_type, *attributes = key[1:-1].split(COMPOSITE_NS)
+    return object_type, attributes
+
+
 class _NsBuilder:
     def __init__(self):
         self.reads: Dict[str, KVRead] = {}
@@ -87,6 +118,26 @@ class ChaincodeStub:
 
     def get_state_by_range(self, start_key: str, end_key: str,
                            limit: int = 0) -> List[Tuple[str, bytes]]:
+        """A scan of the simple keys: an empty start key means "\\x01"
+        (no composite key is ever returned), and a bound in the
+        composite-key namespace is refused, as the shim refuses it."""
+        for bound in (start_key, end_key):
+            if bound.startswith(COMPOSITE_NS):
+                raise SimulationError(
+                    "range bound in the composite-key namespace: use "
+                    "get_state_by_partial_composite_key")
+        return self._scan(start_key or SIMPLE_KEY_START, end_key, limit)
+
+    def get_state_by_partial_composite_key(
+            self, object_type: str, attributes=(),
+            limit: int = 0) -> List[Tuple[str, bytes]]:
+        """Every key of `object_type` whose leading attributes are
+        `attributes`: the range [prefix, prefix + U+10FFFF)."""
+        prefix = create_composite_key(object_type, attributes)
+        return self._scan(prefix, prefix + MAX_CODE_POINT, limit)
+
+    def _scan(self, start_key: str, end_key: str,
+              limit: int) -> List[Tuple[str, bytes]]:
         """Records a RangeQueryInfo with raw reads; validation replays the
         same scan at commit time (rangequery_validator.go, phantom reads).
         Committed state only — this simulation's staged writes are NOT

@@ -215,9 +215,10 @@ class KVLedger:
         """One committed block into the always-on counters: its
         transactions by final code, the writes of its valid txs
         (`history`: how many, and their key + value bytes), the reads
-        the walk checked and the conflicts it found (`tally`), which
-        source supplied its rw-sets and which form the walk took
-        (`mvcc_attrs`, the `ledger.mvcc` span's)."""
+        the walk checked and the conflicts it found, the range queries
+        it replayed (`tally`), which source supplied its rw-sets and
+        which form the walk took (`mvcc_attrs`, the `ledger.mvcc`
+        span's)."""
         from fabric_tpu.ops_plane import registry
         ch = self.channel_id
         txs = registry.counter(
@@ -254,6 +255,21 @@ class KVLedger:
                       against="block")
         conflicts.add(tally.conflicts_state, channel=ch, path="serial",
                       against="state")
+        replayed = tally.ranges_held + tally.ranges_phantom
+        if replayed:
+            ranges = registry.counter(
+                "ledger_mvcc_range_queries_total", "range queries replayed "
+                "at commit, by outcome: the result set held, or a phantom")
+            ranges.add(tally.ranges_held, channel=ch, result="held")
+            ranges.add(tally.ranges_phantom, channel=ch, result="phantom")
+            registry.counter(
+                "ledger_mvcc_range_reads_total", "results the replayed "
+                "range queries re-read (state merged with the block's "
+                "batch)").add(tally.range_reads, channel=ch)
+            registry.histogram(
+                "ledger_mvcc_range_seconds", "seconds a block's range "
+                "replays took: one observation a block that replayed at "
+                "least one").observe(tally.range_s, channel=ch)
 
     _APPLY_BUCKETS = (1.0, 4.0, 16.0, 64.0, 256.0, 1024.0, 4096.0,
                       16384.0, float("inf"))
@@ -316,6 +332,11 @@ class KVLedger:
         mvcc_attrs["walk"] = tally.walk
         if tally.reason is not None:
             mvcc_attrs["reason"] = tally.reason
+        if tally.ranges_held or tally.ranges_phantom:
+            mvcc_attrs.update(
+                range_queries=tally.ranges_held + tally.ranges_phantom,
+                range_reads=tally.range_reads,
+                range_ms=round(1e3 * tally.range_s, 3))
         # split the batch by shard before the apply takes shard locks
         batch.preshard(getattr(self.statedb, "n_shards", 1))
         stats.phase("ledger.mvcc", "state_validation_s", t0, mvcc_attrs)

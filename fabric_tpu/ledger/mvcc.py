@@ -53,6 +53,7 @@ says which, and why) and as the oracle the array pass is held to
 
 from __future__ import annotations
 
+import time
 from typing import List, Optional, Tuple
 
 import numpy as np
@@ -77,11 +78,17 @@ class MvccTally:
     took — "arrays" | "python" — and `reason` why not arrays (`walk_of`;
     None where the source was the envelopes: the source's reason says)."""
     __slots__ = ("reads", "conflicts_block", "conflicts_state", "walk",
-                 "reason")
+                 "reason", "ranges_held", "ranges_phantom", "range_reads",
+                 "range_s")
 
     def __init__(self):
         self.reads = self.conflicts_block = self.conflicts_state = 0
         self.walk, self.reason = "python", None
+        # range queries replayed (only the envelope source's walk meets
+        # one): how each came out, the results the replays re-read, and
+        # the seconds spent in them
+        self.ranges_held = self.ranges_phantom = self.range_reads = 0
+        self.range_s = 0.0
 
 
 def _merged_range(db: StateDB, batch: UpdateBatch, ns: str,
@@ -102,11 +109,26 @@ def _merged_range(db: StateDB, batch: UpdateBatch, ns: str,
 
 
 def _validate_range_query(db: StateDB, batch: UpdateBatch, ns: str,
-                          rq: RangeQueryInfo) -> bool:
+                          rq: RangeQueryInfo,
+                          tally: Optional[MvccTally] = None) -> bool:
     """Raw-reads replay: result set must match read-for-read.  If the
     recorded iterator was NOT exhausted, the replay may see extra trailing
     keys; any difference within the consumed prefix is a phantom."""
+    t0 = time.perf_counter()
     actual = _merged_range(db, batch, ns, rq.start_key, rq.end_key)
+    held = _same_results(rq, actual)
+    if tally is not None:
+        tally.range_reads += len(actual)
+        tally.range_s += time.perf_counter() - t0
+        if held:
+            tally.ranges_held += 1
+        else:
+            tally.ranges_phantom += 1
+    return held
+
+
+def _same_results(rq: RangeQueryInfo, actual: list) -> bool:
+    """The recorded raw reads against the replay's, key and version."""
     recorded = rq.reads
     if rq.itr_exhausted and len(actual) != len(recorded):
         return False
@@ -463,7 +485,7 @@ def validate_and_prepare_batch(
             if not ok:
                 break
             for ns, rq in range_queries:
-                if not _validate_range_query(db, batch, ns, rq):
+                if not _validate_range_query(db, batch, ns, rq, tally):
                     flags.set(tx_num, ValidationCode.PHANTOM_READ_CONFLICT)
                     ok = False
                     break

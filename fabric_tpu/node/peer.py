@@ -38,7 +38,8 @@ from fabric_tpu.chaincode import (
     LifecyclePolicyProvider,
     SimulationError,
 )
-from fabric_tpu.chaincode import asset_sbe, kvstore, smallbank
+from fabric_tpu.chaincode import (asset_queries, asset_sbe, kvstore,
+                                  smallbank)
 from fabric_tpu.chaincode.runtime import FuncContract
 from fabric_tpu.comm.rpc import RpcServer, connect
 from fabric_tpu.committer import Committer, TxValidator
@@ -118,7 +119,8 @@ def _asset_contract():
 DEV_CONTRACTS = {"asset_demo": _asset_contract,
                  "smallbank": smallbank.contract,
                  "kvstore": kvstore.contract,
-                 "asset_sbe": asset_sbe.contract}
+                 "asset_sbe": asset_sbe.contract,
+                 "asset_queries": asset_queries.contract}
 
 
 class RemoteDeliver:
