@@ -45,6 +45,7 @@ import os
 import struct
 import threading
 import time
+from collections import Counter
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Dict, Iterator, List, Optional, Tuple
@@ -619,17 +620,23 @@ class StateDB:
                     f"batch for block {block_num} <= savepoint {self._savepoint}")
             if self.root is not None:
                 self._wal_append(batch, block_num)
-            self._apply_in_memory(batch, block_num)
+            applied = self._apply_in_memory(batch, block_num)
             if self.root is not None:
                 self._batches_since_ckpt += 1
                 if self._batches_since_ckpt >= self.snapshot_every:
                     self._checkpoint_locked()
-        self._observe_shards()
+        self._observe_shards(applied)
 
-    # below this many updates the per-key bisect path wins; above it the
-    # coalesced one-pass merge of sorted_keys is O(N + B log B) instead
-    # of O(B * N) list insert/pop churn
-    _BATCH_APPLY_MIN = 64
+    # how a shard's sorted_keys follows a batch is chosen by the keys the
+    # batch adds to and removes from that shard: up to this many, one
+    # bisect + pop/insort each, in place; above it one filter + sort()
+    # (two sorted runs, merged in C).  On the chip's host (PERF.md §6,
+    # PR 46; ms a shard, bisects | sort): 25,000 keys — 100 changes 0.19 |
+    # 2.4, 1,000 1.6 | 2.7, 3,000 5.8 | 3.8; 125,000 keys — 1,000 5.3 |
+    # 11.9, 3,000 28.6 | 14.0; 5,000 keys — 1,000 0.96 | 0.92.  The two
+    # cross at ~1,700 changes from 25,000 keys up and at ~900 at 5,000
+    # (below that either costs under a millisecond), so a plain count does
+    _INDEX_BISECT_MAX = 1024
     # below this many TOTAL updates (or with only one busy shard) the
     # thread fan-out costs more than it buys
     _PARALLEL_APPLY_MIN = 512
@@ -637,7 +644,10 @@ class StateDB:
     # per-shard loop (still sharded: smaller sorted-key merges) wins
     _HOST_CORES = os.cpu_count() or 1
 
-    def _apply_in_memory(self, batch: UpdateBatch, block_num: int) -> None:
+    def _apply_in_memory(self, batch: UpdateBatch,
+                         block_num: int) -> List[Tuple[str, int]]:
+        """Apply `batch` shard by shard; returns what `_apply_shard` did
+        for each shard the batch touched."""
         meta_delta = self._meta_delta(batch) if batch.touches_meta else 0
         per_shard = batch.items_by_shard(self.n_shards)
         busy = [i for i, items in enumerate(per_shard) if items]
@@ -647,101 +657,64 @@ class StateDB:
             futs = [pool.submit(self._apply_shard, self._shards[i],
                                 per_shard[i])
                     for i in busy]
-            for f in futs:
-                f.result()
+            applied = [f.result() for f in futs]
         else:
-            for i in busy:
-                self._apply_shard(self._shards[i], per_shard[i])
+            applied = [self._apply_shard(self._shards[i], per_shard[i])
+                       for i in busy]
         self._meta_keys += meta_delta      # before the savepoint: meta_keys
         self._savepoint = block_num
+        return applied
 
-    @classmethod
-    def _apply_shard(cls, shard: _StateShard, items: list) -> None:
+    def _apply_shard(self, shard: _StateShard,
+                     items: list) -> Tuple[str, int]:
+        """One pass over a shard's share of a batch: mutate data and the
+        _FieldIndexes per key, then bring sorted_keys after the keys
+        whose existence changed.  Returns the way that took ("none",
+        "incremental" or "merge") and how many such keys there were."""
         with shard.lock:
-            if len(items) >= cls._BATCH_APPLY_MIN:
-                cls._apply_shard_batched(shard, items)
-            else:
-                cls._apply_shard_per_key(shard, items)
-
-    @staticmethod
-    def _apply_shard_per_key(shard: _StateShard, items: list) -> None:
-        ns_indexed = {n for (n, _f) in shard.indexes}
-        data = shard.data
-        sorted_keys = shard.sorted_keys
-        for k, vv in items:
-            ns, key = k
-            if vv is None:
-                if k in data:
-                    del data[k]
-                    i = bisect.bisect_left(sorted_keys, k)
-                    if i < len(sorted_keys) and sorted_keys[i] == k:
-                        sorted_keys.pop(i)
-                if ns in ns_indexed:
-                    for (n, f), idx in shard.indexes.items():
-                        if n == ns:
-                            idx.remove(key)
-            else:
-                if k not in data:
+            ns_indexed = {n for (n, _f) in shard.indexes}
+            removed = set()
+            added = set()
+            data = shard.data
+            for k, vv in items:
+                ns, key = k
+                if vv is None:
+                    if k in data:
+                        del data[k]
+                        removed.add(k)
+                    if ns in ns_indexed:
+                        for (n, f), idx in shard.indexes.items():
+                            if n == ns:
+                                idx.remove(key)
+                else:
+                    if k not in data:
+                        added.add(k)
+                    data[k] = vv
+                    if ns in ns_indexed:
+                        doc = _doc_of(vv.value)
+                        for (n, f), idx in shard.indexes.items():
+                            if n != ns:
+                                continue
+                            if doc is None:
+                                idx.remove(key)
+                            else:
+                                idx.put(key, doc.get(f))
+            changed = len(removed) + len(added)
+            if not changed:
+                return "none", 0
+            sorted_keys = shard.sorted_keys
+            if changed <= self._INDEX_BISECT_MAX:
+                for k in removed:
+                    sorted_keys.pop(bisect.bisect_left(sorted_keys, k))
+                for k in added:
                     bisect.insort(sorted_keys, k)
-                data[k] = vv
-                if ns in ns_indexed:
-                    doc = _doc_of(vv.value)
-                    for (n, f), idx in shard.indexes.items():
-                        if n != ns:
-                            continue
-                        if doc is None:
-                            idx.remove(key)
-                        else:
-                            idx.put(key, doc.get(f))
-
-    @staticmethod
-    def _apply_shard_batched(shard: _StateShard, items: list) -> None:
-        """One coalesced pass: mutate data/_FieldIndexes per key, then
-        rebuild sorted_keys with a single merge of the surviving keys
-        and the sorted set of newly-added ones."""
-        ns_indexed = {n for (n, _f) in shard.indexes}
-        removed = set()
-        added = set()
-        data = shard.data
-        for k, vv in items:
-            ns, key = k
-            if vv is None:
-                if k in data:
-                    del data[k]
-                    removed.add(k)
-                if ns in ns_indexed:
-                    for (n, f), idx in shard.indexes.items():
-                        if n == ns:
-                            idx.remove(key)
-            else:
-                if k not in data:
-                    added.add(k)
-                data[k] = vv
-                if ns in ns_indexed:
-                    doc = _doc_of(vv.value)
-                    for (n, f), idx in shard.indexes.items():
-                        if n != ns:
-                            continue
-                        if doc is None:
-                            idx.remove(key)
-                        else:
-                            idx.put(key, doc.get(f))
-        if not removed and not added:
-            return
-        new_keys = sorted(added)
-        merged: List[Tuple[str, str]] = []
-        append = merged.append
-        i = 0
-        n_new = len(new_keys)
-        for k in shard.sorted_keys:
-            if k in removed:
-                continue
-            while i < n_new and new_keys[i] < k:
-                append(new_keys[i])
-                i += 1
-            append(k)
-        merged.extend(new_keys[i:])
-        shard.sorted_keys = merged
+                return "incremental", changed
+            if removed:
+                shard.sorted_keys = sorted_keys = [
+                    k for k in sorted_keys if k not in removed]
+            sorted_keys.extend(sorted(added))
+            sorted_keys.sort()
+            return "merge", changed
 
     def _get_pool(self) -> ThreadPoolExecutor:
         if self._pool is None:
@@ -929,7 +902,7 @@ class StateDB:
 
     # -- observability ------------------------------------------------------
 
-    def _observe_shards(self) -> None:
+    def _observe_shards(self, applied: List[Tuple[str, int]]) -> None:
         if not self.channel:
             return
         try:
@@ -939,6 +912,15 @@ class StateDB:
             for i, sh in enumerate(self._shards):
                 g.set(float(len(sh.data)), channel=self.channel,
                       shard=str(i))
+            modes = registry.counter(
+                "state_index_update_total",
+                "Shard applies by how sorted_keys followed the batch")
+            for mode, n in Counter(m for m, _n in applied).items():
+                modes.add(n, channel=self.channel, mode=mode)
+            registry.counter(
+                "state_index_changed_keys_total",
+                "Keys a batch added to or removed from a shard").add(
+                    sum(n for _m, n in applied), channel=self.channel)
         except Exception:
             pass
 

@@ -557,3 +557,218 @@ def test_meta_keys_count_moves_before_the_savepoint():
         db.apply_updates(batch, block)
         assert db.meta_keys() == (block, block)
     assert db.at_savepoint == [(None, 0)] + [(b, b) for b in range(1, 9)]
+
+
+# ---------------------------------------------------------------------------
+# a shard's ordered key index follows the keys a batch adds and removes
+# ---------------------------------------------------------------------------
+
+_HUGE = 1 << 60
+
+
+def _index_scenario(name, rng):
+    """(n_shards, batches) of one case; a batch is a list of
+    (ns, key, value-or-None) with one entry a key."""
+    keys = [f"k{i:05d}" for i in range(900)]
+
+    def puts(ns, ks, tag):
+        return [(ns, k, b"%s-%s" % (tag, k.encode())) for k in ks]
+
+    def dels(ns, ks):
+        return [(ns, k, None) for k in ks]
+
+    seed = puts("cc", keys[:400], b"s")
+    if name == "updates_only":
+        return 4, [seed] + [puts("cc", rng.sample(keys[:400], 200), b"u%d" % i)
+                            for i in range(5)]
+    if name == "few_changes":
+        batches, live, spare = [seed], set(keys[:400]), list(keys[400:])
+        for i in range(8):
+            gone = rng.sample(sorted(live), rng.randrange(0, 4))
+            new = [spare.pop() for _ in range(rng.randrange(0, 4))]
+            live = (live - set(gone)) | set(new)
+            kept = rng.sample(sorted(live - set(new)), 200)
+            batches.append(dels("cc", gone) + puts("cc", new + kept, b"f%d" % i))
+        return 4, batches
+    if name == "hundreds":
+        batches, live, spare = [seed], set(keys[:400]), set(keys[400:])
+        for i in range(5):
+            gone = rng.sample(sorted(live), 300)
+            new = rng.sample(sorted(spare), 300)
+            live, spare = (live - set(gone)) | set(new), \
+                (spare - set(new)) | set(gone)
+            batches.append(dels("cc", gone) + puts("cc", new, b"h%d" % i))
+        return 2, batches
+    if name == "delete_absent":
+        return 4, [seed,
+                   dels("cc", keys[500:560]),
+                   dels("cc", keys[560:600]) + puts("cc", keys[:50], b"d"),
+                   dels("cc", keys[600:700] + keys[10:11]),
+                   dels("nowhere", keys[:5])]
+    if name == "delete_then_recreate":
+        pick = rng.sample(keys[:400], 120)
+        return 4, [seed, dels("cc", pick), puts("cc", pick, b"again"),
+                   dels("cc", pick[:60]) + puts("cc", pick[60:], b"upd"),
+                   puts("cc", pick[:60], b"third"), dels("cc", pick)]
+    if name == "meta_twin":
+        batches, live = [puts("cc", keys[:200], b"v")
+                         + puts("cc#meta", keys[:200], b"POL")], set(keys[:200])
+        for i in range(6):
+            gone = rng.sample(sorted(live), 25)
+            new = rng.sample(sorted(set(keys[:400]) - live), 25)
+            live = (live - set(gone)) | set(new)
+            batches.append(dels("cc", gone) + dels("cc#meta", gone)
+                           + puts("cc", new, b"v%d" % i)
+                           + puts("cc#meta", new, b"POL%d" % i)
+                           + puts("cc", rng.sample(sorted(live), 40), b"w"))
+        return 4, batches
+    if name == "empty_shard":
+        # 16 shards and a handful of keys: most shards stay empty, and
+        # the ones that fill do so from nothing
+        return 16, [puts("cc", keys[:3], b"a"), puts("cc", keys[3:6], b"b"),
+                    dels("cc", keys[:2]), puts("cc", keys[:40], b"c")]
+    if name == "empties_a_shard":
+        in_zero = [k for k in keys[:400] if shard_of("cc", k, 4) == 0]
+        return 4, [seed, dels("cc", in_zero), puts("cc", in_zero[:7], b"z"),
+                   dels("cc", keys[:400]), puts("cc", keys[100:140], b"y")]
+    raise AssertionError(name)
+
+
+def _batch_of(ops, block):
+    batch = UpdateBatch()
+    for t, (ns, key, value) in enumerate(ops):
+        if value is None:
+            batch.delete(ns, key, Version(block, t))
+        else:
+            batch.put(ns, key, value, Version(block, t))
+    return batch
+
+
+@pytest.mark.parametrize("name,seed", [
+    ("updates_only", 1), ("few_changes", 2), ("few_changes", 3),
+    ("hundreds", 4), ("delete_absent", 5), ("delete_then_recreate", 6),
+    ("meta_twin", 7), ("meta_twin", 8), ("empty_shard", 9),
+    ("empties_a_shard", 10)])
+def test_sorted_keys_follow_a_batch_the_same_both_ways(tmp_path, name, seed):
+    """The bisects and the one sort are two ways to the same list: with
+    the threshold forced to 0 (every structural change takes the sort)
+    and to a huge number (every one takes the bisects), after every batch
+    each shard's sorted_keys is sorted(shard.data), a range over random
+    bounds is the flat reference's, and the two stores' checkpoints are
+    bit for bit the same."""
+    rng = random.Random(seed)
+    n_shards, batches = _index_scenario(name, rng)
+    stores = {}
+    for way, bisect_max in (("merge", 0), ("incremental", _HUGE)):
+        db = StateDB(str(tmp_path / way), snapshot_every=1000,
+                     n_shards=n_shards)
+        db._INDEX_BISECT_MAX = bisect_max
+        stores[way] = db
+    flat = {}
+    for block, ops in enumerate(batches, start=1):
+        for ns, key, value in ops:
+            if value is None:
+                flat.pop((ns, key), None)
+            else:
+                flat[(ns, key)] = value
+        digests = []
+        for way, db in stores.items():
+            db.apply_updates(_batch_of(ops, block), block)
+            for sh in db._shards:
+                assert sh.sorted_keys == sorted(sh.data), (way, block)
+            assert {k: vv.value for k, vv in db._data.items()} == flat
+            for ns in ("cc", "cc#meta"):
+                lo, hi = sorted(f"k{rng.randrange(950):05d}" for _ in "ab")
+                for start, end in ((lo, hi), ("", hi), (lo, ""), ("", "")):
+                    want = [(k, v) for (n, k), v in sorted(flat.items())
+                            if n == ns and k >= start and (not end or k < end)]
+                    got = [(k, vv.value)
+                           for k, vv in db.range_scan(ns, start, end)]
+                    assert got == want, (way, block, ns, start, end)
+            manifest = db.checkpoint()
+            digests.append([(s["sha256"], s["bytes"])
+                            for s in manifest["shards"]])
+        assert digests[0] == digests[1], block
+    for way, db in stores.items():
+        again = StateDB(str(tmp_path / way), n_shards=n_shards)
+        assert {k: vv.value for k, vv in again._data.items()} == flat
+
+
+def _index_counts(channel):
+    from fabric_tpu.ops_plane.metrics import registry
+    modes = registry.counter("state_index_update_total")
+    keys = registry.counter("state_index_changed_keys_total")
+    return ({m: modes.value(channel=channel, mode=m)
+             for m in ("none", "incremental", "merge")},
+            keys.value(channel=channel))
+
+
+@pytest.mark.parametrize("case,want", [
+    ("one_add_among_200_updates", {"incremental": 1}),
+    ("more_adds_than_the_threshold", {"merge": 1}),
+    ("more_removes_than_the_threshold", {"merge": 1}),
+    ("updates_only", {"none": 1}),
+    ("a_delete_of_an_absent_key", {"none": 1})])
+def test_the_way_is_chosen_by_the_keys_a_batch_adds_and_removes(case, want):
+    """Not by the batch's size: 200 updates and one new key take the
+    bisects; more new (or leaving) keys than the threshold take the sort;
+    no key's existence changed, nothing is done — as the counter says."""
+    channel = "idx-way-" + case
+    db = StateDB(n_shards=1, channel=channel)
+    many = db._INDEX_BISECT_MAX + 1
+    held = [f"k{i:05d}" for i in range(max(400, 2 * many))]
+    db.apply_updates(_batch_of([("cc", k, b"v") for k in held], 1), 1)
+    ops = {
+        "one_add_among_200_updates":
+            [("cc", k, b"w") for k in held[:200]] + [("cc", "new", b"v")],
+        "more_adds_than_the_threshold":
+            [("cc", f"n{i:05d}", b"v") for i in range(many)],
+        "more_removes_than_the_threshold":
+            [("cc", k, None) for k in held[:many]],
+        "updates_only": [("cc", k, b"w") for k in held[:200]],
+        "a_delete_of_an_absent_key":
+            [("cc", "absent", None)] + [("cc", k, b"w") for k in held[:99]],
+    }[case]
+    modes0, keys0 = _index_counts(channel)
+    db.apply_updates(_batch_of(ops, 2), 2)
+    modes1, keys1 = _index_counts(channel)
+    moved = {m: modes1[m] - modes0[m] for m in modes1 if modes1[m] != modes0[m]}
+    assert moved == want
+    assert keys1 - keys0 == {"incremental": 1, "merge": many, "none": 0}[
+        next(iter(want))]
+    assert db._shards[0].sorted_keys == sorted(db._shards[0].data)
+
+
+@pytest.mark.parametrize("bisect_max", [0, StateDB._INDEX_BISECT_MAX, _HUGE])
+def test_changed_keys_counter_is_adds_plus_removes(bisect_max):
+    """`state_index_changed_keys_total` counts every key whose existence
+    a batch changed — re-puts, and deletes of absent keys, are none —
+    and `state_index_update_total` one apply a shard a batch touched."""
+    channel = f"idx-keys-{bisect_max}"
+    rng = random.Random(46)
+    db = StateDB(n_shards=4, channel=channel)
+    db._INDEX_BISECT_MAX = bisect_max
+    live, want_keys, want_applies = set(), 0, 0
+    for block in range(1, 13):
+        batch = UpdateBatch()
+        picked = rng.sample(range(600), rng.choice((5, 80, 400)))
+        for t, i in enumerate(picked):
+            ns, key = ("cc", "cc#meta")[i % 2], f"k{i:04d}"
+            if rng.random() < 0.4:
+                batch.delete(ns, key, Version(block, t))
+                want_keys += (ns, key) in live
+                live.discard((ns, key))
+            else:
+                batch.put(ns, key, b"v", Version(block, t))
+                want_keys += (ns, key) not in live
+                live.add((ns, key))
+        want_applies += len({shard_of(ns, k, 4) for (ns, k), _ in batch.items()})
+        db.apply_updates(batch, block)
+    modes, keys = _index_counts(channel)
+    assert keys == want_keys > 0
+    assert sum(modes.values()) == want_applies
+    assert set(db._data) == live
+    if bisect_max == 0:
+        assert modes["incremental"] == 0 < modes["merge"]
+    if bisect_max == _HUGE:
+        assert modes["merge"] == 0 < modes["incremental"]

@@ -558,7 +558,9 @@ def test_a_bump_run_exposes_what_the_parent_did_and_the_new_series(
 # (the form the provider got each block's unique items in) and PR 41's
 # `validator_creators_total` (a block's creators by whether its memo knew
 # them) and PR 43's `ledger_mvcc_walk_total` (the form the serial MVCC
-# walk took, in transactions)
+# walk took, in transactions) and PR 46's `state_index_update_total` and
+# `state_index_changed_keys_total` (how each shard's ordered key list
+# followed a batch, and how many keys came or went)
 PARENT_FAMILIES = {
     "commit_graph_apply_batch_size",
     "committed_blocks_total", "committed_txs_total",
@@ -567,7 +569,9 @@ PARENT_FAMILIES = {
     "ledger_mvcc_walk_total",
     "ledger_state_writes_total", "ledger_tx_total",
     "pipeline_collect_under_verify_frac", "state_checkpoint_height",
-    "state_checkpoint_seconds", "state_checkpoint_total", "state_shard_keys",
+    "state_checkpoint_seconds", "state_checkpoint_total",
+    "state_index_changed_keys_total", "state_index_update_total",
+    "state_shard_keys",
     "validation_duration_seconds", "validator_stage_seconds",
     "validator_creators_total", "validator_handoff_sigs_total",
     "validator_tail_total"}
