@@ -41,7 +41,7 @@ from fabric_tpu.bccsp.provider import dispatch_site
 from fabric_tpu.msp import Identity
 from fabric_tpu.ops_plane import tracing
 from fabric_tpu.policy import PolicyEvaluator, SignaturePolicy, SignedData
-from fabric_tpu.protocol import Block
+from fabric_tpu.protocol import Block, wire
 from fabric_tpu.protocol.txflags import TxFlags, ValidationCode
 from fabric_tpu.protocol.types import META_TXFLAGS
 from fabric_tpu.protocol.wire import n_txs
@@ -717,10 +717,12 @@ class TxValidator:
         """Pass 1 + async device enqueue for one block; returns the
         in-flight state for validate_finish.
 
-        Splitting begin/finish lets a block-stream driver overlap host
+        The split would let a block-stream driver overlap host
         collection of block N+1 with device verification of block N
-        (BASELINE config 5's 32-block streamed window; the reference
-        has no analogue — its validator is synchronous per block)."""
+        (the reference has no analogue — its validator is synchronous
+        per block).  No such driver exists: outside `tests/` the two
+        halves have one caller, `validate` above, which runs them back
+        to back (ROADMAP S1, D1)."""
         self._msps_snapshot = (self.bundle_source.current().msps
                                if self.bundle_source is not None else None)
         if self.verify_cache is not None and self.bundle_source is not None:
@@ -809,6 +811,37 @@ class TxValidator:
                 resolve = provider.batch_verify_async(misses)
         th, holder = _resolve_eagerly(resolve, self._econ)
         return part, th, holder, (n_arrays, reason)
+
+    def _prepare_lanes(self, block, wait) -> None:
+        """The one step where either tail waits for the device, put to
+        use: the block's programs are enqueued and the host has nothing
+        to do until their verdicts are in, so what the commit derives
+        from the block's bytes alone — its lane table,
+        `wire.prepare_lanes` — is opened here, and the commit that
+        follows finds it open.  It needs no verdict, no flag and no
+        state, whether there is anything to prepare is read off the
+        block, and the extraction holds no interpreter lock, so the
+        resolver thread wakes when the device is done as it did while
+        this thread slept.  A block the extractor fails on is left to the commit,
+        which meets the same failure at the same call, as it would have
+        without this step.  `wait`: the context reserved for the
+        caller's `validator.dispatch_wait`, under which the preparation's
+        span, `validator.lanes_prepare`, is recorded where it did the
+        work."""
+        t0 = time.perf_counter()
+        try:
+            table = wire.prepare_lanes(block, at="validator_wait")
+        except Exception:
+            logger.warning("[%s] block %d: lane table not prepared",
+                           self.channel_id, block.header.number,
+                           exc_info=True)
+            table = None
+        if table is not None:
+            tracing.tracer.record_span(
+                "validator.lanes_prepare", t0, time.perf_counter(),
+                attributes={"block": int(block.header.number),
+                            "txs": table.n_tx},
+                parent=wait)
 
     def _await(self, handle: tuple) -> np.ndarray:
         """Wait for `_dispatch`'s results, store them in the cache and
@@ -1124,13 +1157,16 @@ class TxValidator:
         # device runs the block's own programs
         settled = (self._settle_creators(state) if "deferred" in state
                    else {})
+        wait = tracing.tracer.reserve_span()
+        self._prepare_lanes(block, wait)
         # positional over the table, as gate reads it
         verdict = self._await(state["verify"]).view(np.uint8)
         dispatch_s = time.perf_counter() - t0
         tracing.tracer.record_span(
             "validator.dispatch_wait", t0, t0 + dispatch_s,
             attributes={"block": int(block.header.number),
-                        "unique_items": len(index), **settled})
+                        "unique_items": len(index), **settled},
+            context=wait)
 
         t0 = time.perf_counter()
         _fastcollect.gate(state["plans"], verdict, codes,
@@ -1149,13 +1185,16 @@ class TxValidator:
 
         t0 = time.perf_counter()
         keys = list(items.keys())
+        wait = tracing.tracer.reserve_span()
+        self._prepare_lanes(block, wait)
         verdict: Dict[Tuple, bool] = dict(
             zip(keys, self._await(state["verify"]).tolist()))
         dispatch_s = time.perf_counter() - t0
         tracing.tracer.record_span(
             "validator.dispatch_wait", t0, t0 + dispatch_s,
             attributes={"block": int(block.header.number),
-                        "unique_items": len(keys)})
+                        "unique_items": len(keys)},
+            context=wait)
 
         t0 = time.perf_counter()
         from fabric_tpu.committer.sbe import SbeOverlay

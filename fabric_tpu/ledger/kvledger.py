@@ -24,7 +24,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 from fabric_tpu.protocol import Block
-from fabric_tpu.protocol.wire import n_txs
+from fabric_tpu.protocol.wire import lane_table, n_txs
 from fabric_tpu.protocol.txflags import TxFlags, ValidationCode
 from fabric_tpu.protocol.types import META_COMMIT_HASH, META_TXFLAGS
 
@@ -211,14 +211,16 @@ class KVLedger:
             self.historydb.commit(num, history)  # savepoint-guarded, idempotent
 
     def _count_block(self, flags: TxFlags, tally: MvccTally,
-                     history: list, mvcc_attrs: dict) -> None:
+                     history: list, mvcc_attrs: dict,
+                     opened_at: Optional[str]) -> None:
         """One committed block into the always-on counters: its
         transactions by final code, the writes of its valid txs
         (`history`: how many, and their key + value bytes), the reads
         the walk checked and the conflicts it found, the range queries
         it replayed (`tally`), which source supplied its rw-sets and
         which form the walk took (`mvcc_attrs`, the `ledger.mvcc`
-        span's)."""
+        span's), and where its lane table was first opened
+        (`opened_at`; None: it has none)."""
         from fabric_tpu.ops_plane import registry
         ch = self.channel_id
         txs = registry.counter(
@@ -238,6 +240,13 @@ class KVLedger:
             "serial MVCC walk validated, by what supplied their rw-sets: "
             "the block's lane table, or its envelopes decoded again").add(
                 len(flags), channel=ch, source=mvcc_attrs["source"])
+        if opened_at is not None:
+            registry.counter(
+                "ledger_lane_table_opened_total", "transactions of the "
+                "blocks that have a lane table, by where it was first "
+                "opened: ahead of the commit, while the validator waited "
+                "for the device, or inside the commit").add(
+                    len(flags), channel=ch, at=opened_at)
         registry.counter(
             "ledger_mvcc_walk_total", "transactions of those blocks, by the "
             "form the walk took: one pass over the lane table's arrays, or "
@@ -318,7 +327,9 @@ class KVLedger:
         flags = TxFlags.from_bytes(block.metadata.items[META_TXFLAGS])
         tally = MvccTally()
         # the walk reads the block's lane table where the block allows
-        # it, else its envelopes, decoded again
+        # it (open already where the validator prepared it in its wait;
+        # else extracted here, inside the span), else its envelopes,
+        # decoded again
         source, reason = lane_source_of(block, flags)
         if source is not None:
             mvcc_attrs = {"source": "lanes"}
@@ -365,7 +376,10 @@ class KVLedger:
             stats.phase("ledger.history_commit", "history_commit_s", t0)
 
         self._observe_apply(len(batch), len(history))
-        self._count_block(flags, tally, history, mvcc_attrs)
+        # open by now, where the block has one: the walk asked first
+        table, _ = lane_table(block)
+        self._count_block(flags, tally, history, mvcc_attrs,
+                          table.opened_at if table is not None else None)
         if batch.touches_meta:
             # only such a batch moves the count: a channel without
             # key-level endorsement never shows the series

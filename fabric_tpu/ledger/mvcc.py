@@ -31,7 +31,13 @@ walk and the oracle; its per-tx records come from one of two suppliers:
   one pass over the block (`BlockView.rwset_lanes`) — and names a key by
   its interned slot: keys are decoded and their committed versions
   fetched once a slot (`committed_versions`), no Envelope, Transaction
-  or TxRwSet is built and `block.data` is not materialised.
+  or TxRwSet is built and `block.data` is not materialised.  The table
+  is a pure function of the block's bytes, so whoever asks first opens
+  it: the validator, while it waits for the device
+  (`wire.prepare_lanes`), and the `ledger.mvcc` span then holds the walk
+  alone; the commit (`lane_source_of`, inside the span) for a block
+  nobody validated first.  `ledger_lane_table_opened_total{at}` says
+  which.
 
 Which one a block takes is read off the block (`lane_source_of`), set by
 nobody: the lane source when the block is a `BlockView`, the extractor

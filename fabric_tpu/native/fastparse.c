@@ -658,7 +658,12 @@ reject:
  * All offsets index base_buf.  On collision: (1, 0, 0, 0, 0, None).
  * None for inputs that are not a valid span table over base_buf.
  * Scratch buffers are module-global PyMem_Raw allocations reused
- * across calls — the parse stage stays O(1) Python allocations.      */
+ * across calls — the parse stage stays O(1) Python allocations.  The
+ * walk touches no Python object, so it runs WITHOUT the interpreter
+ * lock (the validator opens a block's lanes while a resolver thread
+ * waits for the device, and that waiter's wake-up time is what the
+ * dispatch account books as the program's end); `g_lane_lock` keeps the
+ * scratch one caller's from its reset until it is copied out.        */
 
 enum {
     LN_OK = 0, LN_SKIP = 1, LN_BAD = 2, LN_RANGE = 3, LN_UNKNOWN = 4,
@@ -670,6 +675,7 @@ static uint64_t *g_rd = NULL;   static size_t g_rd_cap = 0, g_rd_n = 0;
 static uint64_t *g_wr = NULL;   static size_t g_wr_cap = 0, g_wr_n = 0;
 static uint64_t *g_keys = NULL; static size_t g_keys_cap = 0, g_keys_n = 0;
 static uint32_t *g_tab = NULL;  static size_t g_tab_cap = 0;
+static PyThread_type_lock g_lane_lock = NULL;
 
 static uint64_t st_rw_accept = 0;     /* lane calls that produced lanes */
 static uint64_t st_rw_reject = 0;     /* invalid span-table inputs      */
@@ -677,13 +683,15 @@ static uint64_t st_rw_collision = 0;  /* calls demoted on hash collision */
 static uint64_t st_rw_keys = 0;       /* unique rw keys interned (cum.) */
 static uint64_t st_rw_lanes = 0;      /* read+write lanes emitted (cum.) */
 
+/* -1 when out of memory; no exception is set (no interpreter lock
+ * here): py_rwset_lanes raises it */
 static int grow_u64(uint64_t **buf, size_t *cap, size_t need)
 {
     if (*cap >= need) return 0;
     size_t ncap = *cap ? *cap : 256;
     while (ncap < need) ncap <<= 1;
     uint64_t *nb = PyMem_RawRealloc(*buf, ncap * sizeof(uint64_t));
-    if (!nb) { PyErr_NoMemory(); return -1; }
+    if (!nb) return -1;
     *buf = nb;
     *cap = ncap;
     return 0;
@@ -693,7 +701,7 @@ static int tab_grow(void)
 {
     size_t ncap = g_tab_cap ? g_tab_cap * 2 : 64;
     uint32_t *nt = PyMem_RawMalloc(ncap * sizeof(uint32_t));
-    if (!nt) { PyErr_NoMemory(); return -1; }
+    if (!nt) return -1;
     memset(nt, 0, ncap * sizeof(uint32_t));
     for (size_t j = 0; j < g_keys_n; j++) {
         size_t i = (size_t)g_keys[5 * j] & (ncap - 1);
@@ -1138,28 +1146,33 @@ static PyObject *py_rwset_lanes(PyObject *self, PyObject *args)
     size_t blen = (size_t)in.len;
     size_t T = (size_t)sp.len / 16;
 
+    /* what ended the walk early: a span outside base, two keys under
+     * one hash, or no memory */
+    enum { W_DONE, W_REJECT, W_COLLISION, W_OOM } end = W_DONE;
+    Py_BEGIN_ALLOW_THREADS
+    PyThread_acquire_lock(g_lane_lock, WAIT_LOCK);
     g_rd_n = g_wr_n = g_keys_n = 0;
     if (g_tab)
         memset(g_tab, 0, g_tab_cap * sizeof(uint32_t));
     if (grow_u64(&g_tx, &g_tx_cap, T ? T * 3 : 1) < 0)
-        goto error;
-
-    int collision = 0;
-    for (size_t t = 0; t < T; t++) {
+        end = W_OOM;
+    for (size_t t = 0; t < T && end == W_DONE; t++) {
         uint64_t sv[2];
         memcpy(sv, (const uint8_t *)sp.buf + 16 * t, 16);
         if (sv[0] > blen || sv[1] > blen - sv[0]) {
-            st_rw_reject++;
-            goto reject;
+            end = W_REJECT;
+            break;
         }
         size_t rd_mark = g_rd_n, wr_mark = g_wr_n;
         uint64_t txo = 0, txl = 0;
         int st = walk_env(base, base + sv[0], (size_t)sv[1],
                           (uint64_t)t, &txo, &txl);
-        if (st == LN_OOM)
-            goto error;
+        if (st == LN_OOM) {
+            end = W_OOM;
+            break;
+        }
         if (st == LN_COLL) {
-            collision = 1;
+            end = W_COLLISION;
             break;
         }
         if (st != LN_OK) {             /* drop this tx's partial lanes */
@@ -1171,45 +1184,42 @@ static PyObject *py_rwset_lanes(PyObject *self, PyObject *args)
         g_tx[3 * t + 1] = txo;
         g_tx[3 * t + 2] = txl;
     }
-    if (collision) {
-        PyBuffer_Release(&in);
-        PyBuffer_Release(&sp);
+    Py_END_ALLOW_THREADS
+    /* g_lane_lock is still held: the scratch is copied out below */
+    PyObject *res = NULL;
+    if (end == W_REJECT) {
+        st_rw_reject++;
+        res = Py_NewRef(Py_None);
+    } else if (end == W_COLLISION) {
         st_rw_collision++;
-        return Py_BuildValue("(iKKKKO)", 1, 0ULL, 0ULL, 0ULL, 0ULL,
-                             Py_None);
-    }
-    {
+        res = Py_BuildValue("(iKKKKO)", 1, 0ULL, 0ULL, 0ULL, 0ULL,
+                            Py_None);
+    } else if (end == W_OOM) {
+        PyErr_NoMemory();
+    } else {
         size_t R = g_rd_n, W = g_wr_n, K = g_keys_n;
         size_t cells = T * 3 + (R + W + K) * 5;
         FPArena *a = arena_acquire(cells ? cells * 8 : 8);
-        if (!a)
-            goto error;
-        uint64_t *o = (uint64_t *)a->buf;
-        if (T) { memcpy(o, g_tx, T * 3 * 8); o += T * 3; }
-        if (R) { memcpy(o, g_rd, R * 5 * 8); o += R * 5; }
-        if (W) { memcpy(o, g_wr, W * 5 * 8); o += W * 5; }
-        if (K) { memcpy(o, g_keys, K * 5 * 8); }
-        a->len = (Py_ssize_t)(cells * 8);
-        st_rw_accept++;
-        st_rw_lanes += R + W;
-        PyObject *res = Py_BuildValue(
-            "(iKKKKN)", 0,
-            (unsigned long long)T, (unsigned long long)K,
-            (unsigned long long)R, (unsigned long long)W,
-            (PyObject *)a);
-        PyBuffer_Release(&in);
-        PyBuffer_Release(&sp);
-        return res;
+        if (a) {
+            uint64_t *o = (uint64_t *)a->buf;
+            if (T) { memcpy(o, g_tx, T * 3 * 8); o += T * 3; }
+            if (R) { memcpy(o, g_rd, R * 5 * 8); o += R * 5; }
+            if (W) { memcpy(o, g_wr, W * 5 * 8); o += W * 5; }
+            if (K) { memcpy(o, g_keys, K * 5 * 8); }
+            a->len = (Py_ssize_t)(cells * 8);
+            st_rw_accept++;
+            st_rw_lanes += R + W;
+            res = Py_BuildValue(
+                "(iKKKKN)", 0,
+                (unsigned long long)T, (unsigned long long)K,
+                (unsigned long long)R, (unsigned long long)W,
+                (PyObject *)a);
+        }
     }
-
-reject:
+    PyThread_release_lock(g_lane_lock);
     PyBuffer_Release(&in);
     PyBuffer_Release(&sp);
-    Py_RETURN_NONE;
-error:
-    PyBuffer_Release(&in);
-    PyBuffer_Release(&sp);
-    return NULL;
+    return res;
 }
 
 /* ------------------------------------------------------------------ */
@@ -1373,6 +1383,8 @@ PyMODINIT_FUNC PyInit__fastparse(void)
 {
     if (PyType_Ready(&FPArenaType) < 0)
         return NULL;
+    if (!g_lane_lock && !(g_lane_lock = PyThread_allocate_lock()))
+        return PyErr_NoMemory();
     PyObject *m = PyModule_Create(&moduledef);
     if (!m)
         return NULL;

@@ -427,19 +427,33 @@ class Tracer:
             self._register_root(span)
         return span
 
+    def reserve_span(self) -> Optional[SpanContext]:
+        """The context of a span that `record_span(..., context=)` will
+        record under the ambient one once its end is known, so that
+        spans recorded inside it meanwhile can name it as their parent.
+        None where nothing would be recorded."""
+        if not self.enabled:
+            return None
+        parent = getattr(self._tls, "ctx", None)
+        if parent is None or not parent.sampled:
+            return None
+        return SpanContext(parent.trace_id, os.urandom(8).hex(), True)
+
     def record_span(self, name: str, start: float, end: float,
                     attributes: Optional[dict] = None,
-                    parent: Optional[SpanContext] = None) -> None:
+                    parent: Optional[SpanContext] = None,
+                    context: Optional[SpanContext] = None) -> None:
         """Retroactive span from explicit perf_counter() endpoints —
-        used for phases timed by existing code (CommitStats et al.)."""
+        used for phases timed by existing code (CommitStats et al.).
+        `context`: the span's own, where `reserve_span` made it ahead."""
         if not self.enabled:
             return
         if parent is None:
             parent = getattr(self._tls, "ctx", None)
         if parent is None or not parent.sampled:
             return
-        ctx = SpanContext(parent.trace_id, os.urandom(8).hex(),
-                          True)
+        ctx = context or SpanContext(parent.trace_id, os.urandom(8).hex(),
+                                     True)
         span = Span(self, name, ctx, parent.span_id, attributes)
         span.start = start
         span.end(end_time=end)

@@ -25,6 +25,9 @@ metadata splice point — in one C walk, and this module wraps them:
                         fixed-width lanes, extracted once a block by the
                         C walker; what the commit path reads after the
                         validator instead of decoding envelopes again
+  prepare_lanes(block, at)
+                        the same table, opened ahead of the commit by a
+                        caller with time to spare
 
 Fallback semantics: the native parser accepts EXACTLY the strict
 canonical block shape; anything else (including every malformed input)
@@ -290,10 +293,12 @@ class LaneTable:
     """
 
     __slots__ = ("base", "n_tx", "tx", "status", "reads", "writes",
-                 "keys", "_txids", "_key_strs")
+                 "keys", "opened_at", "_txids", "_key_strs")
 
-    def __init__(self, base: _Raw, lanes: tuple):
+    def __init__(self, base: _Raw, lanes: tuple, opened_at: str = "commit"):
         _flags, n_tx, n_keys, n_reads, n_writes, arena = lanes
+        # where the first reader asked: lane_table's `at`
+        self.opened_at = opened_at
         # int64: versions are two's-complement i32 in u64 cells
         cells = np.frombuffer(arena, dtype=np.int64)
         o = 3 * n_tx
@@ -349,12 +354,14 @@ class LaneTable:
         return np.unique(w[slot_has[w[:, 1]], 0]).tolist()
 
 
-def lane_table(block) -> Tuple[Optional[LaneTable], Optional[str]]:
+def lane_table(block, at: str = "commit"
+               ) -> Tuple[Optional[LaneTable], Optional[str]]:
     """(the block's LaneTable, None), or (None, why there is none):
     "no_view" the block is not a BlockView, "no_native" the extractor is
     the Python mirror (byte-by-byte hashing: never a fast path),
     "collision" two keys of the block share a hash, "count" the span
-    table did not extract.  Opened once a block and kept on the view."""
+    table did not extract.  Opened once a block and kept on the view,
+    with the `at` of the call that opened it (`LaneTable.opened_at`)."""
     if not isinstance(block, BlockView):
         return None, "no_view"
     if block._table is not None:
@@ -366,8 +373,26 @@ def lane_table(block) -> Tuple[Optional[LaneTable], Optional[str]]:
         return None, "count"
     if lanes[0]:
         return None, "collision"
-    block._table = LaneTable(block.raw, lanes)
+    block._table = LaneTable(block.raw, lanes, at)
     return block._table, None
+
+
+def prepare_lanes(block, at: str) -> Optional[LaneTable]:
+    """The block's lane table, opened ahead of the commit by a caller
+    with time to spare before it (the validator, while the device
+    verifies: `at`): the extractor's pass over every envelope, which
+    runs without the interpreter lock.  The table's strings (`txids`,
+    `key_strs`) are NOT decoded here: building them holds the lock for
+    milliseconds, and a thread that wakes meanwhile to stamp the end of
+    a device program (bccsp/dispatch_account.py) would stamp it late;
+    their first reader in the commit decodes them, as before.  -> the
+    table where this call opened it; None, and no work, for a block
+    whose table is open already or that has none (`lane_table`'s
+    reasons: the commit decodes its envelopes, as it decides by
+    itself)."""
+    if not isinstance(block, BlockView) or block._table is not None:
+        return None
+    return lane_table(block, at)[0]
 
 
 def lane_txids(block) -> List[Optional[str]]:

@@ -86,3 +86,20 @@ def handed_over():
             return now
         return {p: n - before[p] for p, n in now.items() if n != before[p]}
     return read
+
+
+@pytest.fixture
+def lanes_opened():
+    """lanes_opened() -> `ledger_lane_table_opened_total` on channel `ch`
+    by `at`; lanes_opened(before) -> what moved since `before`."""
+    from fabric_tpu.ops_plane import registry
+
+    def read(before=None):
+        c = registry.counter("ledger_lane_table_opened_total")
+        now = {at: int(c.value(channel="ch", at=at))
+               for at in ("validator_wait", "commit")}
+        if before is None:
+            return now
+        return {at: n - before[at] for at, n in now.items()
+                if n != before[at]}
+    return read

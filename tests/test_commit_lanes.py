@@ -53,10 +53,14 @@ def sw_provider():
 
 
 @pytest.fixture(scope="module")
-def ids():
+def org():
+    return DevOrg("Org1")
+
+
+@pytest.fixture(scope="module")
+def ids(org):
     """(creator, endorsers): MVCC and the readers verify no signature, so
     one org's identities sign everything."""
-    org = DevOrg("Org1")
     return org.new_identity("client"), [org.new_identity("e1"),
                                         org.new_identity("e2")]
 
@@ -178,7 +182,7 @@ def spans_of(reason=None):
             "envelopes": WALKS["envelopes"]}
 
 
-def through_three_walks(stream, config=LedgerConfig):
+def through_three_walks(stream, config=LedgerConfig, prepared=False):
     """Feed `stream` — [(envelopes, gate codes | None[, reason])] — to
     three ledgers: two get BlockViews (the lane source: one walked as
     arrays, one by the Python walk, forced through the rule's seam), one
@@ -187,8 +191,18 @@ def through_three_walks(stream, config=LedgerConfig):
     compared; after it, the ledgers.  A block that comes with a `reason`
     is one `lane_source_of` must refuse for it (a still-VALID range
     query: "range"): there the views' envelopes are walked against the
-    plain block's, and the span says why.
+    plain block's, and the span says why.  `prepared`: every view's
+    table is opened ahead, by the call the validator makes in its wait
+    (`wire.prepare_lanes`), and the walks and commits find it open.
     -> (final codes per block, tally per block)."""
+    def view_of_raw(raw, gate=None):
+        view = view_of(raw, gate)
+        if prepared:
+            opened = wire.prepare_lanes(view, at="validator_wait")
+            assert opened is view._table
+            assert wire.prepare_lanes(view, at="again") is None
+        return view
+
     ledgers = {name: KVLedger("ch", config()) for name in WALKS}
     db = ledgers["arrays"].statedb
     prev, codes, tallies = GENESIS, [], []
@@ -196,15 +210,19 @@ def through_three_walks(stream, config=LedgerConfig):
         reason = reason[0] if reason else None
         raw, nxt = raw_block(number, prev, envelopes)
         gate = bytes(gate if gate is not None else [V] * len(envelopes))
-        view = view_of(raw, gate)
+        view = view_of_raw(raw, gate)
         table, why = mvcc.lane_source_of(view, TxFlags.from_bytes(gate))
         assert why == reason and (table is None) == (reason is not None)
+        # a table that speaks for no still-VALID range is open all the
+        # same, and says who opened it
+        assert view._table.opened_at == (
+            "validator_wait" if prepared else "commit")
         got = {"envelopes": walked(db, number,
                                    _safe_envelopes(plain_of(raw)), gate)}
         if table is None:
             got["arrays"] = walked(db, number, _safe_envelopes(view), gate)
             got["python"] = walked(db, number,
-                                   _safe_envelopes(view_of(raw)), gate,
+                                   _safe_envelopes(view_of_raw(raw)), gate,
                                    python=True)
             forms = [("python", None)] * 3
         else:
@@ -226,7 +244,7 @@ def through_three_walks(stream, config=LedgerConfig):
             assert (view._data is None) == all(
                 st == wire.LANE_OK for st in table.status.tolist())
         with the_python_walk():
-            ledgers["python"].commit(view_of(raw, gate))
+            ledgers["python"].commit(view_of_raw(raw, gate))
         ledgers["envelopes"].commit(plain_of(raw, gate))
         for walk, attrs in spans_of(reason).items():
             assert mvcc_span(ledgers[walk]) == attrs
@@ -588,9 +606,11 @@ def case_doomed_then_rewritten(ids):
     case_gate_losers_write_nothing, case_adjacent_block_chains,
     case_cross_block_range_phantom, case_doomed_then_rewritten],
     ids=lambda c: c.__name__[5:])
-def test_the_three_walks_give_the_same_answers(ids, case):
+@pytest.mark.parametrize("prepared", [False, True],
+                         ids=["opened_in_commit", "opened_ahead"])
+def test_the_three_walks_give_the_same_answers(ids, case, prepared):
     stream, want_codes, want_tallies = case(ids)
-    codes, tallies = through_three_walks(stream)
+    codes, tallies = through_three_walks(stream, prepared=prepared)
     assert codes == want_codes
     if want_tallies is not None:
         assert tallies == want_tallies
@@ -979,6 +999,80 @@ def test_a_demoted_block_commits_through_the_envelope_source(
                                 ("python", "no_view"): 8 + len(gate)})
     want[("python", reason)] += len(gate)
     assert walk_counts(channel, reason, "no_view") == want
+
+
+# -- the table opened ahead: the validator's wait for the device --------------
+
+
+def validated_committer(org, prepare=True):
+    """A committer over channel "ch" whose validator knows the one org
+    that signs everything here.  `prepare=False`: the validator's step in
+    its wait taken out — the program as it was before that step existed,
+    whose answers a block must keep."""
+    from fabric_tpu.committer.committer import Committer
+    from fabric_tpu.committer.txvalidator import PolicyRegistry, TxValidator
+    from fabric_tpu.msp import CachedMSP
+    from fabric_tpu.policy import parse_policy
+    validator = TxValidator(
+        "ch", {"Org1": CachedMSP(org.msp())},
+        init_factories(FactoryOpts(default="SW")),
+        PolicyRegistry(parse_policy("OR('Org1.member')")))
+    if not prepare:
+        validator._prepare_lanes = lambda block, wait: None
+    return Committer(KVLedger("ch", LedgerConfig()), validator)
+
+
+@pytest.mark.parametrize("demotion,source,opened", [
+    (demotion_plain_block, "envelopes", None),
+    (demotion_no_native, "envelopes", None),
+    (demotion_collision, "envelopes", None),
+    # the table is open, and the rule refuses it afterwards as before
+    (demotion_valid_range, "envelopes", "validator_wait"),
+    # the gate refuses a tx whose header does not decode: not VALID, so
+    # its status demotes nothing and the lanes supply the block
+    (demotion_unknown, "lanes", "validator_wait")],
+    ids=lambda d: d.__name__[9:] if callable(d) else None)
+def test_a_validated_block_keeps_its_answers_where_the_wait_prepares_nothing(
+        org, ids, monkeypatch, lanes_opened, demotion, source, opened):
+    """The blocks `wire.prepare_lanes` returns without work for (no view,
+    no native extractor — whose Python mirror must not run — a collision)
+    and the ones whose table opens and is refused later (a VALID range)
+    or speaks for all but one tx (UNKNOWN): validated and committed with
+    the flags, commit hash, state, history rows and `ledger.mvcc` source
+    and reason of a program whose validator prepares nothing."""
+    envelopes, _gate, _parse, reason = demotion(ids, monkeypatch)
+    monkeypatch.undo()
+    subject, oracle = (validated_committer(org, prepare)
+                       for prepare in (True, False))
+    raw0, prev = raw_block(0, GENESIS, seed(ids))
+    raw, _ = raw_block(1, prev, envelopes)
+    mirror = []
+    results = {}
+    for name, committer in (("subject", subject), ("oracle", oracle)):
+        committer.store_block(view_of(raw0))
+        before = lanes_opened()
+        with monkeypatch.context() as patch:
+            patch.setattr(wire, "rwset_lanes_py",
+                          lambda *a: mirror.append(a) or None)
+            parse = demotion(ids, patch)[2]     # its patches: this commit's
+            results[name] = committer.store_block(parse(raw, None))
+        at = opened if name == "subject" else opened and "commit"
+        assert lanes_opened(before) == ({at: len(envelopes)} if at else {})
+    assert not mirror
+    span = {"source": source, "walk": "python" if reason != "unknown"
+            else "arrays"}
+    if source == "envelopes":
+        span["reason"] = reason
+    for committer in (subject, oracle):
+        assert mvcc_span(committer.ledger) == span
+    assert (results["subject"].validation.flags.codes()
+            == results["oracle"].validation.flags.codes())
+    assert (results["subject"].final_flags.codes()
+            == results["oracle"].final_flags.codes())
+    assert V in results["subject"].final_flags.codes()
+    assert subject.ledger.commit_hash == oracle.ledger.commit_hash
+    assert state_of(subject.ledger) == state_of(oracle.ledger)
+    assert history_of(subject.ledger) == history_of(oracle.ledger)
 
 
 @pytest.mark.parametrize("reason", ["no_native"])

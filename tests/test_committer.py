@@ -358,6 +358,18 @@ def test_fast_collect_late_error_parity_and_deep_nesting(world):
 FUZZ_TXS = 400
 
 
+class _NoDigest:
+    """Hide `digest` so the validator takes the classic
+    C-walker + Python-tail path."""
+    def __init__(self, mod):
+        self._mod = mod
+
+    def __getattr__(self, name):
+        if name == "digest":
+            raise AttributeError(name)
+        return getattr(self._mod, name)
+
+
 def _fuzz_kit(world, handed_over):
     """(corpus, run, dup_raw) of the state-fork fuzz: randomized
     adversarial corpora — intra-block txid collisions, carry collisions
@@ -424,17 +436,6 @@ def _fuzz_kit(world, handed_over):
                 raw = bytes(mut)
             raws.append(raw)
         return raws
-
-    class _NoDigest:
-        """Hide `digest` so the validator takes the classic
-        C-walker + Python-tail path."""
-        def __init__(self, mod):
-            self._mod = mod
-
-        def __getattr__(self, name):
-            if name == "digest":
-                raise AttributeError(name)
-            return getattr(self._mod, name)
 
     def run(mode, b1raws, b2raws, dup_raw):
         wiring = {}
@@ -559,3 +560,111 @@ def test_pipelined_inflight_duplicate_txid(world):
     # (catch-up/crash-recovery semantics prune entries >= the number)
     r1b = validator.validate(build.new_block(h, prev, [env]))
     assert r1b.flags.codes() == [int(ValidationCode.VALID)]
+
+
+# -- the block's lane table, opened while the validator waits -----------------
+
+
+def _stored_with_spans(committer, block):
+    """store_block(block) under the tracer -> (result, the spans of its
+    trace by name)."""
+    from fabric_tpu.ops_plane import tracing
+    t = tracing.tracer
+    was = t.enabled
+    t.configure({"enabled": True})
+    try:
+        result = committer.store_block(block)
+        spans = t.recorder.get(result.trace.trace_id)["spans"]
+    finally:
+        t.enabled = was
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s)
+    return result, by_name
+
+
+@pytest.mark.parametrize("tail", ["deep", "classic", "python"])
+def test_the_wait_for_the_device_opens_the_lane_table_for_the_commit(
+        world, tail, monkeypatch, lanes_opened):
+    """On either tail the validator, once the block's items are enqueued,
+    opens the view's lane table (not its strings: they hold the
+    interpreter lock, and are the commit's); the commit that follows
+    extracts nothing again, and the counter says where."""
+    from fabric_tpu.committer import txvalidator as tv
+    from fabric_tpu.protocol import wire
+    org1, org2, committer = world
+    if tail == "python":
+        committer.validator.force_python_collect = True
+    elif tail == "classic":
+        monkeypatch.setattr(tv, "_fastcollect", _NoDigest(tv._fastcollect))
+    envs = [make_tx(org1, org2, rw(writes=[KVWrite(f"k{i}", b"v")]))
+            for i in range(6)]
+    view = wire.parse_block(next_block(committer, envs).serialize())
+    assert isinstance(view, wire.BlockView) and view._table is None
+    extracted = wire._fastparse.stats()["rw_accept"]
+
+    # validate alone leaves the table open, its strings undecoded
+    state = committer.validator.validate_begin(view)
+    assert bool(state.get("deep")) == (tail == "deep")
+    assert view._table is None            # the collect asks for no table
+    committer.validator.validate_finish(state)
+    table = view._table
+    assert table.opened_at == "validator_wait" and table.n_tx == 6
+    assert table._txids is None and table._key_strs is None
+    assert wire._fastparse.stats()["rw_accept"] == extracted + 1
+
+    # ... and the whole path, on a fresh view of the same bytes
+    view = wire.parse_block(bytes(view.raw))
+    before = lanes_opened()
+    result, spans = _stored_with_spans(committer, view)
+    assert result.final_flags.valid_count() == 6
+    assert view._table.opened_at == "validator_wait"
+    assert wire._fastparse.stats()["rw_accept"] == extracted + 2
+    assert lanes_opened(before) == {"validator_wait": 6}
+    (wait,), (prepare,) = (spans["validator.dispatch_wait"],
+                           spans["validator.lanes_prepare"])
+    assert prepare["parent_id"] == wait["span_id"]
+    assert prepare["attributes"] == {"block": int(view.header.number),
+                                     "txs": 6}
+    assert wait["start"] <= prepare["start"]
+    assert (prepare["start"] + prepare["duration_s"]
+            <= wait["start"] + wait["duration_s"])
+    (mvcc,) = spans["ledger.mvcc"]
+    assert mvcc["attributes"] == {"source": "lanes", "walk": "arrays"}
+    assert mvcc["start"] >= wait["start"] + wait["duration_s"]
+
+
+@pytest.mark.parametrize("form", ["plain_block", "open_already",
+                                  "preparation_raises"])
+def test_the_wait_prepares_nothing_where_there_is_nothing_to_prepare(
+        world, form, monkeypatch, lanes_opened):
+    """A Block that is no view has no table; a view whose table somebody
+    opened first keeps it: no span, no second extraction, and the counter
+    names the first opener.  A preparation that raises is the validator's
+    to log, not the block's end: the commit opens the table itself."""
+    from fabric_tpu.protocol import wire
+    org1, org2, committer = world
+    envs = [make_tx(org1, org2, rw(writes=[KVWrite(f"k{i}", b"v")]))
+            for i in range(3)]
+    block = next_block(committer, envs)
+    if form != "plain_block":
+        block = wire.parse_block(block.serialize())
+    if form == "open_already":
+        table, _ = wire.lane_table(block)
+        assert table.opened_at == "commit"
+    if form == "preparation_raises":
+        def boom(block, at):
+            raise MemoryError("no room for the arena")
+        monkeypatch.setattr(wire, "prepare_lanes", boom)
+    extracted = wire._fastparse.stats()["rw_accept"]
+    before = lanes_opened()
+    result, spans = _stored_with_spans(committer, block)
+    monkeypatch.undo()
+    assert result.final_flags.valid_count() == 3
+    assert "validator.lanes_prepare" not in spans
+    assert len(spans["validator.dispatch_wait"]) == 1
+    assert wire._fastparse.stats()["rw_accept"] - extracted == (
+        form == "preparation_raises")
+    assert lanes_opened(before) == (
+        {} if form == "plain_block" else {"commit": 3})
+    assert wire.prepare_lanes(block, at="validator_wait") is None

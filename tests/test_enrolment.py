@@ -352,7 +352,8 @@ def test_both_tails_refuse_revoked_and_forged_creators(unseen_block,
     spans = []
     monkeypatch.setattr(
         tracing.tracer, "record_span",
-        lambda name, t0, t1, attributes=None, parent=None:
+        lambda name, t0, t1, attributes=None, parent=None,
+        context=None:
         spans.append((name, t1 - t0, attributes)))
     block = Block(BlockHeader(3, b"p", b"d"), list(raws), BlockMetadata())
     state = v.validate_begin(block)
@@ -487,7 +488,8 @@ def run_tail(tail, provider, big_block, monkeypatch):
     spans = {}
     monkeypatch.setattr(
         tracing.tracer, "record_span",
-        lambda name, t0, t1, attributes=None, parent=None:
+        lambda name, t0, t1, attributes=None, parent=None,
+        context=None:
         spans.__setitem__(name, attributes))
     state = v.validate_begin(
         Block(BlockHeader(7, b"p", b"d"), list(raws), BlockMetadata()))

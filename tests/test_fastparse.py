@@ -22,6 +22,7 @@ import gc
 import random
 import struct
 import sys
+import time
 
 import pytest
 
@@ -466,6 +467,71 @@ def test_rwset_lanes_native_matches_mirror():
             n_accept += 1
             assert bytes(memoryview(narena)) == bytes(marena)
     assert n_accept > 10 and n_collide > 0  # corpus exercised both paths
+
+
+def _lanes_plain(lanes):
+    return lanes if lanes is None or lanes[5] is None else (
+        lanes[:5] + (bytes(memoryview(lanes[5])),))
+
+
+def test_rwset_lanes_yields_the_interpreter_and_keeps_its_scratch_whole():
+    """The extractor's walk runs without the interpreter lock (the
+    validator opens a block's lanes while another thread waits for the
+    device): a thread that only counts gets to run while one call walks a
+    large block, and calls from four threads at once — every kind of
+    ending: lanes, a collision, a rejected span table — give what the
+    same calls give alone (the scratch is one caller's at a time)."""
+    import threading
+    org1, org2 = _org_world()
+    envs = _lane_envs(org1, org2)
+    corpus = [c for seed in (11, 22) for c in lane_fuzz_corpus(seed,
+                                                               envs=envs)]
+    alone = [_lanes_plain(wire._fastparse.rwset_lanes(b, sp))
+             for b, sp in corpus]
+    assert {None, 0, 1} == {a if a is None else a[0] for a in alone}
+    got = {}
+
+    def worker(k):
+        order = list(range(len(corpus)))
+        random.Random(k).shuffle(order)
+        got[k] = {i: _lanes_plain(wire._fastparse.rwset_lanes(*corpus[i]))
+                  for _ in range(5) for i in order}
+    threads = [threading.Thread(target=worker, args=(k,)) for k in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+        assert not t.is_alive()
+    for k in range(4):
+        assert [got[k][i] for i in range(len(corpus))] == alone
+
+    # one long walk (collision-free envelopes, many times over) beside a
+    # thread that needs the interpreter to count: it counts through the
+    # walk about as fast as through a sleep of the same length.  Under a
+    # walk that kept the interpreter it would get one hand-over at most,
+    # a switch interval (5 ms) of the walk's ~100
+    base, spans = _span_table(envs[:5] * 8000)
+    ticks, done = [0], threading.Event()
+
+    def count():
+        while not done.is_set():
+            ticks[0] += 1
+    counter = threading.Thread(target=count)
+    counter.start()
+    try:
+        time.sleep(0.05)
+        t0, before = time.perf_counter(), ticks[0]
+        lanes = wire._fastparse.rwset_lanes(base, spans)
+        walk_s, during = time.perf_counter() - t0, ticks[0] - before
+        before = ticks[0]
+        time.sleep(walk_s)
+        asleep = ticks[0] - before
+    finally:
+        done.set()
+        counter.join(timeout=60)
+    assert not counter.is_alive()
+    assert lanes[0] == 0 and lanes[1] == 40000
+    assert walk_s > 0.03 and during > 0.4 * asleep, (walk_s, during, asleep)
 
 
 def _arena_strings(mod, base, lanes):

@@ -124,6 +124,31 @@ def test_disabled_tracer_is_noop_everywhere():
     assert t.recorder.list() == {"recent": [], "slowest": []}
 
 
+def test_a_reserved_span_parents_what_is_recorded_before_it():
+    """`reserve_span` mints the context of a span whose end is not known
+    yet; spans recorded inside it name it as their parent, and it is
+    recorded afterwards under the ambient one, with the reserved id."""
+    t = Tracer(FlightRecorder())
+    assert t.reserve_span() is None                 # off: nothing to reserve
+    t.enabled = True
+    assert t.reserve_span() is None                 # no ambient trace
+    with t.start_span("root") as root:
+        wait = t.reserve_span()
+        t.record_span("inner", 1.0, 2.0, parent=wait)
+        t.record_span("wait", 0.5, 3.0, attributes={"n": 1}, context=wait)
+        t.record_span("plain", 3.0, 4.0, context=None)
+    spans = {s["name"]: s for s in
+             t.recorder.get(root.context.trace_id)["spans"]}
+    assert spans["wait"]["span_id"] == wait.span_id
+    assert spans["wait"]["parent_id"] == root.context.span_id
+    assert spans["inner"]["parent_id"] == wait.span_id
+    assert spans["plain"]["parent_id"] == root.context.span_id
+    assert spans["wait"]["attributes"] == {"n": 1}
+    t.sample_rate = 0.0
+    with t.start_span("unsampled"):
+        assert t.reserve_span() is None
+
+
 def test_chrome_export_shape_and_late_span_merge():
     t = Tracer(FlightRecorder())
     t.enabled = True

@@ -542,7 +542,8 @@ def _record_spans(monkeypatch):
     spans = []
     monkeypatch.setattr(
         tracing.tracer, "record_span",
-        lambda name, start, end, attributes=None, parent=None:
+        lambda name, start, end, attributes=None, parent=None,
+        context=None:
         spans.append((name, attributes or {})))
     return spans
 

@@ -560,11 +560,14 @@ def test_a_bump_run_exposes_what_the_parent_did_and_the_new_series(
 # them) and PR 43's `ledger_mvcc_walk_total` (the form the serial MVCC
 # walk took, in transactions) and PR 46's `state_index_update_total` and
 # `state_index_changed_keys_total` (how each shard's ordered key list
-# followed a batch, and how many keys came or went)
+# followed a batch, and how many keys came or went) and PR 47's
+# `ledger_lane_table_opened_total` (where a block's lane table was first
+# opened, in transactions)
 PARENT_FAMILIES = {
     "commit_graph_apply_batch_size",
     "committed_blocks_total", "committed_txs_total",
     "ledger_commit_source_total", "ledger_height",
+    "ledger_lane_table_opened_total",
     "ledger_mvcc_conflicts_total", "ledger_mvcc_reads_total",
     "ledger_mvcc_walk_total",
     "ledger_state_writes_total", "ledger_tx_total",
