@@ -16,6 +16,7 @@ from fabric_tpu.chaincode.runtime import ChaincodeDefinition, Contract
 from fabric_tpu.chaincode.stub import ChaincodeStub, SimulationError
 from fabric_tpu.ledger.statedb import StateDB
 from fabric_tpu.policy import SignaturePolicy
+from fabric_tpu.privdata.collection import chaincode_of
 from fabric_tpu.utils import serde
 
 LIFECYCLE_NS = "_lifecycle"
@@ -205,6 +206,11 @@ class LifecyclePolicyProvider:
         self.system[namespace] = policy
 
     def policy_for(self, namespace: str) -> Optional[SignaturePolicy]:
+        if namespace not in self.system:
+            # a collection's hashed namespace `ns$collection` without a
+            # policy of its own (`set_policy`) is governed by its
+            # chaincode's
+            namespace = chaincode_of(namespace)
         if namespace in self.system:
             return self.system[namespace]
         vv = self.db.get(LIFECYCLE_NS, _def_key(namespace))

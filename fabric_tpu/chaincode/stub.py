@@ -76,14 +76,18 @@ class ChaincodeStub:
 
     def __init__(self, db: StateDB, namespace: str,
                  channel_id: str = "", txid: str = "",
-                 creator: bytes = b"", registry=None, pvt_store=None):
+                 creator: bytes = b"", registry=None, pvt_store=None,
+                 collections=None, transient=None, peer_mspid: str = ""):
         self._db = db
         self._ns = namespace
         self.channel_id = channel_id
         self.txid = txid
         self.creator = creator
+        self.peer_mspid = peer_mspid  # the endorsing peer's org (GetMSPID)
         self._registry = registry  # for cc2cc invoke
         self._pvt_store = pvt_store  # local PvtDataStore for private reads
+        self._collections = collections  # CollectionRegistry: member-only
+        self._transient = dict(transient or {})
         self._pvt_writes: Dict[tuple, Dict[str, object]] = {}
         self._builders: Dict[str, _NsBuilder] = {}
         self._event: bytes = b""
@@ -216,15 +220,46 @@ class ChaincodeStub:
         return None if vv is None else sbe.decode_policy(vv.value)
 
     # -- private data (collections) -----------------------------------------
-    # Reference: the chaincode shim's GetPrivateData/PutPrivateData; the
-    # public rwset carries only hash(key)->hash(value) under the hashed
-    # namespace ns$collection, the cleartext goes to the transient store
-    # (gossip/privdata distribution model, VERDICT.md missing #2).
+    # The shim's GetPrivateData / PutPrivateData / DelPrivateData /
+    # GetPrivateDataHash and GetTransient.  The public rw-set carries only
+    # hash(key) -> hash(value) under the hashed namespace `ns$collection`;
+    # the cleartext is staged beside it (`private_sets`) for the
+    # endorser's transient store and its push to the member peers.  Every
+    # private read is recorded against the hashed namespace, so a member
+    # and a non-member validate it alike.  A collection's member-only
+    # flags are checked here, at simulation, against the creator's org
+    # (where the stub was given the channel's collection registry);
+    # the hashed read needs no membership.
+
+    def get_transient(self) -> Dict[str, bytes]:
+        """The proposal's transient map: inputs that reach this
+        simulation and no rw-set, response or envelope."""
+        return dict(self._transient)
+
+    def creator_mspid(self) -> str:
+        """The submitting client's org (ClientIdentity.GetMSPID)."""
+        from fabric_tpu.utils import serde
+        try:
+            return serde.decode(self.creator)["mspid"]
+        except Exception:
+            raise SimulationError("creator identity does not decode")
+
+    def _check_member(self, collection: str, flag: str, what: str) -> None:
+        cfg = (self._collections.get(self._ns, collection)
+               if self._collections is not None else None)
+        if cfg is None or not getattr(cfg, flag):
+            return
+        org = self.creator_mspid()
+        if not cfg.is_member(org):
+            raise SimulationError(
+                f"collection {collection!r} is member-only for {what}: "
+                f"client org {org!r} is not a member")
 
     def put_private_data(self, collection: str, key: str, value: bytes) -> None:
         self._check_open()
         if not key:
             raise SimulationError("empty key")
+        self._check_member(collection, "member_only_write", "writes")
         from fabric_tpu.privdata.collection import (hash_key, hash_value,
                                                     pvt_namespace)
         hns = pvt_namespace(self._ns, collection)
@@ -234,30 +269,47 @@ class ChaincodeStub:
 
     def del_private_data(self, collection: str, key: str) -> None:
         self._check_open()
+        self._check_member(collection, "member_only_write", "writes")
         from fabric_tpu.privdata.collection import hash_key, pvt_namespace
         hns = pvt_namespace(self._ns, collection)
         self._b(hns).writes[hash_key(key)] = KVWrite(hash_key(key),
                                                      is_delete=True)
         self._pvt_writes.setdefault((self._ns, collection), {})[key] = None
 
-    def get_private_data(self, collection: str, key: str) -> Optional[bytes]:
-        # Cleartext from the local pvt store; the MVCC-relevant read is
-        # recorded against the HASHED namespace so every peer (member or
-        # not) validates it identically.
-        self._check_open()
+    def _read_hashed(self, collection: str, key: str):
+        """Record the read of `key`'s hashed entry; -> its VersionedValue
+        (the value is the cleartext's hash) or None."""
         from fabric_tpu.privdata.collection import hash_key, pvt_namespace
-        staged = self._pvt_writes.get((self._ns, collection), {})
-        if key in staged:
-            return staged[key]
         hns = pvt_namespace(self._ns, collection)
         hk = hash_key(key)
         b = self._b(hns)
         vv = self._db.get(hns, hk)
         if hk not in b.reads:
             b.reads[hk] = KVRead(hk, None if vv is None else vv.version)
+        return vv
+
+    def get_private_data(self, collection: str, key: str) -> Optional[bytes]:
+        # Cleartext from the local pvt store; the MVCC-relevant read is
+        # recorded against the HASHED namespace so every peer (member or
+        # not) validates it identically.
+        self._check_open()
+        self._check_member(collection, "member_only_read", "reads")
+        staged = self._pvt_writes.get((self._ns, collection), {})
+        if key in staged:
+            return staged[key]
+        self._read_hashed(collection, key)
         if self._pvt_store is None:
             return None
         return self._pvt_store.get(self._ns, collection, key)
+
+    def get_private_data_hash(self, collection: str,
+                              key: str) -> Optional[bytes]:
+        """SHA-256 of the committed private value, off the hashed state:
+        what a client of an org that is no member of the collection may
+        read.  Records the same hashed read `get_private_data` does."""
+        self._check_open()
+        vv = self._read_hashed(collection, key)
+        return None if vv is None else vv.value
 
     def private_sets(self) -> Dict[tuple, Dict[str, object]]:
         # {(namespace, collection): {key: value|None}}

@@ -41,6 +41,7 @@ from fabric_tpu.bccsp.provider import dispatch_site
 from fabric_tpu.msp import Identity
 from fabric_tpu.ops_plane import tracing
 from fabric_tpu.policy import PolicyEvaluator, SignaturePolicy, SignedData
+from fabric_tpu.privdata.collection import chaincode_of
 from fabric_tpu.protocol import Block, wire
 from fabric_tpu.protocol.txflags import TxFlags, ValidationCode
 from fabric_tpu.protocol.types import META_TXFLAGS
@@ -84,7 +85,11 @@ class PolicyRegistry:
     """namespace -> endorsement policy (the _lifecycle/plugindispatcher
     lookup surface, dispatcher.go:102).  Falls back to a default policy,
     like a chaincode with no explicit endorsement policy falls back to
-    the channel's majority-endorsement default."""
+    the channel's majority-endorsement default.  A collection's hashed
+    namespace `ns$collection` answers with the collection's own policy
+    where one was set for it, else with its chaincode's (v2.0: a
+    collection-level policy takes the chaincode's place for the
+    collection's keys)."""
 
     def __init__(self, default: Optional[SignaturePolicy] = None):
         self._policies: Dict[str, SignaturePolicy] = {}
@@ -94,7 +99,10 @@ class PolicyRegistry:
         self._policies[namespace] = policy
 
     def policy_for(self, namespace: str) -> Optional[SignaturePolicy]:
-        return self._policies.get(namespace, self._default)
+        pol = self._policies.get(namespace)
+        if pol is None:
+            pol = self._policies.get(chaincode_of(namespace), self._default)
+        return pol
 
 
 @dataclass(slots=True)

@@ -49,7 +49,7 @@ class Endorser:
                  signer: SigningIdentity,
                  proposal_acl: Optional[SignaturePolicy] = None,
                  transient_store=None, pvt_store=None, distribute=None,
-                 ledger_height=None,
+                 ledger_height=None, collections=None,
                  endorsement_plugin: str = "DefaultEndorsement",
                  auth_filters=("ExpirationCheck",), acl=None):
         self.channel_id = channel_id
@@ -76,6 +76,9 @@ class Endorser:
         self.transient_store = transient_store
         self.pvt_store = pvt_store
         self.distribute = distribute      # callable(txid, pvt_sets) -> None
+        # the channel's CollectionRegistry: the shim checks a collection's
+        # member-only flags against the creator's org
+        self.collections = collections
         self.ledger_height = ledger_height or (lambda: 0)
 
     def process_proposal(self, sp: SignedProposal) -> ProposalResponse:
@@ -165,7 +168,10 @@ class Endorser:
                              channel_id=self.channel_id,
                              txid=txid,
                              creator=creator, registry=self.registry,
-                             pvt_store=self.pvt_store)
+                             pvt_store=self.pvt_store,
+                             collections=self.collections,
+                             transient=prop.transient,
+                             peer_mspid=self.signer.mspid)
         status = "500"
         try:
             _, payload = self.registry.execute(

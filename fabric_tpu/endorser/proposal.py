@@ -10,8 +10,8 @@ over the same ProposalResponsePayload).
 
 from __future__ import annotations
 
-from dataclasses import dataclass
-from typing import List, Sequence, Tuple
+from dataclasses import dataclass, field
+from typing import Dict, List, Sequence, Tuple
 
 from fabric_tpu.protocol import Envelope, Transaction, TransactionAction
 from fabric_tpu.protocol.build import (
@@ -32,25 +32,34 @@ from fabric_tpu.utils import serde
 
 @dataclass(frozen=True)
 class Proposal:
-    """peer.Proposal: header + invocation spec."""
+    """peer.Proposal: header + invocation spec, and the transient map
+    (ChaincodeProposalPayload.TransientMap): inputs the client hands the
+    endorser's simulation and nobody else.  It travels in the signed
+    proposal only — `hash()` leaves it out, as upstream's proposal hash
+    does, and nothing of it enters the response or the envelope."""
     header: Header
     chaincode_id: str
     fn: str
     args: Tuple[bytes, ...]
+    transient: Dict[str, bytes] = field(default_factory=dict)
 
     def to_bytes(self) -> bytes:
-        return serde.encode({
+        d = {
             "header": self.header.to_dict(),
             "chaincode_id": self.chaincode_id,
             "fn": self.fn,
             "args": list(self.args),
-        })
+        }
+        if self.transient:       # absent, a proposal's bytes are as before
+            d["transient"] = dict(self.transient)
+        return serde.encode(d)
 
     @staticmethod
     def from_bytes(raw: bytes) -> "Proposal":
         d = serde.decode(raw)
         return Proposal(Header.from_dict(d["header"]), d["chaincode_id"],
-                        d["fn"], tuple(d["args"]))
+                        d["fn"], tuple(d["args"]),
+                        dict(d.get("transient") or {}))
 
     def hash(self) -> bytes:
         ch = self.header.channel_header
@@ -82,11 +91,13 @@ class ResponseMismatchError(Exception):
 
 def signed_proposal(channel_id: str, chaincode_id: str, fn: str,
                     args: Sequence[bytes], signer,
-                    nonce: bytes = None) -> SignedProposal:
+                    nonce: bytes = None,
+                    transient: Dict[str, bytes] = None) -> SignedProposal:
     """Client step 1: build + sign a proposal (CreateChaincodeProposal)."""
     nonce = new_nonce() if nonce is None else nonce
     header = make_header(TX_ENDORSER, channel_id, signer.serialize(), nonce)
-    prop = Proposal(header, chaincode_id, fn, tuple(args))
+    prop = Proposal(header, chaincode_id, fn, tuple(args),
+                    dict(transient or {}))
     raw = prop.to_bytes()
     return SignedProposal(raw, signer.sign(raw))
 

@@ -31,6 +31,7 @@ from fabric_tpu.protocol.types import META_COMMIT_HASH, META_TXFLAGS
 from .blkstorage import BlockStore
 from .historydb import HistoryDB
 from .mvcc import MvccTally, lane_source_of, validate_and_prepare_batch
+from .pvtexpiry import expire_and_schedule
 from .statedb import StateDB
 
 logger = logging.getLogger("fabric_tpu.ledger")
@@ -80,6 +81,10 @@ class LedgerConfig:
     # independently locked + independently flushable shards; 1 = the
     # flat store (differential oracle)
     state_shards: int = 8
+    # {hashed namespace `ns$collection`: block-to-live} of the channel's
+    # collections whose keys expire (`CollectionRegistry.block_to_live`);
+    # empty: the commit has no expiry step
+    pvt_btl: Dict[str, int] = field(default_factory=dict)
 
 
 @dataclass
@@ -90,6 +95,7 @@ class CommitStats:
     block_commit_s: float = 0.0
     state_commit_s: float = 0.0
     history_commit_s: float = 0.0
+    pvt_expiry_s: float = 0.0
     valid_txs: int = 0
     total_txs: int = 0
     # (span name, start, end) of each phase as it really ran, on
@@ -206,6 +212,7 @@ class KVLedger:
         else:
             batch, history = validate_and_prepare_batch(
                 self.statedb, num, envelopes, flags)
+            self._expire_private(batch, num)
             self.statedb.apply_updates(batch, num)
         if self.historydb is not None:
             self.historydb.commit(num, history)  # savepoint-guarded, idempotent
@@ -280,6 +287,25 @@ class KVLedger:
                 "replays took: one observation a block that replayed at "
                 "least one").observe(tally.range_s, channel=ch)
 
+    def _expire_private(self, batch, block_num: int) -> int:
+        """The block's expiry step (ledger/pvtexpiry.py); 0 on a channel
+        whose collections never expire."""
+        if not self.config.pvt_btl:
+            return 0
+        return expire_and_schedule(self.statedb, batch, block_num,
+                                   self.config.pvt_btl)
+
+    def _count_expiry(self, expired: int, seconds: float) -> None:
+        from fabric_tpu.ops_plane import registry
+        registry.counter(
+            "ledger_pvt_expired_keys_total", "hashed keys of private data "
+            "deleted because their collection's block-to-live ended").add(
+                expired, channel=self.channel_id)
+        registry.histogram(
+            "ledger_pvt_expiry_seconds", "seconds a block's expiry step "
+            "took: one observation a block of a channel whose collections "
+            "expire").observe(seconds, channel=self.channel_id)
+
     _APPLY_BUCKETS = (1.0, 4.0, 16.0, 64.0, 256.0, 1024.0, 4096.0,
                       16384.0, float("inf"))
 
@@ -351,6 +377,15 @@ class KVLedger:
         # split the batch by shard before the apply takes shard locks
         batch.preshard(getattr(self.statedb, "n_shards", 1))
         stats.phase("ledger.mvcc", "state_validation_s", t0, mvcc_attrs)
+        if self.config.pvt_btl:
+            # after MVCC, outside the commit hash: the hashed keys whose
+            # block-to-live ends with this block leave with its batch
+            t0 = time.perf_counter()
+            expired = self._expire_private(batch, block.header.number)
+            batch.preshard(getattr(self.statedb, "n_shards", 1))
+            stats.phase("ledger.pvt_expiry", "pvt_expiry_s", t0,
+                        {"expired": expired})
+            self._count_expiry(expired, stats.pvt_expiry_s)
         stats.valid_txs = flags.valid_count()
         # MVCC may have flipped more flags — write the final bitmap back
         block.metadata.items[META_TXFLAGS] = flags.to_bytes()

@@ -38,8 +38,8 @@ from fabric_tpu.chaincode import (
     LifecyclePolicyProvider,
     SimulationError,
 )
-from fabric_tpu.chaincode import (asset_queries, asset_sbe, kvstore,
-                                  smallbank)
+from fabric_tpu.chaincode import (asset_private, asset_queries, asset_sbe,
+                                  kvstore, smallbank)
 from fabric_tpu.chaincode.runtime import FuncContract
 from fabric_tpu.comm.rpc import RpcServer, connect
 from fabric_tpu.committer import Committer, TxValidator
@@ -58,6 +58,7 @@ from fabric_tpu.privdata import (
     Coordinator,
     PvtDataStore,
     TransientStore,
+    pvt_namespace,
 )
 from fabric_tpu.protocol import wire
 from fabric_tpu.protocol.types import Block
@@ -120,7 +121,8 @@ DEV_CONTRACTS = {"asset_demo": _asset_contract,
                  "smallbank": smallbank.contract,
                  "kvstore": kvstore.contract,
                  "asset_sbe": asset_sbe.contract,
-                 "asset_queries": asset_queries.contract}
+                 "asset_queries": asset_queries.contract,
+                 "asset_private": asset_private.contract}
 
 
 class RemoteDeliver:
@@ -331,14 +333,21 @@ class PeerChannel:
         self.snapshot_bootstrap = None   # install info (or None)
         if snap_cfg.get("enabled"):
             self._bootstrap_from_snapshot(ledger_root, snap_cfg)
+        cfg = node.cfg
+        # the channel's collections first: the ledger expires the hashed
+        # keys of those with a block-to-live, recovery replay included
+        self.collections = CollectionRegistry()
+        for col in cfg.get("collections", []):
+            self.collections.define(col["ns"],
+                                    CollectionConfig.from_node_config(col))
         self.ledger = KVLedger(
             self.channel_id,
             LedgerConfig(root=ledger_root,
                          state_shards=int(st_cfg.get("shards", 8)),
                          snapshot_every=int(
-                             st_cfg.get("checkpoint_every", 256))))
+                             st_cfg.get("checkpoint_every", 256)),
+                         pvt_btl=self.collections.block_to_live()))
 
-        cfg = node.cfg
         self.policies = LifecyclePolicyProvider(self.ledger.statedb)
         # the `_lifecycle` namespace endorsement policy: majority of the
         # channel's orgs (the reference's default Application/
@@ -361,6 +370,14 @@ class PeerChannel:
             # META-INF/statedb/couchdb/indexes, created at deploy)
             for field in cc.get("indexes", []):
                 self.ledger.statedb.create_index(cc["name"], field)
+        # a collection's own endorsement policy governs the writes under
+        # its hashed namespace; one without falls to its chaincode's
+        # (`policy_for`)
+        for col in cfg.get("collections", []):
+            if col.get("endorsement_policy"):
+                self.policies.set_policy(
+                    pvt_namespace(col["ns"], col["name"]),
+                    parse_policy(col["endorsement_policy"]))
 
         # per-channel device placement: when the scheduler is live
         # (bccsp_placement) each channel verifies on its own carved
@@ -391,11 +408,6 @@ class PeerChannel:
                                    confighistory=self.confighistory)
 
         # private data plane
-        self.collections = CollectionRegistry()
-        for col in cfg.get("collections", []):
-            self.collections.define(col["ns"], CollectionConfig(
-                col["name"], member_orgs=tuple(col["members"]),
-                block_to_live=int(col.get("btl", 0))))
         self.transient = TransientStore()
         self.pvt_store = PvtDataStore()
         self.coordinator = Coordinator(
@@ -415,7 +427,7 @@ class PeerChannel:
             transient_store=self.transient, pvt_store=self.pvt_store,
             distribute=self._privdata_distribute,
             ledger_height=lambda: self.ledger.height,
-            acl=self.acl)
+            collections=self.collections, acl=self.acl)
 
         self.qscc = Qscc(self.channel_id, self.ledger.blockstore,
                          acl=self.acl)

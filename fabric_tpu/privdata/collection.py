@@ -2,8 +2,11 @@
 
 Reference parity: the collection config package (core/common/privdata,
 collection criteria in gossip/privdata) reduced to the fields this
-framework's planes consume: membership policy (org list), BTL, and the
-required/max peer counts that drive distribution.
+framework's planes consume: membership policy (org list), BTL, the
+required/max peer counts that drive distribution, the member-only
+flags the shim enforces at simulation, and the collection's own
+endorsement policy (v2.0: it takes the chaincode's place for the
+collection's keys).
 """
 
 from __future__ import annotations
@@ -18,6 +21,14 @@ PVT_SEP = "$"
 def pvt_namespace(namespace: str, collection: str) -> str:
     """Public-ledger namespace carrying a collection's write HASHES."""
     return f"{namespace}{PVT_SEP}{collection}"
+
+
+def chaincode_of(namespace: str) -> str:
+    """The chaincode a namespace belongs to: `ns` of a collection's hashed
+    namespace `ns$collection`, the namespace itself otherwise.  Where the
+    collection has no endorsement policy of its own, its chaincode's
+    governs."""
+    return namespace.split(PVT_SEP, 1)[0]
 
 
 def hash_key(key: str) -> str:
@@ -36,22 +47,27 @@ class CollectionConfig:
     block_to_live: int = 0          # 0 = never purge
     required_peer_count: int = 0    # distribution ack threshold
     maximum_peer_count: int = 2
+    member_only_read: bool = False  # a non-member client's read is refused
+    member_only_write: bool = False
+    # signature-policy text (policy/ parse_policy); "" = the chaincode's
+    endorsement_policy: str = ""
 
     def is_member(self, mspid: str) -> bool:
         return mspid in self.member_orgs
 
-    def to_dict(self) -> dict:
-        return {"name": self.name, "member_orgs": list(self.member_orgs),
-                "block_to_live": self.block_to_live,
-                "required_peer_count": self.required_peer_count,
-                "maximum_peer_count": self.maximum_peer_count}
-
     @staticmethod
-    def from_dict(d: dict) -> "CollectionConfig":
-        return CollectionConfig(d["name"], tuple(d["member_orgs"]),
-                                d.get("block_to_live", 0),
-                                d.get("required_peer_count", 0),
-                                d.get("maximum_peer_count", 2))
+    def from_node_config(col: dict) -> "CollectionConfig":
+        """One entry of a node config's `collections`: `name`, `members`,
+        and optionally `btl`, `required_peer_count`, `max_peer_count`,
+        `member_only_read`, `member_only_write`, `endorsement_policy`."""
+        return CollectionConfig(
+            col["name"], member_orgs=tuple(col["members"]),
+            block_to_live=int(col.get("btl", 0)),
+            required_peer_count=int(col.get("required_peer_count", 0)),
+            maximum_peer_count=int(col.get("max_peer_count", 2)),
+            member_only_read=bool(col.get("member_only_read", False)),
+            member_only_write=bool(col.get("member_only_write", False)),
+            endorsement_policy=col.get("endorsement_policy", ""))
 
 
 class CollectionRegistry:
@@ -70,3 +86,9 @@ class CollectionRegistry:
 
     def for_namespace(self, namespace: str) -> List[CollectionConfig]:
         return [c for (ns, _), c in self._configs.items() if ns == namespace]
+
+    def block_to_live(self) -> Dict[str, int]:
+        """{hashed namespace `ns$collection`: BTL} of the collections
+        whose keys expire: what the ledger's expiry step is given."""
+        return {pvt_namespace(ns, c.name): c.block_to_live
+                for (ns, _), c in self._configs.items() if c.block_to_live}

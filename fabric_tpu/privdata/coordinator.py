@@ -12,10 +12,11 @@ reconciliation (reconcile.go), which retries the pull later.
 from __future__ import annotations
 
 import logging
+import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
-from fabric_tpu.ops_plane import tracing
+from fabric_tpu.ops_plane import registry as metrics, tracing
 from fabric_tpu.protocol import Envelope, wire
 from fabric_tpu.protocol.txflags import TxFlags, ValidationCode
 from fabric_tpu.protocol.types import META_TXFLAGS, TxRwSet
@@ -98,6 +99,7 @@ class Coordinator:
         decode = [t for t in valid if t not in known or t in private]
         writes: Dict[Tuple[str, str], Dict[str, object]] = {}
         btl: Dict[Tuple[str, str], int] = {}
+        sets = dict.fromkeys(("resolved", "missing", "not_member"), 0)
         for tx_num in decode:
             try:
                 env = Envelope.deserialize(block.data[tx_num])
@@ -114,14 +116,17 @@ class Coordinator:
                 ns, coll = ns_set.namespace.split(PVT_SEP, 1)
                 cfg = self.registry.get(ns, coll)
                 if cfg is None or not cfg.is_member(self.mspid):
+                    sets["not_member"] += 1
                     continue   # not our collection: hashes only
                 expected = {w.key: (None if w.is_delete else w.value)
                             for w in ns_set.writes}
                 clear = self._resolve(txid, ns, coll, expected)
                 if clear is None:
+                    sets["missing"] += 1
                     self.missing.append(MissingPvtData(
                         block.header.number, txid, ns, coll, dict(expected)))
                     continue
+                sets["resolved"] += 1
                 writes.setdefault((ns, coll), {}).update(clear)
                 self.pvt_store.record_tx(txid, ns, coll, clear,
                                          block_num=block.header.number,
@@ -129,29 +134,67 @@ class Coordinator:
                 btl[(ns, coll)] = cfg.block_to_live
         if writes:
             self.pvt_store.commit(block.header.number, writes, btl)
-        self.pvt_store.process_purges(block.header.number)
+        purged = self.pvt_store.process_purges(block.header.number)
         # the block's VALID txs that decode: nothing to purge them from
         # while the transient store holds nothing
         if len(self.transient):
             self.transient.purge_by_txids(known.values())
+        self._count_block(len(decode), sets, purged)
+
+    def _count_block(self, decoded: int, sets: dict, purged: int) -> None:
+        """One block's private half into the always-on counters.  A
+        channel that never saw a private write-set shows none of them."""
+        if not (decoded or purged or any(sets.values())):
+            return
+        ch = self.ledger.channel_id
+        metrics.counter(
+            "privdata_decoded_txs_total", "envelopes the private half of "
+            "a block's commit decoded: the VALID transactions that write "
+            "under a collection, or that the lane table does not speak "
+            "for").add(decoded, channel=ch)
+        by_result = metrics.counter(
+            "privdata_txs_total", "private write-sets of VALID "
+            "transactions, one a transaction and collection: cleartext "
+            "matched to the on-chain hashes, missing (recorded for "
+            "reconciliation), or of a collection this peer is no member of")
+        for result, n in sets.items():
+            by_result.add(n, channel=ch, result=result)
+        metrics.counter(
+            "privdata_purged_keys_total", "private keys the pvt store "
+            "dropped because their block-to-live ended").add(
+                purged, channel=ch)
+        metrics.gauge(
+            "privdata_transient_entries", "transactions whose private "
+            "write-sets the transient store holds").set(
+                len(self.transient), channel=ch)
 
     def _resolve(self, txid: str, ns: str, coll: str,
                  expected: Dict[str, object]) -> Optional[dict]:
-        """Find cleartext matching the on-chain hashes: transient store,
-        then the network fetcher."""
-        candidates = []
-        for sets in self.transient.get(txid):
-            if (ns, coll) in sets:
-                candidates.append(sets[(ns, coll)])
-        if self.fetch is not None:
-            fetched = self.fetch(txid, ns, coll)
-            if fetched:
-                candidates.append(fetched)
-        for cand in candidates:
-            out = _match_hashes(cand, expected)
-            if out is not None:
-                return out
-        return None
+        """Find cleartext matching the on-chain hashes: the transient
+        store's candidates first, the network fetcher only where none of
+        them explains every hashed write."""
+        t0 = time.perf_counter()
+        try:
+            for sets in self.transient.get(txid):
+                if (ns, coll) in sets:
+                    out = _match_hashes(sets[(ns, coll)], expected)
+                    if out is not None:
+                        return out
+            if self.fetch is not None:
+                metrics.counter(
+                    "privdata_fetch_total", "pulls of a private write-set "
+                    "from the member peers at commit").add(
+                        1, channel=self.ledger.channel_id)
+                fetched = self.fetch(txid, ns, coll)
+                if fetched:
+                    return _match_hashes(fetched, expected)
+            return None
+        finally:
+            metrics.histogram(
+                "privdata_resolve_seconds", "seconds matching one private "
+                "write-set's cleartext to its on-chain hashes took, fetch "
+                "included").observe(time.perf_counter() - t0,
+                                    channel=self.ledger.channel_id)
 
     # -- reconciliation ------------------------------------------------------
 

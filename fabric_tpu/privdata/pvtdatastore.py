@@ -20,8 +20,10 @@ class PvtDataStore:
         self._lock = threading.Lock()
         # (ns, coll, key) -> (value, committed_block)
         self._state: Dict[Tuple[str, str, str], Tuple[bytes, int]] = {}
-        # expiry_block -> list of keys to purge
-        self._expiry: Dict[int, List[Tuple[str, str, str]]] = {}
+        # expiry_block -> [(key, the block whose write expires then)]
+        self._expiry: Dict[int, List[Tuple[Tuple[str, str, str], int]]] = {}
+        # (ns, coll) -> keys held: `has_collection` without a walk
+        self._held: Dict[Tuple[str, str], int] = {}
         # (ns, coll, txid) -> {key: value} — the pull-service index
         self._by_txid: Dict[Tuple[str, str, str], dict] = {}
         # expiry_block -> tx-index entries to drop alongside the state keys
@@ -36,25 +38,35 @@ class PvtDataStore:
                 for key, value in kvs.items():
                     sk = (ns, coll, key)
                     if value is None:
-                        self._state.pop(sk, None)
+                        self._drop(sk)
                         continue
+                    if sk not in self._state:
+                        self._held[(ns, coll)] = \
+                            self._held.get((ns, coll), 0) + 1
                     self._state[sk] = (value, block_num)
                     if btl:
                         self._expiry.setdefault(block_num + btl + 1, []) \
-                            .append(sk)
+                            .append((sk, block_num))
+
+    def _drop(self, sk: Tuple[str, str, str]) -> None:
+        if self._state.pop(sk, None) is not None:
+            self._held[sk[:2]] -= 1
 
     def process_purges(self, block_num: int) -> int:
-        """Purge collections whose BTL elapsed as of block_num
-        (pvtstatepurgemgmt.DeleteExpiredAndUpdateBookkeeping)."""
+        """Purge the keys whose BTL elapsed as of block_num
+        (pvtstatepurgemgmt.DeleteExpiredAndUpdateBookkeeping): a key
+        written at N leaves with block N + BTL + 1 unless written again
+        since — the rule the ledger's expiry step (ledger/pvtexpiry.py)
+        applies to the hashed key, block for block."""
         purged = 0
         with self._lock:
             for expiry in [b for b in self._expiry if b <= block_num]:
-                for sk in self._expiry.pop(expiry):
+                for sk, written in self._expiry.pop(expiry):
                     ent = self._state.get(sk)
-                    # only purge if not rewritten since (a newer write has
+                    # only where not rewritten since (a newer write has
                     # its own expiry entry)
-                    if ent is not None and ent[1] + 1 <= expiry:
-                        del self._state[sk]
+                    if ent is not None and ent[1] == written:
+                        self._drop(sk)
                         purged += 1
             for expiry in [b for b in self._tx_expiry if b <= block_num]:
                 for tk in self._tx_expiry.pop(expiry):
@@ -87,5 +99,9 @@ class PvtDataStore:
 
     def has_collection(self, namespace: str, collection: str) -> bool:
         with self._lock:
-            return any(ns == namespace and c == collection
-                       for (ns, c, _) in self._state)
+            return self._held.get((namespace, collection), 0) > 0
+
+    def keys(self) -> List[Tuple[str, str, str]]:
+        """Every (namespace, collection, key) held, in no order."""
+        with self._lock:
+            return list(self._state)
